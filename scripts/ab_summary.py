@@ -1,7 +1,6 @@
 """Summarize logs/ab_results.jsonl into a markdown table.
 
-Run after the chip watcher (scripts/run_ab.py) has drained some of its
-queue: prints one row per config (latest ok attempt wins), the headline
+Run over a log of ``bench.py --sub`` rows: prints one row per config (latest ok attempt wins), the headline
 value it measured, and the delta vs its family baseline — the exact
 evidence the gate-flip policy (bench._ab_best) consumes, rendered for
 docs/performance.md.
@@ -516,7 +515,7 @@ def main() -> None:
               f"| {r.get('serve_structured_masked_frac', '—')} |")
 
     # serve_wq rows: quantized-weight serving, one sub-table row per
-    # measured dtype (the serve_wq / serve_wq_int4 QUEUE rows) — the
+    # measured dtype (the serve_wq / serve_wq_int4 rows) — the
     # measured-vs-modeled headline is the whole point: modeled is the
     # weight-stream byte ratio (the gate, >= 1.9), measured is what
     # this chip's decode actually did with it (compute-bound CPU

@@ -20,8 +20,6 @@ from torchbooster_tpu.comms.quantized import (dequantize, quantize,
 from torchbooster_tpu.config import CommsConfig
 from torchbooster_tpu.utils import TrainState, make_step
 
-from torchbooster_tpu._jax_compat import shard_map
-
 BUCKET = 64
 
 
@@ -105,7 +103,7 @@ def _sync_fn(mesh, mode, n):
             ef1.reshape(-1), ef2)
         return red, nef1[None], nef2
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("dp"), P("dp"), P("dp"), P()),
         out_specs=(P(), P("dp"), P("dp")), check_vma=False))

@@ -11,15 +11,6 @@ from jax.sharding import Mesh
 from torchbooster_tpu.models import layers as L
 from torchbooster_tpu.parallel.pipeline import pipeline_apply
 
-# old-jax experimental shard_map rejects the ``with_aux`` scalar
-# out_spec when differentiated (_SpecError listing a ShapedArray
-# float32[] among NoFail); jax >= 0.8 (which exports jax.shard_map)
-# accepts it — skip exactly the aux-grad surface on old jax
-needs_aux_grad_specs = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="old-jax shard_map rejects scalar aux out_specs under grad")
-
-
 def make_mlp_stack(rng, n_layers, d):
     ks = jax.random.split(rng, n_layers)
     return jax.vmap(lambda k: L.dense_init(k, d, d))(ks)
@@ -458,7 +449,6 @@ def test_gpt_pipeline_full_composition_pp_tp_sp():
 
 @pytest.mark.parametrize("axes", [("dp", "pp", "ep"),
                                   ("pp", "ep", "tp")])
-@needs_aux_grad_specs
 def test_gpt_pipeline_moe_ep_matches_single_device(axes):
     """Expert parallelism INSIDE the pipeline: each ep rank holds E/ep
     experts and routes its own (replicated) tokens to them — no
@@ -500,7 +490,6 @@ def test_gpt_pipeline_moe_ep_matches_single_device(axes):
                                    rtol=2e-3, atol=2e-3)
 
 
-@needs_aux_grad_specs
 def test_gpt_pipeline_moe_sp_matches_single_device():
     """MoE x sp INSIDE the pipeline: each sequence shard routes its
     local tokens (per-shard capacity, experts replicated in-stage) and
@@ -541,7 +530,6 @@ def test_gpt_pipeline_moe_sp_matches_single_device():
                                    rtol=2e-3, atol=2e-3)
 
 
-@needs_aux_grad_specs
 def test_gpt_pipeline_moe_tp_matches_single_device():
     """MoE x tp INSIDE the pipeline (VERDICT r4 #8): expert hidden
     Megatron-split across tp within each pp stage, routing replicated
@@ -631,7 +619,6 @@ def test_gpt_pipeline_moe_aux_threads_through():
         "aux grad vanished through the pipeline"
 
 
-@needs_aux_grad_specs
 def test_pipeline_aux_grads_match_sequential():
     """The with_aux accumulation (where-mask per tick, fori_loop carry,
     psum over pp, pmean over dp) must TRANSPOSE exactly. MoE's routing

@@ -40,8 +40,6 @@ import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
 from jax.sharding import PartitionSpec as P
 
-from torchbooster_tpu._jax_compat import shard_map
-
 __all__ = ["data_spec", "dequantize", "quantize", "reduce_flat",
            "value_and_grad_sync"]
 
@@ -224,7 +222,7 @@ def value_and_grad_sync(
 
     spec = data_spec(axes)
     grads_spec = spec if scatter else P()
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=comms.mesh,
         in_specs=(P(), spec, spec, P()),
         out_specs=((P(), P()), grads_spec, spec),

@@ -68,7 +68,6 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from torchbooster_tpu._jax_compat import shard_map
 from torchbooster_tpu.comms import GradComms, MODES, make_grad_comms
 
 __all__ = ["BucketPlan", "CommsSchedule", "STAGES", "WIRES",
@@ -780,7 +779,7 @@ def sharded_step(
         aux = jax.tree.map(lambda a: jax.lax.pmean(a, axes), aux)
         return (loss, aux), params_out, new_opt, new_comms
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_spec, specs, comms_spec, dspec, P()),
         out_specs=((P(), P()), param_spec, specs, comms_spec),

@@ -39,8 +39,6 @@ import optax
 from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from torchbooster_tpu._jax_compat import shard_map
-
 __all__ = ["init_opt_state", "opt_state_specs", "padded_size",
            "sharded_update"]
 
@@ -161,7 +159,7 @@ def sharded_update(
 
     from torchbooster_tpu.comms.quantized import data_spec
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), data_spec(axes) if scattered else P(), specs),
         out_specs=(P(), specs),

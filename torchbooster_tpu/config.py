@@ -1197,7 +1197,9 @@ class ServingConfig(BaseConfig):
 
         from torchbooster_tpu.serving import ContinuousBatcher, PagedEngine
         from torchbooster_tpu.serving.tp import check_tp
+        from torchbooster_tpu.utils import enable_compile_cache
 
+        enable_compile_cache()
         # YAML-time rejection: a tp that does not divide the model's
         # KV-head count, exceeds/mismatches the mesh's tp axis, or
         # arrives without a committed mesh must fail HERE, with the

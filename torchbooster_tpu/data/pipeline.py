@@ -27,7 +27,9 @@ from __future__ import annotations
 
 import collections
 import multiprocessing
+import os
 import queue
+import sys
 import threading
 import time
 from concurrent.futures import ProcessPoolExecutor, ThreadPoolExecutor
@@ -108,6 +110,12 @@ _WORKER: dict = {}
 
 
 def _worker_init(dataset: Any, collate_fn: Callable) -> None:
+    # a chip belongs to one process: whatever JAX work the dataset's
+    # code does in a loader child runs on the CPU, never on the device
+    # of the process this child feeds
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
     _WORKER["dataset"] = dataset
     _WORKER["collate"] = collate_fn
 

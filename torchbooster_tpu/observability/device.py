@@ -55,20 +55,12 @@ def record_memory_gauges(registry: Registry | None = None) -> dict:
 
 
 def cost_analysis(compiled: Any) -> dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions
-    (dict on new, list-of-dicts per module on this image's 0.4.x) into
-    one flat dict; {} when the backend offers nothing."""
+    """``Compiled.cost_analysis()`` as a plain dict; {} when the
+    backend offers nothing."""
     try:
         costs = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 — backend-optional surface
         return {}
-    if isinstance(costs, (list, tuple)):
-        merged: dict[str, float] = {}
-        for entry in costs:
-            for key, value in (entry or {}).items():
-                if isinstance(value, (int, float)):
-                    merged[key] = merged.get(key, 0.0) + float(value)
-        return merged
     return dict(costs or {})
 
 

@@ -85,8 +85,8 @@ class _Series:
         else:
             # ONE batched transfer for the whole backlog: per-value
             # device_get would serialize up to _MAX_PENDING D2H round
-            # trips on a tunneled runtime (device_get maps over the
-            # list; plain numbers pass through)
+            # trips (device_get maps over the list; plain numbers
+            # pass through)
             import jax
 
             values = [float(v) for v in jax.device_get(pending)]

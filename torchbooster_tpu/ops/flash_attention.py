@@ -408,7 +408,7 @@ def _block_default(name: str) -> int:
     the resolved ints. NOTE: a caller that wraps :func:`flash_attention`
     in its own outer jit (the GPT train step) bakes the env read into
     that outer trace — mid-process sweeps must re-jit or use fresh
-    processes (scripts/run_ab.py runs one process per config)."""
+    processes."""
     return int(os.environ.get(f"TB_FLASH_BLOCK_{name}", 1024))
 
 

@@ -40,8 +40,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from torchbooster_tpu._jax_compat import CompilerParams as _CompilerParams
-
 # per-sample VMEM working set must fit comfortably; beyond this the
 # XLA path takes over (stem-sized spatial maps)
 _VMEM_BUDGET_BYTES = 12 * 2**20
@@ -163,7 +161,7 @@ def _fwd(x3, w, scale, bias, groups: int, eps: float, relu: bool,
         # vmem_limit raised over the 16M scoped default: the stack's
         # fp32 temporaries run ~1.4× past the _cell_bytes model (the
         # fused_s2d chip OOM), and headroom beats a mis-priced cell
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
@@ -328,7 +326,7 @@ def _conv3x3_gn(x4, w, scale, bias, groups, eps, relu, interpret):
         ],
         out_specs=pl.BlockSpec((g, m, cout), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, m, cout), x4.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,

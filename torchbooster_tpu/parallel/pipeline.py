@@ -55,8 +55,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from torchbooster_tpu._jax_compat import shard_map
-
 
 def _default_microbatches(batch: int, n_stages: int,
                           dp_size: int) -> int:
@@ -248,9 +246,9 @@ def pipeline_apply(
         return out
 
     out_specs = (mb_spec, P()) if with_aux else mb_spec
-    mapped = shard_map(kernel, mesh=mesh,
-                       in_specs=(param_specs, mb_spec),
-                       out_specs=out_specs, check_vma=False)
+    mapped = jax.shard_map(kernel, mesh=mesh,
+                           in_specs=(param_specs, mb_spec),
+                           out_specs=out_specs, check_vma=False)
     if with_aux:
         out_mb, aux = mapped(stacked_params, x_mb)
         return out_mb.reshape(batch, *x.shape[1:]), aux

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from torchbooster_tpu._jax_compat import shard_map
-
 NEG_INF = -1e30
 
 
@@ -363,8 +361,8 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
                             sp_size=sp_size, causal=causal,
                             sm_scale=sm_scale, rep=n_heads // kv_heads,
                             axis=axis, block_k=block_k)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 

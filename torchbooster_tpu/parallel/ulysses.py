@@ -43,8 +43,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from torchbooster_tpu._jax_compat import shard_map
-
 
 def _ulysses_local(q: jax.Array, k: jax.Array, v: jax.Array, *, axis: str,
                    causal: bool, sm_scale: float, impl: str,
@@ -113,8 +111,8 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
 
     body = functools.partial(_ulysses_local, axis=axis, causal=causal,
                              sm_scale=sm_scale, impl=impl, rep=rep)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 

@@ -567,16 +567,15 @@ class PagedEngine:
         self._cow_jit = None
         if self.parallel:
             if self.tp > 1:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import NamedSharding
                 from torchbooster_tpu.serving.tp import POOL_SPEC, REP
                 pool_ns = NamedSharding(mesh, POOL_SPEC)
                 self._cow_jit = jax.jit(
-                    shard_map(self._cow_fn, mesh=mesh,
-                              in_specs=(POOL_SPEC, POOL_SPEC, REP,
-                                        REP),
-                              out_specs=(POOL_SPEC, POOL_SPEC),
-                              check_rep=False),
+                    jax.shard_map(self._cow_fn, mesh=mesh,
+                                  in_specs=(POOL_SPEC, POOL_SPEC, REP,
+                                            REP),
+                                  out_specs=(POOL_SPEC, POOL_SPEC),
+                                  check_vma=False),
                     donate_argnums=(0, 1),
                     out_shardings=(pool_ns, pool_ns))
             else:
@@ -618,17 +617,16 @@ class PagedEngine:
                                            donate_argnums=(1, 2))
             if self.spec_tree:
                 if self.tp > 1:
-                    from jax.experimental.shard_map import shard_map
                     from jax.sharding import NamedSharding
                     from torchbooster_tpu.serving.tp import (
                         POOL_SPEC, REP)
                     pool_ns = NamedSharding(mesh, POOL_SPEC)
                     self._compact_jit = jax.jit(
-                        shard_map(self._compact_fn, mesh=mesh,
-                                  in_specs=(POOL_SPEC, POOL_SPEC,
-                                            REP, REP, REP, REP),
-                                  out_specs=(POOL_SPEC, POOL_SPEC),
-                                  check_rep=False),
+                        jax.shard_map(self._compact_fn, mesh=mesh,
+                                      in_specs=(POOL_SPEC, POOL_SPEC,
+                                                REP, REP, REP, REP),
+                                      out_specs=(POOL_SPEC, POOL_SPEC),
+                                      check_vma=False),
                         donate_argnums=(0, 1),
                         out_shardings=(pool_ns, pool_ns))
                 else:

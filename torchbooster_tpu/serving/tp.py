@@ -47,7 +47,6 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from torchbooster_tpu.parallel.sharding import path_str
@@ -168,7 +167,7 @@ def shard_engine_fn(fn, mesh: Mesh, pspecs: Any, n_host_args: int,
     (shared by all three): ``(params, pool_k, pool_v, *host_args)``
     in, ``(*replicated_outputs, pool_k, pool_v)`` out — pools sharded
     on KV heads, every host-side table/id/rng operand replicated, and
-    the post-psum outputs replicated by construction (``check_rep=
+    the post-psum outputs replicated by construction (``check_vma=
     False``: the pallas table walk inside defeats the static
     replication checker; the token-parity tests are the behavioral
     check).
@@ -185,8 +184,8 @@ def shard_engine_fn(fn, mesh: Mesh, pspecs: Any, n_host_args: int,
     updated in place every call."""
     in_specs = (pspecs, POOL_SPEC, POOL_SPEC) + (REP,) * n_host_args
     out_specs = (REP,) * n_rep_out + (POOL_SPEC, POOL_SPEC)
-    sharded = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     pool_ns = NamedSharding(mesh, POOL_SPEC)
     rep_ns = NamedSharding(mesh, REP)
     return jax.jit(sharded, donate_argnums=(1, 2),

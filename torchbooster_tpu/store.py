@@ -19,8 +19,10 @@ API parity map (ref lmdb.py → here):
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import mmap
+import os
 import threading
 import struct
 import subprocess
@@ -33,6 +35,11 @@ _ENTRY = struct.Struct("<QQ")
 
 _NATIVE_SOURCE = Path(__file__).resolve().parent.parent / "native" / "booster_store.cpp"
 _NATIVE_LIB = _NATIVE_SOURCE.parent / "libbooster_store.so"
+# sha256 of the source the library was built from, written beside it:
+# the rebuild decision is made from content, because a copied tree
+# keeps no useful mtime order and must never load a library built
+# from another source
+_NATIVE_STAMP = _NATIVE_LIB.with_name(_NATIVE_LIB.name + ".sha256")
 
 _lib = None
 _lib_tried = False
@@ -46,40 +53,45 @@ def _load_native() -> ctypes.CDLL | None:
         return _lib
     _lib_tried = True
     try:
-        stale = (_NATIVE_LIB.exists() and _NATIVE_SOURCE.exists()
-                 and _NATIVE_SOURCE.stat().st_mtime
-                 > _NATIVE_LIB.stat().st_mtime)
-        if (not _NATIVE_LIB.exists() or stale) and _NATIVE_SOURCE.exists():
+        want = hashlib.sha256(_NATIVE_SOURCE.read_bytes()).hexdigest()
+        built = (_NATIVE_STAMP.read_text().strip()
+                 if _NATIVE_LIB.exists() and _NATIVE_STAMP.exists()
+                 else None)
+        if built != want:
+            # build aside and rename, so a concurrent loader (xdist
+            # workers, loader processes) never maps a half-written file
+            tmp = _NATIVE_LIB.with_name(f"{_NATIVE_LIB.name}.{os.getpid()}")
             subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", "-o", str(_NATIVE_LIB),
+                ["g++", "-O3", "-shared", "-fPIC", "-o", str(tmp),
                  str(_NATIVE_SOURCE)],
                 check=True, capture_output=True, timeout=120)
-        if _NATIVE_LIB.exists():
-            lib = ctypes.CDLL(str(_NATIVE_LIB))
-            lib.bs_open.restype = ctypes.c_void_p
-            lib.bs_open.argtypes = [ctypes.c_char_p]
-            lib.bs_count.restype = ctypes.c_int64
-            lib.bs_count.argtypes = [ctypes.c_void_p]
-            lib.bs_get.restype = ctypes.c_int
-            lib.bs_get.argtypes = [
-                ctypes.c_void_p, ctypes.c_uint64,
-                ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8)),
-                ctypes.POINTER(ctypes.c_uint64)]
-            lib.bs_get_batch.restype = ctypes.c_int64
-            lib.bs_get_batch.argtypes = [
-                ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64),
-                ctypes.c_uint64, ctypes.c_char_p, ctypes.c_uint64,
-                ctypes.POINTER(ctypes.c_uint64)]
-            lib.bs_close.argtypes = [ctypes.c_void_p]
-            lib.bs_writer_open.restype = ctypes.c_void_p
-            lib.bs_writer_open.argtypes = [ctypes.c_char_p]
-            lib.bs_writer_append.restype = ctypes.c_int
-            lib.bs_writer_append.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint64]
-            lib.bs_writer_close.restype = ctypes.c_int
-            lib.bs_writer_close.argtypes = [ctypes.c_void_p]
-            lib.bs_error.restype = ctypes.c_char_p
-            _lib = lib
+            os.replace(tmp, _NATIVE_LIB)
+            _NATIVE_STAMP.write_text(want + "\n")
+        lib = ctypes.CDLL(str(_NATIVE_LIB))
+        lib.bs_open.restype = ctypes.c_void_p
+        lib.bs_open.argtypes = [ctypes.c_char_p]
+        lib.bs_count.restype = ctypes.c_int64
+        lib.bs_count.argtypes = [ctypes.c_void_p]
+        lib.bs_get.restype = ctypes.c_int
+        lib.bs_get.argtypes = [
+            ctypes.c_void_p, ctypes.c_uint64,
+            ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8)),
+            ctypes.POINTER(ctypes.c_uint64)]
+        lib.bs_get_batch.restype = ctypes.c_int64
+        lib.bs_get_batch.argtypes = [
+            ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64),
+            ctypes.c_uint64, ctypes.c_char_p, ctypes.c_uint64,
+            ctypes.POINTER(ctypes.c_uint64)]
+        lib.bs_close.argtypes = [ctypes.c_void_p]
+        lib.bs_writer_open.restype = ctypes.c_void_p
+        lib.bs_writer_open.argtypes = [ctypes.c_char_p]
+        lib.bs_writer_append.restype = ctypes.c_int
+        lib.bs_writer_append.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint64]
+        lib.bs_writer_close.restype = ctypes.c_int
+        lib.bs_writer_close.argtypes = [ctypes.c_void_p]
+        lib.bs_error.restype = ctypes.c_char_p
+        _lib = lib
     except (subprocess.SubprocessError, OSError) as error:
         logging.warning("native BoosterStore unavailable (%s); using "
                         "python mmap fallback", error)

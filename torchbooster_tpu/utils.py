@@ -26,7 +26,9 @@ Symbol map (ref → here):
 from __future__ import annotations
 
 import logging
+import os
 import random
+from pathlib import Path
 from typing import Any, Callable, Iterable, Iterator, Sequence
 
 import jax
@@ -41,16 +43,45 @@ from jax.sharding import Mesh
 # Environment knobs (ref boost, utils.py:29-45)
 # =========================================================================
 
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache and return its
+    directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+    and no directory is set in code; otherwise the cache lives at the
+    fixed ``<checkout>/.jax_cache`` (never a temp dir: a directory
+    that moves between runs never hits). The compile-time threshold
+    is dropped so the step programs of a short run are kept too.
+    Idempotent; called by :func:`boost` and by the serving build, the
+    two calls every entry point makes before its first big compile.
+
+    A process pinned to the CPU (``JAX_PLATFORMS=cpu``: tests,
+    rehearsals) keeps no cache and gets None: its compiles are short,
+    and XLA:CPU's loader logs an error for every entry it reads back.
+    The pin is read from the config, never from the backend — this
+    runs before ``jax.distributed.initialize`` may."""
+    if (jax.config.jax_platforms or "") == "cpu":
+        return None
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parent.parent / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
 def boost(enable: bool = True) -> None:
     """Performance/debug switch (ref boost utils.py:29-45).
 
-    ``boost(True)`` (default) leaves XLA at full speed. ``boost(False)``
-    is debug mode: enables NaN checking and disables jit so errors point
-    at python lines — the analogue of the reference's anomaly detection
-    (ref utils.py:40-45; its cudnn.benchmark knob has no TPU meaning,
-    XLA autotunes by default)."""
+    ``boost(True)`` (default) leaves XLA at full speed and turns on the
+    persistent compilation cache (:func:`enable_compile_cache`).
+    ``boost(False)`` is debug mode: enables NaN checking and disables
+    jit so errors point at python lines — the analogue of the
+    reference's anomaly detection (ref utils.py:40-45; its
+    cudnn.benchmark knob has no TPU meaning, XLA autotunes by
+    default)."""
     if not enable:
         logging.warning("boost disabled: debug_nans on, jit disabled — slow")
+    else:
+        enable_compile_cache()
     jax.config.update("jax_debug_nans", not enable)
     jax.config.update("jax_disable_jit", not enable)
 
@@ -534,7 +565,8 @@ def make_eval_step(loss_fn: Callable, has_aux: bool = True,
 
 
 __all__ = [
-    "TrainState", "annotate", "boost", "detach", "freeze",
+    "TrainState", "annotate", "boost", "detach", "enable_compile_cache",
+    "freeze",
     "instrument_step", "iter_loader", "make_step", "make_eval_step",
     "seed", "stack_dictionaries", "to_array", "trace",
 ]
