@@ -1,0 +1,6 @@
+"""90th percentile of time from DUE to first token, client clock, requests whose first token fell in the window."""
+from _lib import client_percentile_ms
+
+
+def read(name: str, layers: dict):
+    return client_percentile_ms(layers, "ttfts", 90)

@@ -1,0 +1,137 @@
+"""Find a serve cell's knee: one ladder of rates in one process, warm.
+
+    python benchmark/sweep.py --workload gpt2-xl.serve-chat-r80 \
+        --rates 0.8,1.1,1.4,1.7,2.0,2.4 --seconds 30 --seed 5
+
+The server is built and warmed once; each rate then gets its own pass of
+the open loop (the cell's mix, pre-roll and all), after which whatever
+still runs is dropped and the engine drains. One line per rate: tokens
+offered and delivered per second in the window, the front door's queue
+at the window's edges, occupancy, refusals, and the pooled gap
+percentiles with the histogram — enough to read off the knee (the
+highest rate at which delivered keeps up with offered and the queue
+does not grow) and to see where a percentile sits between the gap's
+modes. The rate written into a traffic file comes from here, once; a
+run never searches.
+"""
+from __future__ import annotations
+
+import argparse
+import asyncio
+import dataclasses
+import gc
+import json
+import sys
+from pathlib import Path
+
+HERE = Path(__file__).resolve().parent
+sys.path.insert(0, str(HERE))
+
+sys.path.insert(0, str(HERE / "layer_metrics"))
+
+import run as harness  # noqa: E402
+from _lib import registry_delta  # noqa: E402
+
+
+def main() -> None:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--workload", required=True)
+    parser.add_argument("--rates", required=True)
+    parser.add_argument("--seconds", type=float, default=30.0)
+    parser.add_argument("--seed", type=int, default=5)
+    parser.add_argument("--root", default=str(harness.ROOT))
+    parser.add_argument("--allow-cpu", action="store_true",
+                        help="rehearsal only: nothing printed is a rate")
+    args = parser.parse_args()
+
+    import jax
+
+    from jobs import serve
+    from loadgen import plan as loadplan
+    import program
+
+    root = Path(args.root)
+    _, cell, cfg, traffic = harness.resolve(args.workload, root)
+    devices = jax.devices() if args.allow_cpu \
+        else harness.find_devices(cell["chips"])
+    out_dir = root / ".bench_out" / "sweep"
+    out_dir.mkdir(parents=True, exist_ok=True)
+    ctx = harness.Context(
+        cell=args.workload, cfg=cfg, traffic=traffic, seed=args.seed,
+        seconds=args.seconds, trace=False, chips=cell["chips"],
+        out_dir=out_dir, t_start=harness.T_START,
+        compiles=harness.CompileCount(), devices=devices[:cell["chips"]])
+    program.set_telemetry(True)     # occupancy comes from the registry
+    batcher, frontend, conf = program.build_serve(
+        cfg, traffic["serving"], args.seed)
+    preroll = float(traffic["preroll_s"])
+    rows = []
+
+    async def ladder() -> None:
+        await frontend.start()
+        try:
+            await serve.warm_up(frontend.port, cfg,
+                                conf.prefill_chunk_pages * conf.page_size,
+                                args.seed)
+            gc.collect()
+            for rate in (float(r) for r in args.rates.split(",")):
+                mix = {**traffic, "rate": rate}
+                requests = loadplan.make_requests(
+                    mix, args.seed, preroll + args.seconds,
+                    cfg["vocab_size"])
+                queue = {}
+
+                async def watch_queue():
+                    await asyncio.sleep(float(traffic.get("lead_s", 1.5))
+                                        + preroll)
+                    queue["open"] = batcher.queue_depth
+                    await asyncio.sleep(args.seconds - 0.2)
+                    queue["close"] = batcher.queue_depth
+
+                watcher = asyncio.create_task(watch_queue())
+                state = await serve.offer(
+                    dataclasses.replace(ctx, traffic=mix), frontend.port,
+                    requests, preroll, args.seconds, False)
+                await watcher
+                while batcher.has_work:      # dropped streams drain
+                    await asyncio.sleep(0.2)
+                lo, hi = preroll, preroll + args.seconds
+                win = serve.client_numbers(state["records"], lo, hi)
+                by_id = {r["id"]: r for r in requests}
+                offered = sum(by_id[r["id"]]["max_tokens"]
+                              for r in state["records"]
+                              if lo <= r["due"] < hi) / args.seconds
+                steps = registry_delta(
+                    state, "span_seconds{name=decode_step}_count")
+                toks = registry_delta(state, "serving_decode_tokens_total")
+                pct = {str(q): round(loadplan.pooled_percentile(
+                    win["gaps"], q) * 1e3, 2)
+                    for q in (50, 75, 90, 95, 97.5, 99)} \
+                    if win["gaps"] else {}
+                row = {
+                    "rate": rate, "requests": len(requests),
+                    "offered_tok_s": round(offered, 1),
+                    "delivered_tok_s": round(
+                        win["tokens_in"] / args.seconds, 1),
+                    "queue_open": queue.get("open"),
+                    "queue_close": queue.get("close"),
+                    "occupancy": round(toks / steps, 2) if steps else None,
+                    "decode_steps_s": round(steps / args.seconds, 2),
+                    "refused": sum(r["status"] not in (None, 200)
+                                   for r in state["records"]),
+                    "ttft_p50_ms": round(loadplan.pooled_percentile(
+                        win["ttfts"], 50) * 1e3, 1) if win["ttfts"] else None,
+                    "n_gaps": len(win["gaps"]), "gap_ms": pct,
+                    "gap_histogram_10ms": serve.gap_histogram(win["gaps"]),
+                }
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+        finally:
+            await frontend.stop(drain=False)
+
+    asyncio.run(ladder())
+    (out_dir / "sweep.json").write_text(json.dumps(rows, indent=1))
+
+
+if __name__ == "__main__":
+    main()
