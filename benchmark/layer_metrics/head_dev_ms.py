@@ -1,0 +1,10 @@
+"""Device time of one train step under the ``head`` and ``loss``
+scopes (final norm, the vocabulary-wide projection, cross entropy;
+forward and backward): median over the traced steps (first chip)."""
+import _lib  # noqa: F401  (puts benchmark/ on the path)
+import xplane_scopes
+
+
+def read(name: str, layers: dict):
+    return xplane_scopes.median_scope_ms(xplane_scopes.load(),
+                                         "step_fn", ("head", "loss"))

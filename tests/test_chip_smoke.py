@@ -42,11 +42,17 @@ _CACHE_PROBE = (
 @pytest.mark.parametrize("case", ["in_checkout", "env_wins", "cpu_pin"])
 def test_compile_cache_placement(tmp_path, case):
     """Unset: ``<checkout>/.jax_cache`` whatever the working
-    directory. ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it and the
-    code sets nothing else. Pinned to the CPU: no cache at all."""
+    directory. ``JAX_COMPILATION_CACHE_DIR`` set: that directory and
+    nothing else. Under either, the subdirectory named after
+    ``PROGRAM_METADATA_VERSION`` (the cache's key leaves the named
+    scopes out, so an older program would be served under new names).
+    Pinned to the CPU: no cache at all."""
+    from torchbooster_tpu.utils import PROGRAM_METADATA_VERSION
+
+    sub = f"m{PROGRAM_METADATA_VERSION}"
     drop = ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR")
     if case == "in_checkout":
-        want = str(REPO / ".jax_cache")
+        want = str(REPO / ".jax_cache" / sub)
         for cwd in (REPO, tmp_path):
             out = _run(["-c", _CACHE_PROBE], cwd=cwd, drop=drop)
             assert out.returncode == 0, out.stderr[-2000:]
@@ -56,7 +62,8 @@ def test_compile_cache_placement(tmp_path, case):
         out = _run(["-c", _CACHE_PROBE], cwd=tmp_path, drop=drop,
                    JAX_COMPILATION_CACHE_DIR=outside)
         assert out.returncode == 0, out.stderr[-2000:]
-        assert json.loads(out.stdout) == [outside, outside]
+        want = str(Path(outside) / sub)
+        assert json.loads(out.stdout) == [want, want]
         assert not (REPO / "placed_from_outside").exists()
     else:
         out = _run(["-c", _CACHE_PROBE], cwd=tmp_path,

@@ -335,13 +335,19 @@ _STABLE_KEYS = {
 }
 
 
-def test_tracing_off_metrics_key_and_value_identical():
+@pytest.mark.parametrize("telemetry", ["tracer", "registry", "both"])
+def test_tracing_off_metrics_key_and_value_identical(telemetry):
     """Two identical traces under a deterministic clock — one with
     tracing off (the default), one with tracing ON — must return the
     SAME metrics dict, key for key and value for value: the tracer
     stamps its own clock and adds no batcher-clock reads, so enabling
     it cannot perturb a single metric. The key set is exactly the
-    pre-tracing stable contract."""
+    pre-tracing stable contract. The same holds with the REGISTRY on:
+    the iteration's span tree (``sched_step`` and its children) times
+    itself on ``perf_counter``, the queue-wait / prefill histograms
+    observe stamps the batcher had taken anyway, and the ``step``
+    field counts iterations — none reads the batcher's clock."""
+    import torchbooster_tpu.observability as obs
     from torchbooster_tpu.serving import ContinuousBatcher, Request
 
     params, cfg = _decisive_model()
@@ -353,15 +359,47 @@ def test_tracing_off_metrics_key_and_value_identical():
         b = ContinuousBatcher(engine, clock=_Tick(), tracer=tracer)
         reqs = [Request(prompt=ids, max_new_tokens=8)
                 for _ in range(3)]
-        return b.run(reqs)
+        return b.run(reqs), reqs
 
-    off = run(None)
-    on_tracer = RequestTracer(enabled=True)
-    on = run(on_tracer)
+    off, off_reqs = run(None)
+    on_tracer = RequestTracer(enabled=telemetry != "registry")
+    registry = obs.get_registry()
+    was = registry.enabled
+    registry.reset()
+    registry.enabled = telemetry != "tracer"
+    try:
+        on, on_reqs = run(on_tracer)
+        snap = registry.snapshot()
+    finally:
+        registry.enabled = was
+        registry.reset()
     assert set(off) == _STABLE_KEYS
     assert off == on
-    assert len(on_tracer) > 0              # tracing actually ran
     assert off["n_preemptions"] > 0        # the rich path, not idle
+    # the requests' own stamps and tokens, not only the summary
+    for a, b in zip(off_reqs, on_reqs):
+        assert (a.tokens, a.admitted_at, a.first_token_at,
+                a.finished_at) == (b.tokens, b.admitted_at,
+                                   b.first_token_at, b.finished_at)
+    if telemetry != "registry":
+        assert len(on_tracer) > 0          # tracing actually ran
+        kinds = {e["kind"] for e in on_tracer.events()}
+        assert {"seated", "tokens", "decode_step"} <= kinds
+        for e in on_tracer.events():
+            if e["kind"] in ("tokens", "decode_step"):
+                assert e["step"] >= 1
+            if e["kind"] == "seated":
+                assert e["queue_wait_s"] >= 0.0
+    if telemetry != "tracer":
+        # the new spans and histograms actually ran
+        for name in ("sched_step", "sched_admit", "sched_grow",
+                     "prefill_args", "decode_args", "decode_advance",
+                     "sched_deliver"):
+            assert snap[f"span_seconds{{name={name}}}_count"] > 0
+        assert snap["serving_queue_wait_seconds_count"] == 3
+        assert snap["serving_prefill_seconds_count"] == 3
+    else:
+        assert not snap                    # off: nothing was written
 
 
 # =====================================================================

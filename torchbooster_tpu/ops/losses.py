@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("loss")
 def cross_entropy(logits: jax.Array, labels: jax.Array,
                   label_smoothing: float = 0.0) -> jax.Array:
     """Softmax cross entropy with integer labels (+ label smoothing,
@@ -60,14 +61,17 @@ def lm_head_cross_entropy(hidden: jax.Array, table: jax.Array,
 
     def body(total, inp):
         xc, yc, mc = inp
-        logits = (xc @ table.astype(xc.dtype).T).astype(jnp.float32)
-        log_probs = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(
-            log_probs, yc[:, None], axis=-1)[:, 0]
-        if label_smoothing:
-            smooth = -log_probs.mean(axis=-1)
-            nll = (1.0 - label_smoothing) * nll + label_smoothing * smooth
-        return total + jnp.sum(nll * mc), None
+        with jax.named_scope("head"):
+            logits = (xc @ table.astype(xc.dtype).T).astype(jnp.float32)
+        with jax.named_scope("loss"):
+            log_probs = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(
+                log_probs, yc[:, None], axis=-1)[:, 0]
+            if label_smoothing:
+                smooth = -log_probs.mean(axis=-1)
+                nll = (1.0 - label_smoothing) * nll \
+                    + label_smoothing * smooth
+            return total + jnp.sum(nll * mc), None
 
     total, _ = jax.lax.scan(jax.checkpoint(body), jnp.zeros((), jnp.float32),
                             (xs, ys, vs))

@@ -68,6 +68,7 @@ import numpy as np
 from torchbooster_tpu.observability import (
     RecompileSentinel,
     get_registry,
+    span,
 )
 from torchbooster_tpu.observability.flight import (
     FlightRecorder,
@@ -266,6 +267,10 @@ class _Session:
         self.filling: dict[int, Request] = {}    # seated, prefill streaming
         self.admit_order: list[int] = []         # oldest-first seated slots
         self.t0 = batcher.clock()
+        # scheduler iterations so far: the ``step`` number that the
+        # engine-track step event and the per-request ``tokens``
+        # events of one iteration share (observability/tracing.py)
+        self.n_steps = 0
         self.decoded = 0
         self.decode_time = 0.0
         self.n_admissions = 0
@@ -724,6 +729,14 @@ class ContinuousBatcher:
                                  "request arrival -> completion"),
             "ttft": reg.histogram("serving_ttft_seconds",
                                   "request arrival -> first token"),
+            # the two halves of TTFT, observed beside it at retirement
+            # so the three series cover the same requests
+            "queue_wait": reg.histogram(
+                "serving_queue_wait_seconds",
+                "request arrival -> first seat"),
+            "prefill": reg.histogram(
+                "serving_prefill_seconds",
+                "first seat -> first token"),
             "slots": reg.gauge("serving_slots_live",
                                "occupied decode slots"),
             "pages": reg.gauge("serving_pages_free",
@@ -941,6 +954,9 @@ class ContinuousBatcher:
         if req.first_token_at is not None:
             s.sample(s.ttft, req.first_token_at - req.arrival)
             inst["ttft"].observe(req.first_token_at - req.arrival)
+            inst["queue_wait"].observe(req.admitted_at - req.arrival)
+            inst["prefill"].observe(
+                req.first_token_at - req.admitted_at)
         self.engine.retire(slot)
         self._release_adapter(req)
         if self.tracer.enabled:
@@ -1224,43 +1240,64 @@ class ContinuousBatcher:
         st = {"wall": 0.0, "prefill": False, "decode": False,
               "spec": False, "prop": 0, "acc": 0}
         events: list = []
-        try:
-            self._step_body(s, st, events)
-        finally:
-            # record in a finally so the step that KILLS the pump
-            # still lands its (partial) row — the crash dump's last
-            # record must be the fatal step, not the one before it
-            recompiled = (eng.decode_compiles + eng.verify_compiles
-                          + eng.prefill_compiles) > c0
-            self.flight.record(
-                kind=step_kind_code(st["prefill"], st["decode"],
-                                    st["spec"]),
-                slots_live=len(s.live),
-                slots_filling=len(s.filling),
-                pages_live=int(eng.tables.n_live_pages),
-                pages_free=int(eng.tables.n_free_pages),
-                pages_cached=int(eng.tables.n_cached_pages),
-                pages_host=int(eng.tables.n_host_pages),
-                spills=eng.spills - sp0,
-                promotions=eng.promotions - pr0,
-                host_hit_pages=eng.host_hit_pages - hh0,
-                queue_depth=len(s.queue),
-                tokens=sum(len(toks) for _, toks in events),
-                accept_rate=(st["acc"] / st["prop"]) if st["prop"]
-                else 0.0,
-                wall_s=st["wall"], recompiled=recompiled,
-                inflight=([r.request_id
-                           for r in (*s.filling.values(),
-                                     *s.live.values())]
-                          if recompiled else ()),
-                tp=eng.tp,
-                branches=eng.branch_slot_count,
-                structured=eng.structured_slot_count,
-                adapters=eng.adapter_slot_count)
+        s.n_steps += 1
+        with span("sched_step"):
+            try:
+                self._step_body(s, st, events)
+            finally:
+                # record in a finally so the step that KILLS the pump
+                # still lands its (partial) row — the crash dump's last
+                # record must be the fatal step, not the one before it
+                recompiled = (eng.decode_compiles + eng.verify_compiles
+                              + eng.prefill_compiles) > c0
+                self.flight.record(
+                    kind=step_kind_code(st["prefill"], st["decode"],
+                                        st["spec"]),
+                    slots_live=len(s.live),
+                    slots_filling=len(s.filling),
+                    pages_live=int(eng.tables.n_live_pages),
+                    pages_free=int(eng.tables.n_free_pages),
+                    pages_cached=int(eng.tables.n_cached_pages),
+                    pages_host=int(eng.tables.n_host_pages),
+                    spills=eng.spills - sp0,
+                    promotions=eng.promotions - pr0,
+                    host_hit_pages=eng.host_hit_pages - hh0,
+                    queue_depth=len(s.queue),
+                    tokens=sum(len(toks) for _, toks in events),
+                    accept_rate=(st["acc"] / st["prop"]) if st["prop"]
+                    else 0.0,
+                    wall_s=st["wall"], recompiled=recompiled,
+                    inflight=([r.request_id
+                               for r in (*s.filling.values(),
+                                         *s.live.values())]
+                              if recompiled else ()),
+                    tp=eng.tp,
+                    branches=eng.branch_slot_count,
+                    structured=eng.structured_slot_count,
+                    adapters=eng.adapter_slot_count)
         return events
 
-    def _step_body(self, s: _Session, st: dict,
-                   events: list) -> list:
+    def _step_body(self, s: _Session, st: dict, events: list) -> None:
+        """The iteration's phases, each under its span (a shared no-op
+        while the registry is off): the tree docs/observability.md
+        draws, read by the benchmark's ``sched_host_ms``."""
+        with span("sched_admit"):
+            self._admit(s, events)
+        # --- ONE prefill chunk per iteration, interleaved with
+        # decode: long prompts stream in while the live slots keep
+        # producing tokens ---
+        if self.engine.has_pending:
+            self._prefill_one(s, st, events)
+        self._inst["slots"].set(len(s.live))
+        self._inst["pages"].set(self.engine.tables.n_free_pages)
+        if not s.live:
+            return
+        with span("sched_grow"):
+            self._grow(s)
+        if s.live:
+            self._decode_one(s, st, events)
+
+    def _admit(self, s: _Session, events: list) -> None:
         now = lambda: self.clock() - s.t0
         # submits drain BEFORE cancels: a request submitted and then
         # cancelled between two steps must be found in the queue
@@ -1339,79 +1376,80 @@ class ContinuousBatcher:
                         slot, req.response_format, req.eos_id,
                         prefix_tokens=req.prompt[req.base_len:]):
                     self._inst["structured"].inc()
+            # the first seat stamps the end of the queue wait; a
+            # re-admission after preemption keeps it
+            readmission = req.admitted_at is not None
+            if not readmission:
+                req.admitted_at = now()
             if self.tracer.enabled:
                 self.tracer.emit(
                     req.request_id, "seated", slot=slot,
                     prefix_hit_pages=int(
                         self.engine.prefix_hit_pages - hits0),
-                    readmission=req.admitted_at is not None,
+                    readmission=readmission,
+                    queue_wait_s=round(
+                        req.admitted_at - req.arrival, 6),
                     # adapter attribution only when one is in play:
                     # base-traffic event payloads stay byte-identical
                     # with the feature off
                     **({"adapter": req.adapter} if req.adapter
                        else {}))
-            if req.admitted_at is None:
-                req.admitted_at = now()
-        # --- ONE prefill chunk per iteration, interleaved with
-        # decode: long prompts stream in while the live slots keep
-        # producing tokens ---
-        if self.engine.has_pending:
-            # host->HBM promotions dispatch BEFORE the chunk issues:
-            # a host-tier hit's TTFT pays the async H2D stream
-            # (overlapped with this iteration's chunk/decode work),
-            # never the recompute FLOPs the hit skipped — and a chunk
-            # that attends promoted pages is ordered after the write
-            # by the donated-pool data dependency
-            if self.engine.host_spill:
-                self.engine.issue_promotions()
-            # the chunk's slot, read only when tracing will use it
-            # (pending_slots builds a list — not free on the hot loop)
-            fill_slot = (self.engine.pending_slots[0]
-                         if self.tracer.enabled else -1)
-            t_chunk = self.clock()
-            done = self.engine.prefill_step()
-            dt = self.clock() - t_chunk
-            self.est_chunk_s = dt if not self.est_chunk_s \
-                else 0.8 * self.est_chunk_s + 0.2 * dt
-            st["prefill"] = True
-            st["wall"] += dt
-            if self.tracer.enabled:
-                # the engine-track slice shares its name with the
-                # serving_prefill_chunk profiler span (spans.py), so
-                # a host trace and a device capture cross-link
-                self.tracer.emit(None, "serving_prefill_chunk",
-                                 dur_s=round(dt, 6), slot=fill_slot)
-                fr = s.filling.get(fill_slot)
-                if fr is not None:
-                    self.tracer.emit(fr.request_id, "prefill_chunk",
-                                     slot=fill_slot,
-                                     dur_s=round(dt, 6))
-            if done is not None:
-                slot, first = done
-                req = s.filling.pop(slot)
-                s.live[slot] = req
-                if req.n_branches > 1 and req.branches is None:
-                    # one prefill, best_of decode branches: fork at
-                    # the boundary so every branch diverges from its
-                    # own first token (branch 0's pick == `first`)
-                    self._fork_request(slot, req, events)
-                else:
-                    if self.engine.parallel:
-                        # the first token's logprob belongs to the
-                        # sequence logprob too (n = 1 requests and
-                        # re-admitted fork branches alike — a
-                        # preempted branch skipping it would bias
-                        # best_of toward preempted siblings); this
-                        # also frees the stashed prompt logits a
-                        # never-forking request otherwise holds
-                        req.cum_logprob += \
-                            self.engine.take_first_logprob(slot)
-                    self._maybe_stop(slot, first)  # prefill's token
-                    events.append((req, [int(first)]))
-        self._inst["slots"].set(len(s.live))
-        self._inst["pages"].set(self.engine.tables.n_free_pages)
-        if not s.live:
-            return events
+
+    def _prefill_one(self, s: _Session, st: dict, events: list) -> None:
+        # host->HBM promotions dispatch BEFORE the chunk issues:
+        # a host-tier hit's TTFT pays the async H2D stream
+        # (overlapped with this iteration's chunk/decode work),
+        # never the recompute FLOPs the hit skipped — and a chunk
+        # that attends promoted pages is ordered after the write
+        # by the donated-pool data dependency
+        if self.engine.host_spill:
+            self.engine.issue_promotions()
+        # the chunk's slot, read only when tracing will use it
+        # (pending_slots builds a list — not free on the hot loop)
+        fill_slot = (self.engine.pending_slots[0]
+                     if self.tracer.enabled else -1)
+        t_chunk = self.clock()
+        done = self.engine.prefill_step()
+        dt = self.clock() - t_chunk
+        self.est_chunk_s = dt if not self.est_chunk_s \
+            else 0.8 * self.est_chunk_s + 0.2 * dt
+        st["prefill"] = True
+        st["wall"] += dt
+        if self.tracer.enabled:
+            # the engine-track slice shares its name with the
+            # serving_prefill_chunk profiler span (spans.py), so
+            # a host trace and a device capture cross-link
+            self.tracer.emit(None, "serving_prefill_chunk",
+                             dur_s=round(dt, 6), slot=fill_slot)
+            fr = s.filling.get(fill_slot)
+            if fr is not None:
+                self.tracer.emit(fr.request_id, "prefill_chunk",
+                                 slot=fill_slot,
+                                 dur_s=round(dt, 6))
+        if done is not None:
+            slot, first = done
+            req = s.filling.pop(slot)
+            s.live[slot] = req
+            if req.n_branches > 1 and req.branches is None:
+                # one prefill, best_of decode branches: fork at
+                # the boundary so every branch diverges from its
+                # own first token (branch 0's pick == `first`)
+                self._fork_request(slot, req, events)
+            else:
+                if self.engine.parallel:
+                    # the first token's logprob belongs to the
+                    # sequence logprob too (n = 1 requests and
+                    # re-admitted fork branches alike — a
+                    # preempted branch skipping it would bias
+                    # best_of toward preempted siblings); this
+                    # also frees the stashed prompt logits a
+                    # never-forking request otherwise holds
+                    req.cum_logprob += \
+                        self.engine.take_first_logprob(slot)
+                self._maybe_stop(slot, first)  # prefill's token
+                events.append((req, [int(first)]))
+
+    def _grow(self, s: _Session) -> None:
         # --- grow: every live slot's next write page must exist
         # (cached prefixes evict first); starved slots preempt the
         # POLICY's victim (FCFS: youngest seated) ---
@@ -1420,8 +1458,8 @@ class ContinuousBatcher:
             if not self._preempt_one(s):
                 break
             starved = self.engine.grow_slots() if s.live else []
-        if not s.live:
-            return events
+
+    def _decode_one(self, s: _Session, st: dict, events: list) -> None:
         # --- one compiled step over every live slot ---
         if self.engine.tp > 1:
             # the step about to run pays its decode-output psum on
@@ -1453,41 +1491,10 @@ class ContinuousBatcher:
                                  dur_s=round(dt, 6),
                                  slots=len(emitted),
                                  proposed=st["prop"],
-                                 accepted=st["acc"])
-            # a cancel that landed while the step ran drops the whole
-            # burst (the slot leaves ``live`` here, before emission)
-            self._drain_cancels(events)
-            # count DELIVERED tokens only: a burst tail past
-            # EOS/max_new_tokens never reaches req.tokens, and
-            # counting it would inflate decode_tok_s vs the
-            # non-speculative arm (whose every counted token is
-            # appended)
-            delivered = 0
-            for slot in sorted(emitted):
-                burst: list[int] = []
-                req = s.live.get(slot)
-                finished = False
-                for tok in emitted[slot]:
-                    if finished or slot not in s.live:
-                        break
-                    delivered += 1
-                    burst.append(int(tok))
-                    # retirement DEFERRED past the burst event below:
-                    # the per-burst token delta must precede retired
-                    # on the request's trace timeline
-                    finished = self._maybe_stop(slot, int(tok),
-                                                finish=False)
-                if burst:
-                    # the whole accepted burst is ONE event — the SSE
-                    # contract is one message per pool read's yield
-                    if self.tracer.enabled:
-                        self.tracer.emit(req.request_id, "tokens",
-                                         n=len(burst), spec=True)
-                    events.append((req, burst))
-                if finished and slot in s.live:
-                    self._finish_request(slot)
-            s.decoded += delivered
-            self._inst["tokens"].inc(delivered)
+                                 accepted=st["acc"],
+                                 step=s.n_steps)
+            with span("sched_deliver"):
+                self._deliver_bursts(s, emitted, events)
         else:
             tokens = self.engine.step()
             dt = self.clock() - t_step
@@ -1499,24 +1506,67 @@ class ContinuousBatcher:
             if self.tracer.enabled:
                 self.tracer.emit(None, "decode_step",
                                  dur_s=round(dt, 6),
-                                 slots=len(s.live))
+                                 slots=len(s.live), step=s.n_steps)
             s.decoded += len(s.live)
             self._inst["tokens"].inc(len(s.live))
-            self._drain_cancels(events)
-            lps = self.engine.step_logprobs
-            for slot in list(s.live):
-                req = s.live[slot]
-                if lps is not None:
-                    # per-branch sequence logprob — what best_of
-                    # ranks by (parallel-sampling engines only)
-                    req.cum_logprob += float(lps[slot])
-                # token delta BEFORE the stop-check: retired must be
-                # the last event on the request's trace timeline
+            with span("sched_deliver"):
+                self._deliver_tokens(s, tokens, events)
+
+    def _deliver_bursts(self, s: _Session, emitted: dict,
+                        events: list) -> None:
+        # a cancel that landed while the step ran drops the whole
+        # burst (the slot leaves ``live`` here, before emission)
+        self._drain_cancels(events)
+        # count DELIVERED tokens only: a burst tail past
+        # EOS/max_new_tokens never reaches req.tokens, and
+        # counting it would inflate decode_tok_s vs the
+        # non-speculative arm (whose every counted token is
+        # appended)
+        delivered = 0
+        for slot in sorted(emitted):
+            burst: list[int] = []
+            req = s.live.get(slot)
+            finished = False
+            for tok in emitted[slot]:
+                if finished or slot not in s.live:
+                    break
+                delivered += 1
+                burst.append(int(tok))
+                # retirement DEFERRED past the burst event below:
+                # the per-burst token delta must precede retired
+                # on the request's trace timeline
+                finished = self._maybe_stop(slot, int(tok),
+                                            finish=False)
+            if burst:
+                # the whole accepted burst is ONE event — the SSE
+                # contract is one message per pool read's yield
                 if self.tracer.enabled:
-                    self.tracer.emit(req.request_id, "tokens", n=1)
-                self._maybe_stop(slot, int(tokens[slot]))
-                events.append((req, [int(tokens[slot])]))
-        return events
+                    self.tracer.emit(req.request_id, "tokens",
+                                     n=len(burst), spec=True,
+                                     step=s.n_steps)
+                events.append((req, burst))
+            if finished and slot in s.live:
+                self._finish_request(slot)
+        s.decoded += delivered
+        self._inst["tokens"].inc(delivered)
+
+    def _deliver_tokens(self, s: _Session, tokens: np.ndarray,
+                        events: list) -> None:
+        self._drain_cancels(events)
+        lps = self.engine.step_logprobs
+        for slot in list(s.live):
+            req = s.live[slot]
+            if lps is not None:
+                # per-branch sequence logprob — what best_of
+                # ranks by (parallel-sampling engines only)
+                req.cum_logprob += float(lps[slot])
+            # token delta BEFORE the stop-check: retired must be
+            # the last event on the request's trace timeline
+            if self.tracer.enabled:
+                self.tracer.emit(req.request_id, "tokens", n=1,
+                                 step=s.n_steps)
+            self._maybe_stop(slot, int(tokens[slot]))
+            events.append((req, [int(tokens[slot])]))
 
     def debug_snapshot(self, timeline_tail: int = 20) -> dict:
         """Live per-request view for the ``/debug/requests`` endpoint:

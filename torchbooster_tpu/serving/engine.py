@@ -688,10 +688,11 @@ class PagedEngine:
         n_heads_l = cfg.n_heads // self.tp
         positions = start + jnp.arange(C)
 
-        x = L.embedding(params["wte"], ids, dtype=self.compute_dtype)
-        if "wpe" in params:
-            x = x + L.embedding(params["wpe"], positions,
-                                dtype=self.compute_dtype)[None]
+        with jax.named_scope("embed"):
+            x = L.embedding(params["wte"], ids, dtype=self.compute_dtype)
+            if "wpe" in params:
+                x = x + L.embedding(params["wpe"], positions,
+                                    dtype=self.compute_dtype)[None]
 
         # chunk pages: table entries [start/ps, start/ps + n_cp); the
         # final chunk's pad pages (beyond the slot's allocation, or
@@ -716,20 +717,23 @@ class PagedEngine:
                 g = k.shape[2]
                 kp = k[0].reshape(n_cp, ps, g, head_dim)
                 vp = v[0].reshape(n_cp, ps, g, head_dim)
+                with jax.named_scope("kv_write"):
+                    if self.quantized:
+                        kq, k_s = _quantize_kv(kp)
+                        vq, v_s = _quantize_kv(vp)
+                        new_k = (pk[0].at[w_pages].set(kq),
+                                 pk[1].at[w_pages].set(k_s))
+                        new_v = (pv[0].at[w_pages].set(vq),
+                                 pv[1].at[w_pages].set(v_s))
+                    else:
+                        new_k = pk.at[w_pages].set(kp.astype(pk.dtype))
+                        new_v = pv.at[w_pages].set(vp.astype(pv.dtype))
                 if self.quantized:
-                    kq, k_s = _quantize_kv(kp)
-                    vq, v_s = _quantize_kv(vp)
-                    new_k = (pk[0].at[w_pages].set(kq),
-                             pk[1].at[w_pages].set(k_s))
-                    new_v = (pv[0].at[w_pages].set(vq),
-                             pv[1].at[w_pages].set(v_s))
                     gk = tuple(a[table_row].reshape(1, mp * ps, g, -1)
                                for a in pk)
                     gv = tuple(a[table_row].reshape(1, mp * ps, g, -1)
                                for a in pv)
                 else:
-                    new_k = pk.at[w_pages].set(kp.astype(pk.dtype))
-                    new_v = pv.at[w_pages].set(vp.astype(pv.dtype))
                     gk = pk[table_row].reshape(1, mp * ps, g, head_dim)
                     gv = pv[table_row].reshape(1, mp * ps, g, head_dim)
                 # prior context (this slot's already-written pages,
@@ -812,11 +816,12 @@ class PagedEngine:
         n_slots = last_ids.shape[0]
         n_heads_l = cfg.n_heads // self.tp    # local heads (tp shard)
 
-        x = L.embedding(params["wte"], last_ids[:, None],
-                        dtype=self.compute_dtype)
-        if "wpe" in params:
-            x = x + L.embedding(params["wpe"], lengths,
-                                dtype=self.compute_dtype)[:, None]
+        with jax.named_scope("embed"):
+            x = L.embedding(params["wte"], last_ids[:, None],
+                            dtype=self.compute_dtype)
+            if "wpe" in params:
+                x = x + L.embedding(params["wpe"], lengths,
+                                    dtype=self.compute_dtype)[:, None]
 
         # page -> lane bookkeeping, shared by every layer: each page
         # carries reference LANES (refs row: the slots holding it —
@@ -854,19 +859,20 @@ class PagedEngine:
             bp, pk, pv = inputs[:3]
 
             def attend(q, k, v):
-                if self.quantized:
-                    (pkv, pks), (pvv, pvs) = pk, pv
-                    kq, k_s = _quantize_kv(k)
-                    vq, v_s = _quantize_kv(v)
-                    new_k = (pkv.at[w_page, w_off].set(kq[:, 0]),
-                             pks.at[w_page, w_off].set(k_s[:, 0]))
-                    new_v = (pvv.at[w_page, w_off].set(vq[:, 0]),
-                             pvs.at[w_page, w_off].set(v_s[:, 0]))
-                else:
-                    new_k = pk.at[w_page, w_off].set(
-                        k[:, 0].astype(pk.dtype))
-                    new_v = pv.at[w_page, w_off].set(
-                        v[:, 0].astype(pv.dtype))
+                with jax.named_scope("kv_write"):
+                    if self.quantized:
+                        (pkv, pks), (pvv, pvs) = pk, pv
+                        kq, k_s = _quantize_kv(k)
+                        vq, v_s = _quantize_kv(v)
+                        new_k = (pkv.at[w_page, w_off].set(kq[:, 0]),
+                                 pks.at[w_page, w_off].set(k_s[:, 0]))
+                        new_v = (pvv.at[w_page, w_off].set(vq[:, 0]),
+                                 pvs.at[w_page, w_off].set(v_s[:, 0]))
+                    else:
+                        new_k = pk.at[w_page, w_off].set(
+                            k[:, 0].astype(pk.dtype))
+                        new_v = pv.at[w_page, w_off].set(
+                            v[:, 0].astype(pv.dtype))
                 if self.decode_backend == "pallas":
                     # the in-kernel block-table walk: the kernel's
                     # grid iterates the compacted live-page list and
@@ -1314,26 +1320,31 @@ class PagedEngine:
             # attend host-matched pages that were not written yet
             self.issue_promotions()
         p = self._pending[0]
-        if self.parallel:
-            # the slot's BRANCH KEY rides the rng operand: the chunk
-            # folds it with s0, so the first token is a pure function
-            # of (branch key, prompt length) — never of traffic order
-            sub = jnp.asarray(self._slot_keys[p["slot"]])
-        else:
-            self._rng, sub = jax.random.split(self._rng)
         C = self.chunk_tokens
-        ids = jnp.asarray(p["ids"][p["start"]:p["start"] + C])[None]
-        table_row = jnp.asarray(self.tables.tables[p["slot"]])
-        sextra = ()
-        if self.structured:
-            # the seating slot's legality row masks the first-token
-            # pick in-chunk (all-True when the request is
-            # unconstrained — exact no-op)
-            sextra = (jnp.asarray(
-                self._cursors.mask[p["slot"]][None]),)
-        # the chunk's (1,) lane id: the seating slot's adapter
-        sextra = sextra + self._lora_operands(
-            self._slot_lanes[p["slot"]:p["slot"] + 1])
+        # the host's work before the program (operands onto the
+        # device, the rng split): its own span, so a traced idle gap
+        # in front of a chunk has a name
+        with span("prefill_args"):
+            if self.parallel:
+                # the slot's BRANCH KEY rides the rng operand: the
+                # chunk folds it with s0, so the first token is a pure
+                # function of (branch key, prompt length) — never of
+                # traffic order
+                sub = jnp.asarray(self._slot_keys[p["slot"]])
+            else:
+                self._rng, sub = jax.random.split(self._rng)
+            ids = jnp.asarray(p["ids"][p["start"]:p["start"] + C])[None]
+            table_row = jnp.asarray(self.tables.tables[p["slot"]])
+            sextra = ()
+            if self.structured:
+                # the seating slot's legality row masks the
+                # first-token pick in-chunk (all-True when the request
+                # is unconstrained — exact no-op)
+                sextra = (jnp.asarray(
+                    self._cursors.mask[p["slot"]][None]),)
+            # the chunk's (1,) lane id: the seating slot's adapter
+            sextra = sextra + self._lora_operands(
+                self._slot_lanes[p["slot"]:p["slot"] + 1])
         # span: host wall time in the event log + the same label on a
         # captured device trace (observability/spans.py); no-op when
         # telemetry is disabled
@@ -1353,30 +1364,35 @@ class PagedEngine:
         if p["start"] < p["s0"]:
             return None
         self._pending.pop(0)
-        if self.parallel:
-            # ONE batched device->host sync; the final-position
-            # logits are what fork() samples sibling branches' first
-            # tokens from. The stash is consumed at the fork (or by
-            # take_first_logprob for requests that never fork), so it
-            # lives one scheduling iteration — the one (vocab,)-row
-            # host copy per ADMISSION is the price of not threading a
-            # will-fork hint through the admission surface.
-            tok, lp, logits = jax.device_get((tok, lp, logits))
-            self._fork_state[p["slot"]] = {
-                "logits": np.asarray(logits[0]),
-                "logprob": float(np.asarray(lp)[0]),
-                "s0": int(p["s0"])}
-        first = int(np.asarray(tok)[0])
-        self.tables.activate(p["slot"], first)
-        self.tables.register_prefix(p["slot"], p["ids"][:p["s0"]])
-        if self._drafter is not None:
-            self._drafter.observe(p["slot"], [first])
-        if self.structured:
-            # same hook site as the drafter: the cursor advances on
-            # the accepted first token (fork() REBASES children, so
-            # a parent about to fork is already correct — branch 0's
-            # stream keeps this very token)
-            self._cursors.observe(p["slot"], [first])
+        # the prompt's LAST chunk: its token is read back here, which
+        # waits for the chunk program the span above only dispatched —
+        # a device wait, named so that it is not taken for host work
+        with span("prefill_finish"):
+            if self.parallel:
+                # ONE batched device->host sync; the final-position
+                # logits are what fork() samples sibling branches'
+                # first tokens from. The stash is consumed at the fork
+                # (or by take_first_logprob for requests that never
+                # fork), so it lives one scheduling iteration — the
+                # one (vocab,)-row host copy per ADMISSION is the
+                # price of not threading a will-fork hint through the
+                # admission surface.
+                tok, lp, logits = jax.device_get((tok, lp, logits))
+                self._fork_state[p["slot"]] = {
+                    "logits": np.asarray(logits[0]),
+                    "logprob": float(np.asarray(lp)[0]),
+                    "s0": int(p["s0"])}
+            first = int(np.asarray(tok)[0])
+            self.tables.activate(p["slot"], first)
+            self.tables.register_prefix(p["slot"], p["ids"][:p["s0"]])
+            if self._drafter is not None:
+                self._drafter.observe(p["slot"], [first])
+            if self.structured:
+                # same hook site as the drafter: the cursor advances
+                # on the accepted first token (fork() REBASES
+                # children, so a parent about to fork is already
+                # correct — branch 0's stream keeps this very token)
+                self._cursors.observe(p["slot"], [first])
         return p["slot"], first
 
     def admit(self, prompt_ids: np.ndarray, seed: int | None = None,
@@ -1664,16 +1680,17 @@ class PagedEngine:
                 raise RuntimeError(
                     "a slot reached cfg.seq_len; the batcher must "
                     "retire sequences at the cache horizon")
-        self._rng, sub = jax.random.split(self._rng)
-        args = self.tables.device_args()
-        extra = self._kernel_operands()
-        if self.structured:
-            # the fused legality mask rides as a VALUE operand —
-            # schema churn flips bits, never shapes
-            extra = extra + (jnp.asarray(self._cursors.mask),)
-        if self.parallel:
-            extra = extra + (jnp.asarray(self._slot_keys),)
-        extra = extra + self._lora_operands(self._slot_lanes)
+        with span("decode_args"):
+            self._rng, sub = jax.random.split(self._rng)
+            args = self.tables.device_args()
+            extra = self._kernel_operands()
+            if self.structured:
+                # the fused legality mask rides as a VALUE operand —
+                # schema churn flips bits, never shapes
+                extra = extra + (jnp.asarray(self._cursors.mask),)
+            if self.parallel:
+                extra = extra + (jnp.asarray(self._slot_keys),)
+            extra = extra + self._lora_operands(self._slot_lanes)
         with span("decode_step"):
             outs = self._decode_jit(
                 self.params, self.pool["k"], self.pool["v"],
@@ -1691,12 +1708,15 @@ class PagedEngine:
                 tokens, pool_k, pool_v = outs
                 self.pool = {"k": pool_k, "v": pool_v}
                 tokens = np.asarray(tokens)
-        for slot in np.flatnonzero(active):
-            self.tables.advance(int(slot), int(tokens[slot]))
-            if self._drafter is not None:
-                self._drafter.observe(int(slot), [int(tokens[slot])])
-            if self.structured:
-                self._cursors.observe(int(slot), [int(tokens[slot])])
+        with span("decode_advance"):
+            for slot in np.flatnonzero(active):
+                self.tables.advance(int(slot), int(tokens[slot]))
+                if self._drafter is not None:
+                    self._drafter.observe(int(slot),
+                                          [int(tokens[slot])])
+                if self.structured:
+                    self._cursors.observe(int(slot),
+                                          [int(tokens[slot])])
         return tokens
 
     def spec_step(self) -> dict[int, list[int]]:
@@ -1771,18 +1791,20 @@ class PagedEngine:
                 vmask[slot] = rows
             drafts[slot] = d
             self.spec_proposed += int((d >= 0).sum())
-        self._rng, sub = jax.random.split(self._rng)
-        args = self.tables.device_args()
-        extra = self._kernel_operands()
-        if self.structured:
-            extra = extra + (jnp.asarray(vmask),)
-        if self.spec_tree:
-            depth, tvis = tree_masks(parents)
-            extra = (jnp.asarray(parents), jnp.asarray(depth),
-                     jnp.asarray(tvis)) + extra
-        extra = extra + self._lora_operands(self._slot_lanes)
-        in_ids = jnp.concatenate(
-            [args["last_ids"][:, None], jnp.asarray(drafts)], axis=1)
+        with span("decode_args"):
+            self._rng, sub = jax.random.split(self._rng)
+            args = self.tables.device_args()
+            extra = self._kernel_operands()
+            if self.structured:
+                extra = extra + (jnp.asarray(vmask),)
+            if self.spec_tree:
+                depth, tvis = tree_masks(parents)
+                extra = (jnp.asarray(parents), jnp.asarray(depth),
+                         jnp.asarray(tvis)) + extra
+            extra = extra + self._lora_operands(self._slot_lanes)
+            in_ids = jnp.concatenate(
+                [args["last_ids"][:, None], jnp.asarray(drafts)],
+                axis=1)
         with span("spec_verify_step"):
             accept, token, pool_k, pool_v = self._verify_jit(
                 self.params, self.pool["k"], self.pool["v"],
@@ -1832,14 +1854,15 @@ class PagedEngine:
                     args["lengths"], args["active"],
                     jnp.asarray(src_off))
             self.pool = {"k": pool_k, "v": pool_v}
-        for slot, emitted in out.items():
-            for t in emitted:
-                self.tables.advance(slot, t)
-            self._drafter.observe(slot, emitted)
-            if self.structured:
-                # the cursor stops at EOS itself; tokens past it in
-                # the burst are the same tail the batcher drops
-                self._cursors.observe(slot, emitted)
+        with span("decode_advance"):
+            for slot, emitted in out.items():
+                for t in emitted:
+                    self.tables.advance(slot, t)
+                self._drafter.observe(slot, emitted)
+                if self.structured:
+                    # the cursor stops at EOS itself; tokens past it
+                    # in the burst are the same tail the batcher drops
+                    self._cursors.observe(slot, emitted)
         return out
 
     def retire(self, slot: int) -> None:

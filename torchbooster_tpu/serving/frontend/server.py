@@ -75,6 +75,7 @@ from urllib.parse import parse_qs
 
 import numpy as np
 
+from torchbooster_tpu.observability import span
 from torchbooster_tpu.serving.batcher import ContinuousBatcher, Request
 from torchbooster_tpu.serving.frontend.http import (
     SSE_DONE,
@@ -292,25 +293,26 @@ class ServingFrontend:
                         s = self._streams.get(id(req.parent))
                     return s
 
-                last = {}
-                for i, (req, _) in enumerate(events):
-                    s = stream_of(req)
-                    if s is not None:
-                        last[id(s)] = i
-                for i, (req, tokens) in enumerate(events):
-                    stream = stream_of(req)
-                    if stream is None:
-                        continue
-                    family = (req.parent.branches if req.parent
-                              else req.branches) or [req]
-                    done = (all(r.finished_at is not None
-                                for r in family)
-                            and last[id(stream)] == i)
-                    stream.queue.put_nowait(
-                        (req.branch, tokens,
-                         req.finish_reason
-                         if req.finished_at is not None else None,
-                         done))
+                with span("frontend_fanout"):
+                    last = {}
+                    for i, (req, _) in enumerate(events):
+                        s = stream_of(req)
+                        if s is not None:
+                            last[id(s)] = i
+                    for i, (req, tokens) in enumerate(events):
+                        stream = stream_of(req)
+                        if stream is None:
+                            continue
+                        family = (req.parent.branches if req.parent
+                                  else req.branches) or [req]
+                        done = (all(r.finished_at is not None
+                                    for r in family)
+                                and last[id(stream)] == i)
+                        stream.queue.put_nowait(
+                            (req.branch, tokens,
+                             req.finish_reason
+                             if req.finished_at is not None else None,
+                             done))
         except Exception:
             self._stopping = True
             # the post-mortem FIRST: persist what the engine was doing

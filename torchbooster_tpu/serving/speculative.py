@@ -391,11 +391,12 @@ def make_verify_fn(engine):
         pos_c = jnp.minimum(sem_pos, cfg.seq_len - 1)
         ids_c = jnp.clip(in_ids, 0, cfg.vocab - 1)
 
-        x = L.embedding(params["wte"], ids_c,
-                        dtype=engine.compute_dtype)
-        if "wpe" in params:
-            x = x + L.embedding(params["wpe"], pos_c,
-                                dtype=engine.compute_dtype)
+        with jax.named_scope("embed"):
+            x = L.embedding(params["wte"], ids_c,
+                            dtype=engine.compute_dtype)
+            if "wpe" in params:
+                x = x + L.embedding(params["wpe"], pos_c,
+                                    dtype=engine.compute_dtype)
 
         # write targets per (slot, position): the page holding
         # ``lengths + j`` — private by construction (the cursor sits
@@ -464,21 +465,24 @@ def make_verify_fn(engine):
                 # (prior context AND intra-draft) comes back in pool
                 # dtype, byte-identical to what S sequential
                 # non-speculative steps would have read
+                with jax.named_scope("kv_write"):
+                    if engine.quantized:
+                        (pkv, pks), (pvv, pvs) = pk, pv
+                        kq, k_s = _quantize_kv(k_new)
+                        vq, v_s = _quantize_kv(v_new)
+                        new_k = (pkv.at[w_page, w_off].set(kq),
+                                 pks.at[w_page, w_off].set(k_s))
+                        new_v = (pvv.at[w_page, w_off].set(vq),
+                                 pvs.at[w_page, w_off].set(v_s))
+                    else:
+                        new_k = pk.at[w_page, w_off].set(
+                            k_new.astype(pk.dtype))
+                        new_v = pv.at[w_page, w_off].set(
+                            v_new.astype(pv.dtype))
                 if engine.quantized:
-                    (pkv, pks), (pvv, pvs) = pk, pv
-                    kq, k_s = _quantize_kv(k_new)
-                    vq, v_s = _quantize_kv(v_new)
-                    new_k = (pkv.at[w_page, w_off].set(kq),
-                             pks.at[w_page, w_off].set(k_s))
-                    new_v = (pvv.at[w_page, w_off].set(vq),
-                             pvs.at[w_page, w_off].set(v_s))
                     rk = tuple(a[1:] for a in new_k)
                     rv = tuple(a[1:] for a in new_v)
                 else:
-                    new_k = pk.at[w_page, w_off].set(
-                        k_new.astype(pk.dtype))
-                    new_v = pv.at[w_page, w_off].set(
-                        v_new.astype(pv.dtype))
                     rk, rv = new_k[1:], new_v[1:]
                 if engine.decode_backend == "pallas":
                     # the fused kernel pass: all S verify positions

@@ -43,11 +43,21 @@ from jax.sharding import Mesh
 # Environment knobs (ref boost, utils.py:29-45)
 # =========================================================================
 
+# JAX's persistent-cache key leaves HLO metadata out
+# (jax_compilation_cache_include_metadata_in_key is false), so a program
+# compiled before a ``jax.named_scope`` was added or renamed is a HIT
+# for the re-scoped one and comes back with the old op names: a trace
+# would attribute device time by a vocabulary the source no longer has.
+# The cache therefore lives in a subdirectory named after this number;
+# bump it whenever the scope vocabulary (docs/observability.md) changes.
+PROGRAM_METADATA_VERSION = 1
+
+
 def enable_compile_cache() -> str | None:
     """Turn on JAX's persistent compilation cache and return its
-    directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
-    and no directory is set in code; otherwise the cache lives at the
-    fixed ``<checkout>/.jax_cache`` (never a temp dir: a directory
+    directory: ``m<PROGRAM_METADATA_VERSION>`` under
+    ``JAX_COMPILATION_CACHE_DIR`` where that is set, otherwise under
+    the fixed ``<checkout>/.jax_cache`` (never a temp dir: a directory
     that moves between runs never hits). The compile-time threshold
     is dropped so the step programs of a short run are kept too.
     Idempotent; called by :func:`boost` and by the serving build, the
@@ -60,10 +70,16 @@ def enable_compile_cache() -> str | None:
     runs before ``jax.distributed.initialize`` may."""
     if (jax.config.jax_platforms or "") == "cpu":
         return None
-    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not path:
-        path = str(Path(__file__).resolve().parent.parent / ".jax_cache")
+    base = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        Path(__file__).resolve().parent.parent / ".jax_cache")
+    path = os.path.join(base, f"m{PROGRAM_METADATA_VERSION}")
+    if jax.config.jax_compilation_cache_dir != path:
+        from jax.experimental.compilation_cache import compilation_cache
+
         jax.config.update("jax_compilation_cache_dir", path)
+        # a compile before this call would have opened the cache at
+        # the environment's own directory, and it opens only once
+        compilation_cache.reset_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return path
 
@@ -368,6 +384,20 @@ def make_step(
             lambda x: x.astype(compute_dtype)
             if jnp.issubdtype(x.dtype, jnp.floating) else x, tree)
 
+    def _ema(state: TrainState, params: Any, boundary: Any = None) -> Any:
+        """The EMA of the updated params (None where the state keeps
+        none); ``boundary``: decay only on this micro-step."""
+        if ema_decay is None or state.ema is None:
+            return state.ema
+        # bias-corrected decay ramp: early steps track params closely
+        # instead of the init snapshot
+        d = jnp.minimum(ema_decay,
+                        (1.0 + state.step) / (10.0 + state.step))
+        if boundary is not None:
+            d = jnp.where(boundary, d, 1.0)
+        return jax.tree.map(lambda e, p: e * d + (1.0 - d) * p,
+                            state.ema, params)
+
     def step_fn(state: TrainState, batch: Any) -> tuple[TrainState, dict]:
         rng, step_rng = jax.random.split(state.rng)
         batch_cast = batch if compute_dtype is None else _cast(batch)
@@ -395,12 +425,8 @@ def make_step(
                 comms, diff_fn, tx, clip, state.params,
                 state.opt_state, state.comms or {}, batch_cast,
                 step_rng, has_aux=has_aux)
-            ema = state.ema
-            if ema_decay is not None and ema is not None:
-                d = jnp.minimum(ema_decay,
-                                (1.0 + state.step) / (10.0 + state.step))
-                ema = jax.tree.map(lambda e, p: e * d + (1.0 - d) * p,
-                                   ema, params)
+            with jax.named_scope("optimizer"):
+                ema = _ema(state, params)
             new_state = state.replace(
                 params=params, opt_state=opt_state,
                 step=state.step + 1, rng=rng, ema=ema,
@@ -433,15 +459,11 @@ def make_step(
             # happens inside (global norm via scalar psum)
             from torchbooster_tpu.comms.zero import sharded_update
 
-            params, opt_state = sharded_update(
-                tx, comms, clip, grads, state.opt_state, state.params,
-                scattered=explicit)
-            ema = state.ema
-            if ema_decay is not None and ema is not None:
-                d = jnp.minimum(ema_decay,
-                                (1.0 + state.step) / (10.0 + state.step))
-                ema = jax.tree.map(lambda e, p: e * d + (1.0 - d) * p,
-                                   ema, params)
+            with jax.named_scope("optimizer"):
+                params, opt_state = sharded_update(
+                    tx, comms, clip, grads, state.opt_state,
+                    state.params, scattered=explicit)
+                ema = _ema(state, params)
             new_state = state.replace(
                 params=params, opt_state=opt_state,
                 step=state.step + 1, rng=rng, ema=ema,
@@ -449,48 +471,40 @@ def make_step(
             return new_state, {"loss": loss, **aux}
 
         boundary = (state.step + 1) % accumulate_every == 0
-        if accumulate:
-            grad_acc = jax.tree.map(jnp.add, state.grad_acc, grads)
+        with jax.named_scope("optimizer"):
+            if accumulate:
+                grad_acc = jax.tree.map(jnp.add, state.grad_acc, grads)
 
-            def apply(_):
-                grads_avg = jax.tree.map(
-                    lambda g: g / accumulate_every, grad_acc)
+                def apply(_):
+                    grads_avg = jax.tree.map(
+                        lambda g: g / accumulate_every, grad_acc)
+                    if clip is not None:
+                        grads_clipped = _clip_by_global_norm(grads_avg,
+                                                             clip)
+                    else:
+                        grads_clipped = grads_avg
+                    updates, opt_state = tx.update(
+                        grads_clipped, state.opt_state, state.params)
+                    params = optax.apply_updates(state.params, updates)
+                    zeros = jax.tree.map(jnp.zeros_like, grad_acc)
+                    return params, opt_state, zeros
+
+                def hold(_):
+                    return state.params, state.opt_state, grad_acc
+
+                params, opt_state, grad_acc = jax.lax.cond(
+                    boundary, apply, hold, None)
+            else:
                 if clip is not None:
-                    grads_clipped = _clip_by_global_norm(grads_avg, clip)
-                else:
-                    grads_clipped = grads_avg
-                updates, opt_state = tx.update(
-                    grads_clipped, state.opt_state, state.params)
+                    grads = _clip_by_global_norm(grads, clip)
+                updates, opt_state = tx.update(grads, state.opt_state,
+                                               state.params)
                 params = optax.apply_updates(state.params, updates)
-                zeros = jax.tree.map(jnp.zeros_like, grad_acc)
-                return params, opt_state, zeros
-
-            def hold(_):
-                return state.params, state.opt_state, grad_acc
-
-            params, opt_state, grad_acc = jax.lax.cond(
-                boundary, apply, hold, None)
-        else:
-            if clip is not None:
-                grads = _clip_by_global_norm(grads, clip)
-            updates, opt_state = tx.update(grads, state.opt_state,
-                                           state.params)
-            params = optax.apply_updates(state.params, updates)
-            grad_acc = state.grad_acc
-
-        ema = state.ema
-        if ema_decay is not None and ema is not None:
-            # bias-corrected decay ramp: early steps track params
-            # closely instead of the init snapshot
-            d = jnp.minimum(ema_decay,
-                            (1.0 + state.step) / (10.0 + state.step))
+                grad_acc = state.grad_acc
             # under accumulation, params only change on boundary
             # micro-steps — decaying on hold steps would shrink the
             # effective half-life by accumulate_every
-            if accumulate:
-                d = jnp.where(boundary, d, 1.0)
-            ema = jax.tree.map(lambda e, p: e * d + (1.0 - d) * p,
-                               ema, params)
+            ema = _ema(state, params, boundary if accumulate else None)
 
         new_state = state.replace(
             params=_pin(params), opt_state=opt_state, step=state.step + 1,
