@@ -1414,3 +1414,132 @@ def test_tp_yaml_config_roundtrip_builds_batcher(tmp_path):
     tails = batcher.flight.tail(4)
     assert tails and all(row["tp"] == 2 for row in tails)
     assert batcher.engine.debug_stats()["tp"] == 2
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode", "verify"])
+@pytest.mark.parametrize("cache_dtype", [None, "int8"],
+                         ids=["bf16-pool", "int8-pool"])
+def test_pool_is_carried_through_the_layer_loop(program, cache_dtype):
+    """The three serving programs carry the stacked pool THROUGH their
+    layer loop: no scanned input (``xs``) and no stacked output
+    (``ys``) of any scan has a pool leaf's shape — those are two
+    buffers even under donation, a copy of the pool a step — and the
+    pool's leaves are among one loop's carries. Read off the jaxpr,
+    so it guards the plumbing where no TPU compiler is installed
+    (tests/test_tpu_aot_compile.py holds the compiled program to the
+    same)."""
+    from torchbooster_tpu.serving import PagedEngine
+    from torchbooster_tpu.serving.speculative import make_verify_fn
+
+    params, cfg = _decisive_model()
+    engine = PagedEngine(params, cfg, page_size=4, n_pages=16,
+                         max_slots=2, cache_dtype=cache_dtype,
+                         speculative=True, draft_len=3)
+    pool_k, pool_v = engine.pool["k"], engine.pool["v"]
+    pool_shapes = {leaf.shape for leaf in jax.tree.leaves(pool_k)}
+    args = engine.tables.device_args()
+    rng = jax.random.PRNGKey(0)
+    if program == "chunk":
+        jaxpr = jax.make_jaxpr(engine._chunk_fn)(
+            params, pool_k, pool_v,
+            jnp.zeros((1, engine.chunk_tokens), jnp.int32),
+            jnp.int32(0), jnp.int32(5), args["tables"][0], rng)
+    else:
+        fn, ids = ((engine._decode_fn, args["last_ids"])
+                   if program == "decode" else
+                   (make_verify_fn(engine),
+                    jnp.zeros((2, 1 + engine.draft_len), jnp.int32)))
+        jaxpr = jax.make_jaxpr(fn)(
+            params, pool_k, pool_v, args["tables"], args["lengths"],
+            args["refs"], args["page_pos"], args["active"], ids, rng)
+
+    def scans(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scans(sub)
+
+    carried = 0
+    for eqn in scans(jaxpr.jaxpr):
+        n_consts = eqn.params["num_consts"]
+        n_carry = eqn.params["num_carry"]
+        shape = lambda v: tuple(v.aval.shape)
+        xs = eqn.invars[n_consts + n_carry:]
+        ys = eqn.outvars[n_carry:]
+        assert not pool_shapes & {shape(v) for v in xs + ys}, (
+            "a pool-shaped leaf rides a scan's xs / ys")
+        carry = eqn.invars[n_consts:n_consts + n_carry]
+        carried += sum(shape(v) in pool_shapes for v in carry)
+    assert carried == 2 * len(jax.tree.leaves(pool_k))
+
+
+@pytest.mark.parametrize("kv_heads,rep", [(3, 1), (2, 2)],
+                         ids=["mha", "gqa"])
+@pytest.mark.parametrize("int8", [False, True], ids=["plain", "int8"])
+@pytest.mark.parametrize("n_q", [1, 3], ids=["decode", "lanes"])
+def test_sweep_attention_matches_the_dense_core(kv_heads, rep, int8,
+                                                n_q):
+    """``kv_pages.sweep_attention`` over the pool's merged rows gives
+    the partials ``_grouped_cache_attention(state=True)`` gives over
+    the same pages with the heads split out: the paged read has its
+    own formulation (block-diagonal queries, the row never split) and
+    this is what ties it to the dense path's core, beside the
+    token-exact parity tests above."""
+    from torchbooster_tpu.models.gpt import (
+        _grouped_cache_attention,
+        _quantize_kv,
+    )
+    from torchbooster_tpu.serving import kv_pages
+
+    rs = np.random.RandomState(0)
+    n_pages, ps, head_dim = 5, 4, 8
+    q = jnp.asarray(rs.randn(n_pages, n_q, kv_heads * rep, head_dim),
+                    jnp.float32)
+    k, v = (jnp.asarray(rs.randn(n_pages, ps, kv_heads, head_dim),
+                        jnp.float32) for _ in range(2))
+    visible = jnp.asarray(rs.rand(n_pages, n_q, ps) > 0.3)
+    width = kv_pages.kv_width(kv_heads, head_dim)
+    assert width % kv_pages.LANES == 0
+    if int8:
+        k, v = _quantize_kv(k), _quantize_kv(v)
+        rows = [kv_pages.quantized_rows(*t, width) for t in (k, v)]
+    else:
+        rows = [kv_pages.to_rows(t, width) for t in (k, v)]
+    want = _grouped_cache_attention(q, k, v, visible[:, None, None],
+                                    state=True)
+    got = kv_pages.sweep_attention(q, *rows, visible, kv_heads)
+    for w, g in zip(want, got):
+        assert w.shape == g.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+@pytest.mark.parametrize("host", [False, True], ids=["jax", "numpy"])
+def test_pool_rows_round_trip(shards, host):
+    """``to_rows`` / ``from_rows`` are inverses at the width
+    ``make_pool`` gives the pool, per tp shard: each shard's heads sit
+    at the start of its own 128-aligned slice of the row, zeros
+    behind them."""
+    from torchbooster_tpu.serving import kv_pages
+
+    cfg = GPTConfig(vocab=97, n_layers=2, d_model=48, n_heads=4,
+                    seq_len=32)
+    pool = kv_pages.make_pool(cfg, page_size=4, n_pages=3,
+                              shards=shards)
+    head_dim = cfg.d_model // cfg.n_heads
+    width = pool["k"].shape[-1]
+    assert pool["k"].shape == (2, 3, 4, width)
+    assert width == kv_pages.kv_width(cfg.kv_heads, head_dim, shards) \
+        == shards * kv_pages.LANES
+    x = np.random.RandomState(1).randn(3, 4, cfg.kv_heads, head_dim)
+    x = x.astype(np.float32) if host else jnp.asarray(x, jnp.float32)
+    rows = kv_pages.to_rows(x, width, shards)
+    assert isinstance(rows, np.ndarray) == host
+    assert rows.shape == (3, 4, width)
+    per = cfg.kv_heads // shards * head_dim
+    split = np.asarray(rows).reshape(3, 4, shards, width // shards)
+    assert not split[..., per:].any()
+    back = kv_pages.from_rows(rows, cfg.kv_heads, head_dim, shards)
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(x))

@@ -148,3 +148,139 @@ def test_gpt2_small_train_step_compiles_and_fits_v5e(one_chip):
     need = (memory.temp_size_in_bytes + memory.argument_size_in_bytes
             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
     assert need < V5E_HBM_BYTES, f"{need / 2**30:.1f} GiB on a 16 GiB chip"
+
+
+# ---- the serve cell's two programs: the pool stays where it is --------
+# GPT-2 XL widths at the benchmark cell's pool geometry (256 pages x 64,
+# 32 slots, 4-page chunks), 4 layers so that a compile takes seconds
+
+XL = dict(vocab=50257, seq_len=1024, d_model=1600, n_heads=25)
+XL_LAYERS, XL_PAGES, XL_PAGE, XL_SLOTS = 4, 256, 64, 32
+
+
+def _top_level_results(text):
+    """(name, opcode, [(dtype, dims, layout)]) of every instruction
+    OUTSIDE fused computations: those are the buffers a program
+    writes."""
+    import re
+
+    shape = re.compile(r"(pred|s4|s8|s32|u8|u32|bf16|f16|f32)"
+                       r"\[([0-9,]*)\](\{[^}]*\})?")
+    fused = False
+    for line in text.splitlines():
+        line = line.strip()
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            fused = "fused_computation" in head.group(2)
+            continue
+        inst = re.match(r"(ROOT )?%?([\w.\-]+) = (.*)", line)
+        if fused or not inst:
+            continue
+        rest = inst.group(3)
+        op = re.search(r"\)?\s([a-z][\w\-]*)\(", rest)
+        if op:
+            yield (inst.group(2), op.group(1),
+                   shape.findall(rest[:op.start() + 1]))
+
+
+@pytest.mark.parametrize("int8_pool", [False, True],
+                         ids=["bf16-pool", "int8-pool"])
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_serve_programs_keep_the_pool_in_place_on_v5e(
+        one_chip, program, int8_pool):
+    """``PagedEngine._decode_fn`` / ``_chunk_fn`` compiled for the
+    described v5e with the pools donated: no instruction writes a
+    buffer the size of a layer's pool other than the in-place update
+    of the pool itself, the pool comes back in the layout it went in
+    with, that layout pads at most 5 %, and the program's scratch is
+    smaller than one layer's K + V (the head's relayout of the tied
+    embedding, vocabulary x d_model, is the model's and excepted by
+    its shape)."""
+    import math
+
+    import torchbooster_tpu.serving.engine as engine_mod
+    from torchbooster_tpu.models.gpt import GPT, GPTConfig
+    from torchbooster_tpu.serving.engine import PagedEngine
+
+    cfg = GPTConfig(n_layers=XL_LAYERS, **XL)
+    abstract = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip), tree)
+    params = abstract(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16),
+        GPT.init(jax.random.PRNGKey(0), cfg))))
+    # the engine's own constructor, with the pool described, not held
+    make_pool = engine_mod.make_pool
+    engine_mod.make_pool = lambda *a, **kw: jax.eval_shape(
+        lambda: make_pool(*a, **kw))
+    try:
+        engine = PagedEngine(
+            params, cfg, page_size=XL_PAGE, n_pages=XL_PAGES,
+            max_slots=XL_SLOTS, prefill_chunk_pages=4,
+            cache_dtype="int8" if int8_pool else None)
+    finally:
+        engine_mod.make_pool = make_pool
+    pool_k, pool_v = abstract(engine.pool["k"]), abstract(engine.pool["v"])
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tables = engine.tables
+    if program == "decode":
+        fn, args = engine._decode_fn, (
+            arg(tables.tables.shape), arg((XL_SLOTS,)),
+            arg(tables.refs.shape), arg((XL_PAGES,)),
+            arg((XL_SLOTS,), jnp.bool_), arg((XL_SLOTS,)),
+            arg((2,), jnp.uint32))
+    else:
+        fn, args = engine._chunk_fn, (
+            arg((1, engine.chunk_tokens)), arg(()), arg(()),
+            arg((tables.max_pages_per_slot,)), arg((2,), jnp.uint32))
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, pool_k, pool_v, *args).compile()
+
+    head_dim = cfg.d_model // cfg.n_heads
+    layer_elems = XL_PAGES * XL_PAGE * cfg.kv_heads * head_dim
+    pool_shapes = {tuple(leaf.shape) for leaf in jax.tree.leaves(pool_k)}
+    moved = []
+    for name, op, results in _top_level_results(compiled.as_text()):
+        if op in ("parameter", "get-tuple-element", "tuple", "bitcast",
+                  "while", "constant"):
+            continue
+        for dtype, dims, _ in results:
+            dims = tuple(int(d) for d in dims.split(",") if d)
+            if math.prod(dims) < layer_elems or cfg.vocab in dims:
+                continue
+            # the pool's own update: a scatter fusion whose result IS
+            # the stacked pool (in place under donation, which the
+            # aliasing assertion below holds it to)
+            if op == "fusion" and dims in pool_shapes:
+                continue
+            moved.append((name, op, dtype, dims))
+    assert not moved, f"pool-sized buffers written: {moved}"
+
+    # the pool goes out in the layout it came in with, aliased
+    n_pool = len(jax.tree.leaves((pool_k, pool_v)))
+    formats_in = jax.tree.leaves(compiled.input_formats[0][1:3])
+    formats_out = jax.tree.leaves(compiled.output_formats)[-n_pool:]
+    assert [f.layout for f in formats_in] \
+        == [f.layout for f in formats_out]
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(
+        math.prod(leaf.shape) * leaf.dtype.itemsize
+        for leaf in jax.tree.leaves((pool_k, pool_v)))
+    assert memory.alias_size_in_bytes >= pool_bytes
+
+    # what the pool weighs on the device against what it holds
+    params_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                       for x in jax.tree.leaves(params))
+    held = 2 * XL_LAYERS * layer_elems * (1 if int8_pool else 2)
+    if int8_pool:       # + one bf16 scale per (token, head)
+        held += 2 * XL_LAYERS * layer_elems // head_dim * 2
+    on_device = memory.argument_size_in_bytes - params_bytes
+    assert on_device <= 1.05 * held + 2**20, (on_device, held)
+
+    head_relayout = cfg.vocab * cfg.d_model * 2
+    layer_kv = 2 * layer_elems * 2
+    assert memory.temp_size_in_bytes - head_relayout < layer_kv, (
+        memory.temp_size_in_bytes, layer_kv)

@@ -2,7 +2,7 @@
 decode step's HBM reads are the LIVE context, not the pool.
 
 The XLA pool sweep (serving/engine.py ``_decode_fn``) reads every
-usable pool page every step — ``(n_pages - 1) · page_size`` K/V rows
+pool page every step — ``n_pages · page_size`` K/V rows
 per layer whatever the occupancy (docs/performance.md "Paged-decode
 roofline"). This kernel is the vLLM-PagedAttention-shaped alternative:
 the grid iterates a COMPACTED work list of the pool's live pages
@@ -178,9 +178,11 @@ def paged_attention(q: jax.Array, pool_k, pool_v,
 
     - ``q``: ``(max_slots, S, n_heads, head_dim)`` queries, ``S ∈
       {1, 1 + draft_len}`` (decode / fused speculative verify);
-    - ``pool_k``/``pool_v``: ONE layer's page pool ``(n_pages,
-      page_size, kv_heads, head_dim)`` — a plain bf16/fp32 array or an
-      ``(int8 values, bf16 scales)`` pair (``make_pool`` layout);
+    - ``pool_k``/``pool_v``: ONE layer's pages with the heads split
+      out, ``(n_pages, page_size, kv_heads, head_dim)`` — a plain
+      bf16/fp32 array or an ``(int8 values, bf16 scales (..., 1))``
+      pair (the engine converts from the pool's merged rows at this
+      boundary: ``PagedEngine._kernel_pages``);
     - ``work_pages (W,)`` / ``work_refs (W, n_lanes)`` / ``work_pos
       (W,)``: the compacted live-page walk (``BlockTables.
       kernel_args()``): pool page id, holder slots (-1 empty lanes),

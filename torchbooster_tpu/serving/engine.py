@@ -33,17 +33,26 @@ Prefill/decode split — both sides compile exactly ONCE:
 
 Why the pool sweep is the length-aware read: the dense decode step
 streams ``max_slots × S_cache`` cache rows regardless of how many
-tokens each slot holds; the sweep streams ``(n_pages - 1) ×
-page_size`` rows — the pool's USABLE capacity (the reserved null page
-is statically sliced out of the read), which the operator sizes to
-expected total occupancy — and free/partial pages contribute nothing
-but masked lanes. Prefix sharing compounds it: k requests on one
-system prompt hold ONE copy of its pages, so the same pool holds more
-live requests. On an HBM-bound loop the read bytes ARE the step time
+tokens each slot holds; the sweep streams ``n_pages × page_size``
+rows — the pool, which the operator sizes to expected total occupancy
+(the reserved null page rides along, one page in ``n_pages``: slicing
+it out would keep a layer's pages from being read in place) — and
+free/partial pages contribute nothing but masked lanes. Prefix
+sharing compounds it: k requests on one system prompt hold ONE copy
+of its pages, so the same pool holds more live requests. On an HBM-bound loop the read bytes ARE the step time
 (the ``serve`` bench rows measure the ratio; ``serve_prefix`` measures
 the cache-hit TTFT and the prefill FLOPs the hits skip; a
 dense-geometry control — ``page_size=seq_len``, one page per slot —
 runs the SAME code at dense bytes).
+
+The pool itself (``kv_pages.make_pool`` owns its shape: ``(n_layers,
+n_pages, page_size, kv_width)``, heads and head dim merged into one
+128-aligned row) is donated to every program and updated IN PLACE:
+the three layer loops carry it (``kv_pages.scan_layers``), write at
+``[layer, page, offset]`` of the stacked array, and read a layer's
+pages where they lie — no program produces a second buffer the size
+of a layer's pool (tests/test_tpu_aot_compile.py holds the compiled
+decode and chunk programs to that).
 
 The compiled step's signature depends only on pool geometry
 ``(n_pages, page_size, max_slots)`` and the model config — admission,
@@ -85,7 +94,16 @@ from torchbooster_tpu.serving.kv_pages import (
     NULL_PAGE,
     BlockTables,
     HostPagePool,
+    from_rows,
+    gather_pages,
+    layer_pages,
     make_pool,
+    pool_map,
+    quantized_rows,
+    scan_layers,
+    sweep_attention,
+    to_rows,
+    write_rows,
 )
 from torchbooster_tpu.serving.tp import (
     check_tp,
@@ -345,7 +363,8 @@ class PagedEngine:
         self.chunk_tokens = self.prefill_chunk_pages * page_size
         self.pool = make_pool(cfg, page_size, n_pages,
                               cache_dtype=cache_dtype,
-                              compute_dtype=compute_dtype)
+                              compute_dtype=compute_dtype,
+                              shards=self.tp)
         # the host spill tier (PR 16): LRU eviction demotes registered
         # prefix pages to a host-DRAM pool (int8 + scales) and a later
         # seat promotes them back through ONE fixed-shape compiled
@@ -637,8 +656,9 @@ class PagedEngine:
     def dense_control(cls, params: dict, cfg: GPTConfig, *,
                       max_slots: int = 8, **kw) -> "PagedEngine":
         """The dense-bytes A/B control: identical engine, one
-        ``seq_len``-wide page per slot (+ the null page), so each step
-        streams exactly what the dense per-slot cache would."""
+        ``seq_len``-wide page per slot (+ the null page, which the
+        sweep reads too), so each step streams what the dense per-slot
+        cache would and one page more."""
         return cls(params, cfg, page_size=cfg.seq_len,
                    n_pages=max_slots + 1, max_slots=max_slots, **kw)
 
@@ -710,38 +730,34 @@ class PagedEngine:
         local = jnp.arange(C)
         vis_chunk = (local[:, None] >= local[None, :])[None, None, None]
 
-        def layer(x, inputs):
-            bp, pk, pv = inputs[:3]
+        def context(pages):
+            # a slot's gathered pages as ONE sequence (1, mp * ps,
+            # kv_heads, head_dim) per leaf
+            return pool_map(lambda a: a.reshape(1, -1, *a.shape[2:]),
+                            self._heads(pages))
+
+        def layer(x, pk, pv, bp, li, lora):
 
             def attend(q, k, v):
                 g = k.shape[2]
-                kp = k[0].reshape(n_cp, ps, g, head_dim)
-                vp = v[0].reshape(n_cp, ps, g, head_dim)
                 with jax.named_scope("kv_write"):
-                    if self.quantized:
-                        kq, k_s = _quantize_kv(kp)
-                        vq, v_s = _quantize_kv(vp)
-                        new_k = (pk[0].at[w_pages].set(kq),
-                                 pk[1].at[w_pages].set(k_s))
-                        new_v = (pv[0].at[w_pages].set(vq),
-                                 pv[1].at[w_pages].set(v_s))
-                    else:
-                        new_k = pk.at[w_pages].set(kp.astype(pk.dtype))
-                        new_v = pv.at[w_pages].set(vp.astype(pv.dtype))
-                if self.quantized:
-                    gk = tuple(a[table_row].reshape(1, mp * ps, g, -1)
-                               for a in pk)
-                    gv = tuple(a[table_row].reshape(1, mp * ps, g, -1)
-                               for a in pv)
-                else:
-                    gk = pk[table_row].reshape(1, mp * ps, g, head_dim)
-                    gv = pv[table_row].reshape(1, mp * ps, g, head_dim)
+                    new_k = write_rows(pk, (li, w_pages), self._page_rows(
+                        k[0].reshape(n_cp, ps, g, head_dim), pk))
+                    new_v = write_rows(pv, (li, w_pages), self._page_rows(
+                        v[0].reshape(n_cp, ps, g, head_dim), pv))
+                # the slot's own pages back out of the stacked pool:
+                # mp pages of this layer, not the layer's pool (read
+                # after the write, so the update stays in place; the
+                # chunk's own pages sit at positions >= start, which
+                # vis_prior masks)
+                gk = context(gather_pages(new_k, li, table_row))
+                gv = context(gather_pages(new_v, li, table_row))
                 # prior context (this slot's already-written pages,
-                # gathered PRE-write and masked to < start) and the
-                # chunk itself (compute-dtype K/V — parity with the
-                # dense prefill's un-quantized intra-prompt attention)
-                # are two flash-style partials merged online-softmax
-                # style — the same math spread over a split token axis
+                # masked to < start) and the chunk itself
+                # (compute-dtype K/V — parity with the dense prefill's
+                # un-quantized intra-prompt attention) are two
+                # flash-style partials merged online-softmax style —
+                # the same math spread over a split token axis
                 oA, mA, lA = _grouped_cache_attention(
                     q, gk, gv, vis_prior, state=True)
                 oB, mB, lB = _grouped_cache_attention(
@@ -762,15 +778,11 @@ class PagedEngine:
                                     float(cfg.n_experts)),
                 positions=positions[None],      # per-slot rope depth
                 tp_attn=self._tp_core,
-                lora=(inputs[3], lane1) if self.lora else None)
-            return x, (pk, pv)
+                lora=(lora, lane1) if self.lora else None)
+            return x, pk, pv
 
-        xs = (params["blocks"], pool_k, pool_v)
-        if self.lora:
-            # the adapter stacks scan per layer beside the block
-            # params (each xs leaf's leading axis is n_layers)
-            xs = xs + (lora_w,)
-        x, (pool_k, pool_v) = jax.lax.scan(layer, x, xs)
+        x, pool_k, pool_v = scan_layers(layer, x, pool_k, pool_v,
+                                        params["blocks"], lora_w)
         last = jax.lax.dynamic_slice_in_dim(
             x, jnp.clip(s0 - 1 - start, 0, C - 1), 1, axis=1)
         logits = _lm_head(params, last)[:, 0]
@@ -832,19 +844,19 @@ class PagedEngine:
         # iff <= that slot's current length (the token this step
         # writes lands AT ``lengths`` and must see itself; a sharer
         # mid-prompt never sees past its own depth). The sweep reads
-        # pages [1:] only — page 0 is the reserved null page
-        # (dead-slot write target, never referenced), and excluding it
-        # keeps the read at exactly the usable capacity, so the
-        # dense-geometry control streams exactly max_slots × seq_len
+        # ALL n_pages pages: page 0, the reserved null page (dead-slot
+        # write target), is never referenced, so its lanes are empty
+        # and its partials land in the trash segment like any other
+        # empty lane's — a static [1:] would keep the layer's slice
+        # of the stacked pool from fusing into the sweep's read
         if self.decode_backend == "xla":
-            refs_t = refs[1:]                   # (P, R)
-            n_lanes = refs_t.shape[1]
-            seg = jnp.where(refs_t >= 0, refs_t, n_slots).reshape(-1)
-            ref_c = jnp.clip(refs_t, 0, n_slots - 1)
-            tok_pos = page_pos[1:, None] * ps + jnp.arange(ps)[None, :]
-            ref_len = jnp.where(refs_t >= 0, lengths[ref_c], -1)
+            n_lanes = refs.shape[1]             # refs (P, R)
+            seg = jnp.where(refs >= 0, refs, n_slots).reshape(-1)
+            ref_c = jnp.clip(refs, 0, n_slots - 1)
+            tok_pos = page_pos[:, None] * ps + jnp.arange(ps)[None, :]
+            ref_len = jnp.where(refs >= 0, lengths[ref_c], -1)
             visible = tok_pos[:, None, :] <= ref_len[:, :, None]
-            # (P, R, ps) broadcast against (P, g, rep, R, ps) scores
+            # (P, R, ps): lane r of page p sees token j
 
         # this step's write target per slot: the page holding position
         # ``lengths`` — ALWAYS private (shared pages are full prompt
@@ -855,24 +867,14 @@ class PagedEngine:
         w_page = jnp.where(active, w_page, 0)
         w_off = lengths % ps
 
-        def layer(x, inputs):
-            bp, pk, pv = inputs[:3]
+        def layer(x, pk, pv, bp, li, lora):
 
             def attend(q, k, v):
                 with jax.named_scope("kv_write"):
-                    if self.quantized:
-                        (pkv, pks), (pvv, pvs) = pk, pv
-                        kq, k_s = _quantize_kv(k)
-                        vq, v_s = _quantize_kv(v)
-                        new_k = (pkv.at[w_page, w_off].set(kq[:, 0]),
-                                 pks.at[w_page, w_off].set(k_s[:, 0]))
-                        new_v = (pvv.at[w_page, w_off].set(vq[:, 0]),
-                                 pvs.at[w_page, w_off].set(v_s[:, 0]))
-                    else:
-                        new_k = pk.at[w_page, w_off].set(
-                            k[:, 0].astype(pk.dtype))
-                        new_v = pv.at[w_page, w_off].set(
-                            v[:, 0].astype(pv.dtype))
+                    new_k = write_rows(pk, (li, w_page, w_off),
+                                       self._page_rows(k[:, 0], pk))
+                    new_v = write_rows(pv, (li, w_page, w_off),
+                                       self._page_rows(v[:, 0], pv))
                 if self.decode_backend == "pallas":
                     # the in-kernel block-table walk: the kernel's
                     # grid iterates the compacted live-page list and
@@ -882,27 +884,23 @@ class PagedEngine:
                     # in VMEM scratch with the same online-softmax
                     # combine the sweep runs through segment ops
                     o = paged_attention(
-                        q, new_k, new_v, work_pages, work_refs,
-                        work_pos, lengths, page_size=ps)
+                        q, self._kernel_pages(new_k, li),
+                        self._kernel_pages(new_v, li), work_pages,
+                        work_refs, work_pos, lengths, page_size=ps)
                     return o.astype(q.dtype), (new_k, new_v)
-                # the pool sweep: each live page attends the queries
-                # of ALL its reference lanes (a gather of the TINY q
-                # tensor into (P, R, H, Dh) — the pool itself is read
-                # in place, ONCE, minus the null page: a static [1:]
-                # slice that fuses into the einsum operand read; lanes
-                # ride the query axis so sharing multiplies only the
-                # small-side compute, never the HBM stream), then
-                # (page, lane) partials merge per slot via the
-                # online-softmax combine
-                if self.quantized:
-                    rk = tuple(a[1:] for a in new_k)
-                    rv = tuple(a[1:] for a in new_v)
-                else:
-                    rk, rv = new_k[1:], new_v[1:]
+                # the pool sweep: each page attends the queries of ALL
+                # its reference lanes (a gather of the TINY q tensor
+                # into (P, R, H, Dh) — the layer's pages are read in
+                # place, ONCE, in the pool's own layout
+                # (kv_pages.sweep_attention); lanes ride the query
+                # axis so sharing multiplies only the small-side
+                # compute, never the HBM stream), then (page, lane)
+                # partials merge per slot via the online-softmax
+                # combine
                 q_lanes = q[:, 0][ref_c]        # (P, R, H, Dh)
-                o_p, m_p, l_p = _grouped_cache_attention(
-                    q_lanes, rk, rv,
-                    visible[:, None, None, :, :], state=True)
+                o_p, m_p, l_p = sweep_attention(
+                    q_lanes, layer_pages(new_k, li),
+                    layer_pages(new_v, li), visible, k.shape[2])
                 # o (P, R, g, rep, Dh); m/l (P, g, rep, R): flatten
                 # the (page, lane) pairs into one segment axis
                 n_pp = o_p.shape[0]
@@ -930,13 +928,11 @@ class PagedEngine:
                                     float(cfg.n_experts)),
                 positions=lengths[:, None],     # per-slot rope depth
                 tp_attn=self._tp_core,
-                lora=(inputs[3], lane_ids) if self.lora else None)
-            return x, (pk, pv)
+                lora=(lora, lane_ids) if self.lora else None)
+            return x, pk, pv
 
-        xs = (params["blocks"], pool_k, pool_v)
-        if self.lora:
-            xs = xs + (lora_w,)
-        x, (pool_k, pool_v) = jax.lax.scan(layer, x, xs)
+        x, pool_k, pool_v = scan_layers(layer, x, pool_k, pool_v,
+                                        params["blocks"], lora_w)
         logits = _lm_head(params, x)[:, 0]
         # constrained slots' rows knock illegal tokens to finfo.min;
         # unconstrained rows are all-True (bitwise no-op — greedy and
@@ -955,6 +951,38 @@ class PagedEngine:
             tokens, lps = self._branch_pick(keys, logits)
             return tokens, lps, pool_k, pool_v
         return self._pick(rng, logits), pool_k, pool_v
+
+    # ---- between the model's (..., kv_heads, head_dim) and the
+    # pool's rows (kv_pages.make_pool owns the layout) ----------------
+    def _page_rows(self, x, pool):
+        """New K or V ``(..., kv_heads, head_dim)`` as what
+        ``kv_pages.write_rows`` stores in ``pool``:
+        rows of the pool's width, or for an int8 pool the quantized
+        ``(rows, scales)`` pair (``_quantize_kv``, as the dense
+        quantized cache)."""
+        if isinstance(pool, tuple):
+            return quantized_rows(*_quantize_kv(x), pool[0].shape[-1])
+        return to_rows(x, pool.shape[-1])
+
+    def _heads(self, pages):
+        """A FEW gathered pages ``(n, page_size, ...)`` per leaf back
+        in ``(n, page_size, kv_heads, head_dim)`` (int8: the scales
+        get their trailing 1 back): the form ``models/gpt.py``'s
+        attention core and the pallas kernel take."""
+        head_dim = self.cfg.d_model // self.cfg.n_heads
+        kv_heads = self.cfg.kv_heads // self.tp
+        if isinstance(pages, tuple):
+            return (from_rows(pages[0], kv_heads, head_dim),
+                    pages[1][..., None])
+        return from_rows(pages, kv_heads, head_dim)
+
+    def _kernel_pages(self, pool, li):
+        """The pallas kernel's operand: one layer's pool split back
+        into heads. The kernel's blocks are ``(page_size, kv_heads,
+        head_dim)``, so this branch pays a relayout of the layer's
+        pool at its own boundary (PERF.md section 7); the default
+        backend never calls it."""
+        return self._heads(layer_pages(pool, li))
 
     def _cow_fn(self, pool_k, pool_v, src_pages, dst_pages):
         """The fork-time copy-on-write tail copy: pool page
@@ -1019,20 +1047,22 @@ class PagedEngine:
         inside a decode step. int8 pools ship their stored payload
         verbatim (a lossless round-trip); wide pools quantize here,
         mirroring ``_quantize_kv``."""
-        if self.quantized:
-            k = np.asarray(jax.device_get(self.pool["k"][0][:, p]))
-            v = np.asarray(jax.device_get(self.pool["v"][0][:, p]))
-            ks = np.asarray(jax.device_get(
-                self.pool["k"][1][:, p])).astype(np.float32)
-            vs = np.asarray(jax.device_get(
-                self.pool["v"][1][:, p])).astype(np.float32)
-        else:
-            kf = np.asarray(jax.device_get(
-                self.pool["k"][:, p])).astype(np.float32)
-            vf = np.asarray(jax.device_get(
-                self.pool["v"][:, p])).astype(np.float32)
-            k, ks = _quantize_page_np(kf)
-            v, vs = _quantize_page_np(vf)
+        head_dim = self.cfg.d_model // self.cfg.n_heads
+
+        def page(pool):
+            # (n_layers, page_size, ...) per leaf, heads split on the
+            # host: the payload's shapes do not follow the pool's
+            rows = pool_map(
+                lambda a: np.asarray(jax.device_get(a[:, p])), pool)
+            if self.quantized:
+                return (from_rows(rows[0], self.cfg.kv_heads, head_dim,
+                                  self.tp),
+                        rows[1][..., None].astype(np.float32))
+            return _quantize_page_np(from_rows(
+                rows.astype(np.float32), self.cfg.kv_heads, head_dim,
+                self.tp))
+
+        (k, ks), (v, vs) = page(self.pool["k"]), page(self.pool["v"])
         self.spills += 1
         return {"k": k, "k_scale": ks, "v": v, "v_scale": vs}
 
@@ -1052,11 +1082,11 @@ class PagedEngine:
             vals = jnp.moveaxis(q, 0, 1)   # (L, lanes, ps, H, D)
             scl = jnp.moveaxis(s, 0, 1)
             if isinstance(pool, tuple):
-                return (pool[0].at[:, dst].set(vals),
-                        pool[1].at[:, dst].set(
-                            scl.astype(pool[1].dtype)))
-            wide = (vals.astype(jnp.float32) * scl).astype(pool.dtype)
-            return pool.at[:, dst].set(wide)
+                rows = quantized_rows(vals, scl, pool[0].shape[-1])
+            else:
+                rows = to_rows(vals.astype(jnp.float32) * scl,
+                               pool.shape[-1])
+            return write_rows(pool, (slice(None), dst), rows)
 
         return write(pool_k, k_q, k_s), write(pool_v, v_q, v_s)
 

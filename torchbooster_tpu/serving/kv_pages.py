@@ -14,11 +14,30 @@ total occupancy — instead of ``max_slots × S_cache``.
 
 Two cooperating halves:
 
-- :func:`make_pool` — the device-side pool (one K and one V array per
-  layer, stacked on the leading layer axis for the ``lax.scan`` decode
-  step; bf16/fp32, or int8 + bf16 scales — the engine quantizes page
-  writes with the SAME ``_quantize_kv`` the dense ``cache_dtype=
-  "int8"`` path uses).
+- :func:`make_pool` — the device-side pool and the ONE place that
+  fixes its shape: K and V are each ``(n_layers, n_pages, page_size,
+  kv_width)``, heads and head dim merged into one minor ROW of
+  ``kv_heads * head_dim`` lanes rounded up to the device's 128-lane
+  tile (:func:`kv_width`; 1600 -> 1664 at GPT-2 XL, +4 %; 768 and 1280
+  pad nothing). bf16/fp32, or int8 rows + bf16 scales ``(n_layers,
+  n_pages, page_size, kv_heads)`` — the engine quantizes page writes
+  with the SAME ``_quantize_kv`` the dense ``cache_dtype="int8"`` path
+  uses. Why this shape: the device tiles an array's two minor axes in
+  ``(8, 128)`` tiles, so a 5-D ``(..., kv_heads, head_dim)`` pool
+  either pads its ``(25, 64)`` minor tiles 2.56 x in row-major or gets
+  the pages-minor layout the compiler picks by default, which every
+  program then relays on every touch (PERF.md, PR 25). With a minor
+  axis that is a multiple of 128 the default layout IS row-major, a
+  page is one contiguous slab, and no program needs a pinned layout.
+  Everything else reads the width from the pool: :func:`to_rows` /
+  :func:`from_rows` convert SMALL tensors (new tokens, a chunk's
+  gathered pages, spill payloads) between ``(..., kv_heads, head_dim)``
+  and pool rows; :func:`write_rows` / :func:`gather_pages` touch the
+  stacked pool at ``[layer, page, ...]`` in place; :func:`sweep_attention` is the decode / verify read, which
+  never splits the merged axis in memory; :func:`scan_layers` is the
+  layer loop of the three serving programs, with the pool CARRIED
+  (never a scan ``xs`` / ``ys``: those are two buffers and cost a copy
+  of the pool a step).
 - :class:`BlockTables` — HOST-side refcount/evict bookkeeping (plain
   integer index arithmetic on numpy arrays, nothing shape-dependent:
   seating, retiring, and evicting only change VALUES inside
@@ -70,14 +89,16 @@ a byte budget; pages that fall off its tail are gone for real.
 """
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Callable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from torchbooster_tpu.models.gpt import GPTConfig
 
 NULL_PAGE = 0
+LANES = 128     # the device tiles an array's minor axis in 128 lanes
 
 
 class PoolExhausted(RuntimeError):
@@ -90,27 +111,218 @@ class PoolExhausted(RuntimeError):
     catch THIS type and let anything else surface immediately."""
 
 
+def kv_width(kv_heads: int, head_dim: int, shards: int = 1) -> int:
+    """Lanes of one pool row: ``kv_heads * head_dim`` rounded up to a
+    whole number of 128-lane tiles — per SHARD, so that a ``tp`` split
+    of the row (serving/tp.py shards it on KV heads) hands every rank
+    a contiguous, tile-aligned slice holding its own heads."""
+    per_shard = kv_heads // shards * head_dim
+    return shards * (-(-per_shard // LANES) * LANES)
+
+
 def make_pool(cfg: GPTConfig, page_size: int, n_pages: int,
               cache_dtype: Any = None,
-              compute_dtype: Any = jnp.bfloat16) -> dict:
+              compute_dtype: Any = jnp.bfloat16,
+              shards: int = 1) -> dict:
     """Allocate the device pool: ``{"k": ..., "v": ...}`` with each
-    entry ``(n_layers, n_pages, page_size, kv_heads, head_dim)`` — a
-    plain array in ``compute_dtype``, or, when ``cache_dtype`` is
-    ``"int8"``, the ``(int8 values, bf16 scales)`` pair layout the
-    dense quantized cache uses (scales keep the trailing head dim as 1
-    for broadcasting)."""
+    entry ``(n_layers, n_pages, page_size, kv_width)`` (module
+    docstring: heads and head dim merged into one 128-aligned minor
+    row) — a plain array in ``compute_dtype``, or, when ``cache_dtype``
+    is ``"int8"``, the pair ``(int8 rows, bf16 scales (n_layers,
+    n_pages, page_size, kv_heads))``. ``shards`` is the engine's ``tp``
+    (:func:`kv_width` pads per shard)."""
     if cache_dtype not in (None, "int8", jnp.int8):
         raise ValueError(
             f"cache_dtype must be None or 'int8', got {cache_dtype!r}")
     head_dim = cfg.d_model // cfg.n_heads
-    shape = (cfg.n_layers, n_pages, page_size, cfg.kv_heads, head_dim)
+    shape = (cfg.n_layers, n_pages, page_size,
+             kv_width(cfg.kv_heads, head_dim, shards))
     if cache_dtype in ("int8", jnp.int8):
-        scale_shape = shape[:-1] + (1,)
+        scale_shape = shape[:-1] + (cfg.kv_heads,)
         mk = lambda: (jnp.zeros(shape, jnp.int8),
                       jnp.ones(scale_shape, jnp.bfloat16))
     else:
         mk = lambda: jnp.zeros(shape, compute_dtype)
     return {"k": mk(), "v": mk()}
+
+
+# ---- the pool's device-side accessors ---------------------------------
+# Everything below works on ONE half of the pool (K or V): a plain
+# array or the int8 ``(rows, scales)`` pair. ``pool_map`` applies a
+# per-leaf function to either form.
+
+def pool_map(fn: Callable, pool, *rest):
+    """``fn`` over a pool half's leaves (and the matching leaves of
+    ``rest``): the array itself, or rows and scales of an int8 pair."""
+    if isinstance(pool, tuple):
+        return tuple(fn(a, *(r[i] for r in rest))
+                     for i, a in enumerate(pool))
+    return fn(pool, *rest)
+
+
+def to_rows(x, width: int, shards: int = 1):
+    """``(..., kv_heads, head_dim)`` -> pool rows ``(..., width)``:
+    merge the two minor axes and zero-pad each shard's heads to its
+    slice of the row. For SMALL tensors (new tokens, staged pages) —
+    numpy in, numpy out; jax in, jax out."""
+    xp = np if isinstance(x, np.ndarray) else jnp
+    g, d = x.shape[-2:]
+    x = x.reshape(*x.shape[:-2], shards, g // shards * d)
+    pad = width // shards - x.shape[-1]
+    if pad:
+        x = xp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+    return x.reshape(*x.shape[:-2], width)
+
+
+def from_rows(rows, kv_heads: int, head_dim: int, shards: int = 1):
+    """Pool rows ``(..., width)`` -> ``(..., kv_heads, head_dim)``:
+    the inverse of :func:`to_rows`, for a few pages at a time (the
+    chunk's gathered context, a spill payload) — never for a layer's
+    pool, where splitting the minor axis is a relayout of all of it."""
+    lead = rows.shape[:-1]
+    rows = rows.reshape(*lead, shards, rows.shape[-1] // shards)
+    rows = rows[..., :kv_heads // shards * head_dim]
+    return rows.reshape(*lead, kv_heads, head_dim)
+
+
+def quantized_rows(q, scale, width: int):
+    """``_quantize_kv``'s ``(values (..., g, d), scales (..., g, 1))``
+    as the int8 pool's pair of leaves ``(rows, scales (..., g))``."""
+    return to_rows(q, width), scale[..., 0]
+
+
+def write_rows(pool, index: tuple, rows):
+    """``pool[index] = rows`` per leaf, in place under donation:
+    token rows at ``(layer, pages, offsets)``, whole pages at
+    ``(layer, pages)``, or pages of every layer at ``(slice(None),
+    pages)`` of the stacked pool (``rows``: the index's shape + what
+    is left of the leaf's)."""
+    return pool_map(lambda a, r: a.at[index].set(r.astype(a.dtype)),
+                    pool, rows)
+
+
+def gather_pages(pool, layer, pages):
+    """``pool[layer, pages]``: a few pages of one layer, ``(n,
+    page_size, ...)`` per leaf."""
+    return pool_map(lambda a: a[layer, pages], pool)
+
+
+def layer_pages(pool, layer):
+    """One layer's pages ``(n_pages, page_size, ...)`` per leaf: a
+    slice of the stacked pool that fuses into its consumer's operand
+    read when the consumer takes ALL pages (:func:`sweep_attention`)."""
+    return pool_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0,
+                                               keepdims=False), pool)
+
+
+def sweep_attention(q_lanes, k_pages, v_pages, visible, kv_heads: int):
+    """The decode / verify READ of one layer's pages, in the pool's
+    own layout: every page attends the queries of its reference lanes
+    and returns its flash-style partial softmax — the contract of
+    ``models.gpt._grouped_cache_attention(state=True)`` with pages as
+    the batch axis, so the caller's online-softmax merge over (page,
+    lane) partials is unchanged.
+
+    - ``q_lanes (n_pages, Q, n_heads, head_dim)``: the queries each
+      page serves (``Q`` = reference lanes x query positions);
+    - ``k_pages`` / ``v_pages``: :func:`layer_pages` of the pool,
+      ``(n_pages, page_size, width)`` rows or the int8 ``(rows,
+      scales (n_pages, page_size, kv_heads))`` pair;
+    - ``visible (n_pages, Q, page_size)``: False -> masked.
+
+    Returns ``(o (n_pages, Q, kv_heads, rep, head_dim) float32
+    unnormalised, m, l (n_pages, kv_heads, rep, Q))``.
+
+    The merged ``kv_heads * head_dim`` row is never split in memory
+    (that is a relayout of the layer's pool: PERF.md, PR 25). Instead
+    the QUERIES are laid out block-diagonally — column ``(g, q, r)``
+    of page p carries head ``(g, r)``'s query in head g's ``head_dim``
+    lanes of the row and zeros in every other head's — so scores are
+    one batched product ``rows (page_size, width) @ columns (width,
+    kv_heads * Q * rep)`` contracting the whole row: products with the
+    zeros add nothing, so each score is exactly the per-head dot, in
+    the same operand dtype with float32 accumulation. The values
+    mirror it: ``probs^T @ rows`` gives every column the weighted sum
+    of ALL heads' lanes, of which its own head's are kept. Both
+    products run on the matrix unit with the pool streamed once in its
+    storage layout; the ``kv_heads``-fold redundant arithmetic is the
+    price (small beside the pool's bytes while Q is small; ROADMAP
+    S2 / D3 weigh it for many lanes). Numerics as the dense core:
+    operands in pool dtype (``q``'s for an int8 pool, whose per-token
+    scales factor out of both products), float32 accumulation and
+    softmax, float32 probabilities into the value product."""
+    n_pages, n_q, n_heads, head_dim = q_lanes.shape
+    quantized = isinstance(k_pages, tuple)
+    k_rows, k_scale = k_pages if quantized else (k_pages, None)
+    v_rows, v_scale = v_pages if quantized else (v_pages, None)
+    page_size, width = k_rows.shape[1:]
+    rep = n_heads // kv_heads
+    dot_t = q_lanes.dtype if quantized else k_rows.dtype
+    exact = jax.lax.Precision.HIGHEST    # float32 operands stay float32
+    # own[g, c]: lane c of a row belongs to head g
+    own = jnp.pad(jnp.repeat(jnp.eye(kv_heads, dtype=bool), head_dim,
+                             axis=1),
+                  ((0, 0), (0, width - kv_heads * head_dim)))
+    # the queries as rows (P, Q * rep, width), then one column per
+    # (head g, query): that row with every other head's lanes zeroed.
+    # Broadcasts only, lanes minor throughout, and (g, query) kept as
+    # two axes of both products: merged into one, the columns and the
+    # value product's result are written out, a layer's pool in size
+    # each once Q > 1 (compiled for the v5e, PR 25); kept apart, both
+    # fuse into the products
+    q_rows = q_lanes.reshape(n_pages, n_q, kv_heads, rep, head_dim)
+    q_rows = to_rows(jnp.swapaxes(q_rows, 2, 3), width).astype(dot_t)
+    q_rows = q_rows.reshape(n_pages, 1, n_q * rep, width)
+    cols = jnp.where(own[None, :, None, :], q_rows,
+                     jnp.zeros((), dot_t))
+    scores = jnp.einsum(
+        "pjc,pgqc->pjgq", k_rows.astype(dot_t), cols, precision=exact,
+        preferred_element_type=jnp.float32) / (head_dim ** 0.5)
+    scores = scores.reshape(n_pages, page_size, kv_heads, n_q, rep)
+    if quantized:
+        scores = scores * k_scale[..., None, None]
+    vis = jnp.transpose(visible, (0, 2, 1))[:, :, None, :, None]
+    scores = jnp.where(vis, scores, -1e30)
+    m = jnp.max(scores, axis=1)                   # (P, g, Q, rep)
+    probs = jnp.exp(scores - m[:, None])
+    l = jnp.sum(probs, axis=1)
+    if quantized:
+        probs = (probs * v_scale[..., None, None]).astype(dot_t)
+        values = v_rows.astype(dot_t)
+    else:
+        values = v_rows.astype(jnp.float32)
+    o = jnp.einsum(
+        "pjgq,pjc->pgqc",
+        probs.reshape(n_pages, page_size, kv_heads, n_q * rep),
+        values, precision=exact, preferred_element_type=jnp.float32)
+    # (P, g, Q * rep, width): keep head g's lanes of column (g, .)
+    o = jnp.sum(jnp.where(own[None, :, None, :], o, 0.0), axis=1)
+    o = from_rows(o, kv_heads, head_dim).reshape(
+        n_pages, n_q, rep, kv_heads, head_dim)
+    o = jnp.transpose(o, (0, 1, 3, 2, 4))
+    to_state = lambda t: jnp.transpose(t, (0, 1, 3, 2))
+    return o, to_state(m), to_state(l)
+
+
+def scan_layers(layer: Callable, x, pool_k, pool_v, blocks,
+                lora_w=None):
+    """The layer loop of the three serving programs (prefill chunk,
+    decode, speculative verify). ``layer(x, pool_k, pool_v, bp, li,
+    lora) -> (x, pool_k, pool_v)`` runs one block: ``bp`` that layer's
+    weights, ``li`` its index into the STACKED pool, ``lora`` its
+    adapter stacks (None without ``lora_w``). The pool is loop-CARRIED
+    beside ``x`` and updated in place at ``[li, ...]``; only the
+    weights (and adapters) are scanned. Returns ``(x, pool_k,
+    pool_v)``."""
+    n_layers = jax.tree.leaves(blocks)[0].shape[0]
+
+    def body(carry, inputs):
+        return layer(*carry, *inputs), None
+
+    carry, _ = jax.lax.scan(body, (x, pool_k, pool_v),
+                            (blocks, jnp.arange(n_layers), lora_w))
+    return carry
 
 
 class HostPagePool:
@@ -945,4 +1157,6 @@ class BlockTables:
 
 
 __all__ = ["BlockTables", "HostPagePool", "NULL_PAGE", "PoolExhausted",
-           "make_pool"]
+           "from_rows", "gather_pages", "kv_width", "layer_pages",
+           "make_pool", "pool_map", "quantized_rows", "scan_layers",
+           "sweep_attention", "to_rows", "write_rows"]

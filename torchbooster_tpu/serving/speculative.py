@@ -61,14 +61,18 @@ import numpy as np
 from torchbooster_tpu.models import layers as L
 from torchbooster_tpu.models.gpt import (
     _block_core,
-    _grouped_cache_attention,
     _lm_head,
     _make_spec_pick,
     _mask_logits,
-    _quantize_kv,
 )
 from torchbooster_tpu.ops.paged_attention import paged_attention
-from torchbooster_tpu.serving.kv_pages import NULL_PAGE
+from torchbooster_tpu.serving.kv_pages import (
+    NULL_PAGE,
+    layer_pages,
+    scan_layers,
+    sweep_attention,
+    write_rows,
+)
 
 # "no proposal" marker in a fixed-width draft row: the verify step
 # never accepts it (ids are non-negative) and its fallback pick is an
@@ -419,14 +423,15 @@ def make_verify_fn(engine):
             # the trash segment. (The pallas backend carries the same
             # (slot, position) state in kernel scratch — the mask rule
             # below lives in the kernel verbatim.)
-            refs_t = refs[1:]                             # (P, R)
-            n_lanes = refs_t.shape[1]
-            ref_c = jnp.clip(refs_t, 0, n_slots - 1)
-            seg = jnp.where(refs_t[:, :, None] >= 0,
+            # (all n_pages pages, the never-referenced null page
+            # included: engine._decode_fn says why)
+            n_lanes = refs.shape[1]                       # refs (P, R)
+            ref_c = jnp.clip(refs, 0, n_slots - 1)
+            seg = jnp.where(refs[:, :, None] >= 0,
                             ref_c[:, :, None] * S + jnp.arange(S),
                             n_slots * S).reshape(-1)
-            tok_pos = page_pos[1:, None] * ps + jnp.arange(ps)[None, :]
-            ref_len = jnp.where(refs_t >= 0, lengths[ref_c], -1)
+            tok_pos = page_pos[:, None] * ps + jnp.arange(ps)[None, :]
+            ref_len = jnp.where(refs >= 0, lengths[ref_c], -1)
             if not tree:
                 # position j's query sees absolute positions <=
                 # lengths + j: j = 0 is exactly the decode step's mask
@@ -456,8 +461,7 @@ def make_verify_fn(engine):
                            | (((off > 0) & (off < S))[:, :, None, :]
                               & sel)).reshape(-1, n_lanes * S, ps)
 
-        def layer(x, inputs):
-            bp, pk, pv = inputs[:3]
+        def layer(x, pk, pv, bp, li, lora):
 
             def attend(q, k_new, v_new):
                 # q/k_new/v_new (n_slots, S, heads, Dh): write ALL
@@ -466,24 +470,10 @@ def make_verify_fn(engine):
                 # dtype, byte-identical to what S sequential
                 # non-speculative steps would have read
                 with jax.named_scope("kv_write"):
-                    if engine.quantized:
-                        (pkv, pks), (pvv, pvs) = pk, pv
-                        kq, k_s = _quantize_kv(k_new)
-                        vq, v_s = _quantize_kv(v_new)
-                        new_k = (pkv.at[w_page, w_off].set(kq),
-                                 pks.at[w_page, w_off].set(k_s))
-                        new_v = (pvv.at[w_page, w_off].set(vq),
-                                 pvs.at[w_page, w_off].set(v_s))
-                    else:
-                        new_k = pk.at[w_page, w_off].set(
-                            k_new.astype(pk.dtype))
-                        new_v = pv.at[w_page, w_off].set(
-                            v_new.astype(pv.dtype))
-                if engine.quantized:
-                    rk = tuple(a[1:] for a in new_k)
-                    rv = tuple(a[1:] for a in new_v)
-                else:
-                    rk, rv = new_k[1:], new_v[1:]
+                    new_k = write_rows(pk, (li, w_page, w_off),
+                                       engine._page_rows(k_new, pk))
+                    new_v = write_rows(pv, (li, w_page, w_off),
+                                       engine._page_rows(v_new, pv))
                 if engine.decode_backend == "pallas":
                     # the fused kernel pass: all S verify positions
                     # ride the kernel's query-block axis, so ONE
@@ -493,19 +483,20 @@ def make_verify_fn(engine):
                     # position) state keying are the kernel's own
                     # (ops/paged_attention.py)
                     o = paged_attention(
-                        q, new_k, new_v, work_pages, work_refs,
-                        work_pos, lengths, page_size=ps,
+                        q, engine._kernel_pages(new_k, li),
+                        engine._kernel_pages(new_v, li), work_pages,
+                        work_refs, work_pos, lengths, page_size=ps,
                         tree_vis=t_vis if tree else None)
                     return o.astype(q.dtype), (new_k, new_v)
                 # ONE pool read serves all S positions of every lane:
                 # queries gather to (P, R·S, H, Dh) — the small side —
                 # while the pool stream stays exactly the decode
-                # step's bytes (minus the statically-sliced null page)
+                # step's bytes
                 q_lanes = q[ref_c].reshape(
                     ref_c.shape[0], n_lanes * S, n_heads_l, head_dim)
-                o_p, m_p, l_p = _grouped_cache_attention(
-                    q_lanes, rk, rv,
-                    visible[:, None, None, :, :], state=True)
+                o_p, m_p, l_p = sweep_attention(
+                    q_lanes, layer_pages(new_k, li),
+                    layer_pages(new_v, li), visible, k_new.shape[2])
                 n_pp = o_p.shape[0]
                 o_f = o_p.reshape(n_pp * n_lanes * S, *o_p.shape[2:])
                 m_f = jnp.moveaxis(m_p, -1, 1).reshape(
@@ -531,16 +522,13 @@ def make_verify_fn(engine):
                                     float(cfg.n_experts)),
                 positions=pos_c,                # per-slot rope depths
                 tp_attn=engine._tp_core,
-                lora=(inputs[3], lane_ids) if engine.lora else None)
-            return x, (pk, pv)
+                lora=(lora, lane_ids) if engine.lora else None)
+            return x, pk, pv
 
-        xs = (params["blocks"], pool_k, pool_v)
-        if engine.lora:
-            # per-layer adapter stacks scan beside the block params —
-            # the verify sweep applies the SAME slot lanes the decode
-            # step does, so accepted drafts are adapter-consistent
-            xs = xs + (lora_w,)
-        x, (pool_k, pool_v) = jax.lax.scan(layer, x, xs)
+        # the verify sweep applies the SAME slot lanes the decode
+        # step does, so accepted drafts are adapter-consistent
+        x, pool_k, pool_v = scan_layers(layer, x, pool_k, pool_v,
+                                        params["blocks"], lora_w)
         logits = _lm_head(params, x)            # (n_slots, S, vocab)
         # structured: mask every position's logits with its automaton
         # row BEFORE the pick/accept rule, so fallback and bonus

@@ -51,10 +51,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from torchbooster_tpu.parallel.sharding import path_str
 
-# the page pool's layout: (n_layers, n_pages, page_size, kv_heads,
-# head_dim) sharded on the KV-HEAD axis (int8 pools are (values,
-# scales) pairs whose trailing dims agree, so one spec serves both)
-POOL_SPEC = P(None, None, None, "tp", None)
+# the page pool (kv_pages.make_pool): (n_layers, n_pages, page_size,
+# kv_width), heads and head dim merged into one minor row that is
+# padded per tp shard — so sharding the row IS sharding the KV heads,
+# each rank's slice contiguous (an int8 pool's scales end in kv_heads,
+# so one spec serves both leaves)
+POOL_SPEC = P(None, None, None, "tp")
 REP = P()
 
 
