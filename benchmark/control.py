@@ -15,7 +15,11 @@ cell's program readings come with every ordinary run) and once for
   rest), and the exchange between chips left out (each chip's gradient
   is of its own rows only: one chip's share of the batch). A step that
   returns its state unchanged reads exactly 1 on ``delta_gap`` by the
-  measure's definition and needs no run.
+  measure's definition and needs no run;
+- a serve cell's **fault**: every token altered where it is produced
+  (``t + 1``, as ``tests/test_run.py`` plants it under the batcher: the
+  engine keeps its own tokens, the reader gets the altered ones), read
+  on the sample the run checked.
 
 Every reading goes through the harness's own comparison: each row holds
 the numbers beside the cell's limits, and ``verdicts`` says what
@@ -69,31 +73,36 @@ def train_readings(cfg: dict, traffic: dict, seed: int, devices,
 
 def serve_control(records: list[dict], requests: list[dict], cfg: dict,
                   traffic: dict, seed: int, w: dict) -> dict:
-    """On the sample a run checks: the program's widest gap, and the
-    widest gap of the token the float8 forward puts first, each beside
-    the cell's limit."""
+    """On the sample a run checks: the program's widest gap, the
+    widest gap of the token the float8 forward puts first, and the
+    widest gap once every served token is altered, each beside the
+    cell's limit."""
     from jobs import serve
     from reference import gpt2
 
     by_id = {r["id"]: r for r in requests}
-    program_gap = control_gap = 0.0
+    program_gap = control_gap = altered_gap = 0.0
     flips = tokens = 0
+    eps, pad = cfg["layer_norm_epsilon"], cfg["n_positions"]
     for rec in serve.pick_sample(records, seed, traffic["check_requests"]):
-        args = (w, by_id[rec["id"]]["prompt"], rec["tokens"], cfg["n_head"])
-        got = gpt2.served_gaps(*args, cfg["layer_norm_epsilon"],
-                               pad_to=cfg["n_positions"])
-        low = gpt2.control_gaps(*args, gpt2.fp8, cfg["layer_norm_epsilon"],
-                                pad_to=cfg["n_positions"])
+        prompt = by_id[rec["id"]]["prompt"]
+        args = (w, prompt, rec["tokens"], cfg["n_head"])
+        got = gpt2.served_gaps(*args, eps, pad_to=pad)
+        low = gpt2.control_gaps(*args, gpt2.fp8, eps, pad_to=pad)
+        off = gpt2.served_gaps(
+            w, prompt, [(t + 1) % cfg["vocab_size"] for t in rec["tokens"]],
+            cfg["n_head"], eps, pad_to=pad)
         program_gap = max(program_gap, float(got.max()))
         control_gap = max(control_gap, float(low.max()))
+        altered_gap = max(altered_gap, float(off.max()))
         flips += int((low > 0).sum())
         tokens += len(rec["tokens"])
     limit = traffic["limits"]["served_gap_max"]
-    return {"program": {"served_gap_max": {"value": program_gap,
-                                           "limit": limit}},
-            "control_fp8": {"served_gap_max": {"value": control_gap,
-                                               "limit": limit},
-                            "tokens_off_best": flips, "tokens": tokens}}
+    held = lambda value: {"served_gap_max": {"value": value, "limit": limit}}
+    return {"program": held(program_gap),
+            "control_fp8": {**held(control_gap),
+                            "tokens_off_best": flips, "tokens": tokens},
+            "altered_token": held(altered_gap)}
 
 
 def verdicts(row: dict) -> dict:

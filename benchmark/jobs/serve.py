@@ -31,6 +31,8 @@ import weights  # noqa: E402
 from loadgen import plan as loadplan  # noqa: E402
 
 CLIENT = HERE / "loadgen" / "client.py"
+# the pooled gaps' percentiles a run's log and a sweep's row both give
+GAP_PERCENTILES = (50, 75, 90, 92, 95, 97.5, 98, 99)
 
 
 # ---------------------------------------------------------------------
@@ -71,6 +73,72 @@ def gap_histogram(gaps: list[float], width_ms: float = 10.0) -> list:
     edges = np.arange(0.0, ms.max() + width_ms, width_ms)
     counts, _ = np.histogram(ms, edges)
     return [[float(e), int(c)] for e, c in zip(edges, counts) if c]
+
+
+def gap_modes(gaps: list[float], bin_ms: float = 1.0) -> dict:
+    """Where the pooled gaps' two modes part. A gap is a decode step, or
+    a decode step behind one prefill chunk: two peaks of a 1 ms
+    histogram (up to the 99.5th percentile, smoothed over three bins).
+    The second peak is the tallest bin, four bins or more from the
+    tallest, that a valley under half its own height parts from it;
+    ``split_ms`` is the bottom of that valley. Gives the two peaks, the
+    split, the share of gaps above it and on which side p92, p95 and p98
+    fall (``tail_in_one_mode``: a p95 with both neighbours on its side
+    lies inside a mode, not on the jump between them). One peak only:
+    ``split_ms`` None, and the tail lies in one mode by definition."""
+    ms = np.asarray(gaps, np.float64) * 1e3
+    if len(ms) < 20:
+        return {}
+    edges = np.arange(0.0, np.percentile(ms, 99.5) + 2 * bin_ms, bin_ms)
+    counts, _ = np.histogram(ms, edges)
+    smooth = np.convolve(counts, np.ones(3) / 3.0, "same")
+    first = int(np.argmax(smooth))
+    second = valley = None
+    for j in np.argsort(-smooth):
+        j = int(j)
+        if abs(j - first) < 4 or smooth[j] < 0.01 * smooth[first]:
+            continue
+        lo, hi = sorted((first, j))
+        k = lo + int(np.argmin(smooth[lo:hi + 1]))
+        if smooth[k] < 0.5 * smooth[j]:
+            second, valley = j, k
+            break
+    tail = {str(q): float(np.percentile(ms, q)) for q in (92, 95, 98)}
+    out = {"peak_ms": float(edges[first] + bin_ms / 2), "split_ms": None,
+           "upper_share": None, "tail_ms": tail, "tail_in_one_mode": True}
+    if second is None:
+        return out
+    split = float(edges[valley] + bin_ms / 2)
+    sides = {v > split for v in tail.values()}
+    out.update(
+        peak_ms=float(edges[min(first, second)] + bin_ms / 2),
+        upper_peak_ms=float(edges[max(first, second)] + bin_ms / 2),
+        split_ms=split, upper_share=float((ms > split).mean()),
+        tail_in_one_mode=len(sides) == 1)
+    return out
+
+
+def gap_median_by_slice(records: list[dict], lo: float, hi: float,
+                        width_s: float = 5.0) -> list[float]:
+    """Median gap, in ms, of each ``width_s`` slice of the window (by
+    when the gap ended): tells a run that was slow throughout from one
+    that was held up for a while."""
+    slices: list[list[float]] = [[] for _ in range(
+        max(int(np.ceil((hi - lo) / width_s)), 1))]
+    for rec in records:
+        times = rec["times"]
+        for i in range(1, len(times)):
+            if lo <= times[i] < hi:
+                slices[int((times[i] - lo) // width_s)].append(
+                    times[i] - times[i - 1])
+    return [float(np.median(g)) * 1e3 if g else None for g in slices]
+
+
+def lifetimes(records: list[dict], lo: float, hi: float) -> list[float]:
+    """Seconds from due to last token of the finished requests that
+    were due in ``[lo, hi)``: what a pre-roll has to cover."""
+    return [r["times"][-1] - r["due"] for r in records
+            if r["finished"] and r["times"] and lo <= r["due"] < hi]
 
 
 # ---------------------------------------------------------------------
@@ -279,12 +347,16 @@ def run(ctx) -> dict:
         "n_gaps": len(win["gaps"]), "n_ttft": len(win["ttfts"]),
         "gap_ms_percentiles": {
             str(q): loadplan.pooled_percentile(win["gaps"], q) * 1e3
-            for q in (50, 75, 90, 95, 97.5, 99)},
+            for q in GAP_PERCENTILES},
+        "gap_modes": gap_modes(win["gaps"]),
+        "gap_p50_ms_by_5s": gap_median_by_slice(records, lo, hi),
         "gap_histogram_10ms": gap_histogram(win["gaps"]),
         "per_request": [
             {"id": r["id"], "due": round(r["due"], 3),
              "prompt": r["prompt_len"], "asked": by_id[r["id"]]["max_tokens"],
              "got": len(r["tokens"]), "status": r["status"],
+             "late": (round(r["sent"] - r["due"], 4)
+                      if r["sent"] is not None else None),
              "ttft": (round(r["times"][0] - r["due"], 4)
                       if r["times"] else None),
              "finished": r["finished"], "aborted": r["aborted"],

@@ -10,7 +10,14 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 import flops  # noqa: E402,F401
 import trace_reduce  # noqa: E402,F401
+import xplane_scopes  # noqa: E402
 from loadgen.plan import pooled_percentile  # noqa: E402,F401
+
+
+def scoped_trace(layers: dict):
+    """The run's trace with the ops' name stacks, from the path
+    ``run.py`` puts into ``layers``; None where there is none."""
+    return xplane_scopes.load(layers.get("trace_path"))
 
 
 def median_ms(seconds: list[float]) -> float | None:

@@ -2,10 +2,10 @@
 least: the largest (host enqueue start - device run start) over the
 program runs both sides stamped with one ``run_id``. A health reading
 for whoever lays host spans over device time."""
-import _lib  # noqa: F401  (puts benchmark/ on the path)
+from _lib import scoped_trace     # puts benchmark/ on the path
 import xplane_scopes
 
 
 def read(name: str, layers: dict):
-    lead = xplane_scopes.clock_lead_seconds(xplane_scopes.load())
+    lead = xplane_scopes.clock_lead_seconds(scoped_trace(layers))
     return None if lead is None else lead * 1e3

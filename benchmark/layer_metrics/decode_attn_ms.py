@@ -2,10 +2,10 @@
 ``attn_core`` scope (scores, softmax, values, with the read of the
 pool; the K/V write nested in it is ``decode_kv_write_ms``): median
 over the traced runs, from the ops' ``tf_op`` name stacks."""
-import _lib  # noqa: F401  (puts benchmark/ on the path)
+from _lib import scoped_trace     # puts benchmark/ on the path
 import xplane_scopes
 
 
 def read(name: str, layers: dict):
-    return xplane_scopes.median_scope_ms(xplane_scopes.load(),
+    return xplane_scopes.median_scope_ms(scoped_trace(layers),
                                          "decode_fn", ("attn_core",))

@@ -3,12 +3,12 @@ stream the weights (``embed``, ``attn_qkv``, ``attn_out``, ``mlp``,
 ``head``): median over the traced runs. Its floor is every weight once
 at the chip's memory bandwidth (gpt2-xl in bf16: 3.1 GB at 819 GB/s =
 3.8 ms)."""
-import _lib  # noqa: F401  (puts benchmark/ on the path)
+from _lib import scoped_trace     # puts benchmark/ on the path
 import xplane_scopes
 
 WEIGHTS = ("embed", "attn_qkv", "attn_out", "mlp", "head")
 
 
 def read(name: str, layers: dict):
-    return xplane_scopes.median_scope_ms(xplane_scopes.load(),
+    return xplane_scopes.median_scope_ms(scoped_trace(layers),
                                          "decode_fn", WEIGHTS)

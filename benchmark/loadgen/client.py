@@ -38,7 +38,7 @@ async def one_request(plan: dict, req: dict, rec: dict) -> None:
         head = await reader.readuntil(b"\r\n\r\n")
         rec["status"] = int(head.split(b" ", 2)[1])
         if rec["status"] != 200:
-            rec["error"] = (await reader.read())[:200].decode("replace")
+            rec["error"] = (await reader.read())[:200].decode("utf-8", "replace")
             return
         while True:
             line = await reader.readline()

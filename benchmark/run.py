@@ -213,6 +213,7 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
 
     import flops
     import trace_reduce
+    import xplane_scopes
 
     manifest, cell, cfg, traffic = resolve(workload, root)
     if devices is None:
@@ -242,17 +243,23 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
             "attempted": int(result["attempted"]),
             "failed": int(result["failed"])}
     if trace:
-        reduced = trace_reduce.reduce(
-            trace_reduce.find_xplane(str(ctx.trace_dir)))
+        trace_path = trace_reduce.find_xplane(str(ctx.trace_dir))
+        reduced = trace_reduce.reduce(trace_path)
         lo, hi = trace_reduce.window_of(reduced)
         device["busy_s"] = trace_reduce.busy_seconds(reduced, lo, hi)
         device["window_s"] = hi - lo
-        layers = {**result["layers"], "trace": reduced, "cfg": cfg,
+        layers = {**result["layers"], "trace": reduced,
+                  "trace_path": trace_path, "cfg": cfg,
                   "peaks": peaks, "chips": cell["chips"],
                   "busy_s": device["busy_s"], "window_s": hi - lo}
         line["metrics"] = read_layer_metrics(
             metrics_of(manifest, workload, "per_layer"), layers, dirs)
-        line["breakdown"] = trace_reduce.breakdown(reduced)
+        scoped = xplane_scopes.load(trace_path)
+        line["breakdown"] = trace_reduce.breakdown(
+            reduced, device_ops=xplane_scopes.top_ops(scoped))
+        if scoped is not None and scoped.devices:
+            # the builder's tables, PERF.md's "where the time goes"
+            print(xplane_scopes.report(scoped), file=sys.stderr, flush=True)
         result["log"]["programs"] = trace_reduce.module_summary(reduced)
         shutil.rmtree(ctx.trace_dir, ignore_errors=True)
     else:

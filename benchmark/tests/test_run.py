@@ -164,9 +164,36 @@ def test_serve_control_reads_above_the_program(serve_out):
     row = control.serve_control(serve_out["records"], requests, cfg,
                                 traffic, seed, w)
     # through the harness's own comparison, as a run's numbers go
-    assert control.verdicts(row) == {"program": True, "control_fp8": False}
+    assert control.verdicts(row) == {"program": True, "control_fp8": False,
+                                     "altered_token": False}
     assert row["control_fp8"]["served_gap_max"]["value"] > \
         3 * row["program"]["served_gap_max"]["value"]
+
+
+# ---- where a tail sits between the gap's two modes -------------------
+
+def test_gap_modes_find_the_split_and_say_where_the_tail_lies():
+    import numpy as np
+
+    from jobs import serve
+
+    rng = np.random.default_rng(0)
+    plain = rng.normal(21e-3, 0.8e-3, 8000)      # a decode step
+    behind = rng.normal(35e-3, 1.5e-3, 2000)     # ... behind a chunk
+    modes = serve.gap_modes(np.concatenate([plain, behind]).tolist())
+    assert 20 <= modes["peak_ms"] <= 22 and 34 <= modes["upper_peak_ms"] <= 36
+    assert 24 <= modes["split_ms"] <= 32
+    assert modes["upper_share"] == pytest.approx(0.2, abs=0.005)
+    assert modes["tail_in_one_mode"] is True     # p92, p95, p98 all above
+    # 6 % of gaps behind a chunk: p92 lies in the lower mode, p95 and
+    # p98 in the upper: the p95 sits within three percentiles of the jump
+    few = serve.gap_modes(np.concatenate([plain, behind[:510]]).tolist())
+    assert few["tail_in_one_mode"] is False
+    assert few["tail_ms"]["92"] < few["split_ms"] < few["tail_ms"]["95"]
+    # one mode: nothing to split, and a tail cannot straddle it
+    one = serve.gap_modes(plain.tolist())
+    assert one["split_ms"] is None and one["tail_in_one_mode"] is True
+    assert serve.gap_modes([0.02] * 5) == {}
 
 
 # ---- a later PR adds files and entries, and edits nothing -----------

@@ -49,6 +49,19 @@ def test_a_named_ops_time_and_the_spans(synthetic):
     assert tr.module_seconds(synthetic, "decode") == [pytest.approx(6 * US)]
 
 
+def test_breakdown_takes_the_ranking_made_from_the_name_stacks(synthetic):
+    # run.py hands over xplane_scopes.top_ops: program, scope and the
+    # end of tf_op beside the compiler's name; the idle gaps stay
+    named = [[f"decode_fn/mlp/dot_general fusion.{i} bf16[8,128]", 12.0 - i]
+             for i in range(12)]
+    out = tr.breakdown(synthetic, device_ops=named)
+    assert out["device_ops"] == named[:10]
+    assert out["idle_gaps"] == tr.breakdown(synthetic)["idle_gaps"]
+    # nothing to take (a trace with no name stacks): the compiler's names
+    assert tr.breakdown(synthetic, device_ops=[])["device_ops"] == \
+        tr.breakdown(synthetic)["device_ops"]
+
+
 def test_gaps_go_to_the_innermost_host_span_under_way(synthetic):
     gaps = tr.idle_gaps(synthetic, min_gap=0.5 * US)
     named = [(round((b - a) / US, 3), name) for a, b, name in gaps]

@@ -191,6 +191,41 @@ def test_shares_clock_and_idle_time(synthetic):
     assert "attn_core" in xs.report(synthetic)
 
 
+def test_top_ops_name_program_scope_and_the_stacks_end(synthetic):
+    top = xs.top_ops(synthetic)
+    assert len(top) <= 10 and top == sorted(top, key=lambda kv: -kv[1])
+    by_name = dict(top)
+    # fusion.1 is two ops: attn_core in the decode program (4 + 6 us),
+    # kv_write in the chunk program (3 us); the while is left out
+    assert by_name[
+        "decode_fn/attn_core/dot_general fusion.1 bf16[8,128]"] == \
+        pytest.approx(10 * US)
+    assert by_name[
+        "chunk_fn/kv_write/scatter fusion.1 bf16[8,128]"] == \
+        pytest.approx(3 * US)
+    assert by_name[
+        "chunk_fn/attn_core.bwd/dot_general fusion.4 bf16[8,128]"] == \
+        pytest.approx(4 * US)
+    assert by_name["chunk_fn/embed/gather fusion.5 bf16[8,128]"] == \
+        pytest.approx(3 * US)
+    assert not any("while" in name for name in by_name)
+    # every op of the device is ranked once: 37 us
+    assert sum(v for _, v in xs.top_ops(synthetic, top=99)) == \
+        pytest.approx(37 * US)
+    assert xs.top_ops(None) == []
+
+
+def test_top_ops_on_the_recorded_traces():
+    top = xs.top_ops(xs.load(DATA / "scoped_tpu.xplane.pb"))
+    assert top[0][0] == "toy_step_fn/mlp/dot_general fusion.101 bf16[512,512]"
+    assert {name.split("/")[1] for name, _ in top} >= {
+        "mlp", "mlp.bwd", "attn_core.bwd", "attn_core.remat", "optimizer"}
+    # a program without a scope: the compiler's name still says what
+    top = xs.top_ops(xs.load(DATA / "tiny_tpu.xplane.pb"))
+    assert top[0][0] == "toy/unscoped/dot_general fusion bf16[1024,1024]"
+    assert top[0][1] == pytest.approx(50.5e-6, rel=1e-2)
+
+
 def test_no_trace_kept_reads_nothing(tmp_path, monkeypatch):
     monkeypatch.setattr(xs, "BENCH_OUT", tmp_path / ".bench_out")
     assert xs.newest_trace() is None and xs.load() is None
@@ -251,6 +286,27 @@ def read_one(name: str, layers: dict):
 @pytest.mark.parametrize("name", sorted(TRACE_READINGS))
 def test_trace_reader(name, kept_trace):
     assert read_one(name, {}) == pytest.approx(TRACE_READINGS[name])
+
+
+PATH_READERS = ["chunk_attn_ms.lat", "chunk_kv_write_ms.lat",
+                "clock_lead_ms.lat", "decode_attn_ms.lat",
+                "decode_kv_write_ms.lat", "decode_weights_ms.lat"]
+
+
+@pytest.mark.parametrize("name", PATH_READERS)
+def test_reader_takes_the_trace_from_the_path_in_layers(
+        name, tmp_path, monkeypatch):
+    """``run.py`` puts ``trace_path`` into ``layers``: the serve readers
+    read THAT file and hunt for no other (the train cells' readers are
+    as they were and still take the newest kept trace)."""
+    monkeypatch.setattr(xs, "BENCH_OUT", tmp_path / "none")
+    path = tmp_path / "elsewhere" / "host.xplane.pb"
+    path.parent.mkdir()
+    path.write_bytes(synthetic_bytes())
+    assert read_one(name, {}) is None
+    assert read_one(name, {"trace_path": str(path)}) == \
+        pytest.approx(TRACE_READINGS[name])
+    assert read_one(name, {"trace_path": str(tmp_path / "gone.pb")}) is None
 
 
 @pytest.mark.parametrize("name", sorted(REGISTRY_READINGS))

@@ -69,7 +69,7 @@ def find_xplane(trace_dir: str) -> str:
     return found[-1]
 
 
-def _label(name: str) -> str:
+def hlo_label(name: str) -> str:
     """An op's short name with its result's type and shape. On the TPU
     an op event is named by its whole HLO line
     (``%copy.29 = bf16[1,256,64,25,64]{...} copy(...)``); the next
@@ -106,7 +106,7 @@ def from_profile(data) -> Trace:
                     if ev.duration_ns > 0:
                         ops.append((ev.start_ns * 1e-9,
                                     (ev.start_ns + ev.duration_ns) * 1e-9,
-                                    _label(ev.name)))
+                                    hlo_label(ev.name)))
             ops.sort()
             mods = trace.modules.setdefault(plane.name, [])
             for ln in lines:
@@ -265,19 +265,26 @@ def idle_gaps(trace: Trace, min_gap: float = 50e-6,
     return out
 
 
-def breakdown(trace: Trace, top: int = 10, min_gap: float = 50e-6) -> dict:
+def breakdown(trace: Trace, top: int = 10, min_gap: float = 50e-6,
+              device_ops: list | None = None) -> dict:
     """The ops that took most device time and the host activities under
-    the longest idle time, ``[[name, seconds], ...]``."""
-    by_op: dict[str, float] = defaultdict(float)
-    for a, b, name in trace.ops[trace.devices[0]]:
-        if not CONTAINER_OP.match(name):
-            by_op[name] += b - a
+    the longest idle time, ``[[name, seconds], ...]``. ``device_ops``:
+    the same ranking made where the ops' name stacks can be read
+    (``xplane_scopes.top_ops``: program, scope and the end of ``tf_op``
+    beside the compiler's name); taken where it is given and not
+    empty, else the ops are ranked here by the compiler's names."""
+    rank = lambda d: [[k, v] for k, v in sorted(
+        d.items(), key=lambda kv: -kv[1])[:top]]
+    if not device_ops:
+        by_op: dict[str, float] = defaultdict(float)
+        for a, b, name in trace.ops[trace.devices[0]]:
+            if not CONTAINER_OP.match(name):
+                by_op[name] += b - a
+        device_ops = rank(by_op)
     by_gap: dict[str, float] = defaultdict(float)
     for a, b, name in idle_gaps(trace, min_gap):
         by_gap[name] += b - a
-    rank = lambda d: [[k, v] for k, v in sorted(
-        d.items(), key=lambda kv: -kv[1])[:top]]
-    return {"device_ops": rank(by_op), "idle_gaps": rank(by_gap)}
+    return {"device_ops": device_ops[:top], "idle_gaps": rank(by_gap)}
 
 
 def describe(path: str, limit: int = 6) -> str:

@@ -42,11 +42,16 @@ What it gives the readers in ``layer_metrics/``:
   by hand: ``python benchmark/xplane_scopes.py [trace]``): the
   builder's view for PERF.md section 5.
 
-``run.py`` hands readers the reduced trace and no path, so
-:func:`load` takes the newest ``*.xplane.pb`` under
-``<checkout>/.bench_out/*/trace/`` (readers run before ``run.py``
-deletes it) and returns None where there is none: the reader then
-returns None and the metric is left out of the line.
+``run.py`` hands readers the trace's path as ``layers["trace_path"]``
+and they give it to :func:`load`. Called without a path (a reader
+written before that key was there) it takes the newest ``*.xplane.pb``
+under ``<checkout>/.bench_out/*/trace/`` (readers run before ``run.py``
+deletes it). Where there is no trace it returns None: the reader then
+returns None and the metric is left out of the line. ``run.py`` also
+takes its ``breakdown``'s device operations from here
+(:func:`top_ops`): program, scope and the end of the name stack beside
+the compiler's name, since ``fusion.208`` alone tells the next reader
+nothing.
 """
 from __future__ import annotations
 
@@ -59,6 +64,10 @@ import sys
 from collections import defaultdict
 from dataclasses import dataclass, field
 from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parent))
+
+import trace_reduce  # noqa: E402  (stdlib only until it reads a trace)
 
 BENCH_OUT = Path(__file__).resolve().parent.parent / ".bench_out"
 
@@ -325,15 +334,11 @@ def newest_trace() -> Path | None:
 _CACHE: dict[Path, tuple[float, Scoped]] = {}
 
 
-def load(path: Path | None = None) -> Scoped | None:
-    """The decoded trace at ``path``, or of the run in progress (the
-    newest kept trace), or None where there is none. Decoded once per
-    file: thirteen readers ask. The run in progress also gets its
-    :func:`report` on standard error, once: ``run.py`` deletes the
-    trace after the readers, and the tables are what PERF.md's "where
-    the time goes" is written from."""
-    in_progress = path is None
-    path = newest_trace() if in_progress else Path(path)
+def load(path: Path | str | None = None) -> Scoped | None:
+    """The decoded trace at ``path`` (``layers["trace_path"]``), or,
+    without one, the newest kept trace; None where there is none.
+    Decoded once per file: thirteen readers and ``run.py`` ask."""
+    path = newest_trace() if path is None else Path(path)
     if path is None or not path.exists():
         return None
     stamp = path.stat().st_mtime
@@ -341,8 +346,6 @@ def load(path: Path | None = None) -> Scoped | None:
     if hit is None or hit[0] != stamp:
         _CACHE.clear()
         _CACHE[path] = hit = (stamp, decode(path.read_bytes()))
-        if in_progress and hit[1].devices:
-            print(report(hit[1]), file=sys.stderr, flush=True)
     return hit[1]
 
 
@@ -494,6 +497,64 @@ def idle_by_span(trace: Scoped, min_gap: float = 50e-6,
                 out[min(cover)[1] if cover else "unattributed"] += gap
         reach = op.end if reach is None else max(reach, op.end)
     return dict(out)
+
+
+def _bare(part: str) -> str:
+    """A name-stack component without the wrappers round it."""
+    while True:
+        found = WRAPPER.match(part)
+        if not found:
+            return part
+        part = found.group(1)
+
+
+def op_label(program: str, name: str, tf_op: str) -> str:
+    """``<program>/<scope>/<end of the name stack> <HLO name> <type and
+    shape>``: what ``breakdown`` calls a device operation. The program
+    is the module's name without ``jit_`` and its hash; the scope
+    carries its phase as :func:`scope_seconds` files it
+    (``attn_core.bwd``); the name stack's end is what follows the
+    scope (the last two parts where no scope is known): the primitive,
+    and an einsum's spec."""
+    scope, phase = scope_of(tf_op)
+    parts = [p for p in tf_op.rstrip(":").split("/")
+             if p and not p.startswith("jit(")]
+    at = max((i for i, p in enumerate(parts) if _bare(p) == scope),
+             default=None)
+    tail = "/".join(parts[-2:] if at is None else parts[at + 1:])
+    program = re.sub(r"^jit_+|\(\d+\)$", "", program)
+    if phase != "fwd":
+        scope += "." + phase
+    return (f"{program}/{scope}/{tail[-60:]} "
+            f"{trace_reduce.hlo_label(name)}")[:160]
+
+
+def top_ops(trace: Scoped | None, top: int = 10) -> list[list]:
+    """``[[label, seconds], ...]``: the device operations of the first
+    device that took most time, containers left out, each filed under
+    the program whose run contains it (equal HLO names in two programs
+    never mix) and labelled by :func:`op_label`. Empty where the trace
+    has no device plane."""
+    if trace is None or not trace.devices:
+        return []
+    device = trace.devices[0]
+    runs = trace.modules.get(device, [])
+    starts = [m.start for m in runs]
+    # a trace holds few distinct (program, op) pairs and very many
+    # events: sum by pair, label the pairs
+    by_pair: dict[tuple[str, str, str], float] = defaultdict(float)
+    for op in trace.ops[device]:
+        if CONTAINER_OP.match(op.name):
+            continue
+        i = bisect.bisect_right(starts, op.start) - 1
+        inside = i >= 0 and op.start < runs[i].end
+        by_pair[(runs[i].name if inside else "no_program",
+                 op.name, op.tf_op)] += op.end - op.start
+    by_op: dict[str, float] = defaultdict(float)
+    for pair, seconds in by_pair.items():
+        by_op[op_label(*pair)] += seconds
+    return [[k, v] for k, v in sorted(by_op.items(),
+                                      key=lambda kv: -kv[1])[:top]]
 
 
 def report(trace: Scoped, top: int = 12) -> str:
