@@ -183,6 +183,31 @@ def _top_level_results(text):
                    shape.findall(rest[:op.start() + 1]))
 
 
+def _pool_sized_writes(compiled, layer_elems: int, pool_k, vocab: int):
+    """Top-level instructions of a compiled serving program that write
+    a buffer as large as one layer's pool (``layer_elems`` elements),
+    other than the pool's own update — a scatter fusion whose result
+    IS the stacked pool, in place under donation (the callers'
+    aliasing assertions hold it to that) — and the head's relayout of
+    the tied embedding (``vocab`` among its dims: the model's)."""
+    import math
+
+    pool_shapes = {tuple(leaf.shape) for leaf in jax.tree.leaves(pool_k)}
+    moved = []
+    for name, op, results in _top_level_results(compiled.as_text()):
+        if op in ("parameter", "get-tuple-element", "tuple", "bitcast",
+                  "while", "constant"):
+            continue
+        for dtype, dims, _ in results:
+            dims = tuple(int(d) for d in dims.split(",") if d)
+            if math.prod(dims) < layer_elems or vocab in dims:
+                continue
+            if op == "fusion" and dims in pool_shapes:
+                continue
+            moved.append((name, op, dtype, dims))
+    return moved
+
+
 @pytest.mark.parametrize("int8_pool", [False, True],
                          ids=["bf16-pool", "int8-pool"])
 @pytest.mark.parametrize("program", ["decode", "chunk"])
@@ -241,22 +266,7 @@ def test_serve_programs_keep_the_pool_in_place_on_v5e(
 
     head_dim = cfg.d_model // cfg.n_heads
     layer_elems = XL_PAGES * XL_PAGE * cfg.kv_heads * head_dim
-    pool_shapes = {tuple(leaf.shape) for leaf in jax.tree.leaves(pool_k)}
-    moved = []
-    for name, op, results in _top_level_results(compiled.as_text()):
-        if op in ("parameter", "get-tuple-element", "tuple", "bitcast",
-                  "while", "constant"):
-            continue
-        for dtype, dims, _ in results:
-            dims = tuple(int(d) for d in dims.split(",") if d)
-            if math.prod(dims) < layer_elems or cfg.vocab in dims:
-                continue
-            # the pool's own update: a scatter fusion whose result IS
-            # the stacked pool (in place under donation, which the
-            # aliasing assertion below holds it to)
-            if op == "fusion" and dims in pool_shapes:
-                continue
-            moved.append((name, op, dtype, dims))
+    moved = _pool_sized_writes(compiled, layer_elems, pool_k, cfg.vocab)
     assert not moved, f"pool-sized buffers written: {moved}"
 
     # the pool goes out in the layout it came in with, aliased
@@ -284,3 +294,95 @@ def test_serve_programs_keep_the_pool_in_place_on_v5e(
     layer_kv = 2 * layer_elems * 2
     assert memory.temp_size_in_bytes - head_relayout < layer_kv, (
         memory.temp_size_in_bytes, layer_kv)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
+        one_chip, program, monkeypatch):
+    """The two programs of the ``lfm2-8b-a1b.serve-rag-r80`` cell at
+    its own size (14 layers at published widths, 2048 pages of 64, 64
+    slots, chunks of 256), compiled for the described v5e with the
+    pool AND the conv mixers' slot state donated: both come back
+    aliased, no instruction writes a second buffer the size of a
+    layer's pool, the experts run as the pallas grouped product the
+    TPU path chooses (no buffer of experts x tokens, and none of
+    XLA's own ``ragged-dot`` kernels, whose time no scope names), and
+    weights, pool, state and scratch together fit the chip."""
+    import json
+    import math
+    import sys
+    from pathlib import Path
+
+    import torchbooster_tpu.models.moe as moe_mod
+    import torchbooster_tpu.serving.engine as engine_mod
+    from torchbooster_tpu.models.lfm2 import LFM2
+
+    # the process is pinned to the CPU; the program compiled is the
+    # chip's
+    monkeypatch.setattr(moe_mod, "_on_tpu", lambda: True)
+    bench = Path(__file__).resolve().parent.parent / "benchmark"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    import program_lfm2
+
+    raw = json.loads((bench / "configs" / "lfm2-8b-a1b.json").read_text())
+    cfg = program_lfm2.model_config(raw, seq_len=4864)
+    slots, pages, page = 64, 2048, 64
+    abstract = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip), tree)
+    params = abstract(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16),
+        LFM2.init(jax.random.PRNGKey(0), cfg))))
+    # the engine's own constructor, with pool and state described
+    real = engine_mod.make_pool, engine_mod.make_slot_state
+    described = lambda make: lambda *a, **kw: jax.eval_shape(
+        lambda: make(*a, **kw))
+    engine_mod.make_pool = described(real[0])
+    engine_mod.make_slot_state = described(real[1])
+    try:
+        engine = engine_mod.PagedEngine(
+            params, cfg, page_size=page, n_pages=pages, max_slots=slots,
+            prefill_chunk_pages=4)
+    finally:
+        engine_mod.make_pool, engine_mod.make_slot_state = real
+    pool_k, pool_v = abstract(engine.pool["k"]), abstract(engine.pool["v"])
+    state = abstract(engine.slot_state)
+    assert pool_k.shape == (3, pages, page, 512)
+    assert state["conv"].shape == (11, slots, 2, 2048)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tables = engine.tables
+    if program == "decode":
+        fn, donate, args = engine._decode_fn, (1, 2, 10), (
+            arg(tables.tables.shape), arg((slots,)),
+            arg(tables.refs.shape), arg((pages,)),
+            arg((slots,), jnp.bool_), arg((slots,)),
+            arg((2,), jnp.uint32), state)
+    else:
+        fn, donate, args = engine._chunk_fn, (1, 2, 8), (
+            arg((1, engine.chunk_tokens)), arg(()), arg(()),
+            arg((tables.max_pages_per_slot,)), arg((2,), jnp.uint32),
+            state, arg(()))
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        params, pool_k, pool_v, *args).compile()
+
+    layer_elems = pages * page * 512
+    moved = _pool_sized_writes(compiled, layer_elems, pool_k, cfg.vocab)
+    assert not moved, f"pool-sized buffers written: {moved}"
+    nbytes = lambda tree: sum(math.prod(x.shape) * x.dtype.itemsize
+                              for x in jax.tree.leaves(tree))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= nbytes((pool_k, pool_v, state))
+    # the experts' three matrices of ONE layer, were they applied to
+    # every pair: the scratch stays far under it
+    pairs = (slots if program == "decode" else engine.chunk_tokens) \
+        * cfg.top_k
+    assert memory.temp_size_in_bytes < cfg.n_experts * pairs \
+        * cfg.expert_width * 2 + cfg.vocab * cfg.d_model * 2 + 2**26
+    assert "ragged-dot" not in compiled.as_text()
+    total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert nbytes(params) > 9.3e9 and total < V5E_HBM_BYTES, total

@@ -221,6 +221,38 @@ def rms_norm(params: dict, x: jax.Array, eps: float = 1e-6) -> jax.Array:
     return x * lax.rsqrt(var + eps) * params["scale"].astype(x.dtype)
 
 
+def rms_norm_f32(scale: jax.Array, x: jax.Array,
+                 eps: float = 1e-5) -> jax.Array:
+    """RMSNorm over the minor axis, computed in float32 whatever
+    ``x``'s dtype and cast back: ``x * rsqrt(mean(x^2) + eps) * g``.
+    Serves a whole row (``scale (d,)``) and a per-head q/k norm
+    (``scale (head_dim,)`` against ``(..., heads, head_dim)``) alike."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(lax.square(xf), axis=-1, keepdims=True)
+    return (xf * lax.rsqrt(var + eps)
+            * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def short_conv(z: jax.Array, w: jax.Array,
+               prev: jax.Array | None = None
+               ) -> tuple[jax.Array, jax.Array]:
+    """Causal depthwise convolution with a SHORT kernel, as a sum of
+    shifted products (channels stay on the lanes; no conv op for a
+    3-tap filter): ``c_t = sum_j w[j] * z_{t - (K-1) + j}``.
+    ``z (B, S, d)``, ``w (K, d)``, ``prev (B, K-1, d)`` the inputs
+    before ``z_0`` (None: zeros, a sequence's start). Returns ``(c
+    (B, S, d), zz (B, K-1+S, d))`` where ``zz`` is ``prev`` and ``z``
+    in one row: a caller that carries state keeps ``zz[:, n:n+K-1]``
+    after ``n`` real inputs."""
+    k = w.shape[0]
+    if prev is None:
+        prev = jnp.zeros((z.shape[0], k - 1, z.shape[2]), z.dtype)
+    zz = jnp.concatenate([prev.astype(z.dtype), z], axis=1)
+    s = z.shape[1]
+    c = sum(w[j].astype(z.dtype) * zz[:, j:j + s] for j in range(k))
+    return c, zz
+
+
 def instance_norm(x: jax.Array, eps: float = 1e-5) -> jax.Array:
     """Parameter-free instance norm over NHWC spatial dims (the core of
     AdaIN, ref adain.py:55-63)."""
@@ -257,5 +289,5 @@ __all__ = [
     "avg_pool", "conv", "conv_init", "conv_transpose", "dense",
     "dense_init", "embedding", "embedding_init", "global_avg_pool",
     "group_norm", "instance_norm", "layer_norm", "max_pool", "norm_init",
-    "normal_init", "rms_norm",
+    "normal_init", "rms_norm", "rms_norm_f32", "short_conv",
 ]

@@ -225,4 +225,144 @@ def moe_apply(params: dict, x: jax.Array, top_k: int = 2,
     return out.reshape(b, s, d), aux_loss
 
 
-__all__ = ["SHARDING_RULES", "moe_apply", "moe_init"]
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _tile(size: int, limit: int, unit: int) -> int:
+    """Largest multiple of ``unit`` that divides ``size`` and is at
+    most ``limit``; ``size`` itself where none is."""
+    return max((t for t in range(unit, min(size, limit) + 1, unit)
+                if size % t == 0), default=size)
+
+
+def grouped_matmul(rows: jax.Array, kernels: jax.Array,
+                   sizes: jax.Array) -> jax.Array:
+    """``rows (m, k)`` sorted by group, ``kernels (G, k, n)``, ``sizes
+    (G,)`` int32 -> ``(m, n)``: rows ``[sum(sizes[:g]), +sizes[g])``
+    times ``kernels[g]``; a group of size 0 is not read, rows behind
+    the last group are left to the caller. ONE grouped product.
+
+    On a TPU this is the pallas grouped-matmul kernel that ships with
+    JAX (``megablox.gmm``: visits only (row tile, group) pairs that
+    exist); elsewhere ``jax.lax.ragged_dot``, the same contract. The
+    choice is the platform's, not a knob: XLA:TPU lowers ``ragged_dot``
+    to a kernel of its own that drops the op's name stack — its time
+    lands under NO ``jax.named_scope``, which blinds every per-scope
+    reading of the trace — and reads the experts at a third of the
+    memory bandwidth (PERF.md, PR 28). Tiles: the whole contraction
+    where it is at most 2048 wide, output tiles of up to ~4 MB of
+    kernel, row tiles of 128 (or what divides ``m``)."""
+    m, k = rows.shape
+    n = kernels.shape[-1]
+    tm = _tile(m, 128, 8)
+    if not _on_tpu() or m % tm or tm % 8:
+        return jax.lax.ragged_dot(rows, kernels, sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    tk = _tile(k, 2048, 128)
+    tn = _tile(n, max((1 << 21) // tk // 128 * 128, 128), 128)
+    return gmm(rows, kernels, sizes, rows.dtype, (tm, tk, tn))
+
+
+def moe_route(params: dict, tokens: jax.Array, top_k: int,
+              scaling: float = 1.0, eps: float = 1e-6
+              ) -> tuple[jax.Array, jax.Array]:
+    """Sigmoid routing with a selection bias, in float32: ``s =
+    sigmoid(u @ W_g)``; the ``top_k`` experts are chosen by ``s + b``
+    (``moe_bias``, a load-balancing buffer that steers SELECTION
+    only) and weighted by the unbiased ``s``, renormalised over the
+    chosen ones: ``w = scaling * s[sel] / (sum(s[sel]) + eps)``.
+    ``tokens (T, d)`` -> ``(sel (T, k) int32, w (T, k) float32)``."""
+    gate = params["moe_gate"]["kernel"].astype(jnp.float32)
+    scores = jax.nn.sigmoid(jnp.dot(
+        tokens.astype(jnp.float32), gate,
+        precision=jax.lax.Precision.HIGHEST))
+    _, sel = jax.lax.top_k(
+        scores + params["moe_bias"].astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    w = scaling * w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
+    return sel.astype(jnp.int32), w
+
+
+def moe_dropless(params: dict, x: jax.Array, top_k: int,
+                 scaling: float = 1.0, valid: jax.Array | None = None,
+                 first_group: jax.Array | int = 0,
+                 route_on: jax.Array | None = None,
+                 ep: tuple | None = None, tp: tuple | None = None
+                 ) -> tuple[jax.Array, jax.Array]:
+    """(B, S, d) -> ((B, S, d), tokens per expert (E,) int32): the
+    DROPLESS expert layer — every selected (token, expert) pair is
+    computed whatever the imbalance, and nothing else is. The ``T*k``
+    pairs are sorted by expert and each projection of the bias-free
+    SwiGLU experts (``moe_fc1`` / ``moe_fc3`` ``(E, d, h)``,
+    ``moe_fc2`` ``(E, h, d)``) is ONE grouped matrix product over the
+    sorted rows (:func:`grouped_matmul`: the group sizes are the
+    tokens per expert, traced values) — no ``(T, E, C)`` tensor, no
+    per-expert buffer, no product of an expert with a token it was
+    not given; an expert nobody chose is not read. The same code
+    serves a decode batch of one token a slot and a prefill chunk.
+
+    The expert kernels may hold MORE groups than this layer's ``E``
+    experts — the experts of several layers stacked on the leading
+    axis, ``(layers * E, d, h)`` — with ``first_group`` (a traced
+    value) saying where this layer's begin: the other layers' groups
+    get size 0 and are never touched. That is how a layer inside a
+    ``lax.scan`` reads its experts where they lie: sliced out of a
+    stacked array for the grouped product's kernel, they would be
+    COPIED every step (compiled for the v5e: 234 MB a matrix).
+
+    ``route_on``: the tokens as the ROUTER reads them where that is
+    not ``x`` — the float32 normed activations before they are
+    rounded to the experts' compute dtype (a score decides a top-k:
+    rounding its input flips near-ties).
+
+    ``valid (B, S)`` marks real tokens (a dead slot, a chunk's pad):
+    the others are sorted behind every group, take no expert's time
+    and count for nothing. Routing follows :func:`moe_route`. The
+    count is a program output the serving engine reads
+    (``serving_moe_*``, docs/observability.md).
+
+    ``ep`` / ``tp`` sharding of this path does not exist yet
+    (ROADMAP M1) and raises."""
+    if ep is not None or tp is not None:
+        raise NotImplementedError(
+            "moe_dropless: ep / tp sharding of the dropless expert "
+            "layer is not implemented")
+    b, s, d = x.shape
+    tokens = x.reshape(b * s, d)
+    n_experts = params["moe_gate"]["kernel"].shape[-1]
+    n_groups = params["moe_fc1"]["kernel"].shape[0]
+    with jax.named_scope("moe_route"):
+        sel, w = moe_route(
+            params, tokens if route_on is None
+            else route_on.reshape(b * s, d), top_k, scaling)
+    with jax.named_scope("moe_experts"):
+        pair_expert = sel.reshape(-1)                    # (T*k,)
+        if valid is not None:
+            pair_expert = jnp.where(
+                jnp.repeat(valid.reshape(-1), top_k), pair_expert,
+                n_experts)
+        order = jnp.argsort(pair_expert, stable=True)
+        counts = jnp.bincount(pair_expert, length=n_experts + 1
+                              )[:n_experts].astype(jnp.int32)
+        rows = tokens[order // top_k]                    # (T*k, d)
+        sizes = counts if n_groups == n_experts else \
+            jax.lax.dynamic_update_slice(
+                jnp.zeros((n_groups,), jnp.int32), counts, (first_group,))
+        grouped = lambda a, name: grouped_matmul(
+            a, params[name]["kernel"].astype(a.dtype), sizes)
+        h = jax.nn.silu(grouped(rows, "moe_fc1")) \
+            * grouped(rows, "moe_fc3")
+        y = grouped(h, "moe_fc2")
+        # rows behind the last group were given to no expert
+        y = jnp.where((jnp.arange(y.shape[0]) < jnp.sum(counts))[:, None],
+                      y, 0)
+        # back to (token, choice) order, weighted sum over the choices
+        y = y[jnp.argsort(order)].reshape(b * s, top_k, d)
+        out = jnp.sum(y.astype(jnp.float32) * w[..., None], axis=1)
+    return out.astype(x.dtype).reshape(b, s, d), counts
+
+
+__all__ = ["SHARDING_RULES", "grouped_matmul", "moe_apply",
+           "moe_dropless", "moe_init", "moe_route"]

@@ -70,7 +70,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from torchbooster_tpu.models import layers as L
-from torchbooster_tpu.observability import span
+from torchbooster_tpu.models import lfm2 as _lfm2
+from torchbooster_tpu.observability import get_registry, span
 from torchbooster_tpu.models.quant import (
     weight_stream_bytes as _weight_stream_bytes,
     weights_dtype as _weights_dtype,
@@ -94,10 +95,12 @@ from torchbooster_tpu.serving.kv_pages import (
     NULL_PAGE,
     BlockTables,
     HostPagePool,
+    cache_spec,
     from_rows,
     gather_pages,
     layer_pages,
     make_pool,
+    make_slot_state,
     pool_map,
     quantized_rows,
     scan_layers,
@@ -138,6 +141,23 @@ def _quantize_page_np(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     scale = np.maximum(scale, 1e-8).astype(np.float32)
     q = np.clip(np.round(x / scale), -127, 127).astype(np.int8)
     return q, scale
+
+
+def _served_model(cfg: Any):
+    """The module whose ``embed`` / ``layers`` / ``head`` the chunk
+    and decode programs call for this model config — chosen by the
+    config's TYPE, never by a knob. None for a ``GPTConfig``: its
+    programs run ``models/gpt.py``'s ``_block_core`` over stacked
+    blocks (``kv_pages.scan_layers``), as they always have. A further
+    architecture is a module with those three functions and a
+    ``cache_spec()`` on its config (models/lfm2.py), and a line
+    here."""
+    if isinstance(cfg, _lfm2.LFM2Config):
+        return _lfm2
+    if isinstance(cfg, GPTConfig):
+        return None
+    raise TypeError(
+        f"PagedEngine: no served model for a {type(cfg).__name__}")
 
 
 class PagedEngine:
@@ -235,9 +255,24 @@ class PagedEngine:
     zero-recompile contract holds per executable; the default
     ``tp=1`` builds no shard_map wrapper at all — same compiled
     artifacts, same call signatures.
+
+    **Other models than GPT.** The engine is chosen by the TYPE of
+    ``cfg`` (:func:`_served_model`): a model that brings its own layer
+    stack (``models/lfm2.py``) gets the same two programs with its
+    ``embed`` / ``layers`` / ``head`` in the place of ``_block_core``,
+    a pool over the layers its ``cache_spec()`` says attend, and —
+    where the spec names slot-indexed states (a short convolution's
+    last inputs) — one more donated operand, carried and updated in
+    place beside the pool. The chunk program reads a slot's state as
+    zeros where its prefill starts at position 0 (a fresh seat, or a
+    preempted request's replay), so seating and retiring write
+    nothing; the decode program shifts live slots' state and leaves
+    the others alone. Features whose bookkeeping takes pages for the
+    WHOLE of a sequence refuse such a model at build
+    (``NotImplementedError`` naming the feature).
     """
 
-    def __init__(self, params: dict, cfg: GPTConfig, *,
+    def __init__(self, params: dict, cfg: Any, *,
                  page_size: int = 64, n_pages: int = 128,
                  max_slots: int = 8, cache_dtype: Any = None,
                  compute_dtype: Any = jnp.bfloat16,
@@ -321,11 +356,40 @@ class PagedEngine:
                 "promotion executable would need a shard_map wrapper "
                 "over the KV-head-sharded pool — run the spill tier "
                 "on tp=1 replicas (the fleet path)")
-        # same params/config positional-encoding guard the dense
-        # generate() applies — a rope checkpoint served with
-        # pos="learned" (or vice versa, or a tp-major-permuted tree)
-        # must fail here, not decode garbage quietly
-        _check_pos(params, cfg)
+        self.model = _served_model(cfg)
+        if self.model is not None:
+            # what is not done for a model with its own layer stack.
+            # With slot state, pages are no longer all of a sequence:
+            # a prefix hit would skip the chunks that build the state,
+            # a rewind or a fork would need the state of an earlier
+            # position, a spilled or exported page carries none of it.
+            # The rest is GPT-shaped code (adapter and tp layouts of
+            # attn_qkv, the kernel's head split, int8 rows)
+            refused = {
+                "host_spill": host_spill,
+                "prefix_cache": prefix_cache,
+                "speculative": speculative,
+                "disagg (prefill_only)": prefill_only,
+                "parallel_sampling (fork)": parallel_sampling,
+                "tp": tp > 1,
+                "cache_dtype: int8": cache_dtype is not None,
+                "decode_backend: pallas": decode_backend != "xla",
+                "structured": structured,
+                "adapters (lora)": lora_rank > 0 or lora_max_live > 0,
+            }
+            for feature, asked in refused.items():
+                if asked:
+                    raise NotImplementedError(
+                        f"serving feature {feature!r} is not "
+                        f"implemented for a {type(cfg).__name__} "
+                        "(a model with its own layer stack and "
+                        "slot-indexed state)")
+        else:
+            # same params/config positional-encoding guard the dense
+            # generate() applies — a rope checkpoint served with
+            # pos="learned" (or vice versa, or a tp-major-permuted
+            # tree) must fail here, not decode garbage quietly
+            _check_pos(params, cfg)
         # tensor-parallel serving (serving/tp.py): tp > 1 shards the
         # attention of every compiled step — Q/K/V/O projections and
         # the KV page pool — over the mesh's tp (heads) axis; all
@@ -365,6 +429,15 @@ class PagedEngine:
                               cache_dtype=cache_dtype,
                               compute_dtype=compute_dtype,
                               shards=self.tp)
+        # slot-indexed state (a conv mixer's last inputs), None for a
+        # model without: donated and updated in place like the pool
+        self.slot_state = make_slot_state(cache_spec(cfg), max_slots,
+                                          compute_dtype)
+        # the last decode step's tokens per expert (n_moe_layers,
+        # n_experts) of a model that routes, and the registry series
+        # fed from it (made at the first step with the registry on)
+        self.moe_counts: np.ndarray | None = None
+        self._moe_inst: dict | None = None
         # the host spill tier (PR 16): LRU eviction demotes registered
         # prefix pages to a host-DRAM pool (int8 + scales) and a later
         # seat promotes them back through ONE fixed-shape compiled
@@ -574,10 +647,15 @@ class PagedEngine:
                 self._decode_fn, mesh, pspecs,
                 7 + n_extra + n_struct + n_par + n_lora, 1 + n_par)
         else:
-            self._chunk_jit = jax.jit(self._chunk_fn,
-                                      donate_argnums=(1, 2))
-            self._decode_jit = jax.jit(self._decode_fn,
-                                       donate_argnums=(1, 2))
+            # the slot state rides first among the trailing operands
+            # (chunk: argument 8, decode: argument 10), donated too
+            stateful = self.slot_state is not None
+            self._chunk_jit = jax.jit(
+                self._chunk_fn,
+                donate_argnums=(1, 2, 8) if stateful else (1, 2))
+            self._decode_jit = jax.jit(
+                self._decode_fn,
+                donate_argnums=(1, 2, 10) if stateful else (1, 2))
         # the fork-time copy-on-write page copy (parallel mode only):
         # ONE fixed-shape executable — (max_slots,) src/dst page-id
         # vectors padded with null->null self-copies — compiled once
@@ -697,6 +775,11 @@ class PagedEngine:
         if self.lora:
             lora_w, lane1 = extra[-5:-1], extra[-1]
             extra = extra[:-5]
+        # a model with slot state: the state and the seating slot ride
+        # FIRST among the trailing operands
+        state = slot = None
+        if self.slot_state is not None:
+            state, slot, extra = extra[0], extra[1], extra[2:]
         cfg, ps = self.cfg, self.page_size
         C = ids.shape[1]
         n_cp = C // ps
@@ -708,11 +791,15 @@ class PagedEngine:
         n_heads_l = cfg.n_heads // self.tp
         positions = start + jnp.arange(C)
 
-        with jax.named_scope("embed"):
-            x = L.embedding(params["wte"], ids, dtype=self.compute_dtype)
-            if "wpe" in params:
-                x = x + L.embedding(params["wpe"], positions,
-                                    dtype=self.compute_dtype)[None]
+        if self.model is not None:
+            x = self.model.embed(params, ids, dtype=self.compute_dtype)
+        else:
+            with jax.named_scope("embed"):
+                x = L.embedding(params["wte"], ids,
+                                dtype=self.compute_dtype)
+                if "wpe" in params:
+                    x = x + L.embedding(params["wpe"], positions,
+                                        dtype=self.compute_dtype)[None]
 
         # chunk pages: table entries [start/ps, start/ps + n_cp); the
         # final chunk's pad pages (beyond the slot's allocation, or
@@ -736,55 +823,85 @@ class PagedEngine:
             return pool_map(lambda a: a.reshape(1, -1, *a.shape[2:]),
                             self._heads(pages))
 
-        def layer(x, pk, pv, bp, li, lora):
+        def attend(q, k, v, pk, pv, li):
+            # layer ``li`` of the pool: this chunk's K/V written, its
+            # queries attended over the slot's pages and the chunk
+            g = k.shape[2]
+            with jax.named_scope("kv_write"):
+                new_k = write_rows(pk, (li, w_pages), self._page_rows(
+                    k[0].reshape(n_cp, ps, g, head_dim), pk))
+                new_v = write_rows(pv, (li, w_pages), self._page_rows(
+                    v[0].reshape(n_cp, ps, g, head_dim), pv))
+            # the slot's own pages back out of the stacked pool:
+            # mp pages of this layer, not the layer's pool (read
+            # after the write, so the update stays in place; the
+            # chunk's own pages sit at positions >= start, which
+            # vis_prior masks)
+            gk = context(gather_pages(new_k, li, table_row))
+            gv = context(gather_pages(new_v, li, table_row))
+            # prior context (this slot's already-written pages,
+            # masked to < start) and the chunk itself
+            # (compute-dtype K/V — parity with the dense prefill's
+            # un-quantized intra-prompt attention) are two
+            # flash-style partials merged online-softmax style —
+            # the same math spread over a split token axis
+            oA, mA, lA = _grouped_cache_attention(
+                q, gk, gv, vis_prior, state=True)
+            oB, mB, lB = _grouped_cache_attention(
+                q, k, v, vis_chunk, state=True)
+            m = jnp.maximum(mA, mB)
+            wA = jnp.exp(mA - m)
+            wB = jnp.exp(mB - m)
+            l = jnp.maximum(lA * wA + lB * wB, 1e-30)
+            # (B, g, rep, S_q) weights -> (B, S_q, g, rep, 1)
+            mv = lambda t: jnp.moveaxis(t, -1, 1)[..., None]
+            o = (oA * mv(wA) + oB * mv(wB)) / mv(l)
+            o = o.reshape(1, C, n_heads_l, head_dim)
+            return o.astype(q.dtype), (new_k, new_v)
 
-            def attend(q, k, v):
-                g = k.shape[2]
-                with jax.named_scope("kv_write"):
-                    new_k = write_rows(pk, (li, w_pages), self._page_rows(
-                        k[0].reshape(n_cp, ps, g, head_dim), pk))
-                    new_v = write_rows(pv, (li, w_pages), self._page_rows(
-                        v[0].reshape(n_cp, ps, g, head_dim), pv))
-                # the slot's own pages back out of the stacked pool:
-                # mp pages of this layer, not the layer's pool (read
-                # after the write, so the update stays in place; the
-                # chunk's own pages sit at positions >= start, which
-                # vis_prior masks)
-                gk = context(gather_pages(new_k, li, table_row))
-                gv = context(gather_pages(new_v, li, table_row))
-                # prior context (this slot's already-written pages,
-                # masked to < start) and the chunk itself
-                # (compute-dtype K/V — parity with the dense prefill's
-                # un-quantized intra-prompt attention) are two
-                # flash-style partials merged online-softmax style —
-                # the same math spread over a split token axis
-                oA, mA, lA = _grouped_cache_attention(
-                    q, gk, gv, vis_prior, state=True)
-                oB, mB, lB = _grouped_cache_attention(
-                    q, k, v, vis_chunk, state=True)
-                m = jnp.maximum(mA, mB)
-                wA = jnp.exp(mA - m)
-                wB = jnp.exp(mB - m)
-                l = jnp.maximum(lA * wA + lB * wB, 1e-30)
-                # (B, g, rep, S_q) weights -> (B, S_q, g, rep, 1)
-                mv = lambda t: jnp.moveaxis(t, -1, 1)[..., None]
-                o = (oA * mv(wA) + oB * mv(wB)) / mv(l)
-                o = o.reshape(1, C, n_heads_l, head_dim)
-                return o.astype(q.dtype), (new_k, new_v)
+        if self.model is not None:
+            # the prompt's real tokens in this chunk: the conv state
+            # kept is that of the prompt's TRUE end, not of the padded
+            # end of a partial last chunk
+            n_real = jnp.clip(s0 - start, 0, C)
 
-            x, _, (pk, pv) = _block_core(
-                bp, x, cfg, attend,
-                capacity_factor=max(cfg.capacity_factor,
-                                    float(cfg.n_experts)),
-                positions=positions[None],      # per-slot rope depth
-                tp_attn=self._tp_core,
-                lora=(lora, lane1) if self.lora else None)
-            return x, pk, pv
+            def conv(z, w, st, li):
+                rows = st["conv"]
+                # a prefill from position 0 (a fresh seat, a preempted
+                # request's replay) starts from zeros whatever the
+                # slot's last tenant left
+                prev = jnp.where(start == 0, 0, rows[li, slot])
+                c, zz = L.short_conv(z, w, prev[None])
+                keep = jax.lax.dynamic_slice_in_dim(
+                    zz[0], n_real, w.shape[0] - 1, axis=0)
+                return c, {"conv": rows.at[li, slot].set(
+                    keep.astype(rows.dtype))}
 
-        x, pool_k, pool_v = scan_layers(layer, x, pool_k, pool_v,
-                                        params["blocks"], lora_w)
+            x, (pool_k, pool_v), state, moe_counts = self.model.layers(
+                params, x, cfg, positions=positions[None],
+                attend=lambda q, k, v, cache, li: attend(q, k, v, *cache,
+                                                         li),
+                conv=conv, cache=(pool_k, pool_v), state=state,
+                valid=(positions < s0)[None])
+        else:
+            def layer(x, pk, pv, bp, li, lora):
+                x, _, (pk, pv) = _block_core(
+                    bp, x, cfg,
+                    lambda q, k, v: attend(q, k, v, pk, pv, li),
+                    capacity_factor=max(cfg.capacity_factor,
+                                        float(cfg.n_experts)),
+                    positions=positions[None],  # per-slot rope depth
+                    tp_attn=self._tp_core,
+                    lora=(lora, lane1) if self.lora else None)
+                return x, pk, pv
+
+            x, pool_k, pool_v = scan_layers(layer, x, pool_k, pool_v,
+                                            params["blocks"], lora_w)
         last = jax.lax.dynamic_slice_in_dim(
             x, jnp.clip(s0 - 1 - start, 0, C - 1), 1, axis=1)
+        if self.model is not None:
+            tok = self._pick(rng, self.model.head(params, last, cfg)[:, 0])
+            return tok, pool_k, pool_v, state, moe_counts
         logits = _lm_head(params, last)[:, 0]
         # structured mode: the trailing operand is the seating slot's
         # (1, vocab) legality row (all-True when unconstrained — a
@@ -824,16 +941,24 @@ class PagedEngine:
             extra = extra[1:]
         if self.parallel:
             slot_keys = extra[-1]
+        state = None
+        if self.slot_state is not None:
+            state, extra = extra[0], extra[1:]
         cfg, ps = self.cfg, self.page_size
         n_slots = last_ids.shape[0]
         n_heads_l = cfg.n_heads // self.tp    # local heads (tp shard)
 
-        with jax.named_scope("embed"):
-            x = L.embedding(params["wte"], last_ids[:, None],
-                            dtype=self.compute_dtype)
-            if "wpe" in params:
-                x = x + L.embedding(params["wpe"], lengths,
-                                    dtype=self.compute_dtype)[:, None]
+        if self.model is not None:
+            x = self.model.embed(params, last_ids[:, None],
+                                 dtype=self.compute_dtype)
+        else:
+            with jax.named_scope("embed"):
+                x = L.embedding(params["wte"], last_ids[:, None],
+                                dtype=self.compute_dtype)
+                if "wpe" in params:
+                    x = x + L.embedding(
+                        params["wpe"], lengths,
+                        dtype=self.compute_dtype)[:, None]
 
         # page -> lane bookkeeping, shared by every layer: each page
         # carries reference LANES (refs row: the slots holding it —
@@ -867,63 +992,86 @@ class PagedEngine:
         w_page = jnp.where(active, w_page, 0)
         w_off = lengths % ps
 
-        def layer(x, pk, pv, bp, li, lora):
-
-            def attend(q, k, v):
-                with jax.named_scope("kv_write"):
-                    new_k = write_rows(pk, (li, w_page, w_off),
-                                       self._page_rows(k[:, 0], pk))
-                    new_v = write_rows(pv, (li, w_page, w_off),
-                                       self._page_rows(v[:, 0], pv))
-                if self.decode_backend == "pallas":
-                    # the in-kernel block-table walk: the kernel's
-                    # grid iterates the compacted live-page list and
-                    # fetches pages by table value, so the HBM stream
-                    # is the live context (shared pages once), not
-                    # the pool; (page, lane) partials merge per slot
-                    # in VMEM scratch with the same online-softmax
-                    # combine the sweep runs through segment ops
-                    o = paged_attention(
-                        q, self._kernel_pages(new_k, li),
-                        self._kernel_pages(new_v, li), work_pages,
-                        work_refs, work_pos, lengths, page_size=ps)
-                    return o.astype(q.dtype), (new_k, new_v)
-                # the pool sweep: each page attends the queries of ALL
-                # its reference lanes (a gather of the TINY q tensor
-                # into (P, R, H, Dh) — the layer's pages are read in
-                # place, ONCE, in the pool's own layout
-                # (kv_pages.sweep_attention); lanes ride the query
-                # axis so sharing multiplies only the small-side
-                # compute, never the HBM stream), then (page, lane)
-                # partials merge per slot via the online-softmax
-                # combine
-                q_lanes = q[:, 0][ref_c]        # (P, R, H, Dh)
-                o_p, m_p, l_p = sweep_attention(
-                    q_lanes, layer_pages(new_k, li),
-                    layer_pages(new_v, li), visible, k.shape[2])
-                # o (P, R, g, rep, Dh); m/l (P, g, rep, R): flatten
-                # the (page, lane) pairs into one segment axis
-                n_pp = o_p.shape[0]
-                o_f = o_p.reshape(n_pp * n_lanes, *o_p.shape[2:])
-                m_f = jnp.moveaxis(m_p, -1, 1).reshape(
-                    n_pp * n_lanes, *m_p.shape[1:3])
-                l_f = jnp.moveaxis(l_p, -1, 1).reshape(
-                    n_pp * n_lanes, *l_p.shape[1:3])
-                m_s = jax.ops.segment_max(m_f, seg,
-                                          num_segments=n_slots + 1)
-                w = jnp.exp(m_f - m_s[seg])
-                l_s = jax.ops.segment_sum(l_f * w, seg,
-                                          num_segments=n_slots + 1)
-                o_s = jax.ops.segment_sum(o_f * w[..., None], seg,
-                                          num_segments=n_slots + 1)
-                o = o_s[:n_slots] / jnp.maximum(
-                    l_s[:n_slots], 1e-30)[..., None]
-                o = o.reshape(n_slots, 1, n_heads_l,
-                              cfg.d_model // cfg.n_heads)
+        def attend(q, k, v, pk, pv, li):
+            # layer ``li`` of the pool: this step's K/V written, the
+            # slots' queries attended over the layer's pages
+            with jax.named_scope("kv_write"):
+                new_k = write_rows(pk, (li, w_page, w_off),
+                                   self._page_rows(k[:, 0], pk))
+                new_v = write_rows(pv, (li, w_page, w_off),
+                                   self._page_rows(v[:, 0], pv))
+            if self.decode_backend == "pallas":
+                # the in-kernel block-table walk: the kernel's
+                # grid iterates the compacted live-page list and
+                # fetches pages by table value, so the HBM stream
+                # is the live context (shared pages once), not
+                # the pool; (page, lane) partials merge per slot
+                # in VMEM scratch with the same online-softmax
+                # combine the sweep runs through segment ops
+                o = paged_attention(
+                    q, self._kernel_pages(new_k, li),
+                    self._kernel_pages(new_v, li), work_pages,
+                    work_refs, work_pos, lengths, page_size=ps)
                 return o.astype(q.dtype), (new_k, new_v)
+            # the pool sweep: each page attends the queries of ALL
+            # its reference lanes (a gather of the TINY q tensor
+            # into (P, R, H, Dh) — the layer's pages are read in
+            # place, ONCE, in the pool's own layout
+            # (kv_pages.sweep_attention); lanes ride the query
+            # axis so sharing multiplies only the small-side
+            # compute, never the HBM stream), then (page, lane)
+            # partials merge per slot via the online-softmax
+            # combine
+            q_lanes = q[:, 0][ref_c]        # (P, R, H, Dh)
+            o_p, m_p, l_p = sweep_attention(
+                q_lanes, layer_pages(new_k, li),
+                layer_pages(new_v, li), visible, k.shape[2])
+            # o (P, R, g, rep, Dh); m/l (P, g, rep, R): flatten
+            # the (page, lane) pairs into one segment axis
+            n_pp = o_p.shape[0]
+            o_f = o_p.reshape(n_pp * n_lanes, *o_p.shape[2:])
+            m_f = jnp.moveaxis(m_p, -1, 1).reshape(
+                n_pp * n_lanes, *m_p.shape[1:3])
+            l_f = jnp.moveaxis(l_p, -1, 1).reshape(
+                n_pp * n_lanes, *l_p.shape[1:3])
+            m_s = jax.ops.segment_max(m_f, seg,
+                                      num_segments=n_slots + 1)
+            w = jnp.exp(m_f - m_s[seg])
+            l_s = jax.ops.segment_sum(l_f * w, seg,
+                                      num_segments=n_slots + 1)
+            o_s = jax.ops.segment_sum(o_f * w[..., None], seg,
+                                      num_segments=n_slots + 1)
+            o = o_s[:n_slots] / jnp.maximum(
+                l_s[:n_slots], 1e-30)[..., None]
+            o = o.reshape(n_slots, 1, n_heads_l,
+                          cfg.d_model // cfg.n_heads)
+            return o.astype(q.dtype), (new_k, new_v)
 
+        if self.model is not None:
+            def conv(z, w, st, li):
+                # live slots shift their state by this step's input;
+                # dead and mid-prefill slots keep theirs
+                rows = st["conv"]
+                prev = rows[li]                 # (slots, K-1, d)
+                c, zz = L.short_conv(z, w, prev)
+                new = jnp.where(active[:, None, None],
+                                zz[:, 1:].astype(rows.dtype), prev)
+                return c, {"conv": rows.at[li].set(new)}
+
+            x, (pool_k, pool_v), state, moe_counts = self.model.layers(
+                params, x, cfg, positions=lengths[:, None],
+                attend=lambda q, k, v, cache, li: attend(q, k, v, *cache,
+                                                         li),
+                conv=conv, cache=(pool_k, pool_v), state=state,
+                valid=active[:, None])
+            tokens = self._pick(
+                rng, self.model.head(params, x, cfg)[:, 0])
+            return tokens, pool_k, pool_v, state, moe_counts
+
+        def layer(x, pk, pv, bp, li, lora):
             x, _, (pk, pv) = _block_core(
-                bp, x, cfg, attend,
+                bp, x, cfg,
+                lambda q, k, v: attend(q, k, v, pk, pv, li),
                 capacity_factor=max(cfg.capacity_factor,
                                     float(cfg.n_experts)),
                 positions=lengths[:, None],     # per-slot rope depth
@@ -1372,6 +1520,9 @@ class PagedEngine:
                 # is unconstrained — exact no-op)
                 sextra = (jnp.asarray(
                     self._cursors.mask[p["slot"]][None]),)
+            if self.slot_state is not None:
+                sextra = (self.slot_state,
+                          jnp.asarray(p["slot"], jnp.int32)) + sextra
             # the chunk's (1,) lane id: the seating slot's adapter
             sextra = sextra + self._lora_operands(
                 self._slot_lanes[p["slot"]:p["slot"] + 1])
@@ -1386,6 +1537,10 @@ class PagedEngine:
                 *sextra)
         if self.parallel:
             tok, lp, logits, pool_k, pool_v = outs
+        elif self.model is not None:
+            # the chunk's expert counts stay on the device: reading
+            # them would wait for a program this call only dispatched
+            tok, pool_k, pool_v, self.slot_state, _ = outs
         else:
             tok, pool_k, pool_v = outs
         self.pool = {"k": pool_k, "v": pool_v}
@@ -1720,6 +1875,8 @@ class PagedEngine:
                 extra = extra + (jnp.asarray(self._cursors.mask),)
             if self.parallel:
                 extra = extra + (jnp.asarray(self._slot_keys),)
+            if self.slot_state is not None:
+                extra = (self.slot_state,) + extra
             extra = extra + self._lora_operands(self._slot_lanes)
         with span("decode_step"):
             outs = self._decode_jit(
@@ -1727,7 +1884,14 @@ class PagedEngine:
                 args["tables"], args["lengths"], args["refs"],
                 args["page_pos"], args["active"], args["last_ids"],
                 sub, *extra)
-            if self.parallel:
+            if self.model is not None:
+                tokens, pool_k, pool_v, self.slot_state, counts = outs
+                self.pool = {"k": pool_k, "v": pool_v}
+                # ONE batched device->host sync for both results
+                tokens, counts = jax.device_get((tokens, counts))
+                tokens = np.asarray(tokens)
+                self._count_experts(np.asarray(counts))
+            elif self.parallel:
                 tokens, lps, pool_k, pool_v = outs
                 self.pool = {"k": pool_k, "v": pool_v}
                 # ONE batched device->host sync for both results
@@ -1895,6 +2059,37 @@ class PagedEngine:
                     self._cursors.observe(slot, emitted)
         return out
 
+    def _count_experts(self, counts: np.ndarray) -> None:
+        """File one decode step's tokens per expert ``(n_moe_layers,
+        n_experts)``: kept as ``moe_counts`` and, while the registry
+        is on, fed to the ``serving_moe_*`` series — experts hit by at
+        least one token (summed over the layers: what the step's
+        expert weights cost to read) and, per layer, the fullest
+        expert's tokens over the mean."""
+        self.moe_counts = counts
+        if not counts.size:
+            return
+        reg = get_registry()
+        if not reg.enabled:
+            return
+        if self._moe_inst is None:
+            self._moe_inst = {
+                "hit": reg.histogram(
+                    "serving_moe_experts_hit",
+                    "experts given at least one token in a decode "
+                    "step, summed over the expert layers"),
+                "load": reg.histogram(
+                    "serving_moe_tokens_per_expert",
+                    "per decode step and expert layer: tokens on the "
+                    "fullest expert over the mean per expert"),
+            }
+        self._moe_inst["hit"].observe(np.count_nonzero(counts))
+        mean = counts.mean(axis=1)
+        live = mean > 0
+        if live.any():
+            fullest = counts.max(axis=1)[live] / mean[live]
+            self._moe_inst["load"].observe(fullest.mean())
+
     def retire(self, slot: int) -> None:
         """Release the slot (cancelling any in-flight prefill); shared
         prefix pages stay resident for later hits, everything else
@@ -1971,8 +2166,14 @@ class PagedEngine:
             "structured_requests": self.structured_requests,
             "structured_slots": self.structured_slot_count,
             "structured_schemas": len(self._sdfa_cache),
-            "weights_dtype": _weights_dtype(self.params),
-            "weight_stream_bytes": _weight_stream_bytes(self.params),
+            "weights_dtype": (_weights_dtype(self.params)
+                              if self.model is None else "bf16"),
+            # a model with its own layer stack: every stored byte (a
+            # dropless expert layer reads only the experts hit)
+            "weight_stream_bytes": (
+                _weight_stream_bytes(self.params) if self.model is None
+                else sum(int(a.nbytes)
+                         for a in jax.tree.leaves(self.params))),
             "lora": self.lora,
             "lora_rank": self.lora_rank,
             "lora_max_live": self.lora_max_live,
@@ -2029,6 +2230,8 @@ class PagedEngine:
             extra = extra + (jnp.asarray(self._cursors.mask),)
         if self.parallel:
             extra = extra + (jnp.asarray(self._slot_keys),)
+        if self.slot_state is not None:
+            extra = (self.slot_state,) + extra
         extra = extra + self._lora_operands(self._slot_lanes)
         lowered = self._decode_jit.lower(
             self.params, self.pool["k"], self.pool["v"],

@@ -89,13 +89,12 @@ a byte budget; pages that fall off its tail are gone for real.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass, field
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from torchbooster_tpu.models.gpt import GPTConfig
 
 NULL_PAGE = 0
 LANES = 128     # the device tiles an array's minor axis in 128 lanes
@@ -111,6 +110,46 @@ class PoolExhausted(RuntimeError):
     catch THIS type and let anything else surface immediately."""
 
 
+@dataclass(frozen=True)
+class CacheSpec:
+    """What a model asks the engine to hold for it: ``kv_layers``
+    layers of paged K/V rows of ``kv_heads * head_dim`` lanes (the
+    layers that attend — not every layer of a model with other mixers),
+    and ``slot_states``: arrays indexed by serving SLOT, not by page —
+    ``{name: (layers, *shape)}`` is allocated as ``(layers, max_slots,
+    *shape)`` (:func:`make_slot_state`). A model with slot state is
+    seated, preempted and retired like any other (its programs reset
+    the state where a prefill starts at position 0), but its pages are
+    not the whole of a sequence any more: the prefix cache, rewind,
+    spill and fork refuse it at build (serving/engine.py)."""
+
+    kv_layers: int
+    kv_heads: int
+    head_dim: int
+    slot_states: dict = field(default_factory=dict)
+
+
+def cache_spec(cfg: Any) -> CacheSpec:
+    """The model's own ``cfg.cache_spec()``, or for a ``GPTConfig``
+    what it has always been: every layer attends."""
+    own = getattr(cfg, "cache_spec", None)
+    if own is not None:
+        return own()
+    return CacheSpec(cfg.n_layers, cfg.kv_heads,
+                     cfg.d_model // cfg.n_heads)
+
+
+def make_slot_state(spec: CacheSpec, max_slots: int,
+                    dtype: Any = jnp.bfloat16) -> dict | None:
+    """The slot-indexed states of ``spec``, zeroed: ``{name: (layers,
+    max_slots, *shape)}``; None for a model that has none (its
+    programs then carry no such operand)."""
+    if not spec.slot_states:
+        return None
+    return {name: jnp.zeros((shape[0], max_slots, *shape[1:]), dtype)
+            for name, shape in spec.slot_states.items()}
+
+
 def kv_width(kv_heads: int, head_dim: int, shards: int = 1) -> int:
     """Lanes of one pool row: ``kv_heads * head_dim`` rounded up to a
     whole number of 128-lane tiles — per SHARD, so that a ``tp`` split
@@ -120,12 +159,13 @@ def kv_width(kv_heads: int, head_dim: int, shards: int = 1) -> int:
     return shards * (-(-per_shard // LANES) * LANES)
 
 
-def make_pool(cfg: GPTConfig, page_size: int, n_pages: int,
+def make_pool(cfg: Any, page_size: int, n_pages: int,
               cache_dtype: Any = None,
               compute_dtype: Any = jnp.bfloat16,
               shards: int = 1) -> dict:
-    """Allocate the device pool: ``{"k": ..., "v": ...}`` with each
-    entry ``(n_layers, n_pages, page_size, kv_width)`` (module
+    """Allocate the device pool for a model config (or its
+    :class:`CacheSpec`): ``{"k": ..., "v": ...}`` with each
+    entry ``(kv_layers, n_pages, page_size, kv_width)`` (module
     docstring: heads and head dim merged into one 128-aligned minor
     row) — a plain array in ``compute_dtype``, or, when ``cache_dtype``
     is ``"int8"``, the pair ``(int8 rows, bf16 scales (n_layers,
@@ -134,11 +174,11 @@ def make_pool(cfg: GPTConfig, page_size: int, n_pages: int,
     if cache_dtype not in (None, "int8", jnp.int8):
         raise ValueError(
             f"cache_dtype must be None or 'int8', got {cache_dtype!r}")
-    head_dim = cfg.d_model // cfg.n_heads
-    shape = (cfg.n_layers, n_pages, page_size,
-             kv_width(cfg.kv_heads, head_dim, shards))
+    spec = cfg if isinstance(cfg, CacheSpec) else cache_spec(cfg)
+    shape = (spec.kv_layers, n_pages, page_size,
+             kv_width(spec.kv_heads, spec.head_dim, shards))
     if cache_dtype in ("int8", jnp.int8):
-        scale_shape = shape[:-1] + (cfg.kv_heads,)
+        scale_shape = shape[:-1] + (spec.kv_heads,)
         mk = lambda: (jnp.zeros(shape, jnp.int8),
                       jnp.ones(scale_shape, jnp.bfloat16))
     else:
@@ -461,7 +501,7 @@ class BlockTables:
     the lane axis collapses to 1 as before.
     """
 
-    def __init__(self, cfg: GPTConfig, page_size: int, n_pages: int,
+    def __init__(self, cfg: Any, page_size: int, n_pages: int,
                  max_slots: int, prefix_cache: bool = False,
                  parallel: bool = False):
         if page_size < 1 or n_pages < 2 or max_slots < 1:
@@ -1156,7 +1196,8 @@ class BlockTables:
                     "host pool key is not page-aligned int32 bytes")
 
 
-__all__ = ["BlockTables", "HostPagePool", "NULL_PAGE", "PoolExhausted",
-           "from_rows", "gather_pages", "kv_width", "layer_pages",
-           "make_pool", "pool_map", "quantized_rows", "scan_layers",
+__all__ = ["BlockTables", "CacheSpec", "HostPagePool", "NULL_PAGE",
+           "PoolExhausted", "cache_spec", "from_rows", "gather_pages",
+           "kv_width", "layer_pages", "make_pool", "make_slot_state",
+           "pool_map", "quantized_rows", "scan_layers",
            "sweep_attention", "to_rows", "write_rows"]
