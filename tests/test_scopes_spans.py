@@ -187,6 +187,11 @@ def test_the_vocabulary_is_the_documented_one():
 TREE = ("sched_admit", "prefill_args", "serving_prefill_chunk",
         "prefill_finish", "sched_grow", "decode_args", "decode_step",
         "decode_advance", "sched_deliver")
+# the chunk rides the decode step as one program: grow first, no
+# chunk-program span, the token read back inside decode_step
+MIXED_TREE = ("sched_admit", "sched_grow", "prefill_args", "decode_args",
+              "decode_step", "prefill_finish", "decode_advance",
+              "sched_deliver")
 
 
 class _Tick:
@@ -222,11 +227,16 @@ def _engine(params, cfg, **kw):
     return PagedEngine(params, cfg, compute_dtype=jnp.float32, **kw)
 
 
-def test_one_step_closes_the_span_tree_once(registry):
+@pytest.mark.parametrize("mixes", [False, True],
+                         ids=["two_programs", "mixed"])
+def test_one_step_closes_the_span_tree_once(registry, mixes):
     from torchbooster_tpu.serving import ContinuousBatcher, Request
 
     params, cfg = _model()
     b = ContinuousBatcher(_engine(params, cfg), clock=_Tick())
+    assert b.engine.mixes
+    b.engine.mixes = mixes
+    tree = MIXED_TREE if mixes else TREE
     events: list[dict] = []
     b.start_session()
     try:
@@ -247,22 +257,24 @@ def test_one_step_closes_the_span_tree_once(registry):
     finally:
         b.finish_session()
     names = [e["name"] for e in events]
-    assert sorted(names) == sorted((*TREE, "sched_step"))    # each once
+    assert sorted(names) == sorted((*tree, "sched_step"))    # each once
     by_name = {e["name"]: e for e in events}
     whole = by_name["sched_step"]
     assert whole["depth"] == 0 and whole["path"] == "sched_step"
-    for name in TREE:
+    for name in tree:
         child = by_name[name]
         assert child["path"] == f"sched_step/{name}", child
         assert whole["ts"] <= child["ts"]
     # in the order the iteration runs them, none overlapping
     assert [e["name"] for e in sorted(events, key=lambda e: e["ts"])
-            if e["name"] != "sched_step"] == list(TREE)
-    assert whole["dur_s"] >= sum(by_name[n]["dur_s"] for n in TREE) - 1e-5
+            if e["name"] != "sched_step"] == list(tree)
+    assert whole["dur_s"] >= sum(by_name[n]["dur_s"] for n in tree) - 1e-5
     snap = registry.snapshot()
-    # the accepted readers count these two: one per program call
+    # the accepted readers count these two: one per program call (a
+    # mixed step is a decode_step, and no chunk program's call)
+    assert b.engine.mixed_steps == (1 if mixes else 0)
     assert snap["span_seconds{name=serving_prefill_chunk}_count"] == \
-        b.engine.prefill_chunks
+        b.engine.prefill_chunks - b.engine.mixed_steps
     steps = sum(1 for r in b.flight.tail() if "decode" in r["kind"])
     assert snap["span_seconds{name=decode_step}_count"] == steps > 0
     assert snap["span_seconds{name=sched_step}_count"] == \

@@ -588,7 +588,8 @@ def test_batcher_prefix_cache_shared_prompt_end_to_end():
     assert metrics["prefix_hit_pages"] >= 4
     assert 0 < metrics["prefix_hit_rate"] <= 1
     assert metrics["n_prefill_chunks"] > 0
-    assert engine.prefill_compiles == 1
+    # the chunk program alone, and with the decode lanes riding
+    assert 1 <= engine.prefill_compiles <= 2
     assert engine.decode_compiles == 1
     engine.tables.check()
 

@@ -208,12 +208,23 @@ def _pool_sized_writes(compiled, layer_elems: int, pool_k, vocab: int):
     return moved
 
 
+def _lane_args(arg, tables, slots, pages) -> dict:
+    """The decode step's operands described, in ``_decode_fn``'s
+    order and under the names ``_chunk_fn(lanes=...)`` takes."""
+    return {"tables": arg(tables.tables.shape), "lengths": arg((slots,)),
+            "refs": arg(tables.refs.shape), "page_pos": arg((pages,)),
+            "active": arg((slots,), jnp.bool_),
+            "last_ids": arg((slots,))}
+
+
 @pytest.mark.parametrize("int8_pool", [False, True],
                          ids=["bf16-pool", "int8-pool"])
-@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("program", ["decode", "chunk", "mixed"])
 def test_serve_programs_keep_the_pool_in_place_on_v5e(
         one_chip, program, int8_pool):
-    """``PagedEngine._decode_fn`` / ``_chunk_fn`` compiled for the
+    """``PagedEngine._decode_fn`` / ``_chunk_fn`` (alone, and MIXED:
+    the decode lanes riding the chunk, both K/V writes and both reads
+    of a layer in one program) compiled for the
     described v5e with the pools donated: no instruction writes a
     buffer the size of a layer's pool other than the in-place update
     of the pool itself, the pool comes back in the layout it went in
@@ -251,18 +262,19 @@ def test_serve_programs_keep_the_pool_in_place_on_v5e(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     tables = engine.tables
+    lanes = _lane_args(arg, tables, XL_SLOTS, XL_PAGES)
+    kw = {}
     if program == "decode":
         fn, args = engine._decode_fn, (
-            arg(tables.tables.shape), arg((XL_SLOTS,)),
-            arg(tables.refs.shape), arg((XL_PAGES,)),
-            arg((XL_SLOTS,), jnp.bool_), arg((XL_SLOTS,)),
-            arg((2,), jnp.uint32))
+            *lanes.values(), arg((2,), jnp.uint32))
     else:
         fn, args = engine._chunk_fn, (
             arg((1, engine.chunk_tokens)), arg(()), arg(()),
             arg((tables.max_pages_per_slot,)), arg((2,), jnp.uint32))
+        if program == "mixed":
+            kw = {"lanes": lanes}
     compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-        params, pool_k, pool_v, *args).compile()
+        params, pool_k, pool_v, *args, **kw).compile()
 
     head_dim = cfg.d_model // cfg.n_heads
     layer_elems = XL_PAGES * XL_PAGE * cfg.kv_heads * head_dim
@@ -296,10 +308,11 @@ def test_serve_programs_keep_the_pool_in_place_on_v5e(
         memory.temp_size_in_bytes, layer_kv)
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("program", ["decode", "chunk", "mixed"])
 def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
         one_chip, program, monkeypatch):
-    """The two programs of the ``lfm2-8b-a1b.serve-rag-r80`` cell at
+    """The programs of the ``lfm2-8b-a1b.serve-rag-r80`` cell (decode,
+    the chunk alone, and the chunk with the decode lanes riding) at
     its own size (14 layers at published widths, 2048 pages of 64, 64
     slots, chunks of 256), compiled for the described v5e with the
     pool AND the conv mixers' slot state donated: both come back
@@ -355,19 +368,20 @@ def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     tables = engine.tables
+    lanes = _lane_args(arg, tables, slots, pages)
+    kw = {}
     if program == "decode":
         fn, donate, args = engine._decode_fn, (1, 2, 10), (
-            arg(tables.tables.shape), arg((slots,)),
-            arg(tables.refs.shape), arg((pages,)),
-            arg((slots,), jnp.bool_), arg((slots,)),
-            arg((2,), jnp.uint32), state)
+            *lanes.values(), arg((2,), jnp.uint32), state)
     else:
         fn, donate, args = engine._chunk_fn, (1, 2, 8), (
             arg((1, engine.chunk_tokens)), arg(()), arg(()),
             arg((tables.max_pages_per_slot,)), arg((2,), jnp.uint32),
             state, arg(()))
+        if program == "mixed":
+            kw = {"lanes": lanes}
     compiled = jax.jit(fn, donate_argnums=donate).lower(
-        params, pool_k, pool_v, *args).compile()
+        params, pool_k, pool_v, *args, **kw).compile()
 
     layer_elems = pages * page * 512
     moved = _pool_sized_writes(compiled, layer_elems, pool_k, cfg.vocab)
@@ -378,11 +392,22 @@ def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
     assert memory.alias_size_in_bytes >= nbytes((pool_k, pool_v, state))
     # the experts' three matrices of ONE layer, were they applied to
     # every pair: the scratch stays far under it
-    pairs = (slots if program == "decode" else engine.chunk_tokens) \
-        * cfg.top_k
+    pairs = {"decode": slots, "chunk": engine.chunk_tokens,
+             "mixed": engine.chunk_tokens + slots}[program] * cfg.top_k
     assert memory.temp_size_in_bytes < cfg.n_experts * pairs \
         * cfg.expert_width * 2 + cfg.vocab * cfg.d_model * 2 + 2**26
     assert "ragged-dot" not in compiled.as_text()
+    # no copy as large as ONE layer's stack of one expert matrix: the
+    # grouped products read the experts where they lie
+    stack = cfg.n_experts * cfg.d_model * cfg.expert_width
+    copies = [(name, dims) for name, op, results
+              in _top_level_results(compiled.as_text()) if op == "copy"
+              for _, dims, _ in results
+              if math.prod(int(d) for d in dims.split(",") if d) >= stack]
+    assert not copies, f"expert-stack-sized copies: {copies}"
     total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
              - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
     assert nbytes(params) > 9.3e9 and total < V5E_HBM_BYTES, total
+    if program == "mixed":
+        # beside the weights: what the cell's two programs took
+        assert total < 10.6e9, total

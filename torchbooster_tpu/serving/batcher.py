@@ -755,8 +755,15 @@ class ContinuousBatcher:
             "hit_pages": reg.counter(
                 "serving_prefix_hit_pages_total",
                 "prompt pages served from the prefix cache"),
+            # both land per chunk (not at the session's end): their
+            # ratio over any window is the share of chunks that rode
+            # a decode step
             "chunks": reg.counter("serving_prefill_chunks_total",
                                   "prefill chunks issued"),
+            "mixed": reg.counter(
+                "serving_mixed_steps_total",
+                "prefill chunks issued in ONE program with the decode "
+                "step (PagedEngine.mixed_step)"),
             "hit_rate": reg.gauge(
                 "serving_prefix_hit_rate",
                 "prefix-cache page hit rate over this run"),
@@ -1281,19 +1288,33 @@ class ContinuousBatcher:
         """The iteration's phases, each under its span (a shared no-op
         while the registry is off): the tree docs/observability.md
         draws, read by the benchmark's ``sched_host_ms``."""
+        eng = self.engine
         with span("sched_admit"):
             self._admit(s, events)
         # --- ONE prefill chunk per iteration, interleaved with
         # decode: long prompts stream in while the live slots keep
-        # producing tokens ---
-        if self.engine.has_pending:
+        # producing tokens. A pending chunk and live slots are ONE
+        # program (the chunk rides the decode step: each weight read
+        # once) where the engine's mode allows; the lanes run in it,
+        # so every live slot's write page must exist first and grow
+        # moves in front — and may preempt the seat that was filling,
+        # or the last live slot ---
+        grown = mixed = eng.mixes and eng.has_pending and bool(s.live)
+        if mixed:
+            with span("sched_grow"):
+                self._grow(s)
+            mixed = eng.has_pending and bool(s.live)
+        if mixed:
+            self._mixed_one(s, st, events)
+        elif eng.has_pending:
             self._prefill_one(s, st, events)
         self._inst["slots"].set(len(s.live))
-        self._inst["pages"].set(self.engine.tables.n_free_pages)
-        if not s.live:
+        self._inst["pages"].set(eng.tables.n_free_pages)
+        if mixed or not s.live:
             return
-        with span("sched_grow"):
-            self._grow(s)
+        if not grown:
+            with span("sched_grow"):
+                self._grow(s)
         if s.live:
             self._decode_one(s, st, events)
 
@@ -1411,10 +1432,19 @@ class ContinuousBatcher:
         t_chunk = self.clock()
         done = self.engine.prefill_step()
         dt = self.clock() - t_chunk
+        self._chunk_issued(s, st, fill_slot, dt)
+        st["wall"] += dt
+        if done is not None:
+            self._prefill_done(s, *done, events)
+
+    def _chunk_issued(self, s: _Session, st: dict, fill_slot: int,
+                      dt: float) -> None:
+        """Book one issued chunk (alone or in a mixed step) that took
+        ``dt`` on the host's clock."""
+        self._inst["chunks"].inc()
         self.est_chunk_s = dt if not self.est_chunk_s \
             else 0.8 * self.est_chunk_s + 0.2 * dt
         st["prefill"] = True
-        st["wall"] += dt
         if self.tracer.enabled:
             # the engine-track slice shares its name with the
             # serving_prefill_chunk profiler span (spans.py), so
@@ -1426,28 +1456,52 @@ class ContinuousBatcher:
                 self.tracer.emit(fr.request_id, "prefill_chunk",
                                  slot=fill_slot,
                                  dur_s=round(dt, 6))
+
+    def _prefill_done(self, s: _Session, slot: int, first: int,
+                      events: list) -> None:
+        """A prompt's last chunk gave its first token: the request
+        moves from filling to live and the token is delivered."""
+        req = s.filling.pop(slot)
+        s.live[slot] = req
+        if req.n_branches > 1 and req.branches is None:
+            # one prefill, best_of decode branches: fork at
+            # the boundary so every branch diverges from its
+            # own first token (branch 0's pick == `first`)
+            self._fork_request(slot, req, events)
+        else:
+            if self.engine.parallel:
+                # the first token's logprob belongs to the
+                # sequence logprob too (n = 1 requests and
+                # re-admitted fork branches alike — a
+                # preempted branch skipping it would bias
+                # best_of toward preempted siblings); this
+                # also frees the stashed prompt logits a
+                # never-forking request otherwise holds
+                req.cum_logprob += \
+                    self.engine.take_first_logprob(slot)
+            self._maybe_stop(slot, first)  # prefill's token
+            events.append((req, [int(first)]))
+
+    def _mixed_one(self, s: _Session, st: dict, events: list) -> None:
+        """The pending chunk and the decode step over every live slot
+        as ONE program (``PagedEngine.mixed_step``). The step's host
+        time feeds BOTH service-time estimates (the chunk and the
+        step are one wait); a prompt's first token leaves with this
+        step's decode tokens and its slot decodes from the next
+        iteration on."""
+        fill_slot = (self.engine.pending_slots[0]
+                     if self.tracer.enabled else -1)
+        decoders = list(s.live)
+        t_step = self.clock()
+        tokens, done = self.engine.mixed_step()
+        dt = self.clock() - t_step
+        self._inst["mixed"].inc()
+        self._chunk_issued(s, st, fill_slot, dt)
+        self._step_done(s, st, dt, len(decoders))
         if done is not None:
-            slot, first = done
-            req = s.filling.pop(slot)
-            s.live[slot] = req
-            if req.n_branches > 1 and req.branches is None:
-                # one prefill, best_of decode branches: fork at
-                # the boundary so every branch diverges from its
-                # own first token (branch 0's pick == `first`)
-                self._fork_request(slot, req, events)
-            else:
-                if self.engine.parallel:
-                    # the first token's logprob belongs to the
-                    # sequence logprob too (n = 1 requests and
-                    # re-admitted fork branches alike — a
-                    # preempted branch skipping it would bias
-                    # best_of toward preempted siblings); this
-                    # also frees the stashed prompt logits a
-                    # never-forking request otherwise holds
-                    req.cum_logprob += \
-                        self.engine.take_first_logprob(slot)
-                self._maybe_stop(slot, first)  # prefill's token
-                events.append((req, [int(first)]))
+            self._prefill_done(s, *done, events)
+        with span("sched_deliver"):
+            self._deliver_tokens(s, tokens, events, decoders)
 
     def _grow(self, s: _Session) -> None:
         # --- grow: every live slot's next write page must exist
@@ -1497,20 +1551,24 @@ class ContinuousBatcher:
                 self._deliver_bursts(s, emitted, events)
         else:
             tokens = self.engine.step()
-            dt = self.clock() - t_step
-            s.decode_time += dt
-            self.est_step_s = dt if not self.est_step_s \
-                else 0.8 * self.est_step_s + 0.2 * dt
-            st["decode"] = True
-            st["wall"] += dt
-            if self.tracer.enabled:
-                self.tracer.emit(None, "decode_step",
-                                 dur_s=round(dt, 6),
-                                 slots=len(s.live), step=s.n_steps)
-            s.decoded += len(s.live)
-            self._inst["tokens"].inc(len(s.live))
+            self._step_done(s, st, self.clock() - t_step, len(s.live))
             with span("sched_deliver"):
-                self._deliver_tokens(s, tokens, events)
+                self._deliver_tokens(s, tokens, events, list(s.live))
+
+    def _step_done(self, s: _Session, st: dict, dt: float,
+                   n_slots: int) -> None:
+        """Book one decode step (plain or mixed) over ``n_slots`` live
+        slots that took ``dt`` on the host's clock."""
+        s.decode_time += dt
+        self.est_step_s = dt if not self.est_step_s \
+            else 0.8 * self.est_step_s + 0.2 * dt
+        st["decode"] = True
+        st["wall"] += dt
+        if self.tracer.enabled:
+            self.tracer.emit(None, "decode_step", dur_s=round(dt, 6),
+                             slots=n_slots, step=s.n_steps)
+        s.decoded += n_slots
+        self._inst["tokens"].inc(n_slots)
 
     def _deliver_bursts(self, s: _Session, emitted: dict,
                         events: list) -> None:
@@ -1551,11 +1609,15 @@ class ContinuousBatcher:
         self._inst["tokens"].inc(delivered)
 
     def _deliver_tokens(self, s: _Session, tokens: np.ndarray,
-                        events: list) -> None:
+                        events: list, decoders: list[int]) -> None:
+        """The step's token to every slot in ``decoders`` (the slots
+        live when it ran) that a cancel has not taken since."""
         self._drain_cancels(events)
         lps = self.engine.step_logprobs
-        for slot in list(s.live):
-            req = s.live[slot]
+        for slot in decoders:
+            req = s.live.get(slot)
+            if req is None:
+                continue
             if lps is not None:
                 # per-branch sequence logprob — what best_of
                 # ranks by (parallel-sampling engines only)
@@ -1640,7 +1702,6 @@ class ContinuousBatcher:
         hit_pages = self.engine.prefix_hit_pages - s.hits0
         lookups = self.engine.prefix_lookup_pages - s.lookups0
         inst["hit_pages"].inc(hit_pages)
-        inst["chunks"].inc(self.engine.prefill_chunks - s.chunks0)
         inst["hit_rate"].set(hit_pages / max(lookups, 1))
         n_spec_prop = self.engine.spec_proposed - s.spec_prop0
         n_spec_acc = self.engine.spec_accepted - s.spec_acc0

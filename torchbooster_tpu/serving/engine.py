@@ -45,6 +45,18 @@ the cache-hit TTFT and the prefill FLOPs the hits skip; a
 dense-geometry control — ``page_size=seq_len``, one page per slot —
 runs the SAME code at dense bytes).
 
+- **a chunk beside live slots is ONE program**: where an iteration
+  has a pending chunk AND slots decoding, the decode lanes ride the
+  chunk program (``mixed_step``; the second and last variant
+  ``_chunk_fn`` compiles to): chunk tokens and lanes share one token
+  axis through every weight product, so an iteration reads each weight
+  once, launches once and reads back once, where a chunk program and a
+  decode program back to back each stream every weight from HBM. What
+  is per sequence (K/V writes, the two attentions, a conv state, the
+  picks) stays split and reuses the two bodies above (``_Pieces``,
+  ``_ride``). A chunk with nothing decoding beside it, and the plain
+  decode step, are the programs they always were.
+
 The pool itself (``kv_pages.make_pool`` owns its shape: ``(n_layers,
 n_pages, page_size, kv_width)``, heads and head dim merged into one
 128-aligned row) is donated to every program and updated IN PLACE:
@@ -52,7 +64,7 @@ the three layer loops carry it (``kv_pages.scan_layers``), write at
 ``[layer, page, offset]`` of the stacked array, and read a layer's
 pages where they lie — no program produces a second buffer the size
 of a layer's pool (tests/test_tpu_aot_compile.py holds the compiled
-decode and chunk programs to that).
+decode, chunk and mixed programs to that).
 
 The compiled step's signature depends only on pool geometry
 ``(n_pages, page_size, max_slots)`` and the model config — admission,
@@ -63,7 +75,7 @@ size).
 """
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -160,6 +172,64 @@ def _served_model(cfg: Any):
         f"PagedEngine: no served model for a {type(cfg).__name__}")
 
 
+class _Pieces(NamedTuple):
+    """What one kind of token brings to a serving program — a prefill
+    chunk's (``PagedEngine._chunk_pieces``) or the decode lanes'
+    (``_lane_pieces``): the embedded input and what is per SEQUENCE
+    about it. The layer stack (``PagedEngine._layers``) runs the
+    weights over ``x`` and calls back for the rest."""
+    x: jax.Array            # (B, S, d) embedded tokens
+    positions: jax.Array    # (B, S) absolute positions (rope)
+    valid: jax.Array        # (B, S) real tokens (expert routing)
+    write: Callable         # (k, v, pool_k, pool_v, li) -> pools
+    read: Callable          # (q, k, v, pool_k, pool_v, li) -> o
+    conv: Callable | None   # (z, w, state, li) -> (c, state)
+    rows: Callable          # x -> (n, 1, d), the rows the head reads
+
+
+def _ride(chunk: _Pieces, lanes: _Pieces) -> _Pieces:
+    """The MIXED program's pieces: the lanes' ``(slots, 1, ...)``
+    tokens ride behind the chunk's ``(1, C, ...)`` on one token axis
+    ``(1, C + slots, ...)``, so every weight product sees both and
+    reads its weight once. What is per sequence splits the axis again
+    at the static ``C`` and goes to the two bodies that exist, ONE
+    pool and one state threaded through both: both K/V writes land
+    before either read (the seating slot is never active, so they
+    touch disjoint pages, and a read between two writes would make
+    the compiler keep a copy of the pool), the outputs are joined
+    back. Expert routing sorts chunk and lane tokens together: one
+    grouped product a projection reads each expert once."""
+    C = chunk.x.shape[1]
+    # lanes' (slots, 1, ...) as (1, slots, ...), and back
+    turn = lambda t: jnp.moveaxis(t, 0, 1)
+    join = lambda c, l: jnp.concatenate([c, turn(l)], axis=1)
+    split = lambda t: (t[:, :C], turn(t[:, C:]))
+
+    def write(k, v, pk, pv, li):
+        (kc, kl), (vc, vl) = split(k), split(v)
+        return lanes.write(kl, vl, *chunk.write(kc, vc, pk, pv, li), li)
+
+    def read(q, k, v, pk, pv, li):
+        (qc, ql), (kc, kl), (vc, vl) = split(q), split(k), split(v)
+        return join(chunk.read(qc, kc, vc, pk, pv, li),
+                    lanes.read(ql, kl, vl, pk, pv, li))
+
+    def conv(z, w, state, li):
+        zc, zl = split(z)
+        cc, state = chunk.conv(zc, w, state, li)
+        cl, state = lanes.conv(zl, w, state, li)
+        return join(cc, cl), state
+
+    def rows(x):
+        xc, xl = split(x)
+        return jnp.concatenate([chunk.rows(xc), lanes.rows(xl)], axis=0)
+
+    return _Pieces(join(chunk.x, lanes.x),
+                   join(chunk.positions, lanes.positions),
+                   join(chunk.valid, lanes.valid), write, read,
+                   conv if chunk.conv is not None else None, rows)
+
+
 class PagedEngine:
     """Single-compile continuous-batching decode over a paged KV pool
     with an optional prompt-prefix cache.
@@ -167,7 +237,21 @@ class PagedEngine:
     ``admit_begin``/``prefill_step``/``step``/``retire`` are the whole
     lifecycle; the host-side batcher (serving/batcher.py) drives them,
     interleaving one prefill chunk per decode step so long prompts
-    never stall in-flight decode. ``admit`` is the one-shot
+    never stall in-flight decode — as ONE program an iteration
+    (``mixed_step``) where a chunk is pending and slots are live.
+    **Which modes ride the mixed program** is decided once at build
+    (``self.mixes``), from the engine's own mode: the defaults ride,
+    and so do ``cache_dtype="int8"``, ``decode_backend="pallas"``,
+    ``structured``, ``prefix_cache`` / ``host_spill`` and a model with
+    its own layer stack and slot state (their operands are the two
+    programs' trailing VALUE operands, and the chunk's and the lanes'
+    bodies are the ones those modes already run). ``speculative`` (its
+    verify step is another program), ``parallel_sampling`` (the chunk
+    returns the fork's logits and picks by branch key), ``adapters``
+    (lora lane ids are per batch row, and the mixed program has one),
+    ``tp > 1`` (the shard_map wrappers fix the operand lists) and
+    ``prefill_only`` (nothing decodes) keep two programs an
+    iteration. ``admit`` is the one-shot
     convenience (seat + drain this request's chunks). ``cache_dtype=
     "int8"`` stores quantized pages (``_quantize_kv`` — the same
     per-(token, head) scheme as the dense cache). ``temperature=0``
@@ -497,6 +581,7 @@ class PagedEngine:
         self._pending: list[dict] = []
         # host-side totals the batcher exports (telemetry counters)
         self.prefill_chunks = 0
+        self.mixed_steps = 0     # of them, issued inside mixed_step
         self.prefix_hit_pages = 0
         self.prefix_lookup_pages = 0
         self.spills = 0          # pages demoted HBM -> host
@@ -685,6 +770,13 @@ class PagedEngine:
         # collapse contract as n_ref_lanes for the prefix cache)
         self.speculative = bool(speculative)
         self.draft_len = draft_len
+        # whether a pending chunk rides the decode step as ONE program
+        # (mixed_step), decided once from the engine's own mode: the
+        # modes whose chunk and decode programs differ in more than
+        # their trailing VALUE operands keep two programs an iteration
+        # (the class docstring lists them)
+        self.mixes = not (self.speculative or self.parallel or self.lora
+                          or self.tp > 1 or self.prefill_only)
         # tree speculative decoding: the drafter proposes a TREE of
         # candidate branches and the verify step scores every node in
         # the same single pass through ancestor-only visibility masks
@@ -742,7 +834,7 @@ class PagedEngine:
 
     # ---- compiled pieces -----------------------------------------
     def _chunk_fn(self, params, pool_k, pool_v, ids, start, s0,
-                  table_row, rng, *extra):
+                  table_row, rng, *extra, lanes=None):
         """ONE prefill chunk: forward ``ids`` (1, chunk_tokens) at
         absolute positions ``start + [0, C)``, writing each layer's
         K/V into the slot's pages and attending prior context through
@@ -767,19 +859,127 @@ class PagedEngine:
         preempted-and-refolded branch resumes its sampling stream
         exactly — and the return grows the pick's logprob plus the
         final-position logits ``fork()`` samples sibling branches'
-        first tokens from."""
+        first tokens from.
+
+        **With ``lanes``** (a dict of the decode step's operands:
+        :meth:`_lane_operands`) the decode lanes RIDE the chunk: this
+        is then the MIXED program, the second and last variant this
+        function compiles to (``lanes`` is None or a dict: pytree
+        structure, so static). The chunk's ``C`` tokens and the
+        ``max_slots`` lanes are one ``(1, C + max_slots)`` token axis
+        through every weight product — each weight is read once where
+        a chunk program and a decode program would each read it — and
+        only what is per sequence stays split (:func:`_ride`): the
+        K/V writes and the two attentions, the conv state, the picks.
+        Returns ``(chunk's token, lanes' tokens, pool_k, pool_v[,
+        state])``."""
         # lora operands ride LAST (appended after every other mode's),
         # so they strip from the end FIRST — the earlier modes' reads
         # (structured extra[0] below) then see their PR-era layout
-        lora_w = lane1 = None
+        lora = None
         if self.lora:
-            lora_w, lane1 = extra[-5:-1], extra[-1]
-            extra = extra[:-5]
+            lora, extra = (extra[-5:-1], extra[-1]), extra[:-5]
         # a model with slot state: the state and the seating slot ride
         # FIRST among the trailing operands
         state = slot = None
         if self.slot_state is not None:
             state, slot, extra = extra[0], extra[1], extra[2:]
+        pieces = self._chunk_pieces(params, ids, start, s0, table_row,
+                                    slot)
+        if lanes is not None:
+            pieces = _ride(pieces, self._lane_pieces(
+                params, lanes["tables"], lanes["lengths"], lanes["refs"],
+                lanes["page_pos"], lanes["active"], lanes["last_ids"],
+                lanes.get("work")))
+        x, pool_k, pool_v, state, moe_counts = self._layers(
+            params, pieces, pool_k, pool_v, state, lora)
+        # the head's one product: the prompt's last real row, and in
+        # the mixed program the max_slots lanes under it
+        logits = self._logits(params, pieces.rows(x))
+        # structured mode: the trailing operand is the seating slot's
+        # (1, vocab) legality row (all-True when unconstrained — a
+        # bitwise no-op, so unconstrained traffic stays token-exact).
+        # The STASHED logits below stay unmasked: fork() masks them
+        # itself with the START-state row so every branch's first
+        # pick replays the independent-run distribution.
+        smask1 = extra[0] if self.structured else None
+        if lanes is not None:
+            # ONE rng operand for the iteration: the chunk's pick and
+            # the lanes' each get a half of its split
+            rng, rng_lanes = jax.random.split(rng)
+            tok = self._pick(rng, _mask_logits(logits[:1], smask1))
+            tokens = self._pick(rng_lanes, _mask_logits(
+                logits[1:], lanes.get("smask")))
+            outs = (tok, tokens, pool_k, pool_v)
+            return outs if state is None else outs + (state,)
+        if self.model is not None:
+            return self._pick(rng, logits), pool_k, pool_v, state, \
+                moe_counts
+        picked = _mask_logits(logits, smask1)
+        if self.parallel:
+            key = jax.random.fold_in(rng, s0)
+            tok, lp = self._branch_pick(key[None], picked)
+            return tok, lp, logits, pool_k, pool_v
+        return self._pick(rng, picked), pool_k, pool_v
+
+    def _decode_fn(self, params, pool_k, pool_v, tables, lengths,
+                   refs, page_pos, active, last_ids, rng, *extra):
+        """One decode step over all slots. Signature shapes depend
+        only on pool geometry — never on which slots are live or how
+        pages are shared. The trailing operands exist only on their
+        modes — ``work_*`` on the pallas backend (the compacted
+        live-page walk from ``kernel_args()``), the slot-key table in
+        parallel-sampling mode — so the default engine's jitted call
+        signature is byte-identical to the pre-feature one."""
+        work = slot_keys = smask = None
+        # lora strips from the END first (its operands append last),
+        # leaving the earlier modes' front/back reads untouched
+        lora = None
+        if self.lora:
+            lora, extra = (extra[-5:-1], extra[-1]), extra[:-5]
+        if self.decode_backend == "pallas":
+            work, extra = extra[:3], extra[3:]
+        if self.structured:
+            smask = extra[0]            # (max_slots, vocab) legality
+            extra = extra[1:]
+        if self.parallel:
+            slot_keys = extra[-1]
+        state = None
+        if self.slot_state is not None:
+            state, extra = extra[0], extra[1:]
+        pieces = self._lane_pieces(params, tables, lengths, refs,
+                                   page_pos, active, last_ids, work)
+        x, pool_k, pool_v, state, moe_counts = self._layers(
+            params, pieces, pool_k, pool_v, state, lora)
+        logits = self._logits(params, pieces.rows(x))
+        if self.model is not None:
+            return self._pick(rng, logits), pool_k, pool_v, state, \
+                moe_counts
+        # constrained slots' rows knock illegal tokens to finfo.min;
+        # unconstrained rows are all-True (bitwise no-op — greedy and
+        # seeded sampling stay token-identical with the feature on)
+        logits = _mask_logits(logits, smask)
+        if self.parallel:
+            # per-branch keys: fold each slot's branch key with its
+            # context length (lengths + 1 — the pending token counts),
+            # so branch b's token at depth d is a pure function of
+            # (branch key, d, logits): token-exact vs an independent
+            # single-slot run with the same key, preemption-invariant
+            # (a refolded prompt re-samples with the same context
+            # count), and graftlint's prng rule stays green (fold_in
+            # is the sanctioned derivation)
+            keys = jax.vmap(jax.random.fold_in)(slot_keys, lengths + 1)
+            tokens, lps = self._branch_pick(keys, logits)
+            return tokens, lps, pool_k, pool_v
+        return self._pick(rng, logits), pool_k, pool_v
+
+    def _chunk_pieces(self, params, ids, start, s0, table_row,
+                      slot) -> "_Pieces":
+        """What is the CHUNK's of a program (:class:`_Pieces`): its
+        ``(1, C)`` tokens embedded, the write of their K/V into the
+        slot's pages, their attention over the slot's pages and
+        themselves, the seating slot's conv state, and the prompt's
+        last real row for the head."""
         cfg, ps = self.cfg, self.page_size
         C = ids.shape[1]
         n_cp = C // ps
@@ -823,22 +1023,24 @@ class PagedEngine:
             return pool_map(lambda a: a.reshape(1, -1, *a.shape[2:]),
                             self._heads(pages))
 
-        def attend(q, k, v, pk, pv, li):
-            # layer ``li`` of the pool: this chunk's K/V written, its
-            # queries attended over the slot's pages and the chunk
+        def write(k, v, pk, pv, li):
+            # layer ``li`` of the pool: this chunk's K/V written
             g = k.shape[2]
             with jax.named_scope("kv_write"):
                 new_k = write_rows(pk, (li, w_pages), self._page_rows(
                     k[0].reshape(n_cp, ps, g, head_dim), pk))
                 new_v = write_rows(pv, (li, w_pages), self._page_rows(
                     v[0].reshape(n_cp, ps, g, head_dim), pv))
+            return new_k, new_v
+
+        def read(q, k, v, pk, pv, li):
             # the slot's own pages back out of the stacked pool:
             # mp pages of this layer, not the layer's pool (read
             # after the write, so the update stays in place; the
             # chunk's own pages sit at positions >= start, which
             # vis_prior masks)
-            gk = context(gather_pages(new_k, li, table_row))
-            gv = context(gather_pages(new_v, li, table_row))
+            gk = context(gather_pages(pk, li, table_row))
+            gv = context(gather_pages(pv, li, table_row))
             # prior context (this slot's already-written pages,
             # masked to < start) and the chunk itself
             # (compute-dtype K/V — parity with the dense prefill's
@@ -857,8 +1059,9 @@ class PagedEngine:
             mv = lambda t: jnp.moveaxis(t, -1, 1)[..., None]
             o = (oA * mv(wA) + oB * mv(wB)) / mv(l)
             o = o.reshape(1, C, n_heads_l, head_dim)
-            return o.astype(q.dtype), (new_k, new_v)
+            return o.astype(q.dtype)
 
+        conv = None
         if self.model is not None:
             # the prompt's real tokens in this chunk: the conv state
             # kept is that of the prompt's TRUE end, not of the padded
@@ -877,73 +1080,22 @@ class PagedEngine:
                 return c, {"conv": rows.at[li, slot].set(
                     keep.astype(rows.dtype))}
 
-            x, (pool_k, pool_v), state, moe_counts = self.model.layers(
-                params, x, cfg, positions=positions[None],
-                attend=lambda q, k, v, cache, li: attend(q, k, v, *cache,
-                                                         li),
-                conv=conv, cache=(pool_k, pool_v), state=state,
-                valid=(positions < s0)[None])
-        else:
-            def layer(x, pk, pv, bp, li, lora):
-                x, _, (pk, pv) = _block_core(
-                    bp, x, cfg,
-                    lambda q, k, v: attend(q, k, v, pk, pv, li),
-                    capacity_factor=max(cfg.capacity_factor,
-                                        float(cfg.n_experts)),
-                    positions=positions[None],  # per-slot rope depth
-                    tp_attn=self._tp_core,
-                    lora=(lora, lane1) if self.lora else None)
-                return x, pk, pv
+        def rows(x):
+            # the row that picks: the prompt's position ``s0 - 1``
+            # where this chunk holds it, (1, 1, d)
+            return jax.lax.dynamic_slice_in_dim(
+                x, jnp.clip(s0 - 1 - start, 0, C - 1), 1, axis=1)
 
-            x, pool_k, pool_v = scan_layers(layer, x, pool_k, pool_v,
-                                            params["blocks"], lora_w)
-        last = jax.lax.dynamic_slice_in_dim(
-            x, jnp.clip(s0 - 1 - start, 0, C - 1), 1, axis=1)
-        if self.model is not None:
-            tok = self._pick(rng, self.model.head(params, last, cfg)[:, 0])
-            return tok, pool_k, pool_v, state, moe_counts
-        logits = _lm_head(params, last)[:, 0]
-        # structured mode: the trailing operand is the seating slot's
-        # (1, vocab) legality row (all-True when unconstrained — a
-        # bitwise no-op, so unconstrained traffic stays token-exact).
-        # The STASHED logits below stay unmasked: fork() masks them
-        # itself with the START-state row so every branch's first
-        # pick replays the independent-run distribution.
-        picked = _mask_logits(logits, extra[0]) if self.structured \
-            else logits
-        if self.parallel:
-            key = jax.random.fold_in(rng, s0)
-            tok, lp = self._branch_pick(key[None], picked)
-            return tok, lp, logits, pool_k, pool_v
-        return self._pick(rng, picked), pool_k, pool_v
+        return _Pieces(x, positions[None], (positions < s0)[None],
+                       write, read, conv, rows)
 
-    def _decode_fn(self, params, pool_k, pool_v, tables, lengths,
-                   refs, page_pos, active, last_ids, rng, *extra):
-        """One decode step over all slots. Signature shapes depend
-        only on pool geometry — never on which slots are live or how
-        pages are shared. The trailing operands exist only on their
-        modes — ``work_*`` on the pallas backend (the compacted
-        live-page walk from ``kernel_args()``), the slot-key table in
-        parallel-sampling mode — so the default engine's jitted call
-        signature is byte-identical to the pre-feature one."""
-        work_pages = work_refs = work_pos = slot_keys = smask = None
-        # lora strips from the END first (its operands append last),
-        # leaving the earlier modes' front/back reads untouched
-        lora_w = lane_ids = None
-        if self.lora:
-            lora_w, lane_ids = extra[-5:-1], extra[-1]
-            extra = extra[:-5]
-        if self.decode_backend == "pallas":
-            work_pages, work_refs, work_pos = extra[:3]
-            extra = extra[3:]
-        if self.structured:
-            smask = extra[0]            # (max_slots, vocab) legality
-            extra = extra[1:]
-        if self.parallel:
-            slot_keys = extra[-1]
-        state = None
-        if self.slot_state is not None:
-            state, extra = extra[0], extra[1:]
+    def _lane_pieces(self, params, tables, lengths, refs, page_pos,
+                     active, last_ids, work=None) -> "_Pieces":
+        """What is the decode LANES' of a program (:class:`_Pieces`):
+        every slot's last token embedded at its own depth ``(slots, 1,
+        d)``, the write of its K/V at ``lengths``, the slots'
+        attention over the pool, the live slots' conv state shifted.
+        ``work``: the pallas backend's compacted live-page walk."""
         cfg, ps = self.cfg, self.page_size
         n_slots = last_ids.shape[0]
         n_heads_l = cfg.n_heads // self.tp    # local heads (tp shard)
@@ -992,14 +1144,17 @@ class PagedEngine:
         w_page = jnp.where(active, w_page, 0)
         w_off = lengths % ps
 
-        def attend(q, k, v, pk, pv, li):
-            # layer ``li`` of the pool: this step's K/V written, the
-            # slots' queries attended over the layer's pages
+        def write(k, v, pk, pv, li):
+            # layer ``li`` of the pool: this step's K/V written
             with jax.named_scope("kv_write"):
                 new_k = write_rows(pk, (li, w_page, w_off),
                                    self._page_rows(k[:, 0], pk))
                 new_v = write_rows(pv, (li, w_page, w_off),
                                    self._page_rows(v[:, 0], pv))
+            return new_k, new_v
+
+        def read(q, k, v, pk, pv, li):
+            # the slots' queries attended over the layer's pages
             if self.decode_backend == "pallas":
                 # the in-kernel block-table walk: the kernel's
                 # grid iterates the compacted live-page list and
@@ -1008,11 +1163,12 @@ class PagedEngine:
                 # the pool; (page, lane) partials merge per slot
                 # in VMEM scratch with the same online-softmax
                 # combine the sweep runs through segment ops
+                work_pages, work_refs, work_pos = work
                 o = paged_attention(
-                    q, self._kernel_pages(new_k, li),
-                    self._kernel_pages(new_v, li), work_pages,
+                    q, self._kernel_pages(pk, li),
+                    self._kernel_pages(pv, li), work_pages,
                     work_refs, work_pos, lengths, page_size=ps)
-                return o.astype(q.dtype), (new_k, new_v)
+                return o.astype(q.dtype)
             # the pool sweep: each page attends the queries of ALL
             # its reference lanes (a gather of the TINY q tensor
             # into (P, R, H, Dh) — the layer's pages are read in
@@ -1024,8 +1180,8 @@ class PagedEngine:
             # combine
             q_lanes = q[:, 0][ref_c]        # (P, R, H, Dh)
             o_p, m_p, l_p = sweep_attention(
-                q_lanes, layer_pages(new_k, li),
-                layer_pages(new_v, li), visible, k.shape[2])
+                q_lanes, layer_pages(pk, li), layer_pages(pv, li),
+                visible, k.shape[2])
             # o (P, R, g, rep, Dh); m/l (P, g, rep, R): flatten
             # the (page, lane) pairs into one segment axis
             n_pp = o_p.shape[0]
@@ -1045,60 +1201,68 @@ class PagedEngine:
                 l_s[:n_slots], 1e-30)[..., None]
             o = o.reshape(n_slots, 1, n_heads_l,
                           cfg.d_model // cfg.n_heads)
-            return o.astype(q.dtype), (new_k, new_v)
+            return o.astype(q.dtype)
+
+        def conv(z, w, st, li):
+            # live slots shift their state by this step's input;
+            # dead and mid-prefill slots keep theirs
+            rows = st["conv"]
+            prev = rows[li]                 # (slots, K-1, d)
+            c, zz = L.short_conv(z, w, prev)
+            new = jnp.where(active[:, None, None],
+                            zz[:, 1:].astype(rows.dtype), prev)
+            return c, {"conv": rows.at[li].set(new)}
+
+        return _Pieces(x, lengths[:, None], active[:, None], write,
+                       read, conv if self.model is not None else None,
+                       lambda x: x)
+
+    def _layers(self, params, pieces: "_Pieces", pool_k, pool_v, state,
+                lora=None):
+        """The layer stack over ``pieces.x``, the pool (and a model's
+        slot state) carried and updated in place: ``_block_core`` over
+        the stacked blocks for a GPT, the served model's own
+        ``layers`` otherwise. ``lora``: ``(the four adapter stacks,
+        lane ids)`` on a lora engine. Returns ``(x, pool_k, pool_v,
+        state, tokens per expert or None)``."""
+        cfg = self.cfg
+
+        def attend(q, k, v, pk, pv, li):
+            # layer ``li`` of the pool: written, then read (after the
+            # write, so the update stays in place)
+            pk, pv = pieces.write(k, v, pk, pv, li)
+            return pieces.read(q, k, v, pk, pv, li), (pk, pv)
 
         if self.model is not None:
-            def conv(z, w, st, li):
-                # live slots shift their state by this step's input;
-                # dead and mid-prefill slots keep theirs
-                rows = st["conv"]
-                prev = rows[li]                 # (slots, K-1, d)
-                c, zz = L.short_conv(z, w, prev)
-                new = jnp.where(active[:, None, None],
-                                zz[:, 1:].astype(rows.dtype), prev)
-                return c, {"conv": rows.at[li].set(new)}
-
             x, (pool_k, pool_v), state, moe_counts = self.model.layers(
-                params, x, cfg, positions=lengths[:, None],
+                params, pieces.x, cfg, positions=pieces.positions,
                 attend=lambda q, k, v, cache, li: attend(q, k, v, *cache,
                                                          li),
-                conv=conv, cache=(pool_k, pool_v), state=state,
-                valid=active[:, None])
-            tokens = self._pick(
-                rng, self.model.head(params, x, cfg)[:, 0])
-            return tokens, pool_k, pool_v, state, moe_counts
+                conv=pieces.conv, cache=(pool_k, pool_v), state=state,
+                valid=pieces.valid)
+            return x, pool_k, pool_v, state, moe_counts
+        lora_w, lane_ids = lora if lora is not None else (None, None)
 
-        def layer(x, pk, pv, bp, li, lora):
+        def layer(x, pk, pv, bp, li, lw):
             x, _, (pk, pv) = _block_core(
                 bp, x, cfg,
                 lambda q, k, v: attend(q, k, v, pk, pv, li),
                 capacity_factor=max(cfg.capacity_factor,
                                     float(cfg.n_experts)),
-                positions=lengths[:, None],     # per-slot rope depth
+                positions=pieces.positions,     # per-slot rope depth
                 tp_attn=self._tp_core,
-                lora=(lora, lane_ids) if self.lora else None)
+                lora=(lw, lane_ids) if self.lora else None)
             return x, pk, pv
 
-        x, pool_k, pool_v = scan_layers(layer, x, pool_k, pool_v,
+        x, pool_k, pool_v = scan_layers(layer, pieces.x, pool_k, pool_v,
                                         params["blocks"], lora_w)
-        logits = _lm_head(params, x)[:, 0]
-        # constrained slots' rows knock illegal tokens to finfo.min;
-        # unconstrained rows are all-True (bitwise no-op — greedy and
-        # seeded sampling stay token-identical with the feature on)
-        logits = _mask_logits(logits, smask)
-        if self.parallel:
-            # per-branch keys: fold each slot's branch key with its
-            # context length (lengths + 1 — the pending token counts),
-            # so branch b's token at depth d is a pure function of
-            # (branch key, d, logits): token-exact vs an independent
-            # single-slot run with the same key, preemption-invariant
-            # (a refolded prompt re-samples with the same context
-            # count), and graftlint's prng rule stays green (fold_in
-            # is the sanctioned derivation)
-            keys = jax.vmap(jax.random.fold_in)(slot_keys, lengths + 1)
-            tokens, lps = self._branch_pick(keys, logits)
-            return tokens, lps, pool_k, pool_v
-        return self._pick(rng, logits), pool_k, pool_v
+        return x, pool_k, pool_v, state, None
+
+    def _logits(self, params, rows):
+        """The head over ``rows (n, 1, d)``: logits ``(n, vocab)``."""
+        if self.model is not None:
+            return self.model.head(params, rows, self.cfg)[:, 0]
+        return _lm_head(params, rows)[:, 0]
 
     # ---- between the model's (..., kv_heads, head_dim) and the
     # pool's rows (kv_pages.make_pool owns the layout) ----------------
@@ -1492,16 +1656,58 @@ class PagedEngine:
         None."""
         if not self._pending:
             return None
+        p = self._pending[0]
+        operands = self._chunk_operands(p)
+        # span: host wall time in the event log + the same label on a
+        # captured device trace (observability/spans.py); no-op when
+        # telemetry is disabled
+        with span("serving_prefill_chunk"):
+            outs = self._chunk_jit(
+                self.params, self.pool["k"], self.pool["v"], *operands)
+        lp = logits = None
+        if self.parallel:
+            tok, lp, logits, pool_k, pool_v = outs
+        elif self.model is not None:
+            # the chunk's expert counts stay on the device: reading
+            # them would wait for a program this call only dispatched
+            tok, pool_k, pool_v, self.slot_state, _ = outs
+        else:
+            tok, pool_k, pool_v = outs
+        self.pool = {"k": pool_k, "v": pool_v}
+        if not self._chunk_issued(p):
+            return None
+        # the prompt's LAST chunk: its token is read back here, which
+        # waits for the chunk program the span above only dispatched —
+        # a device wait, named so that it is not taken for host work
+        with span("prefill_finish"):
+            if self.parallel:
+                # ONE batched device->host sync; the final-position
+                # logits are what fork() samples sibling branches'
+                # first tokens from. The stash is consumed at the fork
+                # (or by take_first_logprob for requests that never
+                # fork), so it lives one scheduling iteration — the
+                # one (vocab,)-row host copy per ADMISSION is the
+                # price of not threading a will-fork hint through the
+                # admission surface.
+                tok, lp, logits = jax.device_get((tok, lp, logits))
+                self._fork_state[p["slot"]] = {
+                    "logits": np.asarray(logits[0]),
+                    "logprob": float(np.asarray(lp)[0]),
+                    "s0": int(p["s0"])}
+            return self._prefill_done(p, int(np.asarray(tok)[0]))
+
+    def _chunk_operands(self, p: dict) -> tuple:
+        """The chunk program's operands after the pool, for the next
+        chunk of the pending prefill ``p``: the host's work before the
+        program (operands onto the device, the rng split), under its
+        own span, so a traced idle gap in front of a chunk has a
+        name."""
         if self.host_spill:
             # defensive for directly-driven engines: the batcher
             # already promoted before chunk issue; a chunk must never
             # attend host-matched pages that were not written yet
             self.issue_promotions()
-        p = self._pending[0]
         C = self.chunk_tokens
-        # the host's work before the program (operands onto the
-        # device, the rng split): its own span, so a traced idle gap
-        # in front of a chunk has a name
         with span("prefill_args"):
             if self.parallel:
                 # the slot's BRANCH KEY rides the rng operand: the
@@ -1526,58 +1732,34 @@ class PagedEngine:
             # the chunk's (1,) lane id: the seating slot's adapter
             sextra = sextra + self._lora_operands(
                 self._slot_lanes[p["slot"]:p["slot"] + 1])
-        # span: host wall time in the event log + the same label on a
-        # captured device trace (observability/spans.py); no-op when
-        # telemetry is disabled
-        with span("serving_prefill_chunk"):
-            outs = self._chunk_jit(
-                self.params, self.pool["k"], self.pool["v"], ids,
-                jnp.asarray(p["start"], jnp.int32),
-                jnp.asarray(p["s0"], jnp.int32), table_row, sub,
-                *sextra)
-        if self.parallel:
-            tok, lp, logits, pool_k, pool_v = outs
-        elif self.model is not None:
-            # the chunk's expert counts stay on the device: reading
-            # them would wait for a program this call only dispatched
-            tok, pool_k, pool_v, self.slot_state, _ = outs
-        else:
-            tok, pool_k, pool_v = outs
-        self.pool = {"k": pool_k, "v": pool_v}
+            return (ids, jnp.asarray(p["start"], jnp.int32),
+                    jnp.asarray(p["s0"], jnp.int32), table_row, sub,
+                    *sextra)
+
+    def _chunk_issued(self, p: dict) -> bool:
+        """Book one issued chunk of the pending prefill ``p``; True
+        when it was the prompt's last (``p`` then leaves the queue)."""
         self.prefill_chunks += 1
-        p["start"] += C
+        p["start"] += self.chunk_tokens
         if p["start"] < p["s0"]:
-            return None
-        self._pending.pop(0)
-        # the prompt's LAST chunk: its token is read back here, which
-        # waits for the chunk program the span above only dispatched —
-        # a device wait, named so that it is not taken for host work
-        with span("prefill_finish"):
-            if self.parallel:
-                # ONE batched device->host sync; the final-position
-                # logits are what fork() samples sibling branches'
-                # first tokens from. The stash is consumed at the fork
-                # (or by take_first_logprob for requests that never
-                # fork), so it lives one scheduling iteration — the
-                # one (vocab,)-row host copy per ADMISSION is the
-                # price of not threading a will-fork hint through the
-                # admission surface.
-                tok, lp, logits = jax.device_get((tok, lp, logits))
-                self._fork_state[p["slot"]] = {
-                    "logits": np.asarray(logits[0]),
-                    "logprob": float(np.asarray(lp)[0]),
-                    "s0": int(p["s0"])}
-            first = int(np.asarray(tok)[0])
-            self.tables.activate(p["slot"], first)
-            self.tables.register_prefix(p["slot"], p["ids"][:p["s0"]])
-            if self._drafter is not None:
-                self._drafter.observe(p["slot"], [first])
-            if self.structured:
-                # same hook site as the drafter: the cursor advances
-                # on the accepted first token (fork() REBASES
-                # children, so a parent about to fork is already
-                # correct — branch 0's stream keeps this very token)
-                self._cursors.observe(p["slot"], [first])
+            return False
+        self._pending.pop(0)        # p is the oldest
+        return True
+
+    def _prefill_done(self, p: dict, first: int) -> tuple[int, int]:
+        """A prompt's last chunk has given its token: the slot decodes
+        from the next step on, its full prompt pages enter the prefix
+        index."""
+        self.tables.activate(p["slot"], first)
+        self.tables.register_prefix(p["slot"], p["ids"][:p["s0"]])
+        if self._drafter is not None:
+            self._drafter.observe(p["slot"], [first])
+        if self.structured:
+            # same hook site as the drafter: the cursor advances
+            # on the accepted first token (fork() REBASES
+            # children, so a parent about to fork is already
+            # correct — branch 0's stream keeps this very token)
+            self._cursors.observe(p["slot"], [first])
         return p["slot"], first
 
     def admit(self, prompt_ids: np.ndarray, seed: int | None = None,
@@ -1853,31 +2035,8 @@ class PagedEngine:
         """One decode step over every ACTIVE slot; advances lengths/
         last_ids for those and returns the (max_slots,) token ids
         (garbage at inactive or mid-prefill slots)."""
-        if self.prefill_only:
-            raise RuntimeError(
-                "step() on a prefill_only engine: the disaggregated "
-                "prefill pool exports pages (export_pages) instead "
-                "of decoding — route decode to the decode host")
-        active = self.tables.active.copy()
-        if active.any():
-            full = self.tables.lengths[active] >= self.cfg.seq_len
-            if full.any():
-                raise RuntimeError(
-                    "a slot reached cfg.seq_len; the batcher must "
-                    "retire sequences at the cache horizon")
-        with span("decode_args"):
-            self._rng, sub = jax.random.split(self._rng)
-            args = self.tables.device_args()
-            extra = self._kernel_operands()
-            if self.structured:
-                # the fused legality mask rides as a VALUE operand —
-                # schema churn flips bits, never shapes
-                extra = extra + (jnp.asarray(self._cursors.mask),)
-            if self.parallel:
-                extra = extra + (jnp.asarray(self._slot_keys),)
-            if self.slot_state is not None:
-                extra = (self.slot_state,) + extra
-            extra = extra + self._lora_operands(self._slot_lanes)
+        active = self._decoding("step")
+        args, sub, extra = self._lane_operands()
         with span("decode_step"):
             outs = self._decode_jit(
                 self.params, self.pool["k"], self.pool["v"],
@@ -1902,6 +2061,103 @@ class PagedEngine:
                 tokens, pool_k, pool_v = outs
                 self.pool = {"k": pool_k, "v": pool_v}
                 tokens = np.asarray(tokens)
+        self._advance(active, tokens)
+        return tokens
+
+    def mixed_step(self) -> tuple[np.ndarray, tuple[int, int] | None]:
+        """ONE program for the oldest pending prefill's next chunk AND
+        the decode step over every active slot (the mixed variant of
+        ``_chunk_fn``): what :meth:`prefill_step` followed by
+        :meth:`step` would do, with one pass over the weights, one
+        launch, one rng split and one read-back. Needs a pending
+        chunk, and an engine whose mode rides (``self.mixes``).
+        Returns ``(tokens, done)``: the (max_slots,) token ids as
+        :meth:`step` gives them, and ``(slot, first_token)`` when the
+        chunk was its prompt's last, else None — that slot joins the
+        decode lanes from the NEXT step on (the two-program iteration
+        decodes it in the same one)."""
+        if not (self.mixes and self._pending):
+            raise RuntimeError(
+                "mixed_step() needs a pending prefill chunk and an "
+                "engine whose mode rides the mixed program "
+                "(PagedEngine.mixes)")
+        active = self._decoding("mixed_step")
+        p = self._pending[0]
+        operands = self._chunk_operands(p)      # the ONE rng split
+        args, _, extra = self._lane_operands(split=False)
+        lanes = dict(args)
+        if self.slot_state is not None:
+            extra = extra[1:]       # the chunk's operands carry it
+        if self.decode_backend == "pallas":
+            lanes["work"], extra = extra[:3], extra[3:]
+        if self.structured:
+            lanes["smask"] = extra[0]
+        # the iteration's ONE decode_step span (dispatch + read-back),
+        # as a plain step's: the readers that divide decoded tokens by
+        # its count see every iteration that decodes
+        with span("decode_step"):
+            outs = self._chunk_jit(
+                self.params, self.pool["k"], self.pool["v"], *operands,
+                lanes=lanes)
+            tok, tokens, pool_k, pool_v = outs[:4]
+            self.pool = {"k": pool_k, "v": pool_v}
+            if self.slot_state is not None:
+                self.slot_state = outs[4]
+            self.mixed_steps += 1
+            last = self._chunk_issued(p)
+            # ONE device->host sync; the chunk's token only where it
+            # is the prompt's first
+            if last:
+                tok, tokens = jax.device_get((tok, tokens))
+            tokens = np.asarray(tokens)
+        done = None
+        if last:
+            # no wait left in it: the token came with the lanes'
+            with span("prefill_finish"):
+                done = self._prefill_done(p, int(np.asarray(tok)[0]))
+        self._advance(active, tokens)
+        return tokens, done
+
+    def _decoding(self, entry: str) -> np.ndarray:
+        """The slots a decode program is about to advance (a copy of
+        the active mask), after the checks every decode entry makes."""
+        if self.prefill_only:
+            raise RuntimeError(
+                f"{entry}() on a prefill_only engine: the disaggregated "
+                "prefill pool exports pages (export_pages) instead "
+                "of decoding — route decode to the decode host")
+        active = self.tables.active.copy()
+        if active.any():
+            full = self.tables.lengths[active] >= self.cfg.seq_len
+            if full.any():
+                raise RuntimeError(
+                    "a slot reached cfg.seq_len; the batcher must "
+                    "retire sequences at the cache horizon")
+        return active
+
+    def _lane_operands(self, split: bool = True
+                       ) -> tuple[dict, jax.Array | None, tuple]:
+        """The decode step's operands: ``tables.device_args()``, the
+        step's half of the rng (None without ``split``) and the modes'
+        trailing operands in ``_decode_fn``'s order."""
+        with span("decode_args"):
+            sub = None
+            if split:
+                self._rng, sub = jax.random.split(self._rng)
+            args = self.tables.device_args()
+            extra = self._kernel_operands()
+            if self.structured:
+                # the fused legality mask rides as a VALUE operand —
+                # schema churn flips bits, never shapes
+                extra = extra + (jnp.asarray(self._cursors.mask),)
+            if self.parallel:
+                extra = extra + (jnp.asarray(self._slot_keys),)
+            if self.slot_state is not None:
+                extra = (self.slot_state,) + extra
+            return args, sub, \
+                extra + self._lora_operands(self._slot_lanes)
+
+    def _advance(self, active: np.ndarray, tokens: np.ndarray) -> None:
         with span("decode_advance"):
             for slot in np.flatnonzero(active):
                 self.tables.advance(int(slot), int(tokens[slot]))
@@ -1911,7 +2167,6 @@ class PagedEngine:
                 if self.structured:
                     self._cursors.observe(int(slot),
                                           [int(tokens[slot])])
-        return tokens
 
     def spec_step(self) -> dict[int, list[int]]:
         """One speculative decode step over every ACTIVE slot: draft
@@ -1928,22 +2183,11 @@ class PagedEngine:
         Returns ``{slot: [tokens]}`` in slot order — multi-token
         emission is why this cannot share :meth:`step`'s fixed
         ``(max_slots,)`` return. Requires ``speculative=True``."""
-        if self.prefill_only:
-            raise RuntimeError(
-                "spec_step() on a prefill_only engine: the "
-                "disaggregated prefill pool exports pages "
-                "(export_pages) instead of decoding")
         if not self.speculative:
             raise RuntimeError(
                 "spec_step() needs a PagedEngine(speculative=True); "
                 "the cold engine decodes through step()")
-        active = self.tables.active.copy()
-        if active.any():
-            full = self.tables.lengths[active] >= self.cfg.seq_len
-            if full.any():
-                raise RuntimeError(
-                    "a slot reached cfg.seq_len; the batcher must "
-                    "retire sequences at the cache horizon")
+        active = self._decoding("spec_step")
         k = self.draft_len
         drafts = np.full((self.max_slots, k), -1, np.int32)
         # chain parents by default (node j+1 off node j): slots with
@@ -2224,15 +2468,7 @@ class PagedEngine:
         serve_tp bench's model-vs-compiler gate). An AOT lower +
         compile with the engine's live operands: bench/debug only,
         never on the decode hot path."""
-        args = self.tables.device_args()
-        extra = self._kernel_operands()
-        if self.structured:
-            extra = extra + (jnp.asarray(self._cursors.mask),)
-        if self.parallel:
-            extra = extra + (jnp.asarray(self._slot_keys),)
-        if self.slot_state is not None:
-            extra = (self.slot_state,) + extra
-        extra = extra + self._lora_operands(self._slot_lanes)
+        args, _, extra = self._lane_operands(split=False)
         lowered = self._decode_jit.lower(
             self.params, self.pool["k"], self.pool["v"],
             args["tables"], args["lengths"], args["refs"],
@@ -2255,9 +2491,10 @@ class PagedEngine:
 
     @property
     def prefill_compiles(self) -> int:
-        """Compiled prefill-chunk count — exactly ONE whatever prompt
+        """Compiled prefill-chunk count — at most TWO whatever prompt
         lengths arrive (chunk position/length/page-ids are traced
-        values, never shapes)."""
+        values, never shapes): the chunk alone, and with the decode
+        lanes riding (``mixed_step``)."""
         return self._chunk_jit._cache_size()
 
     @property
