@@ -131,9 +131,9 @@ def main(conf: Config) -> dict:
             return_hidden=conf.model.chunked_head,
             dropout_rng=dropout_rng)
         if conf.model.chunked_head:
-            # the measured winner (+6.7% at S=1024, recorded on chip —
-            # docs/performance.md): stream tokens through the LM head
-            # so the (T, vocab) logits never materialize
+            # stream tokens through the LM head so the (T, vocab)
+            # logits never materialize (off in both train cells of the
+            # benchmark, so it has no number on the current stack)
             loss = lm_head_cross_entropy(out, GPT.head_table(params),
                                          labels)
         else:

@@ -1,8 +1,8 @@
 """``chip_smoke.py`` and what bring-up added around it, as far as a
 CPU can check: the smoke refuses to run without a TPU, its four-chip
 phases are right at toy widths on virtual devices, the compile cache
-lands where it should, and ``bench.py`` fails loudly without a chip.
-The smoke itself only ever passes on the chip."""
+lands where it should, and the benchmark's ``run.py`` fails loudly
+without a chip. The smoke itself only ever passes on the chip."""
 from __future__ import annotations
 
 import json
@@ -103,46 +103,15 @@ def test_four_chip_phases_at_toy_widths_on_virtual_devices(monkeypatch):
     assert fields["tp4_vs_tp1"]["exact_requests"] == 4
 
 
-def test_bench_sub_exits_nonzero_without_a_chip(monkeypatch, capsys):
-    """No accelerator and no ``JAX_PLATFORMS=cpu`` from the caller: the
-    child exits with the no-chip code before it measures anything, so
-    no row lands under a per-chip name. (In-process: a child that
-    really looked for a TPU would race the AOT tests for the TPU
-    library.)"""
-    monkeypatch.syspath_prepend(str(REPO))
-    import bench
-
-    monkeypatch.delenv("JAX_PLATFORMS")
-    with pytest.raises(SystemExit) as exit_info:
-        bench._sub_main("resnet")
-    assert exit_info.value.code == bench._NO_CHIP_RC
-    assert not capsys.readouterr().out.strip()
-
-
-def test_bench_parent_stops_at_the_first_no_chip_child(monkeypatch,
-                                                       capsys):
-    monkeypatch.syspath_prepend(str(REPO))
-    import bench
-
-    started = []
-
-    def no_chip(name, deadline, env_over=None):
-        started.append(name)
-        return None, bench._NO_CHIP_RC
-
-    from jax._src import xla_bridge
-
-    monkeypatch.setattr(bench, "_run_sub", no_chip)
-    monkeypatch.setattr(sys, "argv", ["bench.py"])
-    # a parent that holds the backend holds the chip its children need
-    monkeypatch.setattr(xla_bridge, "backends_are_initialized",
-                        lambda: True)
-    with pytest.raises(SystemExit, match="touched the jax backend"):
-        bench.main()
-    assert not started
-    monkeypatch.setattr(xla_bridge, "backends_are_initialized",
-                        lambda: False)
-    with pytest.raises(SystemExit, match="no accelerator"):
-        bench.main()
-    assert started == ["resnet"]
-    assert not capsys.readouterr().out.strip()
+def test_the_benchmark_refuses_without_an_accelerator():
+    """``benchmark/run.py``, the command the driver runs for every
+    cell, finds a chip or fails: under ``JAX_PLATFORMS=cpu`` it exits
+    non-zero before it builds or measures anything and prints no
+    result line, so no CPU number lands under a device metric's name.
+    (A fresh interpreter: the seconds it takes are ``import jax``.)"""
+    out = _run([str(REPO / "benchmark" / "run.py"), "--workload",
+                "gpt2-small.train-s1024", "--seed", "1", "--seconds", "1",
+                "--trace", "0"], JAX_PLATFORMS="cpu")
+    assert out.returncode != 0
+    assert "no accelerator" in out.stderr
+    assert not out.stdout.strip()       # no run log, no result line

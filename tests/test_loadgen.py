@@ -24,9 +24,7 @@
 - the SLO conformance report's goodput/percentile math, the
   max-sustainable-x binary search, the ``replay_diff`` regression
   gate (fingerprint mismatches REFUSED, regressions flagged), and
-  the fingerprint-comparability gates in ``bench._ab_best`` and
-  ``scripts/ab_summary.py`` (pinned against the canonical
-  predicate so the three can never fork);
+  the one fingerprint-comparability predicate behind it;
 - the ``loadgen:`` YAML block and the ``frontend.capture_path`` knob.
 """
 import asyncio
@@ -629,45 +627,36 @@ def test_replay_diff_per_class_names_the_regressed_class(tmp_path,
     assert rd.main([str(base), str(good)]) == 0
 
 
-def test_fingerprint_gates_agree_and_ab_best_refuses(tmp_path):
-    """The three comparability gates — the canonical predicate
-    (loadgen.report), bench's _ab_best winner pick, and ab_summary's
-    local mirror — must agree, and a fingerprint-mismatched arm must
-    never flip a gate."""
-    import bench
-    from scripts.ab_summary import _fingerprints_comparable
+def test_fingerprints_comparable_and_replay_diff_refuses_a_mismatch(
+        tmp_path, capsys):
+    """The one comparability predicate (loadgen.report) over its five
+    cases: two results may be compared unless BOTH name a workload and
+    the names differ. And the gate built on it prints no comparison
+    for a pair it refuses, in either mode: exit 2, nothing on stdout
+    that a reader could take for an all-clear."""
+    import scripts.replay_diff as rd
     from torchbooster_tpu.serving.loadgen.report import (
         fingerprints_comparable)
 
-    cases = [({}, {}), ({"workload_fingerprint": "a"}, {}),
+    cases = [({}, {}, True),
+             ({"workload_fingerprint": "a"}, {}, True),
              ({"workload_fingerprint": "a"},
-              {"workload_fingerprint": "a"}),
+              {"workload_fingerprint": "a"}, True),
              ({"workload_fingerprint": "a"},
-              {"workload_fingerprint": "b"}),
-             (None, {"workload_fingerprint": "a"})]
-    for a, b in cases:
-        assert fingerprints_comparable(a, b) \
-            == _fingerprints_comparable(a, b) \
-            == bench.fingerprints_comparable(a, b)
-    # _ab_best: the faster arm served a DIFFERENT trace -> refused,
-    # the baseline keeps the gate; same trace -> the win flips it
-    variants = {"base": {}, "cand": {"TB_TEST_NOPE_KNOB": "1"}}
-    log = tmp_path / "ab.jsonl"
-
-    def write(c_fp):
-        log.write_text("\n".join(json.dumps(e) for e in (
-            {"config": "base", "status": "ok",
-             "result": {"v": 10.0, "workload_fingerprint": "aaa"}},
-            {"config": "cand", "status": "ok",
-             "result": {"v": 99.0, "workload_fingerprint": c_fp}},
-        )) + "\n")
-
-    write("bbb")
-    _, winner = bench._ab_best(variants, "base", "v", path=str(log))
-    assert winner == "base", "a mismatched-trace win must not flip"
-    write("aaa")
-    _, winner = bench._ab_best(variants, "base", "v", path=str(log))
-    assert winner == "cand"
+              {"workload_fingerprint": "b"}, False),
+             (None, {"workload_fingerprint": "a"}, True)]
+    for a, b, comparable in cases:
+        assert fingerprints_comparable(a, b) is comparable
+        assert fingerprints_comparable(b, a) is comparable
+    base, other = tmp_path / "base.json", tmp_path / "other.json"
+    base.write_text(json.dumps(_fake_report()))
+    # the candidate is BETTER on every number, over another trace
+    other.write_text(json.dumps(_fake_report(fp="zzz", goodput=500.0)))
+    for mode in ([], ["--per-class"]):
+        assert rd.main([str(base), str(other), *mode]) == 2
+        out = capsys.readouterr()
+        assert "NOT COMPARABLE" in out.err
+        assert not out.out.strip()
 
 
 # ---- YAML surface ----------------------------------------------------

@@ -124,8 +124,10 @@ def overlap_report(step_s_on: float, step_s_off: float,
     question — overlap-on must not be slower than overlap-off (within
     ``tolerance``, the measurement noise floor). Mirrors the
     accounting-vs-HLO 10% gate in spirit: a schedule that *claims*
-    overlap but serializes anyway fails loudly in the bench instead
-    of shipping a no-op knob."""
+    overlap but serializes anyway fails loudly instead of shipping a
+    no-op knob. (No caller in the package:
+    ``tests/test_zero23.py::test_overlap_report_gate_math`` holds the
+    arithmetic; no cell has clocked ZeRO overlap, ROADMAP S7.)"""
     out = {
         "step_s_on": round(step_s_on, 6),
         "step_s_off": round(step_s_off, 6),
@@ -173,8 +175,8 @@ def promotion_traffic(n_pages: int, *, page_size: int, kv_heads: int,
     ``n_layers * page_size * kv_heads * head_dim``) plus one fp32
     scale per (layer, token, head) — per-(token, head) symmetric
     quantization, ``models/gpt._quantize_kv``'s shape. Integer bytes:
-    the serve_spill bench gates this model EQUAL to the engine's
-    measured ``promoted_bytes`` counter, not approximately so."""
+    ``tests/test_spill.py`` holds this model EQUAL to the engine's
+    ``promoted_bytes`` counter, not approximately so."""
     if n_pages < 0:
         raise ValueError(f"n_pages must be >= 0, got {n_pages}")
     elems = n_layers * page_size * kv_heads
@@ -199,8 +201,8 @@ def disagg_traffic(prompt_len: int, *, page_size: int, kv_heads: int,
     the decode side always re-runs the final chunk itself) in the
     demotion payload format, so the per-page cost is byte-identical
     to :func:`promotion_traffic`'s: K and V as int8 plus one fp32
-    scale per (layer, token, head). Integer bytes: the serve_disagg
-    bench gates this model EQUAL to the pair's measured
+    scale per (layer, token, head). Integer bytes:
+    ``tests/test_disagg.py`` holds this model EQUAL to the pair's
     ``page_bytes_streamed`` counter (payload frames only — the JSON
     routing header is transport overhead the model deliberately
     excludes, reported separately as ``framed_bytes_streamed``)."""

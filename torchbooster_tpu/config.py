@@ -781,8 +781,8 @@ class WeightsConfig(BaseConfig):
     the embedding table per-row at engine build time — ONE host-side
     pass, then every compiled step streams 1 byte per weight and
     widens inside the matmul's operand read; greedy decode stays
-    token-identical in practice (the serve_wq bench gates int8 on
-    exact parity). ``dtype: int4`` packs two values per byte with
+    token-identical in practice (``tests/test_quant_lora.py::
+    test_int8_paged_matches_fullprec_and_dense``). ``dtype: int4`` packs two values per byte with
     per-``group_size``-input-rows scales — 0.5 byte/elem at a real
     (bounded, documented) rounding cost; ``group_size`` must be even
     and divide every kernel's input dim. ``bf16`` (the default) is a
@@ -988,8 +988,9 @@ class RouterConfig(BaseConfig):
     RemoteReplica` socket to a ``python -m torchbooster_tpu.serving.
     replica_server`` process pumping its own batcher. Routing,
     affinity, spill, health, and death-readmission semantics are
-    identical either way (that's the socket-parity gate in the
-    serve_disagg bench family); a dropped connection is replica
+    identical either way (``tests/test_disagg.py::
+    test_socket_replica_parity_tokens_and_assignments``); a dropped
+    connection is replica
     death. Non-empty ``replicas`` overrides ``n_replicas``.
 
     ``audit`` sizes the routing-decision audit ring (``0`` disables
@@ -1389,8 +1390,9 @@ class LoadgenConfig(BaseConfig):
     :class:`~torchbooster_tpu.serving.loadgen.workload.Workload`;
     drive it with ``replay_inprocess(batcher, wl, speed=...)`` or
     ``replay_http(port, wl, speed=...)``. docs/observability.md has
-    the capture-and-replay walkthrough; the ``replay`` bench rows
-    (bench.py) prove the round trip.
+    the capture-and-replay walkthrough;
+    ``tests/test_loadgen.py::test_http_capture_replay_round_trip_exact``
+    proves the round trip.
     """
 
     source: str = "poisson"            # kind | capture-file path

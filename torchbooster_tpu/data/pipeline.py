@@ -8,8 +8,7 @@ data_sampler + config.py:486-525 iterable modulo-sharding):
   loads only its slice of the global batch),
 - worker *threads* decode concurrently by default (numpy decode
   releases the GIL); ``workers="process"`` brings the reference's
-  worker-process model back for python-heavy transforms that hold it
-  (measured crossover in docs/performance.md),
+  worker-process model back for python-heavy transforms that hold it,
 - ``prefetch_to_device`` overlaps host decode with device compute and
   lands batches already sharded over the mesh's data axes — replacing
   the reference's per-step blocking ``.to("cuda")`` (ref
@@ -144,8 +143,7 @@ class DataLoader:
     pickle). Process workers SNAPSHOT the dataset and collate_fn when
     the pool first starts and keep that copy across epochs — mutate
     the dataset between epochs only in thread mode, or call
-    :meth:`close` first so the next epoch re-pickles it. Measured
-    guidance in docs/performance.md."""
+    :meth:`close` first so the next epoch re-pickles it."""
 
     def __init__(
         self,

@@ -359,7 +359,7 @@ def resolve_dataset(conf: Any, split: Split | str, download: bool = True,
                 sys.exit(1)
     try:
         # self-describing provenance: consumers that must report WHAT
-        # data trained (bench_cifar_acc's real-vs-synthetic label) read
+        # data trained (a real-vs-synthetic label on a result) read
         # it instead of re-deriving the chain's decision
         dataset.resolution = resolution
     except (AttributeError, TypeError):  # exotic dataset types: skip

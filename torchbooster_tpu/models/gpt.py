@@ -952,8 +952,8 @@ def _quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     int8 cache bytes at Dh=64 — fp32 scales would cost 4/Dh ≈ 6%), and
     the QUANTIZATION divides by the rounded bf16 scale so the stored
     pair is exactly self-consistent. Decode HBM reads drop to ~half of
-    bf16 *if* XLA folds the widening convert into the dot reads (the
-    queued decode_int8 bench row is the proof either way). Returns a
+    bf16 *if* XLA folds the widening convert into the dot reads (no
+    benchmark cell runs an int8 pool yet: ROADMAP D2). Returns a
     2-tuple ``(int8 values, bf16 scales)`` — scales keep the head dim
     as a trailing 1 for broadcasting."""
     scale = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1,

@@ -161,11 +161,11 @@ def group_norm(params: dict, x: jax.Array, groups: int = 32,
     ``relu=True`` fuses the activation into the same pass (free on the
     pallas path — it rides the normalize write).
 
-    ``impl``: "auto" resolves to the XLA formulation everywhere —
-    measured on v5e, XLA fuses the affine(+relu) into the producing
-    conv's epilogue, which beats the standalone pallas kernel
-    (ops/group_norm.py) inside conv nets (1292 vs 2354 img/s on the
-    ResNet-50 bench when every norm went through pallas). The pallas
+    ``impl``: "auto" resolves to the XLA formulation everywhere:
+    XLA fuses the affine(+relu) into the producing conv's epilogue,
+    which a standalone pallas kernel (ops/group_norm.py) cannot inside
+    a conv net (neither path has a benchmark cell or a ledger line:
+    ROADMAP D6). The pallas
     kernel remains opt-in (``impl="pallas"``) for standalone large-
     spatial normalization with no adjacent producer to fuse into.
 

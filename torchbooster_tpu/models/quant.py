@@ -29,8 +29,8 @@ static flag, so every compiled path dispatches on tree structure alone:
   to compute dtype right before the matmul — the HBM stream is still
   0.5 byte/elem + scales; the widening is exactly the fused convert
   the int8 path relies on, applied pre-dot. int4 rounding costs real
-  logit error (documented tolerance in docs/performance.md) — the
-  bench gates int4 on bounded divergence, int8 on exact greedy parity.
+  logit error — ``tests/test_quant_lora.py`` holds int4 to a bounded
+  dequantization error and int8 to exact greedy parity.
 
 The token embedding (``wte``) quantizes to int8 PER-ROW in both modes
 (``qtable`` + ``qscale (vocab, 1)``): rows must stay gather-addressable
@@ -229,7 +229,7 @@ def weight_stream_bytes(params: dict) -> int:
     kernel, or the tied wte table the head matmul re-reads whole),
     and the final norm. Embedding GATHERS (a few rows) and ``wpe``
     are excluded — they do not scale with the stream. This is the
-    numerator/denominator of the serve_wq bench's modeled ratio and
+    numerator/denominator of the modeled ratio in
     docs/performance.md's "Quantized-weight roofline" section; host
     arithmetic only."""
     total = 0

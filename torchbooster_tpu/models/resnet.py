@@ -115,9 +115,9 @@ def _use_fused(fused: str | bool, norm: str, x: jax.Array,
                cout: int, three: bool = False) -> bool:
     """Conv+GN fusion gate: explicit True/"interpret" engages the pallas
     kernel when the block fits VMEM (ops/fused_block). "auto" currently
-    resolves to the XLA path: the kernel's measured end-to-end numbers
-    do not yet beat XLA on the ResNet-50 bench (docs/performance.md r3
-    notes) — flip happens when they do, the dispatch stays honest."""
+    resolves to the XLA path: the kernel has no number on the current
+    stack (ROADMAP D6) — it gets the default when a benchmark cell
+    shows it ahead, the dispatch stays honest."""
     if norm != "group" or fused in (False, "auto"):
         return False
     from torchbooster_tpu.ops.fused_block import fits, fits3
@@ -328,9 +328,8 @@ class ResNet:
               fused: str | bool = "auto",
               stem_s2d: bool = False) -> jax.Array:
         """``fused``: the 1×1-conv+GN pallas kernel (ops/fused_block).
-        "auto" currently resolves to the plain XLA path — the kernel
-        has not yet beaten XLA end-to-end on the chip bench (see
-        _use_fused and docs/performance.md). True forces it on;
+        "auto" currently resolves to the plain XLA path (see
+        _use_fused). True forces it on;
         "interpret" is the CPU-debuggable variant for tests.
         ``stem_s2d``: run the 7×7/s2 stem as a space-to-depth conv
         (:func:`_stem_s2d`; opt-in pending chip measurement)."""

@@ -18,7 +18,7 @@ that ship it all on a cadence thread.
 - :mod:`recompile` — :class:`RecompileSentinel` over jit cache sizes
   (``on_recompile: ignore | warn | raise``);
 - :mod:`device`    — HBM gauges from ``memory_stats()``, XLA
-  ``cost_analysis`` FLOP cross-checks for bench MFU denominators;
+  ``cost_analysis`` FLOP cross-checks for utilization denominators;
 - :mod:`export`    — JSONL event log + Prometheus text snapshots on a
   background cadence thread;
 - :mod:`slo`       — :class:`SLOBurnEngine`, multi-window burn rates

@@ -7,11 +7,13 @@ Two read paths into what the accelerator actually does:
   al). TPU runtimes report these; CPU returns None and the call is a
   clean no-op, so instrumented code needs no backend branch.
 - :func:`cost_analysis` / :func:`xla_flops` — the compiler's own
-  FLOP/byte accounting from ``Compiled.cost_analysis()``. bench.py
-  cross-checks its hand-derived MFU denominators against this
+  FLOP/byte accounting from ``Compiled.cost_analysis()``.
+  :func:`flop_check` compares a hand-derived FLOP count against this
   (``6·N·D`` formulas drift when architectures grow knobs; XLA's
   count is ground truth for the graph it actually compiled) and warns
-  when they disagree by more than 10%.
+  when they disagree by more than 10%. (The benchmark's utilization
+  readings use ``benchmark/flops.py``; nothing in the package calls
+  these two: ``tests/test_observability.py`` holds them.)
 """
 from __future__ import annotations
 
@@ -67,7 +69,7 @@ def cost_analysis(compiled: Any) -> dict[str, float]:
 def xla_flops(fn: Callable, *args: Any, **kwargs: Any) -> float | None:
     """The compiler's FLOP count for ``fn(*args)``: lower → compile →
     cost_analysis. This builds a second executable (AOT), so call it
-    once per bench, not per step. None when unavailable."""
+    once per program, not per step. None when unavailable."""
     import jax
 
     try:
@@ -84,8 +86,8 @@ def flop_check(name: str, formula_flops: float, measured: float | None,
                tolerance: float = 0.10) -> float | None:
     """Compare a hand-derived FLOP count against XLA's; returns their
     ratio (measured/formula) and WARNS when they disagree beyond
-    ``tolerance`` — the bench's MFU denominators must not silently
-    drift from the graph they describe."""
+    ``tolerance`` — a utilization's denominator must not silently
+    drift from the graph it describes."""
     if not measured or not formula_flops:
         return None
     ratio = measured / formula_flops

@@ -4,8 +4,9 @@ background cadence thread.
 Two formats because they answer different questions:
 
 - **JSONL** (one self-describing dict per line, append-only) is the
-  repo's lingua franca — bench.py emits it, ab_summary.py reads it. Span events stream as they close;
-  registry snapshots land every cadence tick.
+  repo's lingua franca (the request tracer, the flight recorder and
+  the workload captures write it too). Span events stream as they
+  close; registry snapshots land every cadence tick.
 - **Prometheus text format** (a whole-file atomic rewrite per tick)
   is what a node_exporter textfile collector or any Prometheus scrape
   sidecar picks up — the ship-to-production path the ROADMAP's

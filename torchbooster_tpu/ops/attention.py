@@ -51,9 +51,8 @@ def _on_tpu() -> bool:
 
 def flash_auto_engaged(seq_len_q: int, seq_len_kv: int | None = None) -> bool:
     """THE predicate ``attention(impl="auto")`` evaluates — exposed so
-    callers (chip_smoke.py's and bench.py's dispatch assertions) test
-    the real dispatch rather than a lookalike check that can drift
-    from it."""
+    callers (chip_smoke.py's dispatch assertion) test the real
+    dispatch rather than a lookalike check that can drift from it."""
     from torchbooster_tpu.ops.flash_attention import tileable
 
     if seq_len_kv is None:

@@ -1,9 +1,10 @@
 """Fused 1×1-conv + GroupNorm + ReLU pallas kernel (bottleneck body).
 
-The r2 chip ablations (docs/performance.md) showed the ResNet step is
-HBM-bound: GroupNorm costs ~30% of the step because XLA runs it as
-extra full passes over each conv's output (write y → read y for
-moments → read y again for normalize). A 1×1 conv IS a matmul, so this
+The premise: a ResNet step is HBM-bound, and GroupNorm is extra full
+passes over each conv's output (write y → read y for moments → read y
+again for normalize). (No cell of the benchmark runs a conv net, so
+neither the premise nor this kernel has a number on the current
+stack: ROADMAP D6.) A 1×1 conv IS a matmul, so this
 kernel computes, per sample, in one VMEM residency:
 
     y = x @ w            (MXU, fp32 accumulation)
@@ -13,13 +14,13 @@ kernel computes, per sample, in one VMEM residency:
 and writes ONLY ``out`` to HBM — the conv output never round-trips.
 Two of the three norms in every ResNet bottleneck sit behind 1×1 convs
 (conv1 and the widest, conv3), so this removes ~2/3 of the norm
-traffic the ablation measured.
+traffic.
 
 Group moments inside the kernel use a *membership matrix*: per-channel
 sums (one sublane reduction) are multiplied by a constant
 ``(C, C)`` block-diagonal averaging matrix, giving per-channel group
-means directly — no lane-splitting reshape (the layout trap that made
-the naive XLA formulation cost 60% of a forward, docs/performance.md).
+means directly — no lane-splitting reshape (the layout trap of the
+naive XLA formulation: docs/performance.md, "Lane-dim discipline").
 
 Backward is ``custom_vjp`` in plain XLA: it *recomputes* ``y = x @ w``
 from the inputs (MXU FLOPs are cheap here; the step is bandwidth-bound)

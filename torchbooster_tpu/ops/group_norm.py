@@ -24,8 +24,8 @@ Backward math (per group g of m = H·W·(C/groups) elements):
 where mask = [y > 0] when ReLU is fused (recomputed in-kernel), else 1.
 
 Dispatch lives in models/layers.py — where this kernel is OPT-IN
-(``impl="pallas"``), not the default: measured end-to-end on v5e, XLA's
-conv-epilogue fusion beats a standalone norm kernel inside conv nets
+(``impl="pallas"``), not the default: XLA fuses the affine(+relu) into
+the producing conv's epilogue, which a standalone norm kernel cannot
 (see layers.group_norm and docs/performance.md). The kernel earns its
 keep for standalone large-spatial normalization with no adjacent
 producer op to fuse into; the XLA formulation in layers.py is the

@@ -1,9 +1,8 @@
 """Serving subsystem: continuous batching over a paged KV cache.
 
-The north star serves heavy traffic; training-side throughput was
-already measured and tuned (docs/performance.md), and the decode
-roofline says the step time IS the cache bytes it streams. This
-package stops streaming dead bytes:
+The decode roofline (docs/performance.md has the byte models, PERF.md
+what the chip measured) says the step time IS the cache bytes it
+streams. This package stops streaming dead bytes:
 
 - :mod:`kv_pages` — the fixed page pool + host-side block tables with
   REFCOUNTED pages and a prompt-prefix index (seat/retire/evict

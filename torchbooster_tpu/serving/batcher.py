@@ -835,10 +835,9 @@ class ContinuousBatcher:
             # tensor-parallel serving only (absent at tp=1 so the
             # single-chip registry view is untouched): the modeled
             # per-chip wire bytes of each decode/verify step's
-            # decode-output psum (serving/tp.py step_traffic — the
-            # closed-form model the serve_tp bench checks against the
-            # compiled HLO). One host-side float add per step, never
-            # a device read.
+            # decode-output psum (serving/tp.py step_traffic, a
+            # closed-form model). One host-side float add per step,
+            # never a device read.
             inst["tp_bytes"] = reg.counter(
                 "serving_tp_bytes_total",
                 "modeled per-chip decode-output psum wire bytes "

@@ -39,11 +39,9 @@ rows — the pool, which the operator sizes to expected total occupancy
 it out would keep a layer's pages from being read in place) — and
 free/partial pages contribute nothing but masked lanes. Prefix
 sharing compounds it: k requests on one system prompt hold ONE copy
-of its pages, so the same pool holds more live requests. On an HBM-bound loop the read bytes ARE the step time
-(the ``serve`` bench rows measure the ratio; ``serve_prefix`` measures
-the cache-hit TTFT and the prefill FLOPs the hits skip; a
-dense-geometry control — ``page_size=seq_len``, one page per slot —
-runs the SAME code at dense bytes).
+of its pages, so the same pool holds more live requests. On an
+HBM-bound loop the read bytes ARE the step time (PERF.md section 5
+has the decode step's device time by scope).
 
 - **a chunk beside live slots is ONE program**: where an iteration
   has a pending chunk AND slots decoding, the decode lanes ride the
@@ -265,11 +263,6 @@ class PagedEngine:
     IDENTICAL to the cold path (the pages hold bitwise the same K/V a
     re-prefill would write). ``prefill_chunk_pages`` sizes the chunk
     (clamped to the slot's page budget).
-
-    ``dense_control=True`` is the A/B geometry: one ``seq_len``-wide
-    page per slot, so the identical compiled step streams the dense
-    cache's bytes — the control row for the occupancy-proportional
-    serving claim.
 
     ``speculative=True`` switches decode to draft → batched-verify →
     accept/rewind (serving/speculative.py): host-side prompt-lookup
@@ -821,16 +814,6 @@ class PagedEngine:
                 else:
                     self._compact_jit = jax.jit(
                         self._compact_fn, donate_argnums=(0, 1))
-
-    @classmethod
-    def dense_control(cls, params: dict, cfg: GPTConfig, *,
-                      max_slots: int = 8, **kw) -> "PagedEngine":
-        """The dense-bytes A/B control: identical engine, one
-        ``seq_len``-wide page per slot (+ the null page, which the
-        sweep reads too), so each step streams what the dense per-slot
-        cache would and one page more."""
-        return cls(params, cfg, page_size=cfg.seq_len,
-                   n_pages=max_slots + 1, max_slots=max_slots, **kw)
 
     # ---- compiled pieces -----------------------------------------
     def _chunk_fn(self, params, pool_k, pool_v, ids, start, s0,
@@ -2457,24 +2440,9 @@ class PagedEngine:
         speculative-verify (``s_q = 1 + draft_len``) step's
         decode-output psum — zeros at tp=1 (no collective exists).
         Host arithmetic only; the ``serving_tp_bytes_total`` counter
-        and the serve_tp bench's accounting-vs-HLO gate both read
-        this model (serving/tp.py ``step_traffic``)."""
+        reads this model (serving/tp.py ``step_traffic``)."""
         return _tp_step_traffic(self.tp, self.cfg, self.max_slots,
                                 self.compute_dtype, s_q=s_q)
-
-    def decode_hlo_text(self) -> str:
-        """The compiled decode step's HLO text, for OFFLINE collective
-        accounting (``comms/accounting.xla_collective_traffic`` — the
-        serve_tp bench's model-vs-compiler gate). An AOT lower +
-        compile with the engine's live operands: bench/debug only,
-        never on the decode hot path."""
-        args, _, extra = self._lane_operands(split=False)
-        lowered = self._decode_jit.lower(
-            self.params, self.pool["k"], self.pool["v"],
-            args["tables"], args["lengths"], args["refs"],
-            args["page_pos"], args["active"], args["last_ids"],
-            self._rng, *extra)
-        return lowered.compile().as_text()
 
     @property
     def prefix_hit_rate(self) -> float:

@@ -24,9 +24,10 @@ The serving stack's load-testing story, grown from ROADMAP item 2's
 
 Capture wiring: ``ServingFrontend(capture_path=...)`` (or the
 ``serving.frontend.capture_path`` YAML knob) records everything the
-server is offered; ``bench.py --sub replay`` proves the round trip
-and prices the capture overhead. docs/observability.md has the
-"Capture and replay a production trace" walkthrough.
+server is offered;
+``tests/test_loadgen.py::test_http_capture_replay_round_trip_exact``
+proves the round trip. docs/observability.md has the "Capture and
+replay a production trace" walkthrough.
 """
 from torchbooster_tpu.serving.loadgen.replay import (
     ReplayClock,

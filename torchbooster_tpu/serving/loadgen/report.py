@@ -9,8 +9,8 @@ TTFT deadline, per wall second — tokens served late count for
 nothing), shed/cancel/preemption rates, and the workload content
 fingerprint — so two A/B arms can PROVE they served the identical
 trace before anyone compares their numbers
-(:func:`fingerprints_comparable` is the one comparability predicate
-``bench.py`` and ``scripts/ab_summary.py`` share).
+(:func:`fingerprints_comparable` is the one comparability predicate;
+:func:`diff_reports` and ``scripts/replay_diff.py`` refuse on it).
 
 :func:`max_sustainable_speed` binary-searches the time-compression
 axis for the largest ×-factor a serving stack still meets its SLOs at
@@ -129,8 +129,7 @@ def fingerprints_comparable(a: dict | None, b: dict | None) -> bool:
     compared unless BOTH carry a ``workload_fingerprint`` and the
     hashes differ — then they measured different traffic and any
     delta between their numbers is noise dressed as evidence.
-    (Results without fingerprints — the resnet/gpt families — stay
-    comparable as before.)"""
+    (Results without fingerprints stay comparable.)"""
     fa = (a or {}).get("workload_fingerprint")
     fb = (b or {}).get("workload_fingerprint")
     return fa is None or fb is None or fa == fb

@@ -1,11 +1,10 @@
 """Versioned workload format: the one trace shape every load source
 and every driver speak.
 
-ROADMAP item 2 names the gap: every perf claim so far rode ad-hoc
-Poisson loops coded inside ``bench.py`` — scheduling quality is
-invisible under uniform synthetic arrivals, so an SLO-scheduler win
-measured there proves little about production traffic. The fix (the
-MLPerf-Inference / Orca-style methodology) is capture-then-replay:
+Scheduling quality is invisible under uniform synthetic arrivals,
+so an SLO-scheduler win measured on an ad-hoc Poisson loop proves
+little about production traffic. The fix (the MLPerf-Inference /
+Orca-style methodology) is capture-then-replay:
 record what the front door actually served, then re-offer the
 IDENTICAL trace — at ×1 for apples-to-apples A/Bs, compressed ×N for
 stress — and let synthetic generators emit the SAME format so one
@@ -30,8 +29,8 @@ The **fingerprint** is a content hash over the canonical request
 tuples (arrivals, prompts/recipes, priorities, deadlines, output
 budgets, cancel offsets — request ids excluded: identity is not
 content). Two A/B arms carrying the same fingerprint provably served
-the identical trace; ``bench.py``/``scripts/ab_summary.py`` refuse to
-compare arms whose fingerprints differ.
+the identical trace; ``diff_reports`` and ``scripts/replay_diff.py``
+refuse to compare arms whose fingerprints differ.
 
 Capture sources:
 
@@ -306,7 +305,7 @@ class Workload:
     def fingerprint(self) -> str:
         """Content hash of the offered trace (hex). A/B arms that
         report the same fingerprint provably served the identical
-        workload; the bench comparison gates refuse mismatches."""
+        workload; ``diff_reports`` refuses mismatches."""
         payload = json.dumps(
             [r.content_key() for r in self.requests],
             separators=(",", ":"))

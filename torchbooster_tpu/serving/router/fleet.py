@@ -186,7 +186,7 @@ class EngineFleet:
         # per-replica health scoring (health.py): observed every
         # fleet step when attached; consulted by ROUTING only under
         # the opt-in health_aware flag (decisions stay byte-identical
-        # otherwise — the obs_fleet bench pins it)
+        # otherwise — tests/test_fleet_signal_plane.py pins it)
         if health_aware and health is None:
             raise ValueError(
                 "health_aware=True needs a FleetHealth scorer "

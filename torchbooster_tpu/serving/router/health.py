@@ -21,8 +21,8 @@ while quietly missing every deadline routed at it.
   replica has work — the liveness probe for out-of-process replicas,
   whose readiness payloads arrive over a wire);
 - exported as ``router_replica_health{replica}`` plus a transition
-  counter; transitions are also counted locally (``n_flaps``) for
-  the obs_fleet bench's flap gate.
+  counter; transitions are also counted locally (``n_flaps``), so a
+  test or an operator can bound flapping.
 
 Observation is driven by ``EngineFleet.step()`` every ``every``
 fleet steps and reads host counters only (readiness payloads, the

@@ -817,7 +817,7 @@ def compile_response_format(spec: dict, vocab: Sequence[str],
     return dfa
 
 
-# ---- conformance (bench/test side) -------------------------------
+# ---- conformance (test side) -------------------------------------
 def _check_value(schema: dict, value) -> bool:
     if "enum" in schema:
         return any(type(v) is type(value) and v == value
@@ -863,8 +863,9 @@ def _check_value(schema: dict, value) -> bool:
 
 def conforms(spec: dict, text: str) -> bool:
     """Does ``text`` (the decoded completion, EOS stripped) satisfy
-    its ``response_format``? The bench's conformance gate and the
-    e2e tests both call this — it is independent of the automaton
+    its ``response_format``? The end-to-end tests
+    (``tests/test_structured.py``) call this — it is independent of
+    the automaton
     (regex specs use the character DFA; JSON specs parse with the
     stdlib and validate structurally), so a compiler bug cannot
     vacuously pass its own output."""
@@ -889,7 +890,7 @@ def conforms(spec: dict, text: str) -> bool:
 # Every entry is BOUNDED (its DFA is acyclic), so a constrained
 # request with budget >= schema_budget(id) always terminates at an
 # accepting state with EOS forced — the conformance-rate-1.0 contract
-# the serve_structured bench gates on.
+# tests/test_structured.py holds the batcher to.
 SCHEMA_LIBRARY: dict[str, dict] = {
     "enum_color": {"enum": ["red", "green", "blue"]},
     "bool_flag": {"type": "object",

@@ -208,9 +208,9 @@ def step_traffic(tp: int, cfg: Any, max_slots: int, compute_dtype: Any,
     (``s_q=1`` decode, ``1 + draft_len`` speculative verify). It sits
     inside the layer scan, so the compiled module carries ONE
     all-reduce instruction executed ``n_layers`` times per step —
-    ``per_layer_wire_bytes`` is what ``xla_collective_traffic`` reads
-    off the HLO (the serve_tp bench's 10% gate), ``wire_bytes`` the
-    per-step total the ``serving_tp_bytes_total`` counter accumulates.
+    ``per_layer_wire_bytes`` is what ``xla_collective_traffic`` would
+    read off the HLO, ``wire_bytes`` the per-step total the
+    ``serving_tp_bytes_total`` counter accumulates.
     """
     if tp <= 1:
         return {"tp": max(tp, 1), "payload_bytes": 0,
