@@ -353,36 +353,62 @@ def test_mse():
     assert float(mse_loss(jnp.ones(4), jnp.zeros(4))) == 1.0
 
 
-@pytest.mark.parametrize("causal,s_q,s_kv", [
-    pytest.param(True, 128, 128, marks=pytest.mark.slow),
-    (False, 128, 128),
+@pytest.mark.parametrize("causal,s_q,s_kv,heads,kv_heads,d,dtype", [
+    pytest.param(True, 128, 128, 2, 2, 32, jnp.float32,
+                 marks=pytest.mark.slow),
+    (False, 128, 128, 2, 2, 32, jnp.float32),
     # kv-cache alignment (queries align to last keys)
-    pytest.param(True, 128, 256, marks=pytest.mark.slow),
-    pytest.param(False, 64, 128, marks=pytest.mark.slow),
-    (True, 256, 256),   # multi-block accumulation in both bwd sweeps
+    pytest.param(True, 128, 256, 2, 2, 32, jnp.float32,
+                 marks=pytest.mark.slow),
+    pytest.param(False, 64, 128, 2, 2, 32, jnp.float32,
+                 marks=pytest.mark.slow),
+    # multi-block accumulation in both bwd sweeps
+    (True, 256, 256, 2, 2, 32, jnp.float32),
+    # the train cells' head size at the default tiles: the diagonal
+    # tile cut into 2 strips (S=256) and 4 (S=1024), at the compute
+    # dtype and at float32
+    (True, 256, 256, 2, 2, 64, jnp.float32),
+    (True, 256, 256, 2, 2, 64, jnp.bfloat16),
+    (True, 1024, 1024, 1, 1, 64, jnp.float32),
+    (True, 1024, 1024, 1, 1, 64, jnp.bfloat16),
+    (True, 256, 256, 4, 2, 64, jnp.bfloat16),      # GQA
+    (True, 256, 512, 2, 2, 64, jnp.bfloat16),      # seq_kv > seq_q
 ])
-def test_flash_grads_match_reference(causal, s_q, s_kv):
-    """jax.grad through the flash kernel (custom_vjp backward kernels)
-    vs autodiff through mha_reference. fp32 autodiff itself carries
-    ~0.7% error vs f64 truth at these magnitudes (verified), so
-    tolerance scales with each gradient's own magnitude."""
+def test_flash_grads_match_reference(causal, s_q, s_kv, heads, kv_heads,
+                                     d, dtype):
+    """Values and jax.grad through the flash kernel (custom_vjp
+    backward kernels) vs autodiff through mha_reference IN FLOAT32 on
+    the same (rounded) inputs. fp32 autodiff itself carries ~0.7% error
+    vs f64 truth at these magnitudes (verified), so tolerance scales
+    with each gradient's own magnitude; bf16 products (the kernel
+    multiplies at the operands' dtype, accumulates in float32) get the
+    room mha_reference's own bf16 path needs (~0.5-1.5% of the max)."""
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
-    q = jax.random.normal(ks[0], (2, s_q, 2, 32))
-    k = jax.random.normal(ks[1], (2, s_kv, 2, 32))
-    v = jax.random.normal(ks[2], (2, s_kv, 2, 32))
+    q = jax.random.normal(ks[0], (2, s_q, heads, d), dtype)
+    k = jax.random.normal(ks[1], (2, s_kv, kv_heads, d), dtype)
+    v = jax.random.normal(ks[2], (2, s_kv, kv_heads, d), dtype)
+    f32 = lambda *xs: tuple(x.astype(jnp.float32) for x in xs)
+    room = 1.0 if dtype == jnp.float32 else 3.0
 
     def loss(fn):
-        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) ** 2).sum()
 
+    want = mha_reference(*f32(q, k, v), causal=causal)
+    out = attention(q, k, v, causal=causal, impl="flash_interpret")
+    assert out.dtype == dtype
+    np.testing.assert_allclose(np.asarray(f32(out)[0]), np.asarray(want),
+                               rtol=2e-3 * room, atol=4e-3 * room)
     ref = jax.grad(loss(lambda q, k, v: mha_reference(
-        q, k, v, causal=causal)), argnums=(0, 1, 2))(q, k, v)
+        q, k, v, causal=causal)), argnums=(0, 1, 2))(*f32(q, k, v))
     got = jax.grad(loss(lambda q, k, v: attention(
         q, k, v, causal=causal, impl="flash_interpret")),
         argnums=(0, 1, 2))(q, k, v)
     for name, r, g in zip("qkv", ref, got):
+        assert g.shape == r.shape and g.dtype == dtype, name
         scale = float(jnp.max(jnp.abs(r)))
         np.testing.assert_allclose(
-            np.asarray(g), np.asarray(r), rtol=2e-2, atol=0.01 * scale,
+            np.asarray(f32(g)[0]), np.asarray(r), rtol=2e-2,
+            atol=0.01 * room * scale,
             err_msg=f"d{name} (causal={causal}, {s_q}x{s_kv})")
 
 
@@ -526,7 +552,8 @@ def test_on_tpu_follows_the_backend(monkeypatch):
     monkeypatch.setattr(attn_mod.jax, "default_backend", lambda: "tpu")
     assert attn_mod._on_tpu()
     assert attn_mod.flash_auto_engaged(8192)
-    assert not attn_mod.flash_auto_engaged(1024)
+    assert attn_mod.flash_auto_engaged(attn_mod.FLASH_MIN_SEQ)
+    assert not attn_mod.flash_auto_engaged(attn_mod.FLASH_MIN_SEQ // 2)
     assert default_interpret() is False
     monkeypatch.setattr(attn_mod.jax, "default_backend", lambda: "cpu")
     assert not attn_mod._on_tpu()
@@ -789,6 +816,9 @@ def test_flash_block_env_override(monkeypatch):
     monkeypatch.setenv("TB_FLASH_BLOCK_Q", "768")
     assert not tileable(8192)
     monkeypatch.delenv("TB_FLASH_BLOCK_Q")
+    # K is still 32: it divides, but is no whole lane tile
+    assert not tileable(8192)
+    monkeypatch.delenv("TB_FLASH_BLOCK_K")
     assert tileable(8192)
 
 

@@ -92,28 +92,62 @@ def test_paged_attention_compiles_for_v5e(one_chip, s_q, int8_pool):
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
-@pytest.mark.parametrize("q_heads,kv_heads,head_dim", [
-    pytest.param(12, 12, 64, id="mha-d64"),
-    pytest.param(16, 8, 48, id="gpt-long-gqa-d48"),
+@pytest.mark.parametrize("q_rows,kv_rows,seq,head_dim", [
+    pytest.param(12, 12, 8192, 64, id="s8192-mha-d64"),
+    pytest.param(16, 8, 8192, 48, id="s8192-gpt-long-gqa-d48"),
+    # gpt2-small.train-s1024: batch 16 x 12 heads folded into rows
+    pytest.param(16 * 12, 16 * 12, 1024, 64, id="s1024-train-cell"),
 ])
-def test_flash_attention_s8192_compiles_for_v5e(
-        one_chip, q_heads, kv_heads, head_dim, backward):
+def test_flash_attention_compiles_for_v5e(
+        one_chip, q_rows, kv_rows, seq, head_dim, backward):
     from torchbooster_tpu.ops.flash_attention import flash_attention
 
     def flash(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=False)
 
     def grads(q, k, v):
-        return jax.grad(
+        return jax.value_and_grad(
             lambda *qkv: flash(*qkv).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    q = jax.ShapeDtypeStruct((q_heads, 8192, head_dim), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((q_rows, seq, head_dim), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((kv_heads, 8192, head_dim), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((kv_rows, seq, head_dim), jnp.bfloat16,
                               sharding=one_chip)
     text, _ = _compile(grads if backward else flash, q, kv, kv)
-    assert "tpu_custom_call" in text
+    assert text.count("tpu_custom_call") >= (3 if backward else 1)
+
+
+def test_gpt_gradient_with_the_kernel_keeps_no_score_matrix(one_chip):
+    """One GPT-2-small layer at the train cell's batch, remat and bf16
+    compute with ``attn_impl="flash"``: three kernels (forward, dQ,
+    dK/dV — the forward is not run again inside the backward) and no
+    (.., S, S) tensor of any dtype left in the optimised program."""
+    import re
+
+    from torchbooster_tpu.models.gpt import GPT, GPTConfig
+
+    cfg = GPTConfig(n_layers=1)
+
+    def loss(params, ids):
+        logits = GPT.apply(params, ids, cfg=cfg,
+                           compute_dtype=jnp.bfloat16, remat=True,
+                           attn_impl="flash")
+        return logits.astype(jnp.float32).mean()
+
+    def arg(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        arg, jax.eval_shape(lambda: GPT.init(jax.random.PRNGKey(0), cfg)))
+    ids = jax.ShapeDtypeStruct((16, cfg.seq_len), jnp.int32,
+                               sharding=one_chip)
+    _, compiled = _compile(jax.grad(loss), params, ids)
+    text = compiled.as_text()
+    s = cfg.seq_len
+    assert not re.findall(rf"\b(?:f32|bf16)\[[0-9,]*{s},{s}\]", text)
+    kernels = re.findall(r'custom_call_target="tpu_custom_call"', text)
+    assert len(kernels) == 3, len(kernels)
 
 
 def test_gpt2_small_train_step_compiles_and_fits_v5e(one_chip):

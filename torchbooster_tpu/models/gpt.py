@@ -305,8 +305,11 @@ class GPT:
                                           impl=attn_impl), None
             # grouped K/V go straight to the dispatcher: the flash
             # kernel indexes grouped tiles natively (expanded K/V never
-            # exist in HBM); the XLA reference expands internally
-            return attention(q, k, v, causal=True, impl=attn_impl), None
+            # exist in HBM); the XLA reference expands internally. The
+            # mesh goes along: this path is traced under plain jit, and
+            # the kernel runs per device over the batch axes
+            return attention(q, k, v, causal=True, impl=attn_impl,
+                             mesh=mesh), None
 
         def block(carry: tuple, layer_in: tuple) -> tuple[tuple, None]:
             bp, drop_key = layer_in
@@ -316,12 +319,8 @@ class GPT:
                                           dropout_key=drop_key)
             return (x, aux + layer_aux), None
 
-        # save matmul outputs, recompute the cheap elementwise ops —
-        # measured ≥ plain full remat on v5e with much less recompute
         scan_block = jax.checkpoint(
-            block,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        ) if remat else block
+            block, policy=_remat_policy()) if remat else block
         (x, aux), _ = jax.lax.scan(
             lambda carry, layer_in: scan_block(carry, layer_in),
             (x, jnp.zeros((), jnp.float32)),
@@ -735,9 +734,7 @@ def _pipelined_blocks(params: dict, x: jax.Array, cfg: GPTConfig,
         return h, layer_aux
 
     layer = jax.checkpoint(
-        pp_layer,
-        policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-    ) if remat else pp_layer
+        pp_layer, policy=_remat_policy()) if remat else pp_layer
     data = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names) \
         or None
     x_spec = P(None, data, "sp") if use_sp else None
@@ -1542,6 +1539,22 @@ def load_torch_gpt2(state_dict, n_heads: int | None = None):
                 _np(sd["ln_f.bias"]).astype(_onp.float32))},
     }
     return params, cfg
+
+
+def _remat_policy():
+    """What a rematerialised block keeps: matmul outputs, and the two
+    tensors the flash kernel hands its backward (a ``pallas_call`` is
+    no dot, so without their names the backward would run the forward
+    kernel a second time just to rebuild them — 25 MB a layer at GPT-2
+    small, batch 16 x S=1024); the cheap elementwise ops are computed
+    again — measured ≥ plain full remat on v5e with much less
+    recompute."""
+    from torchbooster_tpu.ops.flash_attention import RESIDUALS
+
+    policies = jax.checkpoint_policies
+    return policies.save_from_both_policies(
+        policies.dots_with_no_batch_dims_saveable,
+        policies.save_only_these_names(*RESIDUALS))
 
 
 def _make_constrainer(mesh: Mesh | None):

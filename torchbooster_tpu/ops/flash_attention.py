@@ -14,16 +14,20 @@ dimension innermost and sequential ("arbitrary" semantics): each grid
 step sees only one (block_k, D) K/V tile in VMEM — VMEM use is
 O(block_q·D + block_k·D) regardless of sequence length — while the
 online-softmax state (running max / sum / accumulator) persists in
-VMEM scratch across the KV sweep. Causal masking skips fully-masked
-KV tiles via pl.when. When differentiated, the forward additionally
-emits the per-row logsumexp ``L = m + log(l)``, padded to 8 lanes (the
-sublane width — the smallest Mosaic-legal minor dim) so it stores/
-loads as a clean (block_q, 8) tile at 1/16th the footprint of the
-conventional 128-lane padding.
+VMEM scratch across the KV sweep. Every product runs at the operands'
+own dtype (bf16 in training) with float32 accumulation, contracting the
+shared axis directly; max, sum, logsumexp, delta and every accumulator
+are float32. Causal masking skips fully-masked KV tiles via pl.when,
+runs fully-visible tiles unmasked, and cuts the tile the diagonal
+crosses into 128-wide strips that stop at the diagonal (``_visit``).
+When differentiated, the forward additionally emits the per-row
+logsumexp ``L = m + log(l)`` as dense ROWS, (BH, 1, S) — see
+``_col_to_row``; it and the output carry ``jax.ad_checkpoint`` names
+(``RESIDUALS``) so a caller's remat policy can keep them.
 
 Backward follows the FlashAttention-2 factorization — probabilities
 are *recomputed* from Q·Kᵀ and the saved logsumexp, never saved:
-  delta = rowsum(dO ∘ O)          (in-kernel, from tiles already in VMEM)
+  delta = rowsum(dO ∘ O)
   P     = exp(scale·QKᵀ − L)                 (recomputed per tile)
   dV    = Pᵀ dO
   dS    = P ∘ (dO Vᵀ − delta)
@@ -31,7 +35,10 @@ are *recomputed* from Q·Kᵀ and the saved logsumexp, never saved:
   dK    = scale · dSᵀ Q       — grid (BH, kv_blocks, q_blocks)
 Two kernels, each accumulating its output tile in fp32 VMEM scratch
 over its inner sweep, so dQ rows and dK/dV rows are each written to
-HBM exactly once and no atomics/psums are needed.
+HBM exactly once and no atomics/psums are needed. The dK/dV kernel
+computes the TRANSPOSED tile (keys down the rows: Sᵀ = K Qᵀ), so Pᵀ and
+dSᵀ come out of the matrix unit already turned and nothing is
+transposed on the vector side.
 
 On CPU (tests) the kernels run in interpret mode; `attention` in
 ops.attention only dispatches here on TPU backends.
@@ -44,6 +51,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -53,30 +61,31 @@ from torchbooster_tpu.ops._pallas_util import (
 )
 
 NEG_INF = -1e30
-# Per-row residual (lse) lane padding. Mosaic requires a block's minor
-# dim be a multiple of 128 OR equal to the full array dim — so a (bh,
-# seq, 8) array with (block_q, 8) tiles is legal and 16x smaller than
-# the 128-lane padding jax's bundled kernel uses (verified on v5e).
-LANES = 8
 MIN_BLOCK = 8  # sublane width — smallest sane tile edge
+STRIP = 128    # lane width: what a diagonal tile is cut into, and what
+#                a tile the compiled dK/dV kernel can read is made of
+# jax.ad_checkpoint names of the forward's output and logsumexp
+RESIDUALS = ("flash_out", "flash_lse")
+LSE, DELTA = 0, 1   # the backward's per-query rows (_bwd_pallas)
 
 
 def tileable(seq: int, block: int | None = None) -> bool:
-    """True when :func:`flash_attention` can tile ``seq`` — the auto
-    dispatcher checks this and falls back to the XLA reference instead
-    of crashing on awkward lengths. Delegates to :func:`_pick_block` so
-    the predicate can never drift from the actual tiling policy —
-    including the ``TB_FLASH_BLOCK_*`` env defaults: with no explicit
-    ``block``, BOTH resolved defaults must tile (the caller doesn't say
-    whether ``seq`` is a q or kv length, and a predicate that passes on
-    one geometry while the kernel runs the other is the drift this
-    function exists to prevent)."""
+    """True when :func:`flash_attention` can tile ``seq`` in whole lane
+    tiles — the auto dispatcher checks this and falls back to the XLA
+    reference instead of crashing (or crawling through 8-wide tiles) on
+    awkward lengths: the dK/dV kernel reads its per-query terms as
+    (1, block_q) rows, which the chip's compiler takes in multiples of
+    128 lanes. Delegates to :func:`_pick_block` so the predicate can
+    never drift from the actual tiling policy — including the
+    ``TB_FLASH_BLOCK_*`` env defaults: with no explicit ``block``, BOTH
+    resolved defaults must tile (the caller doesn't say whether ``seq``
+    is a q or kv length, and a predicate that passes on one geometry
+    while the kernel runs the other is the drift this function exists
+    to prevent)."""
     blocks = ([block] if block is not None
               else [_block_default("Q"), _block_default("K")])
     try:
-        for b in blocks:
-            _pick_block(b, seq, "seq")
-        return True
+        return all(_pick_block(b, seq, "seq") % STRIP == 0 for b in blocks)
     except ValueError:
         return False
 
@@ -101,6 +110,120 @@ def _pick_block(block: int, seq: int, name: str) -> int:
 # Forward kernel
 # =========================================================================
 
+def _diag_strips(block_q: int, block_k: int, offset: int) -> int:
+    """How many strips the tile the diagonal crosses is cut into. A
+    square tile whose corner lies ON the diagonal (the offset a
+    multiple of the tile) always holds the same lower triangle, so its
+    visible part is cut, statically, into strips one lane tile (128)
+    wide that stop at the diagonal — 36/64ths of a 1024-wide tile —
+    instead of computing the masked half in full: on a v5e the finest
+    strips measured fastest (PERF.md section 6, PR 31). Any other tile
+    (1) is computed whole under its mask."""
+    if block_q != block_k or offset % block_q or block_q % STRIP:
+        return 1
+    return block_q // STRIP
+
+
+def _visit(tile, q_index, kv_index, *, causal: bool, block_q: int,
+           block_k: int, offset: int, by_keys: bool) -> None:
+    """Run ``tile(q_rows, k_rows, lead)`` over what causality leaves
+    visible of the grid step's (block_q, block_k) tile. Alignment
+    matches mha_reference's tril(offset=seq_kv-seq_q): query row i
+    attends keys [0, i + offset] — queries align to the *last* keys
+    (the decode-with-KV-cache convention). A tile every query of which
+    sees every key runs unmasked (``lead`` None); a tile no query sees
+    is skipped; the tiles the diagonal crosses run masked, ``lead`` =
+    position of the slice's first query less that of its first key —
+    whole, or strip by strip (:func:`_diag_strips`): a strip of queries
+    with the keys up to its last row, or (``by_keys``, the dK/dV
+    kernel) a strip of keys with the queries from its first row on."""
+    whole_tile = (pl.ds(0, block_q), pl.ds(0, block_k))
+    if not causal:
+        tile(*whole_tile, None)
+        return
+    first_q = q_index * block_q + offset
+    first_k = kv_index * block_k
+    visible = first_q + block_q > first_k
+    whole = first_q >= first_k + block_k - 1
+    pl.when(whole)(lambda: tile(*whole_tile, None))
+
+    @pl.when(visible & jnp.logical_not(whole))
+    def _diagonal():
+        n = _diag_strips(block_q, block_k, offset)
+        if n == 1:
+            tile(*whole_tile, first_q - first_k)
+            return
+        strip = block_q // n
+        for i in range(n):
+            if by_keys:
+                tile(pl.ds(i * strip, block_q - i * strip),
+                     pl.ds(i * strip, strip), 0)
+            else:
+                tile(pl.ds(i * strip, strip),
+                     pl.ds(0, (i + 1) * strip), i * strip)
+
+
+def _folds(sm_scale: float) -> bool:
+    """A power-of-two scale (1/8 at head size 64) multiplies a bf16 or
+    float32 tile EXACTLY, so it goes onto the (block, D) operand once
+    instead of onto every (block_q, block_k) score; any other scale
+    multiplies the float32 scores, as mha_reference does."""
+    return math.frexp(sm_scale)[0] == 0.5
+
+
+def _nt(a, b):
+    """``a @ b.T`` with float32 accumulation, contracting the shared
+    minor axis directly (no transpose is materialised) at the operands'
+    own dtype — bf16 tiles go to the matrix unit as bf16."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _exact_pieces(x):
+    """Float32 ``x`` as three bf16 pieces that sum to it exactly (8 + 8
+    + 8 mantissa bits), so a product with them is exact on the matrix
+    unit whatever precision it gives float32 operands."""
+    pieces = []
+    for _ in range(3):
+        pieces.append(x.astype(jnp.bfloat16))
+        x = x - pieces[-1].astype(jnp.float32)
+    return pieces
+
+
+# The per-query logsumexp lives in HBM as ROWS, (BH, 1, S): a column
+# (S, 1) — how the forward's running max and sum lie — is padded to 128
+# lanes there, 128 times its bytes (1.2 GB through the layer scan's
+# saved stack at GPT-2 small, batch 16; PERF.md section 6, PR 31).
+
+def _col_to_row(col):
+    """(n, 1) float32 -> (1, n), exactly, on the matrix unit (cheaper
+    on a v5e than a transpose of the lane-broadcast column): the mean
+    of 128 copies of each bf16 piece, contracted along the lanes."""
+    wide = jnp.broadcast_to(col, (col.shape[0], STRIP))
+    mean = jnp.full((8, STRIP), 1.0 / STRIP, jnp.bfloat16)
+    return sum(_nt(mean, piece) for piece in _exact_pieces(wide))[:1]
+
+
+def _scores(rows, cols, *, sm_scale: float, lead, q_axis: int):
+    """float32 ``scale * rows @ cols.T`` (the caller has folded a
+    foldable scale into one operand); queries run along ``q_axis``.
+    Masked positions (``lead`` given: see :func:`_visit`) go to NEG_INF
+    *before* any exp so an unmasked large score can never overflow."""
+    s = _nt(rows, cols)
+    if not _folds(sm_scale):
+        s = s * sm_scale
+    if lead is not None:
+        q_pos = lead + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    return s
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, block_q: int, block_k: int, causal: bool, sm_scale: float,
                 seq_q: int, seq_kv: int):
@@ -114,47 +237,33 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal alignment matches mha_reference's tril(offset=seq_kv-seq_q):
-    # query row i attends keys [0, i + seq_kv - seq_q] — queries align to
-    # the *last* keys (the decode-with-KV-cache convention)
-    offset = seq_kv - seq_q
-    if causal:
-        # any key in this tile visible to any query in the q tile?
-        visible = (q_index + 1) * block_q + offset > kv_index * block_k
-    else:
-        visible = True
-
-    @pl.when(visible)
-    def _body():
-        q = q_ref[:].astype(jnp.float32) * sm_scale
-        k = k_ref[:]
-        v = v_ref[:]
-        scores = q @ k.astype(jnp.float32).T      # (block_q, block_k) on MXU
-
-        if causal:
-            q_pos = q_index * block_q + offset + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kv_index * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            scores = jnp.where(q_pos >= k_pos, scores, NEG_INF)
-
-        m_prev = m_scr[:, 0]
-        l_prev = l_scr[:, 0]
-        m_cur = jnp.maximum(m_prev, scores.max(axis=1))
+    def tile(qs, ks, lead):
+        q = q_ref[qs, :]
+        if _folds(sm_scale):
+            q = q * sm_scale
+        v = v_ref[ks, :]
+        scores = _scores(q, k_ref[ks, :], sm_scale=sm_scale, lead=lead,
+                         q_axis=0)                # (queries, keys)
+        m_prev = m_scr[qs, :]
+        m_cur = jnp.maximum(m_prev, scores.max(axis=1, keepdims=True))
         correction = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(scores - m_cur[:, None])
-        l_scr[:, 0] = l_prev * correction + p.sum(axis=1)
-        m_scr[:, 0] = m_cur
-        acc_scr[:] = (acc_scr[:] * correction[:, None]
-                      + p @ v.astype(jnp.float32))
+        p = jnp.exp(scores - m_cur)
+        l_scr[qs, :] = (l_scr[qs, :] * correction
+                        + p.sum(axis=1, keepdims=True))
+        m_scr[qs, :] = m_cur
+        # p goes to its product at the values' dtype, as mha_reference
+        # casts probs; the row sum above is of the float32 p
+        acc_scr[qs, :] = (acc_scr[qs, :] * correction
+                          + _nn(p.astype(v.dtype), v))
+
+    _visit(tile, q_index, kv_index, causal=causal, block_q=block_q,
+           block_k=block_k, offset=seq_kv - seq_q, by_keys=False)
 
     @pl.when(kv_index == n_kv - 1)
     def _finalize():
-        o_ref[:] = (acc_scr[:] / l_scr[:, 0][:, None]).astype(o_ref.dtype)
+        o_ref[:] = (acc_scr[:] / l_scr[:]).astype(o_ref.dtype)
         if lse_ref is not None:
-            lse = m_scr[:, 0] + jnp.log(l_scr[:, 0])
-            lse_ref[:] = jax.lax.broadcast_in_dim(
-                lse, (block_q, LANES), (0,))
+            lse_ref[:] = _col_to_row(m_scr[:] + jnp.log(l_scr[:]))
 
 
 def _fwd_pallas(q, k, v, *, causal, sm_scale, block_q, block_k, interpret,
@@ -171,9 +280,9 @@ def _fwd_pallas(q, k, v, *, causal, sm_scale, block_q, block_k, interpret,
                               lambda b, i, j: (b, i, 0))]
     if save_residuals:
         out_shape.append(
-            jax.ShapeDtypeStruct((bh, seq_q, LANES), jnp.float32))
-        out_specs.append(pl.BlockSpec((None, block_q, LANES),
-                                      lambda b, i, j: (b, i, 0)))
+            jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32))
+        out_specs.append(pl.BlockSpec((None, 1, block_q),
+                                      lambda b, i, j: (b, 0, i)))
     else:
         out_shape.append(None)
         out_specs.append(None)
@@ -200,6 +309,7 @@ def _fwd_pallas(q, k, v, *, causal, sm_scale, block_q, block_k, interpret,
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -208,61 +318,40 @@ def _fwd_pallas(q, k, v, *, causal, sm_scale, block_q, block_k, interpret,
 # Backward kernels
 # =========================================================================
 
-def _recompute_p(q_ref, k_ref, lse_ref, *, sm_scale, causal, block_q,
-                 block_k, q_index, kv_index, offset):
-    """(block_q, block_k) normalized probabilities from the saved
-    logsumexp. Masked positions go through NEG_INF *before* the exp so
-    an unmasked large score can never overflow it."""
-    q = q_ref[:].astype(jnp.float32) * sm_scale
-    scores = q @ k_ref[:].astype(jnp.float32).T
-    if causal:
-        q_pos = q_index * block_q + offset + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = kv_index * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        scores = jnp.where(q_pos >= k_pos, scores, NEG_INF)
-    return jnp.exp(scores - lse_ref[:, :1])
-
-
-def _dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
-               dq_scr, delta_scr, *, block_q: int, block_k: int,
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, rows_ref, dq_ref,
+               dq_scr, cols_scr, *, block_q: int, block_k: int,
                causal: bool, sm_scale: float, seq_q: int, seq_kv: int):
     q_index = pl.program_id(1)
     kv_index = pl.program_id(2)
     n_kv = pl.num_programs(2)
-    offset = seq_kv - seq_q
 
     @pl.when(kv_index == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
-        # delta = rowsum(dO ∘ O): one cheap elementwise pass over tiles
-        # already streaming into VMEM — computing it here avoids a whole
-        # (bh, seq, LANES) fp32 residual array in HBM
-        delta_scr[:, 0] = jnp.sum(
-            o_ref[:].astype(jnp.float32) * do_ref[:].astype(jnp.float32),
-            axis=-1)
+        # the per-query rows (_bwd_pallas) as columns: one transpose of
+        # a sublane tile — the cheaper way on a v5e (PERF.md section 6)
+        cols_scr[:] = rows_ref[:].T
 
-    if causal:
-        visible = (q_index + 1) * block_q + offset > kv_index * block_k
-    else:
-        visible = True
+    def tile(qs, ks, lead):
+        q = q_ref[qs, :]
+        if _folds(sm_scale):
+            q = q * sm_scale
+        k = k_ref[ks, :]
+        scores = _scores(q, k, sm_scale=sm_scale, lead=lead, q_axis=0)
+        p = jnp.exp(scores - cols_scr[qs, LSE:LSE + 1])   # (queries, keys)
+        dp = _nt(do_ref[qs, :], v_ref[ks, :])
+        ds = p * (dp - cols_scr[qs, DELTA:DELTA + 1])
+        dq_scr[qs, :] += _nn(ds.astype(k.dtype), k)
 
-    @pl.when(visible)
-    def _body():
-        p = _recompute_p(q_ref, k_ref, lse_ref, sm_scale=sm_scale,
-                         causal=causal, block_q=block_q, block_k=block_k,
-                         q_index=q_index, kv_index=kv_index, offset=offset)
-        do = do_ref[:].astype(jnp.float32)
-        dp = do @ v_ref[:].astype(jnp.float32).T      # (block_q, block_k)
-        ds = p * (dp - delta_scr[:, 0][:, None])
-        dq_scr[:] += sm_scale * (ds @ k_ref[:].astype(jnp.float32))
+    _visit(tile, q_index, kv_index, causal=causal, block_q=block_q,
+           block_k=block_k, offset=seq_kv - seq_q, by_keys=False)
 
     @pl.when(kv_index == n_kv - 1)
     def _finalize():
-        dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[:] = (sm_scale * dq_scr[:]).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, rows_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr, *, block_q: int,
                 block_k: int, causal: bool, sm_scale: float, seq_q: int,
                 seq_kv: int, n_qblocks: int):
@@ -270,39 +359,43 @@ def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     # sweep innermost — each GROUPED kv tile owns its dK/dV rows and
     # sweeps all q tiles of every head in its group; the causal mask
     # depends only on the POSITION part of the sweep index.
+    #
+    # Everything here is the TRANSPOSE of the dQ kernel's tile — keys
+    # down the rows, queries along the lanes — so all four products are
+    # plain a @ b or a @ b.T on the matrix unit and no (block_q,
+    # block_k) float32 tile is ever transposed: Sᵀ = K Qᵀ, dPᵀ = V dOᵀ,
+    # dV += Pᵀ dO, dK += dSᵀ Q. The per-query logsumexp and delta
+    # are used as they arrive: ROWS of (8, block_q).
     kv_index = pl.program_id(1)
     sweep = pl.program_id(2)
-    q_index = sweep % n_qblocks
     n_sweep = pl.num_programs(2)
-    offset = seq_kv - seq_q
 
     @pl.when(sweep == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    if causal:
-        visible = (q_index + 1) * block_q + offset > kv_index * block_k
-    else:
-        visible = True
+    def tile(qs, ks, lead):
+        q = q_ref[qs, :]
+        k = k_ref[ks, :]
+        if _folds(sm_scale):
+            k = k * sm_scale
+        do = do_ref[qs, :]
+        scores_t = _scores(k, q, sm_scale=sm_scale, lead=lead,
+                           q_axis=1)                  # (keys, queries)
+        p_t = jnp.exp(scores_t - rows_ref[LSE:LSE + 1, qs])
+        dv_scr[ks, :] += _nn(p_t.astype(do.dtype), do)
+        dp_t = _nt(v_ref[ks, :], do)
+        ds_t = p_t * (dp_t - rows_ref[DELTA:DELTA + 1, qs])
+        dk_scr[ks, :] += _nn(ds_t.astype(q.dtype), q)
 
-    @pl.when(visible)
-    def _body():
-        p = _recompute_p(q_ref, k_ref, lse_ref, sm_scale=sm_scale,
-                         causal=causal, block_q=block_q, block_k=block_k,
-                         q_index=q_index, kv_index=kv_index, offset=offset)
-        do = do_ref[:].astype(jnp.float32)
-        dv_scr[:] += p.T @ do
-        dp = do @ v_ref[:].astype(jnp.float32).T
-        # recomputed per visit: block_q·D mul-adds, noise next to the
-        # block_q·block_k·D matmuls above
-        delta = jnp.sum(o_ref[:].astype(jnp.float32) * do, axis=-1)
-        ds = p * (dp - delta[:, None])
-        dk_scr[:] += sm_scale * (ds.T @ q_ref[:].astype(jnp.float32))
+    _visit(tile, sweep % n_qblocks, kv_index, causal=causal,
+           block_q=block_q, block_k=block_k, offset=seq_kv - seq_q,
+           by_keys=True)
 
     @pl.when(sweep == n_sweep - 1)
     def _finalize():
-        dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
+        dk_ref[:] = (sm_scale * dk_scr[:]).astype(dk_ref.dtype)
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
@@ -312,28 +405,35 @@ def _bwd_pallas(q, k, v, out, lse, do, *, causal, sm_scale, block_q,
     bh_kv, seq_kv, _ = k.shape
     kv_rep = bh // bh_kv
 
+    # what both kernels need per query, as dense rows of ONE sublane
+    # tile: the logsumexp as it is stored, and delta = rowsum(dO ∘ O),
+    # summed here by XLA (rows LSE and DELTA; the other six are padding)
+    delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
+                    axis=-1)[:, None, :]
+    rows = jnp.concatenate(
+        [lse, delta, jnp.zeros((bh, 6, seq_q), jnp.float32)], axis=1)
+
     q_spec = pl.BlockSpec((None, block_q, head_dim),
                           lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec((None, block_k, head_dim),
                            lambda b, i, j, r=kv_rep: (b // r, j, 0))
-    row_spec = pl.BlockSpec((None, block_q, LANES),
-                            lambda b, i, j: (b, i, 0))
+    rows_spec = pl.BlockSpec((None, 8, block_q), lambda b, i, j: (b, 0, i))
     common = dict(causal=causal, sm_scale=sm_scale, block_q=block_q,
                   block_k=block_k, seq_q=seq_q, seq_kv=seq_kv)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **common),
         grid=(bh, seq_q // block_q, seq_kv // block_k),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
-        out_specs=pl.BlockSpec((None, block_q, head_dim),
-                               lambda b, i, j: (b, i, 0)),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, rows_spec],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, head_dim), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32),
-                        pltpu.VMEM((block_q, 1), jnp.float32)],
+                        pltpu.VMEM((block_q, 8), jnp.float32)],
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, out, do, lse)
+        name="flash_dq",
+    )(q, k, v, do, rows)
 
     # transposed grid: (bh_kv, kv_blocks, q_blocks·rep) — each GROUPED
     # kv row owns its dK/dV tile and sweeps every q tile of every query
@@ -345,18 +445,14 @@ def _bwd_pallas(q, k, v, out, lse, do, *, causal, sm_scale, block_q,
         lambda b, i, j, r=kv_rep, n=n_q: (b * r + j // n, j % n, 0))
     kv_spec_t = pl.BlockSpec((None, block_k, head_dim),
                              lambda b, i, j: (b, i, 0))
-    row_spec_t = pl.BlockSpec(
-        (None, block_q, LANES),
-        lambda b, i, j, r=kv_rep, n=n_q: (b * r + j // n, j % n, 0))
+    rows_spec_t = pl.BlockSpec(
+        (None, 8, block_q),
+        lambda b, i, j, r=kv_rep, n=n_q: (b * r + j // n, 0, j % n))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, n_qblocks=n_q, **common),
         grid=(bh_kv, seq_kv // block_k, n_q * kv_rep),
-        in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, q_spec_t,
-                  row_spec_t],
-        out_specs=[
-            pl.BlockSpec((None, block_k, head_dim), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, head_dim), lambda b, i, j: (b, i, 0)),
-        ],
+        in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, rows_spec_t],
+        out_specs=[kv_spec_t, kv_spec_t],
         out_shape=[
             jax.ShapeDtypeStruct((bh_kv, seq_kv, head_dim), k.dtype),
             jax.ShapeDtypeStruct((bh_kv, seq_kv, head_dim), v.dtype),
@@ -366,7 +462,8 @@ def _bwd_pallas(q, k, v, out, lse, do, *, causal, sm_scale, block_q,
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, out, do, lse)
+        name="flash_dkv",
+    )(q, k, v, do, rows)
     return dq, dk, dv
 
 
@@ -386,6 +483,12 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     out, lse = _fwd_pallas(q, k, v, causal=causal, sm_scale=sm_scale,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret, save_residuals=True)
+    # what the kernel hands its backward, by name: a pallas_call is no
+    # dot, so a jax.checkpoint policy that saves dots would run this
+    # forward again inside the backward just to rebuild the two — a
+    # caller's policy keeps them with save_only_these_names(*RESIDUALS)
+    out = checkpoint_name(out, RESIDUALS[0])
+    lse = checkpoint_name(lse, RESIDUALS[1])
     return out, (q, k, v, out, lse)
 
 
@@ -432,10 +535,14 @@ def flash_attention(
     """Blocked attention over (BH, S, D) tensors; differentiable (the
     backward recomputes probabilities from the saved logsumexp — see
     module docstring). Block sizes shrink (by halving, floor 8) to
-    divide the sequence lengths; the 1024 defaults measured ~2x faster
-    than 128 at S=8k on v5e (the TPU grid runs blocks sequentially per
-    core, so bigger tiles amortize overhead — VMEM, not parallelism,
-    is the constraint).
+    divide the sequence lengths. The 1024 defaults are the measured
+    optimum on a v5e from S=1024 to S=8192 at head size 64 (the TPU
+    grid runs blocks sequentially per core and a step's per-row work —
+    the softmax's reductions — does not shrink with a narrower tile, so
+    bigger tiles amortize it: forward + backward 4.0 / 4.7 / 7.4 ms at
+    1024 / 512 / 256 for 16 x 12 heads of S=1024; 2048 runs out of
+    VMEM; PERF.md section 6, PR 31). The causal saving comes from
+    inside the diagonal tile instead (``_diag_strips``).
 
     GQA-native: k/v may carry FEWER leading rows than q (q flattened
     batch-major with group-contiguous heads, k/v at grouped width) —

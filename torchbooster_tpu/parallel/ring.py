@@ -201,7 +201,7 @@ def _rf_forward(qf, kf, vf, axis, sp_size, causal, sm_scale, interpret):
             qf, k_t, v_t, causal=causal_flag, sm_scale=sm_scale,
             block_q=blk, block_k=blk, interpret=interpret,
             save_residuals=True)
-        return o, l[..., 0]
+        return o, l[:, 0]
 
     # t = 0 peeled: every device starts on its OWN (diagonal) chunk —
     # the only step that needs the causal-kernel flavor — and it
@@ -242,12 +242,12 @@ def _rf_fwd(qf, kf, vf, axis, sp_size, causal, sm_scale, interpret):
 
 
 def _rf_bwd(axis, sp_size, causal, sm_scale, interpret, res, do):
-    from torchbooster_tpu.ops.flash_attention import (LANES, _bwd_pallas,
+    from torchbooster_tpu.ops.flash_attention import (_bwd_pallas,
                                                       _pick_block)
 
     qf, kf, vf, out, lse = res
     blk = _pick_block(1024, qf.shape[1], "ring chunk")
-    lse_b = jnp.broadcast_to(lse[..., None], (*lse.shape, LANES))
+    lse_b = lse[:, None]
     my = lax.axis_index(axis)
     perm = [(j, (j + 1) % sp_size) for j in range(sp_size)]
 

@@ -90,7 +90,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
     Same contract as :func:`parallel.ring.ring_attention` (drop-in);
     requires the per-device head counts (query AND grouped k/v) to
     divide by the ``sp`` size. ``impl`` feeds the local attention
-    dispatch ("auto" engages the flash kernel on TPU from S≥4096).
+    dispatch ("auto" engages the flash kernel on TPU from S≥512).
     """
     *_, n_heads, head_dim = q.shape
     rep = _validate_heads(q, k)
