@@ -445,3 +445,236 @@ def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
     if program == "mixed":
         # beside the weights: what the cell's two programs took
         assert total < 10.6e9, total
+
+
+# sha256 of the StableHLO text each accepted serve cell's programs
+# lower to for the described v5e (Mosaic kernels' bodies included, call
+# sites' line numbers left out), taken at PR 31's tree (c95a217)
+ACCEPTED_SERVE_TEXT = {
+    ("gpt2-xl.serve-chat-r80", "decode"): 
+        "b88fdf7218f7919fabca3da537193df5c98b1c5679da0f3355c2119cf5fc6ff4",
+    ("gpt2-xl.serve-chat-r80", "chunk"): 
+        "f7f5c189c05dbede89816005a29e3cceb9d565a57adbd50154af71202aaaaca4",
+    ("gpt2-xl.serve-chat-r80", "mixed"): 
+        "1e29c9cb275698ce4f21c2b1e1b6141b0e57866448780708043847f5da279001",
+    ("lfm2-8b-a1b.serve-rag-r80", "decode"): 
+        "da02a725e86ba5a4599e2f5a7a21f205406c5d8d6a0981722b932b401af16435",
+    ("lfm2-8b-a1b.serve-rag-r80", "chunk"): 
+        "cabf5d4c2b628d42b61e0958399c320546c5b8cb1eb411a50c2cf54d46765ac6",
+    ("lfm2-8b-a1b.serve-rag-r80", "mixed"): 
+        "845314329d18a4d9023c428cbdda7b7f930b59b7f567c4d4751c24f0919f67c7",
+}
+
+
+def _accepted_cell_lowered(one_chip, cell: str, program: str):
+    """One serve program of an accepted cell, lowered (not compiled)
+    for the described v5e at the cell's OWN geometry: the
+    configuration file's model at full depth, the traffic file's
+    ``serving:`` block and positions, weights in bfloat16."""
+    import json
+    import sys
+    from pathlib import Path
+
+    import torchbooster_tpu.serving.engine as engine_mod
+
+    bench = Path(__file__).resolve().parent.parent / "benchmark"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    manifest = json.loads((bench.parent / "BENCHMARK.json").read_text())
+    entry = next(w for w in manifest["workloads"] if w["name"] == cell)
+    raw = json.loads((bench.parent / next(
+        c["file"] for c in manifest["configs"]
+        if c["name"] == entry["config"])).read_text())
+    traffic = json.loads(
+        (bench / "traffic" / f"{entry['traffic']}.json").read_text())
+    serving = traffic["serving"]
+    if traffic["job"] == "serve_lfm2":
+        import program_lfm2
+        from torchbooster_tpu.models.lfm2 import LFM2 as model
+
+        cfg = program_lfm2.model_config(raw, traffic["max_positions"])
+    else:
+        import program as program_gpt
+        from torchbooster_tpu.models.gpt import GPT as model
+
+        cfg = program_gpt.gpt_config(raw)
+    abstract = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip), tree)
+    params = abstract(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16),
+        model.init(jax.random.PRNGKey(0), cfg))))
+    real = engine_mod.make_pool, engine_mod.make_slot_state
+    described = lambda make: lambda *a, **kw: jax.eval_shape(
+        lambda: make(*a, **kw))
+    engine_mod.make_pool = described(real[0])
+    engine_mod.make_slot_state = described(real[1])
+    try:
+        engine = engine_mod.PagedEngine(
+            params, cfg, page_size=serving["page_size"],
+            n_pages=serving["n_pages"], max_slots=serving["max_slots"],
+            prefill_chunk_pages=serving["prefill_chunk_pages"])
+    finally:
+        engine_mod.make_pool, engine_mod.make_slot_state = real
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tables = engine.tables
+    lanes = _lane_args(arg, tables, serving["max_slots"],
+                       serving["n_pages"])
+    state = () if engine.slot_state is None \
+        else (abstract(engine.slot_state),)
+    kw = {}
+    if program == "decode":
+        fn, args = engine._decode_fn, (
+            *lanes.values(), arg((2,), jnp.uint32), *state)
+    else:
+        fn, args = engine._chunk_fn, (
+            arg((1, engine.chunk_tokens)), arg(()), arg(()),
+            arg((tables.max_pages_per_slot,)), arg((2,), jnp.uint32),
+            *state, *((arg(()),) if state else ()))
+        if program == "mixed":
+            kw = {"lanes": lanes}
+    return jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, abstract(engine.pool["k"]), abstract(engine.pool["v"]),
+        *args, **kw)
+
+
+@pytest.mark.parametrize("cell,program", sorted(ACCEPTED_SERVE_TEXT))
+def test_accepted_serve_cells_lower_to_the_text_pr31_left(
+        one_chip, cell, program, monkeypatch):
+    """The decode, chunk and mixed programs of the two serve cells the
+    benchmark had before PR 32 (GPT-2 XL and LFM2, each at its cell's
+    own geometry and full depth) lower to the StableHLO text they
+    lowered to at PR 31's tree: a third model family in
+    ``serving/engine.py`` and ``models/moe.py`` left them as they
+    were. A PR that MEANS to change one of these programs replaces its
+    hash here and says so in CHANGES.md; one that does not and fails
+    here has moved a cell it did not measure."""
+    import hashlib
+
+    import torchbooster_tpu.models.moe as moe_mod
+
+    # the process is pinned to the CPU; the program lowered is the
+    # chip's (the pallas grouped product)
+    monkeypatch.setattr(moe_mod, "_on_tpu", lambda: True)
+    # a Mosaic kernel's serialized body carries its call sites' files
+    # and line numbers: with no frames kept, a line added above a call
+    # does not change the text
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        text = _accepted_cell_lowered(one_chip, cell, program).as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == ACCEPTED_SERVE_TEXT[cell, program]
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "mixed"])
+def test_sarvam_mla_serve_programs_fit_and_stay_in_place_on_v5e(
+        one_chip, program, monkeypatch):
+    """The programs of the ``sarvam-105b.serve-longdoc-r80`` cell
+    (decode, the chunk alone, and the chunk with the decode lanes
+    riding) at its own size (6 layers at published widths, 32 of 128
+    experts a layer, 4096 pages of 64, 32 slots, chunks of 256 over an
+    8,960-position table), compiled for the described v5e with the
+    pool donated: the pool is ONE array of 640-lane rows with no V
+    half and comes back aliased, no instruction writes a second buffer
+    the size of a layer's pool, the experts run as the pallas grouped
+    product with no copy of an expert stack, both attentions run as
+    the latent paged kernel (Mosaic compiles it; a chunk's float32
+    scores over its table never reach HBM: the scratch stays far under
+    them), and weights, pool and scratch together fit the chip."""
+    import json
+    import math
+    import sys
+    from pathlib import Path
+
+    import torchbooster_tpu.models.moe as moe_mod
+    import torchbooster_tpu.serving.engine as engine_mod
+    from torchbooster_tpu.models.mla_moe import MLAMoE
+
+    monkeypatch.setattr(moe_mod, "_on_tpu", lambda: True)
+    bench = Path(__file__).resolve().parent.parent / "benchmark"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    import program_sarvam_mla
+
+    raw = json.loads((bench / "configs" / "sarvam-105b.json").read_text())
+    traffic = json.loads(
+        (bench / "traffic" / "serve-longdoc-r80.json").read_text())
+    serving = traffic["serving"]
+    cfg = program_sarvam_mla.model_config(raw, traffic["max_positions"])
+    slots, pages, page = (serving["max_slots"], serving["n_pages"],
+                          serving["page_size"])
+    abstract = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip), tree)
+    params = abstract(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16)
+        if x.dtype == jnp.float32 and x.ndim > 1 else x,
+        MLAMoE.init(jax.random.PRNGKey(0), cfg, jnp.bfloat16))))
+    real = engine_mod.make_pool
+    engine_mod.make_pool = lambda *a, **kw: jax.eval_shape(
+        lambda: real(*a, **kw))
+    try:
+        engine = engine_mod.PagedEngine(
+            params, cfg, page_size=page, n_pages=pages, max_slots=slots,
+            prefill_chunk_pages=serving["prefill_chunk_pages"])
+    finally:
+        engine_mod.make_pool = real
+    assert engine.pool["v"] is None and engine.slot_state is None
+    assert engine.chunk_tokens == 256
+    pool_k = abstract(engine.pool["k"])
+    assert pool_k.shape == (6, pages, page, 640)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tables = engine.tables
+    lanes = _lane_args(arg, tables, slots, pages)
+    kw = {}
+    if program == "decode":
+        fn, args = engine._decode_fn, (
+            *lanes.values(), arg((2,), jnp.uint32))
+    else:
+        fn, args = engine._chunk_fn, (
+            arg((1, engine.chunk_tokens)), arg(()), arg(()),
+            arg((tables.max_pages_per_slot,)), arg((2,), jnp.uint32))
+        if program == "mixed":
+            kw = {"lanes": lanes}
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, pool_k, None, *args, **kw).compile()
+
+    layer_elems = pages * page * 640
+    moved = _pool_sized_writes(compiled, layer_elems, pool_k, cfg.vocab)
+    assert not moved, f"pool-sized buffers written: {moved}"
+    nbytes = lambda tree: sum(math.prod(x.shape) * x.dtype.itemsize
+                              for x in jax.tree.leaves(tree))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= nbytes(pool_k)
+    assert "ragged-dot" not in compiled.as_text()
+    # no copy as large as ONE layer's stack of one expert matrix
+    stack = cfg.experts_held[1] * cfg.d_model * cfg.expert_width
+    copies = [(name, dims) for name, op, results
+              in _top_level_results(compiled.as_text()) if op == "copy"
+              for _, dims, _ in results
+              if math.prod(int(d) for d in dims.split(",") if d) >= stack]
+    assert not copies, f"expert-stack-sized copies: {copies}"
+    # a chunk's float32 scores of all heads over the table would be
+    # chunk x heads x table x 4 B (587 MB), the decode lanes' per-page
+    # partials 671 MB: the kernel keeps both on the chip
+    scores = engine.chunk_tokens * cfg.n_heads \
+        * tables.max_pages_per_slot * page * 4
+    assert memory.temp_size_in_bytes < scores // 2, \
+        memory.temp_size_in_bytes
+    assert "tpu_custom_call" in compiled.as_text()
+    total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    print(f"sarvam-mla {program}: args "
+          f"{memory.argument_size_in_bytes / 1e9:.3f} GB, temp "
+          f"{memory.temp_size_in_bytes / 1e9:.3f} GB, total "
+          f"{total / 1e9:.3f} GB")
+    assert nbytes(params) > 10.9e9 and total < V5E_HBM_BYTES, total

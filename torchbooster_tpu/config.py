@@ -1178,9 +1178,11 @@ class ServingConfig(BaseConfig):
         """Build the engine + batcher for ``params``/``model_cfg`` (a
         :class:`~torchbooster_tpu.models.gpt.GPTConfig`, or another
         served model's config such as
-        :class:`~torchbooster_tpu.models.lfm2.LFM2Config`: the engine
-        is chosen by its type, and the features a model lacks raise
-        ``NotImplementedError`` naming the feature). Returns the
+        :class:`~torchbooster_tpu.models.lfm2.LFM2Config` or
+        :class:`~torchbooster_tpu.models.mla_moe.MLAMoEConfig`: the
+        engine is chosen by its type, and the features a model lacks
+        raise ``NotImplementedError`` naming the feature and the
+        reason). Returns the
         :class:`~torchbooster_tpu.serving.ContinuousBatcher` — with
         the ``frontend:`` block's scheduler policy installed (the
         default is FCFS, byte-for-byte the policy-less batcher); its
@@ -1205,6 +1207,20 @@ class ServingConfig(BaseConfig):
         from torchbooster_tpu.utils import enable_compile_cache
 
         enable_compile_cache()
+        from torchbooster_tpu.models.gpt import GPTConfig
+
+        if not isinstance(model_cfg, GPTConfig):
+            # a model with its own layer stack (models/lfm2.py,
+            # models/mla_moe.py): the engine is chosen by the config's
+            # type and refuses the features it lacks, each with the
+            # model's own reason; these three never reach it
+            from torchbooster_tpu.serving.engine import refuse_unserved
+
+            refuse_unserved(model_cfg, {
+                "weights (int8/int4)":
+                    self.weights.dtype not in ("", "bf16"),
+                "disagg (prefill_only)": self.disagg.enabled,
+                "tp": self.tp > 1})
         # YAML-time rejection: a tp that does not divide the model's
         # KV-head count, exceeds/mismatches the mesh's tp axis, or
         # arrives without a committed mesh must fail HERE, with the
@@ -1214,20 +1230,6 @@ class ServingConfig(BaseConfig):
         # (and therefore before the engine's tp-major permute — the
         # permute moves qkernel/qscale columns like any other layout
         # fact); every replica shares the quantized tree
-        from torchbooster_tpu.models.gpt import GPTConfig
-
-        if not isinstance(model_cfg, GPTConfig):
-            # a model with its own layer stack (models/lfm2.py): the
-            # engine is chosen by the config's type and refuses the
-            # features it lacks; these two never reach it
-            if self.weights.dtype not in ("", "bf16"):
-                raise NotImplementedError(
-                    "serving feature 'weights (int8/int4)' is not "
-                    f"implemented for a {type(model_cfg).__name__}")
-            if self.disagg.enabled:
-                raise NotImplementedError(
-                    "serving feature 'disagg' is not implemented for "
-                    f"a {type(model_cfg).__name__}")
         params = self.weights.quantize(params)
         n_replicas = self.router.n_replicas
         if n_replicas < 1:

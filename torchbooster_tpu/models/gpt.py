@@ -410,15 +410,18 @@ def _qkv_tp_marker(params: dict) -> int | None:
 
 
 def _rope(x: jax.Array, positions: jax.Array,
-          base: float = 10_000.0) -> jax.Array:
+          base: float = 10_000.0, freqs=None) -> jax.Array:
     """Rotary position embedding (rotate-half form) over (B, S, H, D);
     ``positions`` is (S,) absolute indices shared across the batch, or
     (B, S) per-example indices (continuous batching: every serving
     slot decodes at its OWN depth, so one shared index would rotate
     most slots wrong). Angles in fp32 — bf16 position·frequency
-    products alias at long context."""
+    products alias at long context. ``freqs (D / 2,)``: the
+    frequencies themselves where they are not ``base``'s (a scaled
+    rope: models/mla_moe.py's YaRN)."""
     half = x.shape[-1] // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angles = positions.astype(jnp.float32)[..., None] * freqs
     if positions.ndim == 1:        # (S, half): broadcast over batch
         cos = jnp.cos(angles)[None, :, None, :]

@@ -53,6 +53,32 @@ from torchbooster_tpu.models.moe import moe_dropless
 from torchbooster_tpu.ops.attention import mha_reference
 
 CONV, ATTENTION = "conv", "full_attention"
+# What the paged engine does not serve for this family, and why
+# (serving/engine.py raises these at build): with slot state, pages
+# are no longer all of a sequence; the rest is GPT-shaped code.
+_STATE = "the conv layers' slot-indexed state: "
+UNSERVED = {
+    "prefix_cache": _STATE + "a prefix hit would skip the chunks that "
+                             "build it",
+    "speculative": _STATE + "a rewind would need the state of an "
+                            "earlier position",
+    "parallel_sampling (fork)": _STATE + "a fork would need a copy of "
+                                         "the parent's",
+    "host_spill": _STATE + "a spilled page carries none of it",
+    "disagg (prefill_only)": _STATE + "an exported page carries none "
+                                      "of it",
+    "tp": "the tp layout of attn_qkv and of the pool's rows is "
+          "GPTConfig's",
+    "cache_dtype: int8": "the int8 rows and their per-head scales are "
+                         "wired for GPTConfig's attention core",
+    "decode_backend: pallas": "the paged-attention kernel's head "
+                              "split is GPTConfig's",
+    "structured": "the programs of a model with its own layer stack "
+                  "carry no legality-mask operand",
+    "adapters (lora)": "the adapter stacks are laid out for GPTConfig's "
+                       "fused attn_qkv",
+    "weights (int8/int4)": "the quantizer walks GPTConfig's block tree",
+}
 # the published model's layer pattern (24 layers: 18 conv, 6 attention)
 LAYER_TYPES = (
     CONV, CONV, ATTENTION, CONV, CONV, CONV, ATTENTION, CONV, CONV, CONV,
@@ -212,7 +238,7 @@ def _layer(lp: dict, x: jax.Array, cfg: LFM2Config, kind: str, *,
             # rounding to the compute dtype
             u32 = L.rms_norm_f32(lp["ffn_norm"]["scale"],
                                  x.astype(jnp.float32), cfg.norm_eps)
-            m, counts = moe_dropless(
+            m, counts, _ = moe_dropless(
                 lp, u32.astype(x.dtype), cfg.top_k, cfg.routed_scaling,
                 valid=valid, first_group=first_group, route_on=u32)
         else:
@@ -340,4 +366,4 @@ class LFM2:
 
 
 __all__ = ["ATTENTION", "CONV", "LAYER_TYPES", "LFM2", "LFM2Config",
-           "embed", "head", "layers"]
+           "UNSERVED", "embed", "head", "layers"]

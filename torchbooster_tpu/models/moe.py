@@ -289,11 +289,13 @@ def moe_dropless(params: dict, x: jax.Array, top_k: int,
                  scaling: float = 1.0, valid: jax.Array | None = None,
                  first_group: jax.Array | int = 0,
                  route_on: jax.Array | None = None,
-                 ep: tuple | None = None, tp: tuple | None = None
-                 ) -> tuple[jax.Array, jax.Array]:
-    """(B, S, d) -> ((B, S, d), tokens per expert (E,) int32): the
-    DROPLESS expert layer — every selected (token, expert) pair is
-    computed whatever the imbalance, and nothing else is. The ``T*k``
+                 held: tuple[int, int] | None = None,
+                 tp: tuple | None = None
+                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """(B, S, d) -> ((B, S, d), tokens per expert (E,) int32, pairs
+    elsewhere () int32): the DROPLESS expert layer — every selected
+    (token, expert) pair is computed whatever the imbalance, and
+    nothing else is. The ``T*k``
     pairs are sorted by expert and each projection of the bias-free
     SwiGLU experts (``moe_fc1`` / ``moe_fc3`` ``(E, d, h)``,
     ``moe_fc2`` ``(E, h, d)``) is ONE grouped matrix product over the
@@ -323,26 +325,49 @@ def moe_dropless(params: dict, x: jax.Array, top_k: int,
     count is a program output the serving engine reads
     (``serving_moe_*``, docs/observability.md).
 
-    ``ep`` / ``tp`` sharding of this path does not exist yet
-    (ROADMAP M1) and raises."""
-    if ep is not None or tp is not None:
+    ``held = (first, n)``: this device's SHARE of the experts — one
+    expert-parallel rank's. The router keeps its width (``moe_gate``
+    scores all ``E``), the top-k and its renormalisation run over all
+    of them wherever they live, and the kernels hold only experts
+    ``[first, first + n)``: a pair whose expert is not held is sorted
+    behind every group exactly as a dead token's is, takes no expert's
+    time and adds nothing to the output — the part of the layer's
+    result that THIS rank's experts give. The counts are then of the
+    experts held ``(n,)``, and the third output counts the live pairs
+    that went elsewhere (0 without ``held``). On one device the layer
+    runs without its exchange; summing the ranks' outputs (the
+    combine of expert parallelism) is the caller's, and nothing here
+    stands in for an absent rank.
+
+    ``tp`` sharding of this path does not exist yet (ROADMAP M1) and
+    raises."""
+    if tp is not None:
         raise NotImplementedError(
-            "moe_dropless: ep / tp sharding of the dropless expert "
-            "layer is not implemented")
+            "moe_dropless: tp sharding of the dropless expert layer "
+            "is not implemented")
     b, s, d = x.shape
     tokens = x.reshape(b * s, d)
-    n_experts = params["moe_gate"]["kernel"].shape[-1]
     n_groups = params["moe_fc1"]["kernel"].shape[0]
+    # experts a pair can land on HERE: the router's width, or the share
+    n_experts = params["moe_gate"]["kernel"].shape[-1] if held is None \
+        else held[1]
     with jax.named_scope("moe_route"):
         sel, w = moe_route(
             params, tokens if route_on is None
             else route_on.reshape(b * s, d), top_k, scaling)
     with jax.named_scope("moe_experts"):
         pair_expert = sel.reshape(-1)                    # (T*k,)
-        if valid is not None:
-            pair_expert = jnp.where(
-                jnp.repeat(valid.reshape(-1), top_k), pair_expert,
-                n_experts)
+        live = None if valid is None \
+            else jnp.repeat(valid.reshape(-1), top_k)
+        elsewhere = jnp.zeros((), jnp.int32)
+        if held is not None:
+            local = pair_expert - held[0]
+            away = (local < 0) | (local >= n_experts)
+            pair_expert = jnp.where(away, n_experts, local)
+            elsewhere = jnp.sum(away if live is None else away & live,
+                                dtype=jnp.int32)
+        if live is not None:
+            pair_expert = jnp.where(live, pair_expert, n_experts)
         order = jnp.argsort(pair_expert, stable=True)
         counts = jnp.bincount(pair_expert, length=n_experts + 1
                               )[:n_experts].astype(jnp.int32)
@@ -361,7 +386,7 @@ def moe_dropless(params: dict, x: jax.Array, top_k: int,
         # back to (token, choice) order, weighted sum over the choices
         y = y[jnp.argsort(order)].reshape(b * s, top_k, d)
         out = jnp.sum(y.astype(jnp.float32) * w[..., None], axis=1)
-    return out.astype(x.dtype).reshape(b, s, d), counts
+    return out.astype(x.dtype).reshape(b, s, d), counts, elsewhere
 
 
 __all__ = ["SHARDING_RULES", "grouped_matmul", "moe_apply",

@@ -73,6 +73,7 @@ size).
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -81,6 +82,7 @@ import numpy as np
 
 from torchbooster_tpu.models import layers as L
 from torchbooster_tpu.models import lfm2 as _lfm2
+from torchbooster_tpu.models import mla_moe as _mla_moe
 from torchbooster_tpu.observability import get_registry, span
 from torchbooster_tpu.models.quant import (
     weight_stream_bytes as _weight_stream_bytes,
@@ -98,6 +100,9 @@ from torchbooster_tpu.models.gpt import (
     _mask_logits,
     _quantize_kv,
     qkv_to_tp_major,
+)
+from torchbooster_tpu.ops.latent_paged_attention import (
+    latent_paged_attention,
 )
 from torchbooster_tpu.ops.paged_attention import paged_attention
 from torchbooster_tpu.serving.adapters import AdapterRegistry
@@ -153,21 +158,42 @@ def _quantize_page_np(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return q, scale
 
 
+# query tokens of a prefill chunk one group of the latent paged kernel
+# attends (x n_heads rows in VMEM: 8 x 64 heads = 512 rows)
+LATENT_CHUNK_TOKENS = 8
+
+
 def _served_model(cfg: Any):
     """The module whose ``embed`` / ``layers`` / ``head`` the chunk
     and decode programs call for this model config — chosen by the
     config's TYPE, never by a knob. None for a ``GPTConfig``: its
     programs run ``models/gpt.py``'s ``_block_core`` over stacked
     blocks (``kv_pages.scan_layers``), as they always have. A further
-    architecture is a module with those three functions and a
-    ``cache_spec()`` on its config (models/lfm2.py), and a line
-    here."""
+    architecture is a module with those three functions, a
+    ``cache_spec()`` on its config and ``UNSERVED`` — the serving
+    features it is refused, each with its reason — (models/lfm2.py,
+    models/mla_moe.py), and a line here."""
     if isinstance(cfg, _lfm2.LFM2Config):
         return _lfm2
+    if isinstance(cfg, _mla_moe.MLAMoEConfig):
+        return _mla_moe
     if isinstance(cfg, GPTConfig):
         return None
     raise TypeError(
         f"PagedEngine: no served model for a {type(cfg).__name__}")
+
+
+def refuse_unserved(cfg: Any, asked: dict[str, bool]) -> None:
+    """Raise ``NotImplementedError`` for the first serving feature in
+    ``asked`` ({feature: was it asked for}) that a model with its own
+    layer stack does not serve, naming the feature and the reason its
+    module gives (``UNSERVED``)."""
+    why = _served_model(cfg).UNSERVED
+    for feature, on in asked.items():
+        if on:
+            raise NotImplementedError(
+                f"serving feature {feature!r} is not implemented for "
+                f"a {type(cfg).__name__}: {why[feature]}")
 
 
 class _Pieces(NamedTuple):
@@ -179,6 +205,7 @@ class _Pieces(NamedTuple):
     x: jax.Array            # (B, S, d) embedded tokens
     positions: jax.Array    # (B, S) absolute positions (rope)
     valid: jax.Array        # (B, S) real tokens (expert routing)
+    # a latent pool: k is the token's one row, v and pool_v are None
     write: Callable         # (k, v, pool_k, pool_v, li) -> pools
     read: Callable          # (q, k, v, pool_k, pool_v, li) -> o
     conv: Callable | None   # (z, w, state, li) -> (c, state)
@@ -201,7 +228,8 @@ def _ride(chunk: _Pieces, lanes: _Pieces) -> _Pieces:
     # lanes' (slots, 1, ...) as (1, slots, ...), and back
     turn = lambda t: jnp.moveaxis(t, 0, 1)
     join = lambda c, l: jnp.concatenate([c, turn(l)], axis=1)
-    split = lambda t: (t[:, :C], turn(t[:, C:]))
+    split = lambda t: (None, None) if t is None \
+        else (t[:, :C], turn(t[:, C:]))
 
     def write(k, v, pk, pv, li):
         (kc, kl), (vc, vl) = split(k), split(v)
@@ -435,14 +463,11 @@ class PagedEngine:
                 "on tp=1 replicas (the fleet path)")
         self.model = _served_model(cfg)
         if self.model is not None:
-            # what is not done for a model with its own layer stack.
-            # With slot state, pages are no longer all of a sequence:
-            # a prefix hit would skip the chunks that build the state,
-            # a rewind or a fork would need the state of an earlier
-            # position, a spilled or exported page carries none of it.
-            # The rest is GPT-shaped code (adapter and tp layouts of
-            # attn_qkv, the kernel's head split, int8 rows)
-            refused = {
+            # what is not done for a model with its own layer stack:
+            # the model's module says why, feature by feature
+            # (``UNSERVED``: slot state that pages do not carry, a
+            # latent pool with no V half, GPT-shaped layouts)
+            refuse_unserved(cfg, {
                 "host_spill": host_spill,
                 "prefix_cache": prefix_cache,
                 "speculative": speculative,
@@ -453,14 +478,7 @@ class PagedEngine:
                 "decode_backend: pallas": decode_backend != "xla",
                 "structured": structured,
                 "adapters (lora)": lora_rank > 0 or lora_max_live > 0,
-            }
-            for feature, asked in refused.items():
-                if asked:
-                    raise NotImplementedError(
-                        f"serving feature {feature!r} is not "
-                        f"implemented for a {type(cfg).__name__} "
-                        "(a model with its own layer stack and "
-                        "slot-indexed state)")
+            })
         else:
             # same params/config positional-encoding guard the dense
             # generate() applies — a rope checkpoint served with
@@ -508,12 +526,17 @@ class PagedEngine:
                               shards=self.tp)
         # slot-indexed state (a conv mixer's last inputs), None for a
         # model without: donated and updated in place like the pool
-        self.slot_state = make_slot_state(cache_spec(cfg), max_slots,
-                                          compute_dtype)
+        spec = cache_spec(cfg)
+        self.slot_state = make_slot_state(spec, max_slots, compute_dtype)
+        # a latent pool (CacheSpec.value_dim): ONE array of rows whose
+        # leading lanes are the values; pool["v"] is None and rides
+        # through every program as an empty pytree
+        self.latent_dim: int | None = spec.value_dim
         # the last decode step's tokens per expert (n_moe_layers,
         # n_experts) of a model that routes, and the registry series
         # fed from it (made at the first step with the registry on)
         self.moe_counts: np.ndarray | None = None
+        self.moe_elsewhere: np.ndarray | None = None
         self._moe_inst: dict | None = None
         # the host spill tier (PR 16): LRU eviction demotes registered
         # prefix pages to a host-DRAM pool (int8 + scales) and a later
@@ -1044,8 +1067,36 @@ class PagedEngine:
             o = o.reshape(1, C, n_heads_l, head_dim)
             return o.astype(q.dtype)
 
+        if self.latent_dim is not None:
+            # a latent pool: ONE row a token written, then the chunk's
+            # tokens attend in the absorbed form through the paged
+            # kernel, in blocks of LATENT_CHUNK_TOKENS on the seating
+            # slot's table — its own rows are in it (read after the
+            # write), so one rule (a key's position <= the query's)
+            # covers prior context and the chunk alike. A block's walk
+            # stops at its own last position: a chunk costs what its
+            # prompt so far costs, not what the table could hold
+            per_block = math.gcd(C, LATENT_CHUNK_TOKENS)
+            n_blocks = C // per_block
+
+            def write(k, v, pk, pv, li):
+                with jax.named_scope("kv_write"):
+                    pk = write_rows(pk, (li, w_pages), to_rows(
+                        k[0].reshape(n_cp, ps, 1, -1), pk.shape[-1]))
+                return pk, pv
+
+            def read(q, k, v, pk, pv, li):
+                with jax.named_scope("mla_chunk"):
+                    o = latent_paged_attention(
+                        q[0].reshape(n_blocks, -1, q.shape[-1]), pk, li,
+                        jnp.broadcast_to(table_row, (n_blocks, mp)),
+                        start + jnp.arange(n_blocks) * per_block,
+                        jnp.ones((n_blocks,), bool),
+                        n_heads=n_heads_l, value_dim=self.latent_dim)
+                return o.reshape(1, C, n_heads_l, -1).astype(q.dtype)
+
         conv = None
-        if self.model is not None:
+        if self.slot_state is not None:
             # the prompt's real tokens in this chunk: the conv state
             # kept is that of the prompt's TRUE end, not of the padded
             # end of a partial last chunk
@@ -1186,6 +1237,25 @@ class PagedEngine:
                           cfg.d_model // cfg.n_heads)
             return o.astype(q.dtype)
 
+        if self.latent_dim is not None:
+            # a latent pool: the slot's one row written, and every
+            # slot's own pages attended in the absorbed form by the
+            # paged kernel, the pool read in place — a row serves 64
+            # query heads, so per-page partials would outweigh the
+            # pages (ops/latent_paged_attention.py)
+            def write(k, v, pk, pv, li):
+                with jax.named_scope("kv_write"):
+                    pk = write_rows(pk, (li, w_page, w_off),
+                                    to_rows(k[:, 0], pk.shape[-1]))
+                return pk, pv
+
+            def read(q, k, v, pk, pv, li):
+                with jax.named_scope("mla_sweep"):
+                    o = latent_paged_attention(
+                        q[:, 0], pk, li, tables, lengths, active,
+                        n_heads=n_heads_l, value_dim=self.latent_dim)
+                return o[:, None].astype(q.dtype)
+
         def conv(z, w, st, li):
             # live slots shift their state by this step's input;
             # dead and mid-prefill slots keep theirs
@@ -1197,7 +1267,7 @@ class PagedEngine:
             return c, {"conv": rows.at[li].set(new)}
 
         return _Pieces(x, lengths[:, None], active[:, None], write,
-                       read, conv if self.model is not None else None,
+                       read, conv if self.slot_state is not None else None,
                        lambda x: x)
 
     def _layers(self, params, pieces: "_Pieces", pool_k, pool_v, state,
@@ -2032,7 +2102,7 @@ class PagedEngine:
                 # ONE batched device->host sync for both results
                 tokens, counts = jax.device_get((tokens, counts))
                 tokens = np.asarray(tokens)
-                self._count_experts(np.asarray(counts))
+                self._count_experts(counts)
             elif self.parallel:
                 tokens, lps, pool_k, pool_v = outs
                 self.pool = {"k": pool_k, "v": pool_v}
@@ -2286,14 +2356,24 @@ class PagedEngine:
                     self._cursors.observe(slot, emitted)
         return out
 
-    def _count_experts(self, counts: np.ndarray) -> None:
+    def _count_experts(self, counts) -> None:
         """File one decode step's tokens per expert ``(n_moe_layers,
         n_experts)``: kept as ``moe_counts`` and, while the registry
         is on, fed to the ``serving_moe_*`` series — experts hit by at
         least one token (summed over the layers: what the step's
         expert weights cost to read) and, per layer, the fullest
-        expert's tokens over the mean."""
-        self.moe_counts = counts
+        expert's tokens over the mean. A model that holds a SHARE of
+        its experts hands ``{"held": (n_moe_layers, n held),
+        "elsewhere": (n_moe_layers,)}``: the series above are then
+        over the experts held, ``moe_elsewhere`` keeps the pairs routed
+        to experts this device does not hold, and
+        ``serving_moe_pairs_total{where=here|elsewhere}`` counts
+        both."""
+        elsewhere = None
+        if isinstance(counts, dict):
+            counts, elsewhere = counts["held"], counts["elsewhere"]
+            self.moe_elsewhere = np.asarray(elsewhere)
+        counts = self.moe_counts = np.asarray(counts)
         if not counts.size:
             return
         reg = get_registry()
@@ -2310,6 +2390,16 @@ class PagedEngine:
                     "per decode step and expert layer: tokens on the "
                     "fullest expert over the mean per expert"),
             }
+            if elsewhere is not None:
+                self._moe_inst["pairs"] = reg.counter(
+                    "serving_moe_pairs_total",
+                    "(token, expert) pairs of the decode steps: "
+                    "computed on the experts held here, or routed to "
+                    "experts another device holds")
+        if elsewhere is not None:
+            pairs = self._moe_inst["pairs"]
+            pairs.inc(int(counts.sum()), where="here")
+            pairs.inc(int(self.moe_elsewhere.sum()), where="elsewhere")
         self._moe_inst["hit"].observe(np.count_nonzero(counts))
         mean = counts.mean(axis=1)
         live = mean > 0

@@ -127,6 +127,11 @@ class CacheSpec:
     kv_heads: int
     head_dim: int
     slot_states: dict = field(default_factory=dict)
+    # a LATENT cache (models/mla_moe.py): one row a token — the key
+    # row's leading ``value_dim`` lanes ARE the values, so no V half is
+    # allocated (``make_pool``'s "v" is None) and the reads go through
+    # ``ops/latent_paged_attention.py``
+    value_dim: int | None = None
 
 
 def cache_spec(cfg: Any) -> CacheSpec:
@@ -170,7 +175,8 @@ def make_pool(cfg: Any, page_size: int, n_pages: int,
     row) — a plain array in ``compute_dtype``, or, when ``cache_dtype``
     is ``"int8"``, the pair ``(int8 rows, bf16 scales (n_layers,
     n_pages, page_size, kv_heads))``. ``shards`` is the engine's ``tp``
-    (:func:`kv_width` pads per shard)."""
+    (:func:`kv_width` pads per shard). A latent spec
+    (``value_dim``) gets ONE array: ``"v"`` is None."""
     if cache_dtype not in (None, "int8", jnp.int8):
         raise ValueError(
             f"cache_dtype must be None or 'int8', got {cache_dtype!r}")
@@ -183,6 +189,11 @@ def make_pool(cfg: Any, page_size: int, n_pages: int,
                       jnp.ones(scale_shape, jnp.bfloat16))
     else:
         mk = lambda: jnp.zeros(shape, compute_dtype)
+    if spec.value_dim is not None:
+        if cache_dtype is not None or shards != 1:
+            raise ValueError("a latent pool is one unsharded array of "
+                             "rows in the compute dtype")
+        return {"k": mk(), "v": None}
     return {"k": mk(), "v": mk()}
 
 
