@@ -1438,21 +1438,15 @@ def test_pool_is_carried_through_the_layer_loop(program, cache_dtype):
                          speculative=True, draft_len=3)
     pool_k, pool_v = engine.pool["k"], engine.pool["v"]
     pool_shapes = {leaf.shape for leaf in jax.tree.leaves(pool_k)}
-    args = engine.tables.device_args()
-    rng = jax.random.PRNGKey(0)
-    if program == "chunk":
-        jaxpr = jax.make_jaxpr(engine._chunk_fn)(
-            params, pool_k, pool_v,
-            jnp.zeros((1, engine.chunk_tokens), jnp.int32),
-            jnp.int32(0), jnp.int32(5), args["tables"][0], rng)
-    else:
-        fn, ids = ((engine._decode_fn, args["last_ids"])
-                   if program == "decode" else
-                   (make_verify_fn(engine),
-                    jnp.zeros((2, 1 + engine.draft_len), jnp.int32)))
-        jaxpr = jax.make_jaxpr(fn)(
-            params, pool_k, pool_v, args["tables"], args["lengths"],
-            args["refs"], args["page_pos"], args["active"], ids, rng)
+    # every program's operands after the pool: the packed buffer
+    # (the chunk's ids and cursors, the tables and the drafts are
+    # slices of it) and the key
+    engine._op["chunk"][:] = (0, 5, 0)
+    fn = {"chunk": engine._chunk_fn, "decode": engine._decode_fn,
+          "verify": make_verify_fn(engine)}[program]
+    jaxpr = jax.make_jaxpr(fn)(
+        params, pool_k, pool_v, jnp.asarray(engine.operands.host),
+        jax.random.PRNGKey(0))
 
     def scans(jaxpr):
         for eqn in jaxpr.eqns:

@@ -242,13 +242,21 @@ def _pool_sized_writes(compiled, layer_elems: int, pool_k, vocab: int):
     return moved
 
 
-def _lane_args(arg, tables, slots, pages) -> dict:
-    """The decode step's operands described, in ``_decode_fn``'s
-    order and under the names ``_chunk_fn(lanes=...)`` takes."""
-    return {"tables": arg(tables.tables.shape), "lengths": arg((slots,)),
-            "refs": arg(tables.refs.shape), "page_pos": arg((pages,)),
-            "active": arg((slots,), jnp.bool_),
-            "last_ids": arg((slots,))}
+def _step_args(arg, engine) -> tuple:
+    """What every serve program takes after the pool, described: the
+    engine's packed operand buffer (the tables, the chunk's ids and
+    cursors: one int32 array, sliced apart inside the program) and
+    the rng key."""
+    return arg(engine.operands.host.shape), arg((2,), jnp.uint32)
+
+
+def _serve_program(engine, program: str):
+    """``(function, keyword arguments)`` of one of an engine's three
+    serve programs: decode, the chunk alone, and the chunk with the
+    decode lanes riding (MIXED: ``lanes`` a dict, here empty)."""
+    if program == "decode":
+        return engine._decode_fn, {}
+    return engine._chunk_fn, ({"lanes": {}} if program == "mixed" else {})
 
 
 @pytest.mark.parametrize("int8_pool", [False, True],
@@ -295,20 +303,9 @@ def test_serve_programs_keep_the_pool_in_place_on_v5e(
     def arg(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    tables = engine.tables
-    lanes = _lane_args(arg, tables, XL_SLOTS, XL_PAGES)
-    kw = {}
-    if program == "decode":
-        fn, args = engine._decode_fn, (
-            *lanes.values(), arg((2,), jnp.uint32))
-    else:
-        fn, args = engine._chunk_fn, (
-            arg((1, engine.chunk_tokens)), arg(()), arg(()),
-            arg((tables.max_pages_per_slot,)), arg((2,), jnp.uint32))
-        if program == "mixed":
-            kw = {"lanes": lanes}
+    fn, kw = _serve_program(engine, program)
     compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-        params, pool_k, pool_v, *args, **kw).compile()
+        params, pool_k, pool_v, *_step_args(arg, engine), **kw).compile()
 
     head_dim = cfg.d_model // cfg.n_heads
     layer_elems = XL_PAGES * XL_PAGE * cfg.kv_heads * head_dim
@@ -401,21 +398,11 @@ def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
     def arg(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    tables = engine.tables
-    lanes = _lane_args(arg, tables, slots, pages)
-    kw = {}
-    if program == "decode":
-        fn, donate, args = engine._decode_fn, (1, 2, 10), (
-            *lanes.values(), arg((2,), jnp.uint32), state)
-    else:
-        fn, donate, args = engine._chunk_fn, (1, 2, 8), (
-            arg((1, engine.chunk_tokens)), arg(()), arg(()),
-            arg((tables.max_pages_per_slot,)), arg((2,), jnp.uint32),
-            state, arg(()))
-        if program == "mixed":
-            kw = {"lanes": lanes}
-    compiled = jax.jit(fn, donate_argnums=donate).lower(
-        params, pool_k, pool_v, *args, **kw).compile()
+    # the slot state rides right behind the buffer and the key
+    fn, kw = _serve_program(engine, program)
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 5)).lower(
+        params, pool_k, pool_v, *_step_args(arg, engine), state,
+        **kw).compile()
 
     layer_elems = pages * page * 512
     moved = _pool_sized_writes(compiled, layer_elems, pool_k, cfg.vocab)
@@ -449,20 +436,28 @@ def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
 
 # sha256 of the StableHLO text each accepted serve cell's programs
 # lower to for the described v5e (Mosaic kernels' bodies included, call
-# sites' line numbers left out), taken at PR 31's tree (c95a217)
+# sites' line numbers left out), taken at PR 33's tree: the operand
+# list (params, pool, ONE packed buffer, the key) and the in-program
+# rng split are what PR 33 changed of PR 31's text
 ACCEPTED_SERVE_TEXT = {
-    ("gpt2-xl.serve-chat-r80", "decode"): 
-        "b88fdf7218f7919fabca3da537193df5c98b1c5679da0f3355c2119cf5fc6ff4",
-    ("gpt2-xl.serve-chat-r80", "chunk"): 
-        "f7f5c189c05dbede89816005a29e3cceb9d565a57adbd50154af71202aaaaca4",
-    ("gpt2-xl.serve-chat-r80", "mixed"): 
-        "1e29c9cb275698ce4f21c2b1e1b6141b0e57866448780708043847f5da279001",
-    ("lfm2-8b-a1b.serve-rag-r80", "decode"): 
-        "da02a725e86ba5a4599e2f5a7a21f205406c5d8d6a0981722b932b401af16435",
-    ("lfm2-8b-a1b.serve-rag-r80", "chunk"): 
-        "cabf5d4c2b628d42b61e0958399c320546c5b8cb1eb411a50c2cf54d46765ac6",
-    ("lfm2-8b-a1b.serve-rag-r80", "mixed"): 
-        "845314329d18a4d9023c428cbdda7b7f930b59b7f567c4d4751c24f0919f67c7",
+    ("gpt2-xl.serve-chat-r80", "decode"):
+        "0843e6f3799b6e7e3f78b2759b7cbf193d5d7b907d0e7c0a5b8cdd2b0cc6df81",
+    ("gpt2-xl.serve-chat-r80", "chunk"):
+        "05c426615d8c6b937ff32cec6df1db2687d2aec3557389dcdfa1010771264bc9",
+    ("gpt2-xl.serve-chat-r80", "mixed"):
+        "38b868ab3a7ad64cca16b892844f5114b056fe7b0e68eca511d7a7ac31030086",
+    ("lfm2-8b-a1b.serve-rag-r80", "decode"):
+        "b4d5573719b7781337bea43cc91c10719097cbd21a966ea1f3aac0cf310bdcc8",
+    ("lfm2-8b-a1b.serve-rag-r80", "chunk"):
+        "1e4814c87644f4630fa7f4b37b7c654b274315bfe7291c4c022064a961c79dd9",
+    ("lfm2-8b-a1b.serve-rag-r80", "mixed"):
+        "eca722a39f10f34a9d82dc4a2e6f029d4db2f3807c691e697e5057b9a84f9281",
+    ("sarvam-105b.serve-longdoc-r80", "decode"):
+        "0631a488036c67a28edfc450eadeb1f69c871b5cbd49a1697bb6a037262117fd",
+    ("sarvam-105b.serve-longdoc-r80", "chunk"):
+        "091831f3a3a59a773f31d715f629251b75362a3a3c69cb64e55cd4adbcd110b3",
+    ("sarvam-105b.serve-longdoc-r80", "mixed"):
+        "146624de818f35120c57ad8014547a82778eda5ea9a498c939142dfd68603a83",
 }
 
 
@@ -488,11 +483,22 @@ def _accepted_cell_lowered(one_chip, cell: str, program: str):
     traffic = json.loads(
         (bench / "traffic" / f"{entry['traffic']}.json").read_text())
     serving = traffic["serving"]
+    init = lambda: jax.tree.map(lambda x: x.astype(jnp.bfloat16),
+                                model.init(jax.random.PRNGKey(0), cfg))
     if traffic["job"] == "serve_lfm2":
         import program_lfm2
         from torchbooster_tpu.models.lfm2 import LFM2 as model
 
         cfg = program_lfm2.model_config(raw, traffic["max_positions"])
+    elif traffic["job"] == "serve_sarvam_mla":
+        import program_sarvam_mla
+        from torchbooster_tpu.models.mla_moe import MLAMoE as model
+
+        cfg = program_sarvam_mla.model_config(
+            raw, traffic["max_positions"])
+        # made in bfloat16; the router's float32 bias stays as served
+        init = lambda: model.init(jax.random.PRNGKey(0), cfg,
+                                  jnp.bfloat16)
     else:
         import program as program_gpt
         from torchbooster_tpu.models.gpt import GPT as model
@@ -501,9 +507,7 @@ def _accepted_cell_lowered(one_chip, cell: str, program: str):
     abstract = lambda tree: jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                        sharding=one_chip), tree)
-    params = abstract(jax.eval_shape(lambda: jax.tree.map(
-        lambda x: x.astype(jnp.bfloat16),
-        model.init(jax.random.PRNGKey(0), cfg))))
+    params = abstract(jax.eval_shape(init))
     real = engine_mod.make_pool, engine_mod.make_slot_state
     described = lambda make: lambda *a, **kw: jax.eval_shape(
         lambda: make(*a, **kw))
@@ -520,38 +524,25 @@ def _accepted_cell_lowered(one_chip, cell: str, program: str):
     def arg(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    tables = engine.tables
-    lanes = _lane_args(arg, tables, serving["max_slots"],
-                       serving["n_pages"])
     state = () if engine.slot_state is None \
         else (abstract(engine.slot_state),)
-    kw = {}
-    if program == "decode":
-        fn, args = engine._decode_fn, (
-            *lanes.values(), arg((2,), jnp.uint32), *state)
-    else:
-        fn, args = engine._chunk_fn, (
-            arg((1, engine.chunk_tokens)), arg(()), arg(()),
-            arg((tables.max_pages_per_slot,)), arg((2,), jnp.uint32),
-            *state, *((arg(()),) if state else ()))
-        if program == "mixed":
-            kw = {"lanes": lanes}
+    fn, kw = _serve_program(engine, program)
     return jax.jit(fn, donate_argnums=(1, 2)).lower(
         params, abstract(engine.pool["k"]), abstract(engine.pool["v"]),
-        *args, **kw)
+        *_step_args(arg, engine), *state, **kw)
 
 
 @pytest.mark.parametrize("cell,program", sorted(ACCEPTED_SERVE_TEXT))
-def test_accepted_serve_cells_lower_to_the_text_pr31_left(
+def test_accepted_serve_cells_lower_to_the_text_pr33_left(
         one_chip, cell, program, monkeypatch):
-    """The decode, chunk and mixed programs of the two serve cells the
-    benchmark had before PR 32 (GPT-2 XL and LFM2, each at its cell's
-    own geometry and full depth) lower to the StableHLO text they
-    lowered to at PR 31's tree: a third model family in
-    ``serving/engine.py`` and ``models/moe.py`` left them as they
-    were. A PR that MEANS to change one of these programs replaces its
-    hash here and says so in CHANGES.md; one that does not and fails
-    here has moved a cell it did not measure."""
+    """The decode, chunk and mixed programs of the three serve cells
+    (GPT-2 XL, LFM2 and the latent-attention family, each at its
+    cell's own geometry and full depth) lower to the StableHLO text
+    they lowered to at PR 33's tree, which gave every program the
+    packed operand buffer and the device-carried key. A PR that MEANS
+    to change one of these programs replaces its hash here and says so
+    in CHANGES.md; one that does not and fails here has moved a cell
+    it did not measure."""
     import hashlib
 
     import torchbooster_tpu.models.moe as moe_mod
@@ -634,19 +625,9 @@ def test_sarvam_mla_serve_programs_fit_and_stay_in_place_on_v5e(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     tables = engine.tables
-    lanes = _lane_args(arg, tables, slots, pages)
-    kw = {}
-    if program == "decode":
-        fn, args = engine._decode_fn, (
-            *lanes.values(), arg((2,), jnp.uint32))
-    else:
-        fn, args = engine._chunk_fn, (
-            arg((1, engine.chunk_tokens)), arg(()), arg(()),
-            arg((tables.max_pages_per_slot,)), arg((2,), jnp.uint32))
-        if program == "mixed":
-            kw = {"lanes": lanes}
+    fn, kw = _serve_program(engine, program)
     compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-        params, pool_k, None, *args, **kw).compile()
+        params, pool_k, None, *_step_args(arg, engine), **kw).compile()
 
     layer_elems = pages * page * 640
     moved = _pool_sized_writes(compiled, layer_elems, pool_k, cfg.vocab)

@@ -110,6 +110,7 @@ from torchbooster_tpu.serving.kv_pages import (
     NULL_PAGE,
     BlockTables,
     HostPagePool,
+    OperandBuffer,
     cache_spec,
     from_rows,
     gather_pages,
@@ -269,9 +270,10 @@ class PagedEngine:
     (``self.mixes``), from the engine's own mode: the defaults ride,
     and so do ``cache_dtype="int8"``, ``decode_backend="pallas"``,
     ``structured``, ``prefix_cache`` / ``host_spill`` and a model with
-    its own layer stack and slot state (their operands are the two
-    programs' trailing VALUE operands, and the chunk's and the lanes'
-    bodies are the ones those modes already run). ``speculative`` (its
+    its own layer stack and slot state (their operands are slices of
+    the one operand buffer or the two programs' trailing VALUE
+    operands, and the chunk's and the lanes' bodies are the ones
+    those modes already run). ``speculative`` (its
     verify step is another program), ``parallel_sampling`` (the chunk
     returns the fork's logits and picks by branch key), ``adapters``
     (lora lane ids are per batch row, and the mixed program has one),
@@ -360,6 +362,19 @@ class PagedEngine:
     zero-recompile contract holds per executable; the default
     ``tp=1`` builds no shard_map wrapper at all — same compiled
     artifacts, same call signatures.
+
+    **A step's operands** are the params, the pool, ONE packed int32
+    buffer and the rng key (``OperandBuffer``, kv_pages.py): every
+    small integer the host knows — the tables, the chunk's ids and
+    cursors, the modes' lane ids, work lists, branch keys and drafts —
+    lives in one preallocated host buffer whose layout is fixed at
+    build from the geometry and the modes, crosses to the device as
+    one transfer an iteration (``operand_puts`` /
+    ``serving_operand_puts_total`` count them) and is sliced apart
+    inside the program. The key never leaves the device: each program
+    splits it and hands the new key back with its results. Only a
+    mode's LARGE operand (the structured legality mask, the tree's
+    visibility matrix) rides beside the buffer, as one more transfer.
 
     **Other models than GPT.** The engine is chosen by the TYPE of
     ``cfg`` (:func:`_served_model`): a model that brings its own layer
@@ -619,11 +634,10 @@ class PagedEngine:
         self.fork_pages = 0      # pages SHARED into children at fork
         self.cow_copies = 0      # private tail pages copied at fork
         # per-slot branch PRNG state (parallel sampling only): the
-        # request's BASE key, the slot's folded branch key, and its
-        # branch index — host numpy, rebuilt at admit/fork, one
-        # fixed-shape operand per decode step
+        # request's BASE key, the slot's folded branch key (made with
+        # the operand buffer below: it rides there), and its branch
+        # index — host numpy, rebuilt at admit/fork
         self._base_keys = np.zeros((max_slots, 2), np.uint32)
-        self._slot_keys = np.zeros((max_slots, 2), np.uint32)
         self._branch_of = np.zeros(max_slots, np.int32)
         # prefill-final logits + branch-0 logprob stashed per slot so
         # fork() can sample every branch's own first token from the
@@ -665,12 +679,12 @@ class PagedEngine:
         # batched multi-LoRA decode (serving/adapters.py): adapters
         # live STACKED on a device lane axis (lane 0 = the all-zero
         # base adapter) and every compiled step gathers each slot's
-        # lane by a traced per-slot id operand — adapter churn
-        # (hot-load/evict/mixed batches) moves VALUES, never shapes,
-        # so the zero-recompile contract holds; off (the default) no
-        # lora operand crosses the jit boundary and every call
-        # signature is byte-identical to the pre-feature engine (the
-        # same collapse contract as the structured mask)
+        # lane by a traced per-slot id (a slice of the operand
+        # buffer) — adapter churn (hot-load/evict/mixed batches)
+        # moves VALUES, never shapes, so the zero-recompile contract
+        # holds; off (the default) no lora operand crosses the jit
+        # boundary and the buffer has no lane slice (the same
+        # collapse contract as the structured mask)
         if (lora_rank > 0) != (lora_max_live > 0):
             raise ValueError(
                 f"lora_rank={lora_rank} with lora_max_live="
@@ -680,7 +694,6 @@ class PagedEngine:
         self.lora = lora_rank > 0
         self.lora_rank = int(lora_rank)
         self.lora_max_live = int(lora_max_live)
-        self._slot_lanes = np.zeros(max_slots, np.int32)
         self._lora_buf = None
         self._lora_load_jit = None
         self.adapters = None
@@ -717,6 +730,52 @@ class PagedEngine:
                     self._lora_write_fn, donate_argnums=(0,))
             self._lora_buf = buf
             self.adapters = AdapterRegistry(self)
+        # the step's host-known operands: ONE int32 buffer, laid out
+        # here from the geometry and the modes and never again (no
+        # option chooses it). The tables become views of it; the
+        # chunk's ids and (start, s0, slot) have their slices in every
+        # engine, a mode's small operands where the mode is on.
+        fields = self.tables.operand_fields()
+        fields["chunk_ids"] = (self.chunk_tokens,)
+        fields["chunk"] = (3,)
+        if decode_backend == "pallas":
+            n_walk = n_pages - 1
+            fields.update(
+                work_pages=(n_walk,),
+                work_refs=(n_walk, self.tables.n_ref_lanes),
+                work_pos=(n_walk,))
+        if self.parallel:
+            fields["slot_keys"] = (max_slots, 2)
+        if self.lora:
+            fields["slot_lanes"] = (max_slots,)
+        if speculative:
+            fields["drafts"] = (max_slots, draft_len)
+            if spec_tree:
+                fields["parents"] = (max_slots, draft_len)
+                fields["depth"] = (max_slots, 1 + draft_len)
+        self.operands = OperandBuffer(fields)
+        self.tables.bind(self.operands)
+        self._op = {name: self.operands.view(name) for name in fields}
+        # the slot's folded branch key (its uint32 words as they lie
+        # in the buffer) and the slot's adapter lane: views where
+        # their mode packs them, plain arrays nobody sends otherwise
+        self._slot_keys = (
+            self._op["slot_keys"].view(np.uint32) if self.parallel
+            else np.zeros((max_slots, 2), np.uint32))
+        self._slot_lanes = (
+            self._op["slot_lanes"] if self.lora
+            else np.zeros(max_slots, np.int32))
+        # host-to-device transfers issued for step operands (_put)
+        self.operand_puts = 0
+        # where a step's operands go: replicated over the mesh at
+        # tp > 1 (the key too: committed like the keys the programs
+        # hand back, so the first call is no cache entry of its own)
+        self._rep = None
+        if self.tp > 1:
+            from jax.sharding import NamedSharding
+            from torchbooster_tpu.serving.tp import REP
+            self._rep = NamedSharding(mesh, REP)
+            self._rng = jax.device_put(self._rng, self._rep)
         # the pool crosses the jit boundary EVERY call — donate it so
         # XLA updates the pages in place; an undonated pool would copy
         # pool-sized bytes per step, re-taxing exactly the HBM traffic
@@ -725,38 +784,32 @@ class PagedEngine:
         # sharded on KV heads, host tables replicated, outputs
         # replicated post-psum; at tp == 1 the un-wrapped jits below
         # are byte-identical to the single-chip engine's.
-        n_extra = 3 if decode_backend == "pallas" else 0
-        # the per-branch pick path threads one extra operand (the
-        # slot-key table) and returns one extra replicated output
-        # (per-slot logprobs); the chunk returns (token, logprob,
-        # final logits) instead of just the token
+        # after the pool every program takes the operand buffer and
+        # the rng key, then what rides beside: structured mode's
+        # legality mask, lora's four adapter stacks (device-resident)
+        n_beside = 2 + (1 if self.structured else 0) \
+            + (4 if self.lora else 0)
+        # every program hands the new key back first; the per-branch
+        # pick path returns one more replicated output (per-slot
+        # logprobs), its chunk (token, logprob, final logits)
         n_par = 1 if self.parallel else 0
-        # structured mode threads one replicated legality-mask operand
-        # into the chunk, decode, and verify signatures
-        n_struct = 1 if self.structured else 0
-        # lora threads five trailing operands (four adapter stacks +
-        # the per-slot lane ids) into all three signatures
-        n_lora = 5 if self.lora else 0
         self._branch_pick = _make_branch_pick(
             temperature, top_k, top_p, jnp.int32)
         if self.tp > 1:
             pspecs = _tp_param_specs(self.params)
             self._chunk_jit = _shard_engine_fn(
-                self._chunk_fn, mesh, pspecs, 5 + n_struct + n_lora,
-                3 if self.parallel else 1)
+                self._chunk_fn, mesh, pspecs, n_beside,
+                4 if self.parallel else 2)
             self._decode_jit = _shard_engine_fn(
-                self._decode_fn, mesh, pspecs,
-                7 + n_extra + n_struct + n_par + n_lora, 1 + n_par)
+                self._decode_fn, mesh, pspecs, n_beside, 2 + n_par)
         else:
             # the slot state rides first among the trailing operands
-            # (chunk: argument 8, decode: argument 10), donated too
-            stateful = self.slot_state is not None
-            self._chunk_jit = jax.jit(
-                self._chunk_fn,
-                donate_argnums=(1, 2, 8) if stateful else (1, 2))
-            self._decode_jit = jax.jit(
-                self._decode_fn,
-                donate_argnums=(1, 2, 10) if stateful else (1, 2))
+            # (argument 5 of both programs), donated too
+            donate = (1, 2, 5) if self.slot_state is not None else (1, 2)
+            self._chunk_jit = jax.jit(self._chunk_fn,
+                                      donate_argnums=donate)
+            self._decode_jit = jax.jit(self._decode_fn,
+                                       donate_argnums=donate)
         # the fork-time copy-on-write page copy (parallel mode only):
         # ONE fixed-shape executable — (max_slots,) src/dst page-id
         # vectors padded with null->null self-copies — compiled once
@@ -812,11 +865,11 @@ class PagedEngine:
                 self._drafter = PromptLookupDrafter(
                     draft_len, ngram_min=ngram_min)
             verify_fn = make_verify_fn(self)
-            n_tree = 3 if self.spec_tree else 0
             if self.tp > 1:
+                # the tree's visibility matrix rides beside too
                 self._verify_jit = _shard_engine_fn(
                     verify_fn, mesh, pspecs,
-                    7 + n_tree + n_extra + n_struct + n_lora, 2)
+                    n_beside + (1 if self.spec_tree else 0), 3)
             else:
                 self._verify_jit = jax.jit(verify_fn,
                                            donate_argnums=(1, 2))
@@ -829,7 +882,7 @@ class PagedEngine:
                     self._compact_jit = jax.jit(
                         jax.shard_map(self._compact_fn, mesh=mesh,
                                       in_specs=(POOL_SPEC, POOL_SPEC,
-                                                REP, REP, REP, REP),
+                                                REP, REP),
                                       out_specs=(POOL_SPEC, POOL_SPEC),
                                       check_vma=False),
                         donate_argnums=(0, 1),
@@ -839,15 +892,18 @@ class PagedEngine:
                         self._compact_fn, donate_argnums=(0, 1))
 
     # ---- compiled pieces -----------------------------------------
-    def _chunk_fn(self, params, pool_k, pool_v, ids, start, s0,
-                  table_row, rng, *extra, lanes=None):
-        """ONE prefill chunk: forward ``ids`` (1, chunk_tokens) at
-        absolute positions ``start + [0, C)``, writing each layer's
-        K/V into the slot's pages and attending prior context through
+    def _chunk_fn(self, params, pool_k, pool_v, operands, rng, *extra,
+                  lanes=None):
+        """ONE prefill chunk: forward the buffer's ``chunk_ids`` as
+        ``(1, chunk_tokens)`` at absolute positions ``start + [0, C)``,
+        writing each layer's K/V into the slot's pages (row ``slot``
+        of the buffer's ``tables``) and attending prior context through
         the pool. Shapes depend only on (chunk size, pool geometry,
-        model) — ``start``/``s0``/``table_row`` are traced VALUES, so
-        this compiles exactly once whatever prompt lengths arrive
-        (the old ``_prefill_fn`` compiled per page COUNT).
+        model) — ``start``/``s0``/``slot`` are traced VALUES of the
+        buffer, so this compiles exactly once whatever prompt lengths
+        arrive (the old ``_prefill_fn`` compiled per page COUNT).
+        ``rng`` is the engine's key: split here, the new key goes back
+        FIRST among the results.
 
         Numerics: the chunk's own tokens attend each other in compute
         dtype (the un-quantized intra-prompt attention the dense
@@ -856,19 +912,21 @@ class PagedEngine:
         merge with the standard online-softmax combine. Pad tokens in
         the final chunk write K/V at positions >= ``s0`` (or into the
         reserved null page past the table) which every mask excludes.
-        Returns ``(picked token, pool_k, pool_v)`` — the pick is only
-        meaningful on the chunk containing position ``s0 - 1`` (the
-        host uses it there; earlier chunks discard it). In PARALLEL
-        mode the ``rng`` operand is the slot's BRANCH KEY (not a
-        per-step split): the pick key is ``fold_in(key, s0)`` — a
+        Returns ``(key, picked token, pool_k, pool_v)`` — the pick is
+        only meaningful on the chunk containing position ``s0 - 1``
+        (the host uses it there; earlier chunks discard it). In
+        PARALLEL mode the pick takes the slot's BRANCH KEY (row
+        ``slot`` of the buffer's ``slot_keys``, not the step's
+        split): the pick key is ``fold_in(key, s0)`` — a
         pure function of (branch key, context length), so a
         preempted-and-refolded branch resumes its sampling stream
         exactly — and the return grows the pick's logprob plus the
         final-position logits ``fork()`` samples sibling branches'
         first tokens from.
 
-        **With ``lanes``** (a dict of the decode step's operands:
-        :meth:`_lane_operands`) the decode lanes RIDE the chunk: this
+        **With ``lanes``** (a dict: empty, or structured mode's
+        ``smask`` for all slots, the chunk's row among them) the
+        decode lanes RIDE the chunk, on the SAME buffer: this
         is then the MIXED program, the second and last variant this
         function compiles to (``lanes`` is None or a dict: pytree
         structure, so static). The chunk's ``C`` tokens and the
@@ -877,89 +935,102 @@ class PagedEngine:
         a chunk program and a decode program would each read it — and
         only what is per sequence stays split (:func:`_ride`): the
         K/V writes and the two attentions, the conv state, the picks.
-        Returns ``(chunk's token, lanes' tokens, pool_k, pool_v[,
+        Returns ``(key, chunk's token, lanes' tokens, pool_k, pool_v[,
         state])``."""
-        # lora operands ride LAST (appended after every other mode's),
-        # so they strip from the end FIRST — the earlier modes' reads
-        # (structured extra[0] below) then see their PR-era layout
+        ops = self.operands.unpack(operands)
+        start, s0, slot = ops["chunk"]
+        # ONE split an iteration, of the key the last program left
+        rng, sub = jax.random.split(rng)
+        # the adapter stacks ride LAST (appended after every other
+        # mode's), so they strip from the end FIRST; the chunk's
+        # (1,) lane id is the seating slot's
         lora = None
         if self.lora:
-            lora, extra = (extra[-5:-1], extra[-1]), extra[:-5]
-        # a model with slot state: the state and the seating slot ride
-        # FIRST among the trailing operands
-        state = slot = None
+            lora, extra = (extra[-4:], jax.lax.dynamic_slice_in_dim(
+                ops["slot_lanes"], slot, 1)), extra[:-4]
+        # a model with slot state: the state rides FIRST among the
+        # trailing operands
+        state = None
         if self.slot_state is not None:
-            state, slot, extra = extra[0], extra[1], extra[2:]
-        pieces = self._chunk_pieces(params, ids, start, s0, table_row,
-                                    slot)
+            state, extra = extra[0], extra[1:]
+        pieces = self._chunk_pieces(params, ops["chunk_ids"][None], start,
+                                    s0, ops["tables"][slot], slot)
         if lanes is not None:
-            pieces = _ride(pieces, self._lane_pieces(
-                params, lanes["tables"], lanes["lengths"], lanes["refs"],
-                lanes["page_pos"], lanes["active"], lanes["last_ids"],
-                lanes.get("work")))
+            pieces = _ride(pieces, self._lane_pieces(params, ops))
         x, pool_k, pool_v, state, moe_counts = self._layers(
             params, pieces, pool_k, pool_v, state, lora)
         # the head's one product: the prompt's last real row, and in
         # the mixed program the max_slots lanes under it
         logits = self._logits(params, pieces.rows(x))
-        # structured mode: the trailing operand is the seating slot's
-        # (1, vocab) legality row (all-True when unconstrained — a
-        # bitwise no-op, so unconstrained traffic stays token-exact).
-        # The STASHED logits below stay unmasked: fork() masks them
-        # itself with the START-state row so every branch's first
-        # pick replays the independent-run distribution.
-        smask1 = extra[0] if self.structured else None
+        # structured mode: the seating slot's (1, vocab) legality row
+        # (all-True when unconstrained — a bitwise no-op, so
+        # unconstrained traffic stays token-exact) is the trailing
+        # operand, or in the mixed program the slot's row of the
+        # lanes' mask. The STASHED logits below stay unmasked: fork()
+        # masks them itself with the START-state row so every
+        # branch's first pick replays the independent-run
+        # distribution.
+        smask = smask1 = None
+        if self.structured and lanes is not None:
+            smask = lanes["smask"]
+            smask1 = jax.lax.dynamic_slice_in_dim(smask, slot, 1)
+        elif self.structured:
+            smask1 = extra[0]
         if lanes is not None:
-            # ONE rng operand for the iteration: the chunk's pick and
-            # the lanes' each get a half of its split
-            rng, rng_lanes = jax.random.split(rng)
-            tok = self._pick(rng, _mask_logits(logits[:1], smask1))
-            tokens = self._pick(rng_lanes, _mask_logits(
-                logits[1:], lanes.get("smask")))
-            outs = (tok, tokens, pool_k, pool_v)
+            # the iteration's ONE split, halved: the chunk's pick and
+            # the lanes' each get a half
+            sub, sub_lanes = jax.random.split(sub)
+            tok = self._pick(sub, _mask_logits(logits[:1], smask1))
+            tokens = self._pick(sub_lanes,
+                                _mask_logits(logits[1:], smask))
+            outs = (rng, tok, tokens, pool_k, pool_v)
             return outs if state is None else outs + (state,)
         if self.model is not None:
-            return self._pick(rng, logits), pool_k, pool_v, state, \
+            return rng, self._pick(sub, logits), pool_k, pool_v, state, \
                 moe_counts
         picked = _mask_logits(logits, smask1)
         if self.parallel:
-            key = jax.random.fold_in(rng, s0)
+            key = jax.random.fold_in(
+                self._branch_keys(ops)[slot], s0)
             tok, lp = self._branch_pick(key[None], picked)
-            return tok, lp, logits, pool_k, pool_v
-        return self._pick(rng, picked), pool_k, pool_v
+            return rng, tok, lp, logits, pool_k, pool_v
+        return rng, self._pick(sub, picked), pool_k, pool_v
 
-    def _decode_fn(self, params, pool_k, pool_v, tables, lengths,
-                   refs, page_pos, active, last_ids, rng, *extra):
+    @staticmethod
+    def _branch_keys(ops: dict):
+        """The slots' branch keys ``(max_slots, 2)`` as the uint32
+        words they are (the buffer carries their bit pattern)."""
+        return jax.lax.bitcast_convert_type(ops["slot_keys"], jnp.uint32)
+
+    def _decode_fn(self, params, pool_k, pool_v, operands, rng, *extra):
         """One decode step over all slots. Signature shapes depend
         only on pool geometry — never on which slots are live or how
-        pages are shared. The trailing operands exist only on their
-        modes — ``work_*`` on the pallas backend (the compacted
-        live-page walk from ``kernel_args()``), the slot-key table in
-        parallel-sampling mode — so the default engine's jitted call
-        signature is byte-identical to the pre-feature one."""
-        work = slot_keys = smask = None
-        # lora strips from the END first (its operands append last),
-        # leaving the earlier modes' front/back reads untouched
+        pages are shared. The tables, and the modes' small operands
+        (``work_*`` on the pallas backend: the compacted live-page
+        walk from ``kernel_args()``; the slot-key table in
+        parallel-sampling mode; the adapter lanes), are slices of the
+        ONE operand buffer; ``rng`` is the engine's key, split here,
+        and the new key goes back first among the results. The
+        trailing operands exist only on their modes: the slot state,
+        structured mode's mask, the adapter stacks."""
+        ops = self.operands.unpack(operands)
+        rng, sub = jax.random.split(rng)
+        # the adapter stacks strip from the END first (they append
+        # last), leaving the earlier modes' front reads untouched
         lora = None
         if self.lora:
-            lora, extra = (extra[-5:-1], extra[-1]), extra[:-5]
-        if self.decode_backend == "pallas":
-            work, extra = extra[:3], extra[3:]
-        if self.structured:
-            smask = extra[0]            # (max_slots, vocab) legality
-            extra = extra[1:]
-        if self.parallel:
-            slot_keys = extra[-1]
+            lora, extra = (extra[-4:], ops["slot_lanes"]), extra[:-4]
         state = None
         if self.slot_state is not None:
             state, extra = extra[0], extra[1:]
-        pieces = self._lane_pieces(params, tables, lengths, refs,
-                                   page_pos, active, last_ids, work)
+        # structured: the (max_slots, vocab) legality mask
+        smask = extra[0] if self.structured else None
+        pieces = self._lane_pieces(params, ops)
         x, pool_k, pool_v, state, moe_counts = self._layers(
             params, pieces, pool_k, pool_v, state, lora)
         logits = self._logits(params, pieces.rows(x))
         if self.model is not None:
-            return self._pick(rng, logits), pool_k, pool_v, state, \
+            return rng, self._pick(sub, logits), pool_k, pool_v, state, \
                 moe_counts
         # constrained slots' rows knock illegal tokens to finfo.min;
         # unconstrained rows are all-True (bitwise no-op — greedy and
@@ -974,10 +1045,11 @@ class PagedEngine:
             # (a refolded prompt re-samples with the same context
             # count), and graftlint's prng rule stays green (fold_in
             # is the sanctioned derivation)
-            keys = jax.vmap(jax.random.fold_in)(slot_keys, lengths + 1)
+            keys = jax.vmap(jax.random.fold_in)(
+                self._branch_keys(ops), ops["lengths"] + 1)
             tokens, lps = self._branch_pick(keys, logits)
-            return tokens, lps, pool_k, pool_v
-        return self._pick(rng, logits), pool_k, pool_v
+            return rng, tokens, lps, pool_k, pool_v
+        return rng, self._pick(sub, logits), pool_k, pool_v
 
     def _chunk_pieces(self, params, ids, start, s0, table_row,
                       slot) -> "_Pieces":
@@ -1123,14 +1195,18 @@ class PagedEngine:
         return _Pieces(x, positions[None], (positions < s0)[None],
                        write, read, conv, rows)
 
-    def _lane_pieces(self, params, tables, lengths, refs, page_pos,
-                     active, last_ids, work=None) -> "_Pieces":
+    def _lane_pieces(self, params, ops: dict) -> "_Pieces":
         """What is the decode LANES' of a program (:class:`_Pieces`):
         every slot's last token embedded at its own depth ``(slots, 1,
         d)``, the write of its K/V at ``lengths``, the slots'
         attention over the pool, the live slots' conv state shifted.
-        ``work``: the pallas backend's compacted live-page walk."""
+        ``ops``: the operand buffer unpacked — the tables and, on the
+        pallas backend, the compacted live-page walk ``work_*``."""
         cfg, ps = self.cfg, self.page_size
+        tables, lengths, refs, page_pos, last_ids = (
+            ops[name] for name in ("tables", "lengths", "refs",
+                                   "page_pos", "last_ids"))
+        active = ops["active"] != 0
         n_slots = last_ids.shape[0]
         n_heads_l = cfg.n_heads // self.tp    # local heads (tp shard)
 
@@ -1197,11 +1273,11 @@ class PagedEngine:
                 # the pool; (page, lane) partials merge per slot
                 # in VMEM scratch with the same online-softmax
                 # combine the sweep runs through segment ops
-                work_pages, work_refs, work_pos = work
                 o = paged_attention(
                     q, self._kernel_pages(pk, li),
-                    self._kernel_pages(pv, li), work_pages,
-                    work_refs, work_pos, lengths, page_size=ps)
+                    self._kernel_pages(pv, li), ops["work_pages"],
+                    ops["work_refs"], ops["work_pos"], lengths,
+                    page_size=ps)
                 return o.astype(q.dtype)
             # the pool sweep: each page attends the queries of ALL
             # its reference lanes (a gather of the TINY q tensor
@@ -1365,8 +1441,7 @@ class PagedEngine:
 
         return copy(pool_k), copy(pool_v)
 
-    def _compact_fn(self, pool_k, pool_v, tables, lengths, active,
-                    src_off):
+    def _compact_fn(self, pool_k, pool_v, operands, src_off):
         """Post-acceptance K/V compaction for TREE speculative
         decoding: the accepted root-to-leaf path's nodes sit at their
         tree STORAGE offsets (``lengths + node_id``), which are not
@@ -1377,7 +1452,12 @@ class PagedEngine:
         inactive slots divert to the null page). Functional gathers
         read every source before any write lands, so overlapping
         moves (always downward — node ids exceed their path index)
-        are safe."""
+        are safe. ``operands`` is the buffer the verify step took,
+        already on the device: the tables as they stood before the
+        advance."""
+        ops = self.operands.unpack(operands)
+        tables, lengths = ops["tables"], ops["lengths"]
+        active = ops["active"] != 0
         ps = self.page_size
         n_slots, S = src_off.shape
         mp = tables.shape[1]
@@ -1710,7 +1790,18 @@ class PagedEngine:
         if not self._pending:
             return None
         p = self._pending[0]
-        operands = self._chunk_operands(p)
+        # the host's work before the program, under its own span, so
+        # a traced idle gap in front of a chunk has a name
+        with span("prefill_args"):
+            self._fill_chunk(p)
+            operands = self._put_operands() + self._state_operand()
+            if self.structured:
+                # the seating slot's legality row masks the
+                # first-token pick in-chunk (all-True when the request
+                # is unconstrained — exact no-op)
+                operands += (self._put(
+                    self._cursors.mask[p["slot"]][None]),)
+            operands += self._lora_operands()
         # span: host wall time in the event log + the same label on a
         # captured device trace (observability/spans.py); no-op when
         # telemetry is disabled
@@ -1719,13 +1810,13 @@ class PagedEngine:
                 self.params, self.pool["k"], self.pool["v"], *operands)
         lp = logits = None
         if self.parallel:
-            tok, lp, logits, pool_k, pool_v = outs
+            self._rng, tok, lp, logits, pool_k, pool_v = outs
         elif self.model is not None:
             # the chunk's expert counts stay on the device: reading
             # them would wait for a program this call only dispatched
-            tok, pool_k, pool_v, self.slot_state, _ = outs
+            self._rng, tok, pool_k, pool_v, self.slot_state, _ = outs
         else:
-            tok, pool_k, pool_v = outs
+            self._rng, tok, pool_k, pool_v = outs
         self.pool = {"k": pool_k, "v": pool_v}
         if not self._chunk_issued(p):
             return None
@@ -1749,45 +1840,48 @@ class PagedEngine:
                     "s0": int(p["s0"])}
             return self._prefill_done(p, int(np.asarray(tok)[0]))
 
-    def _chunk_operands(self, p: dict) -> tuple:
-        """The chunk program's operands after the pool, for the next
-        chunk of the pending prefill ``p``: the host's work before the
-        program (operands onto the device, the rng split), under its
-        own span, so a traced idle gap in front of a chunk has a
-        name."""
+    def _fill_chunk(self, p: dict) -> None:
+        """The next chunk of the pending prefill ``p``, written into
+        its slices of the operand buffer: the ids and ``(start, s0,
+        slot)``. The slot's table row, its adapter lane and (parallel
+        mode) its BRANCH KEY are read by ``slot`` inside the program:
+        the chunk folds the key with s0, so the first token is a pure
+        function of (branch key, prompt length) — never of traffic
+        order."""
         if self.host_spill:
             # defensive for directly-driven engines: the batcher
             # already promoted before chunk issue; a chunk must never
             # attend host-matched pages that were not written yet
             self.issue_promotions()
-        C = self.chunk_tokens
-        with span("prefill_args"):
-            if self.parallel:
-                # the slot's BRANCH KEY rides the rng operand: the
-                # chunk folds it with s0, so the first token is a pure
-                # function of (branch key, prompt length) — never of
-                # traffic order
-                sub = jnp.asarray(self._slot_keys[p["slot"]])
-            else:
-                self._rng, sub = jax.random.split(self._rng)
-            ids = jnp.asarray(p["ids"][p["start"]:p["start"] + C])[None]
-            table_row = jnp.asarray(self.tables.tables[p["slot"]])
-            sextra = ()
-            if self.structured:
-                # the seating slot's legality row masks the
-                # first-token pick in-chunk (all-True when the request
-                # is unconstrained — exact no-op)
-                sextra = (jnp.asarray(
-                    self._cursors.mask[p["slot"]][None]),)
-            if self.slot_state is not None:
-                sextra = (self.slot_state,
-                          jnp.asarray(p["slot"], jnp.int32)) + sextra
-            # the chunk's (1,) lane id: the seating slot's adapter
-            sextra = sextra + self._lora_operands(
-                self._slot_lanes[p["slot"]:p["slot"] + 1])
-            return (ids, jnp.asarray(p["start"], jnp.int32),
-                    jnp.asarray(p["s0"], jnp.int32), table_row, sub,
-                    *sextra)
+        start = p["start"]
+        self._op["chunk_ids"][:] = p["ids"][start:start + self.chunk_tokens]
+        self._op["chunk"][:] = (start, p["s0"], p["slot"])
+
+    def _put(self, host: np.ndarray) -> jax.Array:
+        """ONE host-to-device transfer of a step operand, counted
+        (``operand_puts``, ``serving_operand_puts_total``)."""
+        self.operand_puts += 1
+        get_registry().counter(
+            "serving_operand_puts_total",
+            "host-to-device transfers issued for step operands: the "
+            "packed buffer, and a mode's large operand beside it").inc()
+        return jax.device_put(host, self._rep)
+
+    def _put_operands(self) -> tuple:
+        """The iteration's ONE transfer: the packed buffer as it
+        stands, and with it the key the last program left on the
+        device — every program's two operands after the pool. What
+        is put is a snapshot (a memcpy of a few KB): the host writes
+        the buffer again before a program that was only dispatched
+        (a lone chunk) has run, and the CPU backend aliases an
+        aligned numpy array where a device copies it."""
+        self.tables.pack()
+        return self._put(self.operands.host.copy()), self._rng
+
+    def _state_operand(self) -> tuple:
+        """A model's slot state, first among the trailing operands
+        (device-resident, donated); empty for a model without."""
+        return () if self.slot_state is None else (self.slot_state,)
 
     def _chunk_issued(self, p: dict) -> bool:
         """Book one issued chunk of the pending prefill ``p``; True
@@ -2054,16 +2148,14 @@ class PagedEngine:
                 jnp.asarray(stacks["a_proj"]),
                 jnp.asarray(stacks["b_proj"]))
 
-    def _lora_operands(self, lanes: np.ndarray) -> tuple:
-        """The lora modes' five trailing step operands: the four lane
-        stacks plus the per-slot (or per-chunk ``(1,)``) lane ids —
-        all VALUES; empty when lora is off so the default engine's
-        call signatures stay byte-identical."""
+    def _lora_operands(self) -> tuple:
+        """The lora mode's four trailing step operands: the lane
+        stacks, device-resident (the per-slot lane ids ride the
+        operand buffer); empty when lora is off."""
         if not self.lora:
             return ()
         b = self._lora_buf
-        return (b["a_qkv"], b["b_qkv"], b["a_proj"], b["b_proj"],
-                jnp.asarray(lanes, jnp.int32))
+        return (b["a_qkv"], b["b_qkv"], b["a_proj"], b["b_proj"])
 
     @property
     def lora_load_compiles(self) -> int:
@@ -2073,29 +2165,27 @@ class PagedEngine:
         return (self._lora_load_jit._cache_size()
                 if self._lora_load_jit is not None else 0)
 
-    def _kernel_operands(self) -> tuple:
-        """The pallas backend's extra decode/verify operands (the
-        compacted live-page walk); empty on the XLA sweep, so the
-        default backend's jitted call signature — and therefore its
-        compiled artifact — is byte-identical to the pre-kernel
-        engine's."""
-        if self.decode_backend != "pallas":
-            return ()
-        ka = self.tables.kernel_args()
-        return (ka["work_pages"], ka["work_refs"], ka["work_pos"])
+    def _pack_kernel_walk(self) -> None:
+        """The pallas backend's compacted live-page walk, written into
+        its slices of the operand buffer; nothing on the XLA sweep,
+        whose buffer has no such slices."""
+        if self.decode_backend == "pallas":
+            for name, walk in self.tables.kernel_args().items():
+                self._op[name][...] = walk
 
     def step(self) -> np.ndarray:
         """One decode step over every ACTIVE slot; advances lengths/
         last_ids for those and returns the (max_slots,) token ids
         (garbage at inactive or mid-prefill slots)."""
         active = self._decoding("step")
-        args, sub, extra = self._lane_operands()
+        operands, smask = self._lane_operands()
+        if smask is not None:
+            operands += (smask,)
         with span("decode_step"):
             outs = self._decode_jit(
-                self.params, self.pool["k"], self.pool["v"],
-                args["tables"], args["lengths"], args["refs"],
-                args["page_pos"], args["active"], args["last_ids"],
-                sub, *extra)
+                self.params, self.pool["k"], self.pool["v"], *operands,
+                *self._lora_operands())
+            self._rng, outs = outs[0], outs[1:]
             if self.model is not None:
                 tokens, pool_k, pool_v, self.slot_state, counts = outs
                 self.pool = {"k": pool_k, "v": pool_v}
@@ -2122,8 +2212,9 @@ class PagedEngine:
         the decode step over every active slot (the mixed variant of
         ``_chunk_fn``): what :meth:`prefill_step` followed by
         :meth:`step` would do, with one pass over the weights, one
-        launch, one rng split and one read-back. Needs a pending
-        chunk, and an engine whose mode rides (``self.mixes``).
+        transfer, one launch, one rng split and one read-back. Needs
+        a pending chunk, and an engine whose mode rides
+        (``self.mixes``).
         Returns ``(tokens, done)``: the (max_slots,) token ids as
         :meth:`step` gives them, and ``(slot, first_token)`` when the
         chunk was its prompt's last, else None — that slot joins the
@@ -2136,15 +2227,12 @@ class PagedEngine:
                 "(PagedEngine.mixes)")
         active = self._decoding("mixed_step")
         p = self._pending[0]
-        operands = self._chunk_operands(p)      # the ONE rng split
-        args, _, extra = self._lane_operands(split=False)
-        lanes = dict(args)
-        if self.slot_state is not None:
-            extra = extra[1:]       # the chunk's operands carry it
-        if self.decode_backend == "pallas":
-            lanes["work"], extra = extra[:3], extra[3:]
-        if self.structured:
-            lanes["smask"] = extra[0]
+        with span("prefill_args"):
+            self._fill_chunk(p)
+        # the ONE transfer; structured mode's mask for all slots (the
+        # chunk's row among them) is the program's ``lanes``
+        operands, smask = self._lane_operands()
+        lanes = {} if smask is None else {"smask": smask}
         # the iteration's ONE decode_step span (dispatch + read-back),
         # as a plain step's: the readers that divide decoded tokens by
         # its count see every iteration that decodes
@@ -2152,10 +2240,10 @@ class PagedEngine:
             outs = self._chunk_jit(
                 self.params, self.pool["k"], self.pool["v"], *operands,
                 lanes=lanes)
-            tok, tokens, pool_k, pool_v = outs[:4]
+            self._rng, tok, tokens, pool_k, pool_v = outs[:5]
             self.pool = {"k": pool_k, "v": pool_v}
             if self.slot_state is not None:
-                self.slot_state = outs[4]
+                self.slot_state = outs[5]
             self.mixed_steps += 1
             last = self._chunk_issued(p)
             # ONE device->host sync; the chunk's token only where it
@@ -2188,27 +2276,20 @@ class PagedEngine:
                     "retire sequences at the cache horizon")
         return active
 
-    def _lane_operands(self, split: bool = True
-                       ) -> tuple[dict, jax.Array | None, tuple]:
-        """The decode step's operands: ``tables.device_args()``, the
-        step's half of the rng (None without ``split``) and the modes'
-        trailing operands in ``_decode_fn``'s order."""
+    def _lane_operands(self) -> tuple[tuple, jax.Array | None]:
+        """What a program that runs the decode lanes takes after the
+        pool: the iteration's ONE transfer of the operand buffer (the
+        tables are views of it; the pallas walk is written here), the
+        key and the slot state; and structured mode's fused legality
+        mask (None without the mode), which rides beside the buffer
+        as a VALUE operand (max_slots x vocab: too large to pack) —
+        schema churn flips bits, never shapes."""
         with span("decode_args"):
-            sub = None
-            if split:
-                self._rng, sub = jax.random.split(self._rng)
-            args = self.tables.device_args()
-            extra = self._kernel_operands()
-            if self.structured:
-                # the fused legality mask rides as a VALUE operand —
-                # schema churn flips bits, never shapes
-                extra = extra + (jnp.asarray(self._cursors.mask),)
-            if self.parallel:
-                extra = extra + (jnp.asarray(self._slot_keys),)
-            if self.slot_state is not None:
-                extra = (self.slot_state,) + extra
-            return args, sub, \
-                extra + self._lora_operands(self._slot_lanes)
+            self._pack_kernel_walk()
+            operands = self._put_operands() + self._state_operand()
+            smask = self._put(self._cursors.mask) \
+                if self.structured else None
+            return operands, smask
 
     def _advance(self, active: np.ndarray, tokens: np.ndarray) -> None:
         with span("decode_advance"):
@@ -2283,24 +2364,23 @@ class PagedEngine:
             drafts[slot] = d
             self.spec_proposed += int((d >= 0).sum())
         with span("decode_args"):
-            self._rng, sub = jax.random.split(self._rng)
-            args = self.tables.device_args()
-            extra = self._kernel_operands()
-            if self.structured:
-                extra = extra + (jnp.asarray(vmask),)
+            self._pack_kernel_walk()
+            self._op["drafts"][...] = drafts
+            beside = ()
             if self.spec_tree:
+                # parents and depths pack; the (slots, S, S)
+                # visibility matrix rides beside, like the mask
                 depth, tvis = tree_masks(parents)
-                extra = (jnp.asarray(parents), jnp.asarray(depth),
-                         jnp.asarray(tvis)) + extra
-            extra = extra + self._lora_operands(self._slot_lanes)
-            in_ids = jnp.concatenate(
-                [args["last_ids"][:, None], jnp.asarray(drafts)],
-                axis=1)
+                self._op["parents"][...] = parents
+                self._op["depth"][...] = depth
+                beside = (self._put(tvis),)
+            if self.structured:
+                beside = beside + (self._put(vmask),)
+            operands = self._put_operands()
         with span("spec_verify_step"):
-            accept, token, pool_k, pool_v = self._verify_jit(
-                self.params, self.pool["k"], self.pool["v"],
-                args["tables"], args["lengths"], args["refs"],
-                args["page_pos"], args["active"], in_ids, sub, *extra)
+            self._rng, accept, token, pool_k, pool_v = self._verify_jit(
+                self.params, self.pool["k"], self.pool["v"], *operands,
+                *beside, *self._lora_operands())
             self.pool = {"k": pool_k, "v": pool_v}
             # ONE batched device->host sync for both results (two
             # np.asarray calls would serialize two round-trips into
@@ -2341,9 +2421,8 @@ class PagedEngine:
                     src_off[slot, i] = node
             with span("spec_tree_compact"):
                 pool_k, pool_v = self._compact_jit(
-                    self.pool["k"], self.pool["v"], args["tables"],
-                    args["lengths"], args["active"],
-                    jnp.asarray(src_off))
+                    self.pool["k"], self.pool["v"], operands[0],
+                    self._put(src_off))
             self.pool = {"k": pool_k, "v": pool_v}
         with span("decode_advance"):
             for slot, emitted in out.items():

@@ -43,6 +43,12 @@ Two cooperating halves:
   seating, retiring, and evicting only change VALUES inside
   fixed-shape tables, so the compiled decode step — whose signature
   depends only on pool geometry — never recompiles).
+- :class:`OperandBuffer` — what a step's program needs to know of
+  that bookkeeping (and the engine's other small host-known
+  integers), as ONE int32 host buffer with named static slices: the
+  tables ARE views of it (:meth:`BlockTables.bind`), so an iteration
+  hands them to the device with one transfer and the program slices
+  them apart again.
 
 **Page lifetime (PR 4: alloc/free → refcount/evict).** A page is in
 exactly one of three states: *referenced* (``refcount > 0`` — one or
@@ -89,6 +95,7 @@ a byte budget; pages that fall off its tail are gone for real.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
@@ -464,14 +471,52 @@ class HostPagePool:
             f"{self.budget_bytes}")
 
 
+class OperandBuffer:
+    """A step's host-known operands as ONE int32 host buffer.
+
+    ``fields`` maps a name to a shape; each gets a static slice of
+    ``host`` in the order given. The host fills the slices in place
+    (:meth:`view` — numpy views, so what is kept in them costs no copy
+    at all), ONE ``jax.device_put`` an iteration carries a snapshot of
+    the whole buffer over (so the next iteration may write it again
+    at once), and the program takes it apart with the same static
+    offsets (:meth:`unpack`: slices and reshapes XLA fuses into their
+    consumers). The layout follows shapes known at
+    build, so it is one constant of every program that takes the
+    buffer: churn moves VALUES, never a shape. Booleans ride as 0/1,
+    unsigned words as their bit pattern."""
+
+    def __init__(self, fields: dict[str, tuple[int, ...]]):
+        self.fields: dict[str, tuple[int, tuple[int, ...]]] = {}
+        size = 0
+        for name, shape in fields.items():
+            self.fields[name] = (size, tuple(shape))
+            size += math.prod(shape)
+        self.host = np.zeros(size, np.int32)
+
+    def _slice(self, buf, name: str):
+        start, shape = self.fields[name]
+        return buf[start:start + math.prod(shape)].reshape(shape)
+
+    def view(self, name: str) -> np.ndarray:
+        """The host's writable view of one field."""
+        return self._slice(self.host, name)
+
+    def unpack(self, buf) -> dict:
+        """Every field of a buffer of this layout, by name (inside a
+        program: ``buf`` is the traced operand)."""
+        return {name: self._slice(buf, name) for name in self.fields}
+
+
 class BlockTables:
     """Host-side refcounted page bookkeeping for ``max_slots`` serving
     slots over a ``n_pages``-page pool (page 0 reserved null).
 
     All state is fixed-shape numpy; seat/retire/evict is integer index
-    arithmetic. The decode step consumes :meth:`device_args` — the
-    VALUES change per step, the shapes never do, so slot churn cannot
-    trigger a recompile.
+    arithmetic. The decode step consumes the arrays :data:`OPERANDS`
+    names, as slices of the engine's :class:`OperandBuffer`
+    (:meth:`bind`) — the VALUES change per step, the shapes never do,
+    so slot churn cannot trigger a recompile.
 
     Arrays:
 
@@ -1053,19 +1098,35 @@ class BlockTables:
         return ids
 
     # ---- device view ---------------------------------------------
-    def device_args(self) -> dict:
-        """The decode step's table operands, as jnp arrays. Fixed
+    # what a decode program reads of the tables, in the buffer's order
+    OPERANDS = ("tables", "lengths", "refs", "page_pos", "active",
+                "last_ids")
+
+    def operand_fields(self) -> dict[str, tuple[int, ...]]:
+        """The :class:`OperandBuffer` fields the tables fill. Fixed
         shapes by construction — only values change across seat/
         retire/evict, which is what keeps the compiled step signature
         occupancy-independent."""
-        return {
-            "tables": jnp.asarray(self.tables),
-            "lengths": jnp.asarray(self.lengths),
-            "refs": jnp.asarray(self.refs),
-            "page_pos": jnp.asarray(self.page_pos),
-            "active": jnp.asarray(self.active),
-            "last_ids": jnp.asarray(self.last_ids),
-        }
+        return {name: getattr(self, name).shape for name in self.OPERANDS}
+
+    def bind(self, operands: OperandBuffer) -> None:
+        """Move the int32 arrays INTO ``operands``: from here on they
+        are views of its host buffer (every writer above writes in
+        place, so none of them changes), and handing the tables to
+        the device costs the host nothing but :meth:`pack`."""
+        for name in self.OPERANDS:
+            view = operands.view(name)
+            view[...] = getattr(self, name)
+            if name != "active":
+                setattr(self, name, view)
+        # ``active`` stays a bool array of its own (~active and
+        # lengths[active] are all over the host code); pack() copies it
+        self._active_operand = operands.view("active")
+
+    def pack(self) -> None:
+        """What is no view of the buffer, written into it: ``active``
+        as 0/1. Call before the iteration's transfer."""
+        self._active_operand[:] = self.active
 
     def kernel_args(self) -> dict:
         """The pallas decode kernel's COMPACTED live-page walk
@@ -1076,7 +1137,8 @@ class BlockTables:
         pool page by table VALUE; the all-null padding tail is fetched
         once, so HBM reads track the LIVE entries. Shapes are
         geometry-only (values change under churn — the same
-        zero-recompile contract as :meth:`device_args`). Cached
+        zero-recompile contract as the tables' own operands; host
+        arrays, which the engine copies into its operand buffer). Cached
         refcount-0 prefix pages are deliberately absent: no live slot
         references them, so the kernel never pays for residency —
         exactly the pool-sweep cost the XLA backend cannot avoid."""
@@ -1089,11 +1151,8 @@ class BlockTables:
         work_pages[:n] = live
         work_refs[:n] = self.refs[live]
         work_pos[:n] = self.page_pos[live]
-        return {
-            "work_pages": jnp.asarray(work_pages),
-            "work_refs": jnp.asarray(work_refs),
-            "work_pos": jnp.asarray(work_pos),
-        }
+        return {"work_pages": work_pages, "work_refs": work_refs,
+                "work_pos": work_pos}
 
     @property
     def n_live_pages(self) -> int:

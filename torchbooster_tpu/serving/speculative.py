@@ -304,13 +304,15 @@ def tree_accept_path(accept_row: np.ndarray,
 def make_verify_fn(engine):
     """Build the engine's ONE compiled multi-token verify step.
 
-    ``fn(params, pool_k, pool_v, tables, lengths, refs, page_pos,
-    active, in_ids, rng) -> (accept, token, pool_k, pool_v)`` (the
-    pallas backend appends the ``work_*`` live-page-walk operands —
-    see ``PagedEngine._kernel_operands`` — and a structured engine
-    appends the per-position legality mask LAST; tree operands, when
-    present, ride at the front of ``extra``) where
-    ``in_ids`` is ``(max_slots, 1 + draft_len)``: column 0 each slot's
+    ``fn(params, pool_k, pool_v, operands, rng) -> (rng, accept,
+    token, pool_k, pool_v)``: the ``_decode_fn`` convention —
+    ``operands`` is the engine's packed buffer (the tables, the
+    ``drafts``, the tree's ``parents`` / ``depth`` and the pallas
+    backend's ``work_*`` walk are slices of it), ``rng`` its key, split
+    here and handed back first. A tree engine's visibility matrix
+    rides FIRST in ``extra``, a structured engine's per-position
+    legality mask after it, the adapter stacks last. The step's
+    ``in_ids`` are ``(max_slots, 1 + draft_len)``: column 0 each slot's
     pending token, columns 1.. the draft (``NO_DRAFT``-padded). Shapes
     depend ONLY on pool geometry, the model config, and the
     trace-time-fixed ``draft_len`` — slot churn, accept-length churn,
@@ -332,7 +334,7 @@ def make_verify_fn(engine):
     ``_make_spec_pick`` (models/gpt.py) over the final logits.
 
     With ``engine.spec_tree`` the SAME executable verifies a TREE of
-    candidate branches: three extra traced operands — per-slot parent
+    candidate branches: three extra traced values — per-slot parent
     vectors ``(B, k)``, node depths ``(B, S)``, and the
     ancestor-or-self matrix ``(B, S, S)`` (``tree_masks``) — replace
     the chain's implicit ``arange`` structure. Node j still WRITES at
@@ -356,26 +358,34 @@ def make_verify_fn(engine):
     spec_pick = _make_spec_pick(engine.temperature, engine.top_k,
                                 engine.top_p, jnp.int32)
 
-    def verify_fn(params, pool_k, pool_v, tables, lengths, refs,
-                  page_pos, active, in_ids, rng, *extra):
+    def verify_fn(params, pool_k, pool_v, operands, rng, *extra):
+        ops = engine.operands.unpack(operands)
+        tables, lengths, refs, page_pos = (
+            ops[name] for name in ("tables", "lengths", "refs",
+                                   "page_pos"))
+        active = ops["active"] != 0
+        in_ids = jnp.concatenate(
+            [ops["last_ids"][:, None], ops["drafts"]], axis=1)
+        rng, sub = jax.random.split(rng)
         # None-init every mode operand (the _decode_fn convention):
         # the closures below reference them by name, and a use that
         # ever escaped its mode guard must fail as a loud None error,
         # not a NameError-at-trace trap for the next refactor
         t_parent = t_depth = t_vis = None
         work_pages = work_refs = work_pos = smask = None
-        # lora operands append LAST (spec_step), so strip from the
-        # end FIRST — the front reads below keep their layout
+        # the adapter stacks append LAST (spec_step), so strip from
+        # the end FIRST — the front reads below keep their layout
         lora_w = lane_ids = None
         if engine.lora:
-            lora_w, lane_ids = extra[-5:-1], extra[-1]
-            extra = extra[:-5]
+            lora_w, lane_ids = extra[-4:], ops["slot_lanes"]
+            extra = extra[:-4]
         if tree:
-            t_parent, t_depth, t_vis = extra[:3]
-            extra = extra[3:]
+            t_parent, t_depth = ops["parents"], ops["depth"]
+            t_vis, extra = extra[0], extra[1:]
         if engine.decode_backend == "pallas":
-            work_pages, work_refs, work_pos = extra[:3]
-            extra = extra[3:]
+            work_pages, work_refs, work_pos = (
+                ops[name] for name in ("work_pages", "work_refs",
+                                       "work_pos"))
         if engine.structured:
             # (max_slots, S, vocab) per-position legality rows from
             # the slot cursors' draft pre-validation (all-True for
@@ -535,9 +545,9 @@ def make_verify_fn(engine):
         # picks are legal by construction (drafts were pre-validated
         # host-side; the -1 sentinel never accepts)
         logits = _mask_logits(logits, smask)
-        accept, token = spec_pick(rng, logits, in_ids[:, 1:],
+        accept, token = spec_pick(sub, logits, in_ids[:, 1:],
                                   parent=t_parent if tree else None)
-        return accept, token, pool_k, pool_v
+        return rng, accept, token, pool_k, pool_v
 
     return verify_fn
 

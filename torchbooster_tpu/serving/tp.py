@@ -167,8 +167,10 @@ def shard_engine_fn(fn, mesh: Mesh, pspecs: Any, n_host_args: int,
     the verify fn) in shard_map over the tp axis AND jit it with the
     engine's donation + pinned output shardings. Argument convention
     (shared by all three): ``(params, pool_k, pool_v, *host_args)``
-    in, ``(*replicated_outputs, pool_k, pool_v)`` out — pools sharded
-    on KV heads, every host-side table/id/rng operand replicated, and
+    in — the packed operand buffer, the rng key, then what a mode
+    rides beside them — and ``(*replicated_outputs, pool_k, pool_v)``
+    out, the new key first — pools sharded on KV heads, the buffer,
+    the key and every operand beside them replicated, and
     the post-psum outputs replicated by construction (``check_vma=
     False``: the pallas table walk inside defeats the static
     replication checker; the token-parity tests are the behavioral
