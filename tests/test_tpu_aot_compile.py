@@ -227,6 +227,8 @@ def _pool_sized_writes(compiled, layer_elems: int, pool_k, vocab: int):
     import math
 
     pool_shapes = {tuple(leaf.shape) for leaf in jax.tree.leaves(pool_k)}
+    # a pool of ONE layer is updated under its shape without that axis
+    pool_shapes |= {s[1:] for s in pool_shapes if s[0] == 1}
     moved = []
     for name, op, results in _top_level_results(compiled.as_text()):
         if op in ("parameter", "get-tuple-element", "tuple", "bitcast",
@@ -659,3 +661,98 @@ def test_sarvam_mla_serve_programs_fit_and_stay_in_place_on_v5e(
           f"{memory.temp_size_in_bytes / 1e9:.3f} GB, total "
           f"{total / 1e9:.3f} GB")
     assert nbytes(params) > 10.9e9 and total < V5E_HBM_BYTES, total
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "mixed"])
+def test_afmoe_serve_programs_fit_and_keep_both_pools_in_place_on_v5e(
+        one_chip, program, monkeypatch):
+    """The programs of the ``trinity-large-preview.serve-longctx-r80``
+    cell (decode, the chunk alone, and the chunk with the decode lanes
+    riding) at its own size (5 layers at published widths — four
+    sliding, one full —, 32 of 256 experts a layer, 32 slots, chunks
+    of 256, a full-layer pool of 4096 pages of 64 under a
+    17,152-position table and a window pool of 32 x 69 ring pages),
+    compiled for the described v5e with BOTH pools donated: each
+    comes back aliased (no copy of either: the pool-sized writes are
+    the two scatters), no instruction writes another buffer the size
+    of a layer of either pool, the experts run as the pallas grouped
+    product with no copy of an expert stack, a chunk's float32 scores
+    over its whole table (843 MB) never exist — the full layer is
+    walked in blocks — and weights, both pools and scratch fit the
+    chip."""
+    import json
+    import math
+    import sys
+    from pathlib import Path
+
+    import torchbooster_tpu.models.moe as moe_mod
+    import torchbooster_tpu.serving.engine as engine_mod
+    from torchbooster_tpu.models.afmoe import Afmoe
+
+    monkeypatch.setattr(moe_mod, "_on_tpu", lambda: True)
+    bench = Path(__file__).resolve().parent.parent / "benchmark"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    import program_afmoe
+
+    raw = json.loads(
+        (bench / "configs" / "trinity-large-preview.json").read_text())
+    traffic = json.loads(
+        (bench / "traffic" / "serve-longctx-r80.json").read_text())
+    serving = traffic["serving"]
+    cfg = program_afmoe.model_config(raw, traffic["max_positions"])
+    slots, pages, page = (serving["max_slots"], serving["n_pages"],
+                          serving["page_size"])
+    abstract = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip), tree)
+    params = abstract(jax.eval_shape(
+        lambda: Afmoe.init(jax.random.PRNGKey(0), cfg, jnp.bfloat16)))
+    real = engine_mod.make_pool
+    engine_mod.make_pool = lambda *a, **kw: jax.eval_shape(
+        lambda: real(*a, **kw))
+    try:
+        engine = engine_mod.PagedEngine(
+            params, cfg, page_size=page, n_pages=pages, max_slots=slots,
+            prefill_chunk_pages=serving["prefill_chunk_pages"])
+    finally:
+        engine_mod.make_pool = real
+    assert engine.ring == 69 and engine.chunk_tokens == 256
+    assert engine.tables.max_pages_per_slot == 268
+    pool_k, pool_v = (abstract(engine.pool[h]) for h in "kv")
+    assert pool_k["full"].shape == (1, pages, page, 1024)
+    assert pool_k["window"].shape == (4, slots * 69, page, 1024)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, kw = _serve_program(engine, program)
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, pool_k, pool_v, *_step_args(arg, engine), **kw).compile()
+
+    nbytes = lambda tree: sum(math.prod(x.shape) * x.dtype.itemsize
+                              for x in jax.tree.leaves(tree))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= nbytes(pool_k) + nbytes(pool_v)
+    # nothing the size of the smaller pool's layer is written but the
+    # pools' own updates
+    layer_elems = min(pages, slots * 69) * page * 1024
+    moved = _pool_sized_writes(compiled, layer_elems, pool_k, cfg.vocab)
+    assert not moved, f"pool-sized buffers written: {moved}"
+    assert "ragged-dot" not in compiled.as_text()
+    stack = cfg.experts_held[1] * cfg.d_model * cfg.expert_width
+    copies = [(name, dims) for name, op, results
+              in _top_level_results(compiled.as_text()) if op == "copy"
+              for _, dims, _ in results
+              if math.prod(int(d) for d in dims.split(",") if d) >= stack]
+    assert not copies, f"expert-stack-sized copies: {copies}"
+    scores = engine.chunk_tokens * cfg.n_heads \
+        * engine.tables.max_pages_per_slot * page * 4
+    assert memory.temp_size_in_bytes < scores, memory.temp_size_in_bytes
+    total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    print(f"afmoe {program}: args "
+          f"{memory.argument_size_in_bytes / 1e9:.3f} GB, temp "
+          f"{memory.temp_size_in_bytes / 1e9:.3f} GB, total "
+          f"{total / 1e9:.3f} GB")
+    assert nbytes(params) > 8.6e9 and total < V5E_HBM_BYTES, total

@@ -1178,8 +1178,9 @@ class ServingConfig(BaseConfig):
         """Build the engine + batcher for ``params``/``model_cfg`` (a
         :class:`~torchbooster_tpu.models.gpt.GPTConfig`, or another
         served model's config such as
-        :class:`~torchbooster_tpu.models.lfm2.LFM2Config` or
-        :class:`~torchbooster_tpu.models.mla_moe.MLAMoEConfig`: the
+        :class:`~torchbooster_tpu.models.lfm2.LFM2Config`,
+        :class:`~torchbooster_tpu.models.mla_moe.MLAMoEConfig` or
+        :class:`~torchbooster_tpu.models.afmoe.AfmoeConfig`: the
         engine is chosen by its type, and the features a model lacks
         raise ``NotImplementedError`` naming the feature and the
         reason). Returns the
@@ -1211,9 +1212,10 @@ class ServingConfig(BaseConfig):
 
         if not isinstance(model_cfg, GPTConfig):
             # a model with its own layer stack (models/lfm2.py,
-            # models/mla_moe.py): the engine is chosen by the config's
-            # type and refuses the features it lacks, each with the
-            # model's own reason; these three never reach it
+            # models/mla_moe.py, models/afmoe.py): the engine is chosen
+            # by the config's type and refuses the features it lacks,
+            # each with the model's own reason; these three never
+            # reach it
             from torchbooster_tpu.serving.engine import refuse_unserved
 
             refuse_unserved(model_cfg, {

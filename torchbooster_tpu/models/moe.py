@@ -290,7 +290,7 @@ def moe_dropless(params: dict, x: jax.Array, top_k: int,
                  first_group: jax.Array | int = 0,
                  route_on: jax.Array | None = None,
                  held: tuple[int, int] | None = None,
-                 tp: tuple | None = None
+                 tp: tuple | None = None, route_eps: float = 1e-6
                  ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """(B, S, d) -> ((B, S, d), tokens per expert (E,) int32, pairs
     elsewhere () int32): the DROPLESS expert layer — every selected
@@ -354,7 +354,7 @@ def moe_dropless(params: dict, x: jax.Array, top_k: int,
     with jax.named_scope("moe_route"):
         sel, w = moe_route(
             params, tokens if route_on is None
-            else route_on.reshape(b * s, d), top_k, scaling)
+            else route_on.reshape(b * s, d), top_k, scaling, route_eps)
     with jax.named_scope("moe_experts"):
         pair_expert = sel.reshape(-1)                    # (T*k,)
         live = None if valid is None \

@@ -81,6 +81,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from torchbooster_tpu.models import layers as L
+from torchbooster_tpu.models import afmoe as _afmoe
 from torchbooster_tpu.models import lfm2 as _lfm2
 from torchbooster_tpu.models import mla_moe as _mla_moe
 from torchbooster_tpu.observability import get_registry, span
@@ -119,6 +120,8 @@ from torchbooster_tpu.serving.kv_pages import (
     make_slot_state,
     pool_map,
     quantized_rows,
+    ring_pages,
+    ring_positions,
     scan_layers,
     sweep_attention,
     to_rows,
@@ -173,11 +176,13 @@ def _served_model(cfg: Any):
     architecture is a module with those three functions, a
     ``cache_spec()`` on its config and ``UNSERVED`` — the serving
     features it is refused, each with its reason — (models/lfm2.py,
-    models/mla_moe.py), and a line here."""
+    models/mla_moe.py, models/afmoe.py), and a line here."""
     if isinstance(cfg, _lfm2.LFM2Config):
         return _lfm2
     if isinstance(cfg, _mla_moe.MLAMoEConfig):
         return _mla_moe
+    if isinstance(cfg, _afmoe.AfmoeConfig):
+        return _afmoe
     if isinstance(cfg, GPTConfig):
         return None
     raise TypeError(
@@ -206,7 +211,9 @@ class _Pieces(NamedTuple):
     x: jax.Array            # (B, S, d) embedded tokens
     positions: jax.Array    # (B, S) absolute positions (rope)
     valid: jax.Array        # (B, S) real tokens (expert routing)
-    # a latent pool: k is the token's one row, v and pool_v are None
+    # a latent pool: k is the token's one row, v and pool_v are None;
+    # pools of two kinds (window and full layers): pool_k and pool_v
+    # are {kind: array} and both closures take ``kind=`` as well
     write: Callable         # (k, v, pool_k, pool_v, li) -> pools
     read: Callable          # (q, k, v, pool_k, pool_v, li) -> o
     conv: Callable | None   # (z, w, state, li) -> (c, state)
@@ -232,14 +239,15 @@ def _ride(chunk: _Pieces, lanes: _Pieces) -> _Pieces:
     split = lambda t: (None, None) if t is None \
         else (t[:, :C], turn(t[:, C:]))
 
-    def write(k, v, pk, pv, li):
+    def write(k, v, pk, pv, li, **kind):
         (kc, kl), (vc, vl) = split(k), split(v)
-        return lanes.write(kl, vl, *chunk.write(kc, vc, pk, pv, li), li)
+        return lanes.write(
+            kl, vl, *chunk.write(kc, vc, pk, pv, li, **kind), li, **kind)
 
-    def read(q, k, v, pk, pv, li):
+    def read(q, k, v, pk, pv, li, **kind):
         (qc, ql), (kc, kl), (vc, vl) = split(q), split(k), split(v)
-        return join(chunk.read(qc, kc, vc, pk, pv, li),
-                    lanes.read(ql, kl, vl, pk, pv, li))
+        return join(chunk.read(qc, kc, vc, pk, pv, li, **kind),
+                    lanes.read(ql, kl, vl, pk, pv, li, **kind))
 
     def conv(z, w, state, li):
         zc, zl = split(z)
@@ -255,6 +263,19 @@ def _ride(chunk: _Pieces, lanes: _Pieces) -> _Pieces:
                    join(chunk.positions, lanes.positions),
                    join(chunk.valid, lanes.valid), write, read,
                    conv if chunk.conv is not None else None, rows)
+
+
+def _merge_partials(a: tuple, b: tuple) -> tuple:
+    """Two flash-style partials ``(o unnormalised (B, S_q, g, rep, D),
+    m, l (B, g, rep, S_q))`` over disjoint keys as ONE such partial:
+    the online-softmax combine. A partial that saw no key (``m`` =
+    -1e30) gets weight 0 beside one that did."""
+    (oa, ma, la), (ob, mb, lb) = a, b
+    m = jnp.maximum(ma, mb)
+    wa, wb = jnp.exp(ma - m), jnp.exp(mb - m)
+    # (B, g, rep, S_q) weights -> (B, S_q, g, rep, 1)
+    mv = lambda t: jnp.moveaxis(t, -1, 1)[..., None]
+    return oa * mv(wa) + ob * mv(wb), m, la * wa + lb * wb
 
 
 class PagedEngine:
@@ -390,6 +411,21 @@ class PagedEngine:
     the others alone. Features whose bookkeeping takes pages for the
     WHOLE of a sequence refuse such a model at build
     (``NotImplementedError`` naming the feature).
+
+    **Window and full attention layers in one model**
+    (``models/afmoe.py``; ``CacheSpec.kv_kinds``): two pools, one a
+    kind, both donated to all three programs. The full layers' pool is
+    the one the block tables page; a window layer's is a ring of
+    ``window / page_size + prefill_chunk_pages + 1`` pages a slot
+    (``self.ring``), written at ``(position // page_size) % ring`` and
+    read with every row's position recovered from the slot's length
+    (``kv_pages.ring_positions``), so ``0 <= q_pos - k_pos < window``
+    is the whole of visibility: it hides what a recycled page still
+    holds and what the slot's last tenant left, and seating, preempting
+    and retiring touch nothing of it. The decode lanes sweep that pool
+    as they sweep the other; a chunk reads its slot's ring, never its
+    table, for a window layer, and walks its table in blocks up to its
+    own position for a full one.
     """
 
     def __init__(self, params: dict, cfg: Any, *,
@@ -535,13 +571,30 @@ class PagedEngine:
         self.prefill_chunk_pages = min(prefill_chunk_pages,
                                        self.tables.max_pages_per_slot)
         self.chunk_tokens = self.prefill_chunk_pages * page_size
-        self.pool = make_pool(cfg, page_size, n_pages,
-                              cache_dtype=cache_dtype,
-                              compute_dtype=compute_dtype,
-                              shards=self.tp)
+        spec = cache_spec(cfg)
+        # a head's lanes: the model's own where it states them (a
+        # model whose heads are not d_model / n_heads wide)
+        self.head_dim = getattr(cfg, "head_dim",
+                                cfg.d_model // cfg.n_heads)
+        # window layers (CacheSpec.kv_kinds): their cache is a RING a
+        # slot — ``ring`` pages of a second pool that slot ``s`` owns
+        # for good, position p at ring page (p // page_size) % ring —
+        # bounded whatever the sequences' lengths and with no host
+        # bookkeeping: pool["k"] / pool["v"] are then {kind: array},
+        # donated whole, and the full layers' half is what the block
+        # tables page. None where every layer holds every token.
+        self.window: int | None = None
+        self.ring: int | None = None
+        if spec.layers_of("window"):
+            self.window = spec.window
+            self.ring = ring_pages(spec.window, page_size,
+                                   self.prefill_chunk_pages)
+        self.pool = make_pool(
+            cfg, page_size, n_pages, cache_dtype=cache_dtype,
+            compute_dtype=compute_dtype, shards=self.tp,
+            ring=None if self.ring is None else (max_slots, self.ring))
         # slot-indexed state (a conv mixer's last inputs), None for a
         # model without: donated and updated in place like the pool
-        spec = cache_spec(cfg)
         self.slot_state = make_slot_state(spec, max_slots, compute_dtype)
         # a latent pool (CacheSpec.value_dim): ONE array of rows whose
         # leading lanes are the values; pool["v"] is None and rides
@@ -553,6 +606,11 @@ class PagedEngine:
         self.moe_counts: np.ndarray | None = None
         self.moe_elsewhere: np.ndarray | None = None
         self._moe_inst: dict | None = None
+        # rows of a window layer's ring overwritten by a later
+        # position, and the registry series of the two kinds' rows
+        # (made at the first step with the registry on)
+        self.window_rows_recycled = 0
+        self._kv_inst: dict | None = None
         # the host spill tier (PR 16): LRU eviction demotes registered
         # prefix pages to a host-DRAM pool (int8 + scales) and a later
         # seat promotes them back through ONE fixed-shape compiled
@@ -1062,7 +1120,7 @@ class PagedEngine:
         C = ids.shape[1]
         n_cp = C // ps
         mp = table_row.shape[0]
-        head_dim = cfg.d_model // cfg.n_heads
+        head_dim = self.head_dim
         # per-shard head count: cfg.n_heads / tp local query heads
         # under the tp shard_map, == cfg.n_heads at tp=1 (python
         # arithmetic — the single-chip jaxpr is unchanged)
@@ -1166,6 +1224,10 @@ class PagedEngine:
                         jnp.ones((n_blocks,), bool),
                         n_heads=n_heads_l, value_dim=self.latent_dim)
                 return o.reshape(1, C, n_heads_l, -1).astype(q.dtype)
+
+        if self.ring is not None:
+            write, read = self._chunk_kinds(
+                write, context, table_row, slot, start, C)
 
         conv = None
         if self.slot_state is not None:
@@ -1309,8 +1371,7 @@ class PagedEngine:
                                       num_segments=n_slots + 1)
             o = o_s[:n_slots] / jnp.maximum(
                 l_s[:n_slots], 1e-30)[..., None]
-            o = o.reshape(n_slots, 1, n_heads_l,
-                          cfg.d_model // cfg.n_heads)
+            o = o.reshape(n_slots, 1, n_heads_l, self.head_dim)
             return o.astype(q.dtype)
 
         if self.latent_dim is not None:
@@ -1332,6 +1393,9 @@ class PagedEngine:
                         n_heads=n_heads_l, value_dim=self.latent_dim)
                 return o[:, None].astype(q.dtype)
 
+        if self.ring is not None:
+            write, read = self._lane_kinds(write, read, lengths, active)
+
         def conv(z, w, st, li):
             # live slots shift their state by this step's input;
             # dead and mid-prefill slots keep theirs
@@ -1346,6 +1410,145 @@ class PagedEngine:
                        read, conv if self.slot_state is not None else None,
                        lambda x: x)
 
+    def _chunk_kinds(self, write_full, context, table_row, slot, start,
+                     C: int):
+        """A chunk's ``write`` / ``read`` over pools of two kinds
+        (``pool_k`` / ``pool_v``: ``{kind: array}``; ``kind`` static in
+        the model's layer stack). A FULL layer is written as every
+        paged layer is (``write_full``, on that kind's pool) and read
+        by walking the slot's table in blocks of ``walk`` pages up to
+        the chunk's own position — a chunk costs what its prompt so
+        far costs, never what the table could hold, and the float32
+        scores of one block are all that exists of them at a time. A
+        WINDOW layer is written into the slot's ring and reads the
+        ring and nothing else, whatever the sequence's length: every
+        row's position recovered from the chunk's place
+        (``ring_positions``), visible where ``0 <= q_pos - k_pos <
+        window``. Both reads merge the prior context with the chunk's
+        own compute-dtype K/V, as the one-kind chunk read does."""
+        ps, ring, window = self.page_size, self.ring, self.window
+        n_cp, mp = C // ps, table_row.shape[0]
+        kv_heads = self.cfg.kv_heads
+        q_pos = start + jnp.arange(C)
+        local = jnp.arange(C)
+        back = local[:, None] - local[None, :]
+        # the chunk's own keys: causal, and inside the window
+        vis_own = {"full": back >= 0,
+                   "window": (back >= 0) & (back < window)}
+        # the ring as the chunk's last page leaves it
+        ring_pos = ring_positions(start // ps + n_cp - 1, ring,
+                                  ps).reshape(-1)
+        vis_ring = (ring_pos >= 0) & (ring_pos < start) \
+            & (q_pos[:, None] - ring_pos[None, :] < window)
+        w_ring = slot * ring + (start // ps + jnp.arange(n_cp)) % ring
+        walk = min(mp, 4 * n_cp)        # pages a block of the walk
+        n_blocks = (start + walk * ps - 1) // (walk * ps)
+
+        def write(k, v, pk, pv, li, kind):
+            if kind == "full":
+                fk, fv = write_full(k, v, pk["full"], pv["full"], li)
+                return {**pk, "full": fk}, {**pv, "full": fv}
+            rows = lambda t, pool: self._page_rows(
+                t[0].reshape(n_cp, ps, kv_heads, -1), pool)
+            with jax.named_scope("kv_write"):
+                wk = write_rows(pk["window"], (li, w_ring),
+                                rows(k, pk["window"]))
+                wv = write_rows(pv["window"], (li, w_ring),
+                                rows(v, pv["window"]))
+            return {**pk, "window": wk}, {**pv, "window": wv}
+
+        def prior_window(q, pk, pv, li):
+            # the slot's ring: ``ring`` contiguous pages of the layer
+            mine = lambda pool: context(jax.lax.dynamic_slice(
+                pool, (li, slot * ring, 0, 0),
+                (1, ring, *pool.shape[2:]))[0])
+            return _grouped_cache_attention(
+                q, mine(pk), mine(pv), vis_ring[None, None, None],
+                state=True)
+
+        def prior_full(q, pk, pv, li):
+            def block(b, acc):
+                idx = b * walk + jnp.arange(walk)
+                pages = jnp.where(idx < mp,
+                                  table_row[jnp.clip(idx, 0, mp - 1)],
+                                  NULL_PAGE)
+                pos = (idx[:, None] * ps + jnp.arange(ps)).reshape(-1)
+                return _merge_partials(acc, _grouped_cache_attention(
+                    q, context(gather_pages(pk, li, pages)),
+                    context(gather_pages(pv, li, pages)),
+                    (pos < start)[None, None, None, None], state=True))
+
+            g, rep = kv_heads, q.shape[2] // kv_heads
+            none = (jnp.zeros((1, C, g, rep, q.shape[3]), jnp.float32),
+                    jnp.full((1, g, rep, C), -1e30, jnp.float32),
+                    jnp.zeros((1, g, rep, C), jnp.float32))
+            return jax.lax.fori_loop(0, n_blocks, block, none)
+
+        def read(q, k, v, pk, pv, li, kind):
+            prior = prior_full if kind == "full" else prior_window
+            o, _, l = _merge_partials(
+                prior(q, pk[kind], pv[kind], li),
+                _grouped_cache_attention(
+                    q, k, v, vis_own[kind][None, None, None], state=True))
+            o = o / jnp.moveaxis(jnp.maximum(l, 1e-30), -1, 1)[..., None]
+            return o.reshape(1, C, -1, q.shape[3]).astype(q.dtype)
+
+        return write, read
+
+    def _lane_kinds(self, write_full, read_full, lengths, active):
+        """The decode lanes' ``write`` / ``read`` over pools of two
+        kinds. A FULL layer is what every paged layer is
+        (``write_full`` / ``read_full`` on that kind's pool: the block
+        tables' pages, the pool sweep). A WINDOW layer writes the
+        slot's row at its ring place — a slot that is not decoding
+        writes nowhere: the ring pool has no null page, its index is
+        past the pool and the update is dropped — and sweeps the ring
+        pool, every page attended by its owner slot's query under the
+        window's mask on recovered positions."""
+        ps, ring, window = self.page_size, self.ring, self.window
+        n_slots = lengths.shape[0]
+        kv_heads = self.cfg.kv_heads
+        w_page = jnp.where(
+            active, jnp.arange(n_slots) * ring + (lengths // ps) % ring,
+            n_slots * ring)
+        w_off = lengths % ps
+        pos = ring_positions(lengths // ps, ring, ps)  # (slots, ring, ps)
+        at = lengths[:, None, None]
+        visible = ((pos >= 0) & (pos <= at) & (at - pos < window)
+                   ).reshape(n_slots * ring, 1, ps)
+
+        def write(k, v, pk, pv, li, kind):
+            if kind == "full":
+                fk, fv = write_full(k, v, pk["full"], pv["full"], li)
+                return {**pk, "full": fk}, {**pv, "full": fv}
+            put = lambda pool, t: pool.at[li, w_page, w_off].set(
+                self._page_rows(t[:, 0], pool).astype(pool.dtype),
+                mode="drop")
+            with jax.named_scope("kv_write"):
+                return ({**pk, "window": put(pk["window"], k)},
+                        {**pv, "window": put(pv["window"], v)})
+
+        def read(q, k, v, pk, pv, li, kind):
+            if kind == "full":
+                return read_full(q, k, v, pk["full"], pv["full"], li)
+            # a ring page serves its owner's one query; the (page)
+            # partials of a slot are its ``ring`` neighbours
+            q_lanes = jnp.repeat(q[:, 0], ring, axis=0)[:, None]
+            o, m, l = sweep_attention(
+                q_lanes, layer_pages(pk["window"], li),
+                layer_pages(pv["window"], li), visible, kv_heads)
+            # o (slots * ring, 1, g, rep, D); m, l (slots * ring, g,
+            # rep, 1): the online-softmax merge over a slot's pages
+            by_slot = lambda t: t.reshape(n_slots, ring, *t.shape[1:3])
+            o = o.reshape(n_slots, ring, *o.shape[2:])
+            m, l = by_slot(m), by_slot(l)
+            w = jnp.exp(m - jnp.max(m, axis=1, keepdims=True))
+            o = jnp.sum(o * w[..., None], axis=1) / jnp.maximum(
+                jnp.sum(l * w, axis=1), 1e-30)[..., None]
+            return o.reshape(n_slots, 1, -1, q.shape[3]).astype(q.dtype)
+
+        return write, read
+
     def _layers(self, params, pieces: "_Pieces", pool_k, pool_v, state,
                 lora=None):
         """The layer stack over ``pieces.x``, the pool (and a model's
@@ -1356,17 +1559,21 @@ class PagedEngine:
         state, tokens per expert or None)``."""
         cfg = self.cfg
 
-        def attend(q, k, v, pk, pv, li):
+        def attend(q, k, v, pk, pv, li, **kind):
             # layer ``li`` of the pool: written, then read (after the
-            # write, so the update stays in place)
-            pk, pv = pieces.write(k, v, pk, pv, li)
-            return pieces.read(q, k, v, pk, pv, li), (pk, pv)
+            # write, so the update stays in place). ``kind``: which of
+            # two pools, where a model's layers cache in two ways (one
+            # whose layers all hold every token has one pool)
+            if self.ring is None:
+                kind = {}
+            pk, pv = pieces.write(k, v, pk, pv, li, **kind)
+            return pieces.read(q, k, v, pk, pv, li, **kind), (pk, pv)
 
         if self.model is not None:
             x, (pool_k, pool_v), state, moe_counts = self.model.layers(
                 params, pieces.x, cfg, positions=pieces.positions,
-                attend=lambda q, k, v, cache, li: attend(q, k, v, *cache,
-                                                         li),
+                attend=lambda q, k, v, cache, li, **kind: attend(
+                    q, k, v, *cache, li, **kind),
                 conv=pieces.conv, cache=(pool_k, pool_v), state=state,
                 valid=pieces.valid)
             return x, pool_k, pool_v, state, moe_counts
@@ -1410,7 +1617,7 @@ class PagedEngine:
         in ``(n, page_size, kv_heads, head_dim)`` (int8: the scales
         get their trailing 1 back): the form ``models/gpt.py``'s
         attention core and the pallas kernel take."""
-        head_dim = self.cfg.d_model // self.cfg.n_heads
+        head_dim = self.head_dim
         kv_heads = self.cfg.kv_heads // self.tp
         if isinstance(pages, tuple):
             return (from_rows(pages[0], kv_heads, head_dim),
@@ -1818,6 +2025,7 @@ class PagedEngine:
         else:
             self._rng, tok, pool_k, pool_v = outs
         self.pool = {"k": pool_k, "v": pool_v}
+        self._count_rows(None, chunk=p)
         if not self._chunk_issued(p):
             return None
         # the prompt's LAST chunk: its token is read back here, which
@@ -2193,6 +2401,7 @@ class PagedEngine:
                 tokens, counts = jax.device_get((tokens, counts))
                 tokens = np.asarray(tokens)
                 self._count_experts(counts)
+                self._count_rows(active)
             elif self.parallel:
                 tokens, lps, pool_k, pool_v = outs
                 self.pool = {"k": pool_k, "v": pool_v}
@@ -2245,6 +2454,7 @@ class PagedEngine:
             if self.slot_state is not None:
                 self.slot_state = outs[5]
             self.mixed_steps += 1
+            self._count_rows(active, chunk=p)
             last = self._chunk_issued(p)
             # ONE device->host sync; the chunk's token only where it
             # is the prompt's first
@@ -2486,6 +2696,61 @@ class PagedEngine:
             fullest = counts.max(axis=1)[live] / mean[live]
             self._moe_inst["load"].observe(fullest.mean())
 
+    def _count_rows(self, active: np.ndarray | None,
+                    chunk: dict | None = None) -> None:
+        """File what the two kinds of cache hold at this step (an
+        engine with window layers; host arithmetic on the tables, made
+        BEFORE the step's advance): the rows ONE layer of each kind
+        keeps for the slots ``active`` once the step has written — a
+        full layer every position, a window layer the last ``window``
+        — as the gauge ``serving_kv_rows_live{kind}`` and summed over
+        the steps in ``serving_kv_rows_read_total{kind}`` (what the
+        steps' attention had to read of a layer of that kind), and the
+        rows of a ring this step's writes recycled
+        (``serving_window_rows_recycled_total``: a position at or past
+        the ring's size lands on the row of the position one ring
+        before it): the lanes' and the ``chunk``'s real tokens'.
+        ``active`` None: a lone chunk, no lane decodes."""
+        if self.ring is None:
+            return
+        at = np.zeros(0, np.int64) if active is None \
+            else self.tables.lengths[active].astype(np.int64)
+        span = self.ring * self.page_size
+        recycled = int(np.count_nonzero(at >= span))
+        if chunk is not None:
+            recycled += max(0, min(chunk["start"] + self.chunk_tokens,
+                                   chunk["s0"])
+                            - max(chunk["start"], span))
+        self.window_rows_recycled += recycled
+        reg = get_registry()
+        if not reg.enabled:
+            return
+        if self._kv_inst is None:
+            self._kv_inst = {
+                "live": reg.gauge(
+                    "serving_kv_rows_live",
+                    "cached rows ONE layer of the kind holds for the "
+                    "decoding slots at the last step: a window layer "
+                    "at most its window a slot"),
+                "read": reg.counter(
+                    "serving_kv_rows_read_total",
+                    "serving_kv_rows_live summed over the steps: the "
+                    "rows the steps' attention needed of one layer of "
+                    "the kind"),
+                "recycled": reg.counter(
+                    "serving_window_rows_recycled_total",
+                    "rows of a window layer's ring overwritten by a "
+                    "later position"),
+            }
+        self._kv_inst["recycled"].inc(recycled)
+        if active is None:
+            return
+        rows = {"full": int((at + 1).sum()),
+                "window": int(np.minimum(at + 1, self.window).sum())}
+        for kind, n in rows.items():
+            self._kv_inst["live"].set(n, kind=kind)
+            self._kv_inst["read"].inc(n, kind=kind)
+
     def retire(self, slot: int) -> None:
         """Release the slot (cancelling any in-flight prefill); shared
         prefix pages stay resident for later hits, everything else
@@ -2562,6 +2827,9 @@ class PagedEngine:
             "structured_requests": self.structured_requests,
             "structured_slots": self.structured_slot_count,
             "structured_schemas": len(self._sdfa_cache),
+            "window": self.window,
+            "ring_pages": self.ring,
+            "window_rows_recycled": self.window_rows_recycled,
             "weights_dtype": (_weights_dtype(self.params)
                               if self.model is None else "bf16"),
             # a model with its own layer stack: every stored byte (a

@@ -50,6 +50,22 @@ Two cooperating halves:
   hands them to the device with one transfer and the program slices
   them apart again.
 
+**Two kinds of cache in one model.** Where a model's attention layers
+do not all see the same keys (``CacheSpec.kv_kinds``:
+models/afmoe.py), :func:`make_pool` builds one pool a KIND. The
+``"full"`` layers' pool is everything this module describes: pages by
+block table, growing with the sequence. A ``"window"`` layer needs the
+last ``window`` positions only, and its pool is a RING a slot
+(:func:`ring_pages`): slot ``s`` owns ``ring`` pages for good,
+position ``p`` lives at ring page ``(p // page_size) % ring``, and a
+row's position is recovered from the slot's length
+(:func:`ring_positions`) — so visibility is ``0 <= q_pos - k_pos <
+window`` on recovered positions, the same rule hides what a recycled
+page still holds and what the slot's last tenant left, and NO host
+bookkeeping exists for that pool: nothing to seat, retire, evict or
+clear, no null page (a write that must land nowhere is dropped), fixed
+shapes whatever the sequences' lengths.
+
 **Page lifetime (PR 4: alloc/free → refcount/evict).** A page is in
 exactly one of three states: *referenced* (``refcount > 0`` — one or
 more slots hold it in their tables; a prefix page shared by k live
@@ -105,6 +121,7 @@ import numpy as np
 
 NULL_PAGE = 0
 LANES = 128     # the device tiles an array's minor axis in 128 lanes
+KINDS = ("full", "window")      # what a K/V layer's cache can be
 
 
 class PoolExhausted(RuntimeError):
@@ -139,6 +156,33 @@ class CacheSpec:
     # allocated (``make_pool``'s "v" is None) and the reads go through
     # ``ops/latent_paged_attention.py``
     value_dim: int | None = None
+    # the KIND of each K/V layer where they differ (models/afmoe.py):
+    # ``"full"`` layers hold every token of a sequence in pages its
+    # block table names, as all layers do without this field;
+    # ``"window"`` layers see the last ``window`` positions only and
+    # keep them in a RING of pages a slot owns for good
+    # (:func:`ring_pages`): a pool of its own, bounded whatever the
+    # sequences' lengths, with no block table and no host bookkeeping
+    kv_kinds: tuple[str, ...] | None = None
+    window: int | None = None
+
+    def __post_init__(self):
+        kinds = self.kv_kinds
+        if kinds is None:
+            return
+        if len(kinds) != self.kv_layers or set(kinds) - set(KINDS):
+            raise ValueError(
+                f"kv_kinds names {self.kv_layers} layers as one of "
+                f"{KINDS}, got {kinds}")
+        if "window" in kinds and not self.window:
+            raise ValueError("a window layer needs the window's size")
+
+    def layers_of(self, kind: str) -> int:
+        """How many K/V layers are of ``kind`` (all are ``"full"``
+        where the spec names no kinds)."""
+        if self.kv_kinds is None:
+            return self.kv_layers if kind == "full" else 0
+        return self.kv_kinds.count(kind)
 
 
 def cache_spec(cfg: Any) -> CacheSpec:
@@ -171,10 +215,43 @@ def kv_width(kv_heads: int, head_dim: int, shards: int = 1) -> int:
     return shards * (-(-per_shard // LANES) * LANES)
 
 
+def ring_pages(window: int, page_size: int, chunk_pages: int) -> int:
+    """Pages of ONE slot's ring in a window layer's pool: the
+    ``window`` positions a query may see, the chunk being written, and
+    one page more. Position ``p`` lives at ring page ``(p //
+    page_size) % ring`` (:func:`ring_positions` is the way back), so a
+    write of up to ``chunk_pages`` pages lands on rows whose old
+    positions lie more than ``window + page_size`` behind it: no query
+    of that write's step, or of any later one, can see them."""
+    if window % page_size:
+        raise ValueError(
+            f"page_size ({page_size}) must divide the attention window "
+            f"({window}): a ring is whole pages")
+    return window // page_size + chunk_pages + 1
+
+
+def ring_positions(top_page, ring: int, page_size: int):
+    """The absolute position of every row of a ring whose NEWEST page
+    is the sequence's page ``top_page`` (traced; any leading shape):
+    ``(..., ring, page_size)`` int32. Ring page ``r`` holds the newest
+    page ``a <= top_page`` with ``a % ring == r``; where that is
+    negative the page was never written by this sequence and its
+    positions come out negative. What a recycled page still holds of
+    older positions, or of the slot's last tenant, is thereby given
+    the position of what SHOULD lie there: rows a sequence has not
+    written yet read as positions ahead of it, and every mask of the
+    form ``0 <= q_pos - k_pos < window`` hides them."""
+    top = jnp.asarray(top_page, jnp.int32)[..., None]
+    page = top - jnp.mod(top - jnp.arange(ring, dtype=jnp.int32), ring)
+    return page[..., None] * page_size \
+        + jnp.arange(page_size, dtype=jnp.int32)
+
+
 def make_pool(cfg: Any, page_size: int, n_pages: int,
               cache_dtype: Any = None,
               compute_dtype: Any = jnp.bfloat16,
-              shards: int = 1) -> dict:
+              shards: int = 1, ring: tuple[int, int] | None = None
+              ) -> dict:
     """Allocate the device pool for a model config (or its
     :class:`CacheSpec`): ``{"k": ..., "v": ...}`` with each
     entry ``(kv_layers, n_pages, page_size, kv_width)`` (module
@@ -183,11 +260,30 @@ def make_pool(cfg: Any, page_size: int, n_pages: int,
     is ``"int8"``, the pair ``(int8 rows, bf16 scales (n_layers,
     n_pages, page_size, kv_heads))``. ``shards`` is the engine's ``tp``
     (:func:`kv_width` pads per shard). A latent spec
-    (``value_dim``) gets ONE array: ``"v"`` is None."""
+    (``value_dim``) gets ONE array: ``"v"`` is None. A spec with
+    window layers (``kv_kinds``) gets one pool a KIND: ``"k"`` and
+    ``"v"`` are each ``{"full": (full layers, n_pages, ...), "window":
+    (window layers, max_slots * ring pages, ...)}`` — ``ring =
+    (max_slots, pages a slot's ring)`` (:func:`ring_pages`): slot ``s``
+    owns pages ``[s * ring, (s + 1) * ring)`` of every window layer,
+    so that pool's size follows from the slots and the window, never
+    from ``n_pages`` or the sequences' lengths."""
     if cache_dtype not in (None, "int8", jnp.int8):
         raise ValueError(
             f"cache_dtype must be None or 'int8', got {cache_dtype!r}")
     spec = cfg if isinstance(cfg, CacheSpec) else cache_spec(cfg)
+    if spec.layers_of("window"):
+        if cache_dtype is not None or shards != 1 or ring is None:
+            raise ValueError(
+                "a pool with window layers is unsharded rows in the "
+                "compute dtype, and needs ring=(max_slots, ring pages)")
+        width = kv_width(spec.kv_heads, spec.head_dim)
+        pages = {"full": n_pages, "window": ring[0] * ring[1]}
+        half = lambda: {
+            kind: jnp.zeros((spec.layers_of(kind), pages[kind],
+                             page_size, width), compute_dtype)
+            for kind in KINDS}
+        return {"k": half(), "v": half()}
     shape = (spec.kv_layers, n_pages, page_size,
              kv_width(spec.kv_heads, spec.head_dim, shards))
     if cache_dtype in ("int8", jnp.int8):
@@ -1269,5 +1365,5 @@ class BlockTables:
 __all__ = ["BlockTables", "CacheSpec", "HostPagePool", "NULL_PAGE",
            "PoolExhausted", "cache_spec", "from_rows", "gather_pages",
            "kv_width", "layer_pages", "make_pool", "make_slot_state",
-           "pool_map", "quantized_rows", "scan_layers",
-           "sweep_attention", "to_rows", "write_rows"]
+           "pool_map", "quantized_rows", "ring_pages", "ring_positions",
+           "scan_layers", "sweep_attention", "to_rows", "write_rows"]
