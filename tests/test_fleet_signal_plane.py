@@ -681,10 +681,11 @@ def test_fleet_crash_dump_tags_replicas_and_audit(tmp_path):
     async def run():
         await fe.start()
 
-        def boom():
+        def boom(*launch):
             raise RuntimeError("synthetic replica death")
 
-        fleet.replicas[0].batcher.engine.step = boom
+        engine = fleet.replicas[0].batcher.engine
+        engine.step = engine.step_ahead = boom
         status, _, _ = await _unary(
             fe.port, "/v1/completions",
             {"prompt": [1, 2, 3], "max_tokens": 4})

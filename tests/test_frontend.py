@@ -607,7 +607,9 @@ def test_http_soak_mixed_priority_cancel_shed_zero_recompiles():
     assert m["n_shed"] >= 1
     assert m["n_cancelled"] >= 1
     assert engine.decode_compiles == 1         # THE contract
-    assert engine.prefill_compiles == 1
+    # the chunk alone and the chunk with the lanes riding: which of
+    # the two a soak's timing meets is not the contract (at most both)
+    assert 1 <= engine.prefill_compiles <= 2
     engine.tables.check()
     assert engine.tables.n_free_pages == engine.n_pages - 1
 

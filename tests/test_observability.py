@@ -579,7 +579,7 @@ def test_batcher_sentinel_guards_decode_recompiles(global_obs, caplog):
     # of freezing a stale mid-loop value in the export forever
     from unittest import mock
 
-    with mock.patch.object(engine, "step",
+    with mock.patch.object(engine, "step_ahead",
                            side_effect=RuntimeError("boom")):
         with pytest.raises(RuntimeError, match="boom"):
             batcher.run([Request(prompt=prompt, max_new_tokens=4)])

@@ -85,6 +85,18 @@ def count_puts(engine) -> dict[str, list[int]]:
                 seen[name].append(puts)
             return out
         setattr(engine, name, wrapped)
+
+    # the look-ahead loop's entry: a launch of the plain or the mixed
+    # step behind the one in flight (``mixed`` None: it only lands)
+    def ahead(flight, mixed, real=engine.step_ahead):
+        before = engine.operand_puts, total.value()
+        out = real(flight, mixed)
+        puts = engine.operand_puts - before[0]
+        assert puts == total.value() - before[1]
+        if mixed is not None:
+            seen["mixed_step" if mixed else "step"].append(puts)
+        return out
+    engine.step_ahead = ahead
     return seen
 
 

@@ -192,6 +192,13 @@ TREE = ("sched_admit", "prefill_args", "serving_prefill_chunk",
 MIXED_TREE = ("sched_admit", "sched_grow", "prefill_args", "decode_args",
               "decode_step", "prefill_finish", "decode_advance",
               "sched_deliver")
+# one step in flight: the iteration that LAUNCHES the mixed step lands
+# the plain step before it (decode_step: the dispatch and the wait),
+# and the prompt's first token is the next iteration's to book
+AHEAD_TREE = ("sched_admit", "sched_grow", "prefill_args", "decode_args",
+              "decode_step", "decode_advance", "sched_deliver")
+AHEAD_NEXT = ("sched_admit", "sched_grow", "decode_args", "decode_step",
+              "prefill_finish", "decode_advance", "sched_deliver")
 
 
 class _Tick:
@@ -227,16 +234,18 @@ def _engine(params, cfg, **kw):
     return PagedEngine(params, cfg, compute_dtype=jnp.float32, **kw)
 
 
-@pytest.mark.parametrize("mixes", [False, True],
-                         ids=["two_programs", "mixed"])
-def test_one_step_closes_the_span_tree_once(registry, mixes):
+@pytest.mark.parametrize("mixes,ahead", [
+    (False, False), (True, False), (True, True)],
+    ids=["two_programs", "mixed", "lookahead"])
+def test_one_step_closes_the_span_tree_once(registry, mixes, ahead):
     from torchbooster_tpu.serving import ContinuousBatcher, Request
 
     params, cfg = _model()
     b = ContinuousBatcher(_engine(params, cfg), clock=_Tick())
-    assert b.engine.mixes
-    b.engine.mixes = mixes
-    tree = MIXED_TREE if mixes else TREE
+    assert b.engine.mixes and b.engine.looks_ahead
+    # off: the synchronous loop the other modes keep
+    b.engine.mixes, b.engine.looks_ahead = mixes, ahead
+    tree = AHEAD_TREE if ahead else MIXED_TREE if mixes else TREE
     events: list[dict] = []
     b.start_session()
     try:
@@ -253,7 +262,22 @@ def test_one_step_closes_the_span_tree_once(registry, mixes):
         finally:
             unsubscribe()
         assert b.engine.prefill_chunks == chunks0 + 1
+        if ahead:
+            # the prompt's token lands an iteration later, under the
+            # next launch: the same tree but for who finishes
+            assert len(b._s.live) == 1 and b._s.flight is not None
+            after: list[dict] = []
+            unsubscribe = obs.span_events_subscribe(after.append)
+            try:
+                b.step()
+            finally:
+                unsubscribe()
+            assert [e["name"] for e in sorted(
+                after, key=lambda e: e["ts"])
+                if e["name"] != "sched_step"] == list(AHEAD_NEXT)
         assert len(b._s.live) == 2
+        while b.has_work:               # nothing in flight at the end
+            b.step()
     finally:
         b.finish_session()
     names = [e["name"] for e in events]

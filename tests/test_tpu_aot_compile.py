@@ -252,13 +252,20 @@ def _step_args(arg, engine) -> tuple:
     return arg(engine.operands.host.shape), arg((2,), jnp.uint32)
 
 
-def _serve_program(engine, program: str):
-    """``(function, keyword arguments)`` of one of an engine's three
-    serve programs: decode, the chunk alone, and the chunk with the
-    decode lanes riding (MIXED: ``lanes`` a dict, here empty)."""
+def _serve_program(engine, program: str, arg):
+    """``(function, trailing operands, keyword arguments)`` of one of
+    an engine's three serve programs: decode, the chunk alone, and the
+    chunk with the decode lanes riding (MIXED: ``lanes`` a dict). The
+    programs with lanes take the last program's ``tokens`` (an engine
+    that looks ahead): last of all in decode, in ``lanes`` in the
+    mixed one."""
+    prev = arg((engine.max_slots,)) if engine.looks_ahead else None
     if program == "decode":
-        return engine._decode_fn, {}
-    return engine._chunk_fn, ({"lanes": {}} if program == "mixed" else {})
+        return engine._decode_fn, () if prev is None else (prev,), {}
+    if program == "chunk":
+        return engine._chunk_fn, (), {}
+    return engine._chunk_fn, (), {
+        "lanes": {} if prev is None else {"prev": prev}}
 
 
 @pytest.mark.parametrize("int8_pool", [False, True],
@@ -305,9 +312,10 @@ def test_serve_programs_keep_the_pool_in_place_on_v5e(
     def arg(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    fn, kw = _serve_program(engine, program)
+    fn, tail, kw = _serve_program(engine, program, arg)
     compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-        params, pool_k, pool_v, *_step_args(arg, engine), **kw).compile()
+        params, pool_k, pool_v, *_step_args(arg, engine), *tail,
+        **kw).compile()
 
     head_dim = cfg.d_model // cfg.n_heads
     layer_elems = XL_PAGES * XL_PAGE * cfg.kv_heads * head_dim
@@ -401,9 +409,9 @@ def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     # the slot state rides right behind the buffer and the key
-    fn, kw = _serve_program(engine, program)
+    fn, tail, kw = _serve_program(engine, program, arg)
     compiled = jax.jit(fn, donate_argnums=(1, 2, 5)).lower(
-        params, pool_k, pool_v, *_step_args(arg, engine), state,
+        params, pool_k, pool_v, *_step_args(arg, engine), state, *tail,
         **kw).compile()
 
     layer_elems = pages * page * 512
@@ -438,28 +446,40 @@ def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
 
 # sha256 of the StableHLO text each accepted serve cell's programs
 # lower to for the described v5e (Mosaic kernels' bodies included, call
-# sites' line numbers left out), taken at PR 33's tree: the operand
-# list (params, pool, ONE packed buffer, the key) and the in-program
-# rng split are what PR 33 changed of PR 31's text
+# sites' line numbers left out), taken at PR 35's tree. What PR 35
+# changed of PR 33's text (the operand list of params, pool, ONE
+# packed buffer and the key, and the in-program rng split): the buffer
+# is ``max_slots`` words longer (``known``), the programs with lanes
+# take the last program's ``tokens`` and select each lane's last token
+# between it and the buffer's ``last_ids``, and the mixed program
+# writes a prompt's first token into its slot's lane of ``tokens``.
+# The trinity cell's three programs joined the table then (PR 34 had
+# left them out)
 ACCEPTED_SERVE_TEXT = {
     ("gpt2-xl.serve-chat-r80", "decode"):
-        "0843e6f3799b6e7e3f78b2759b7cbf193d5d7b907d0e7c0a5b8cdd2b0cc6df81",
+        "55d734b80a7726add900c461f55048d94d1d173c46c8654c91a750f876013797",
     ("gpt2-xl.serve-chat-r80", "chunk"):
-        "05c426615d8c6b937ff32cec6df1db2687d2aec3557389dcdfa1010771264bc9",
+        "64fb3db321c03d436b92c9a5f2d424f1b3c17e7e16b2fb93f451d24a86d70d7a",
     ("gpt2-xl.serve-chat-r80", "mixed"):
-        "38b868ab3a7ad64cca16b892844f5114b056fe7b0e68eca511d7a7ac31030086",
+        "46e99daf4361c62ba336bef86ddeb4dcee2faa61cbd3e7cb36edff6def1f917a",
     ("lfm2-8b-a1b.serve-rag-r80", "decode"):
-        "b4d5573719b7781337bea43cc91c10719097cbd21a966ea1f3aac0cf310bdcc8",
+        "667964cc8b1ff468f11ed6fe625e3df854fef1d6b5a12e9bb0d97189df2daf5f",
     ("lfm2-8b-a1b.serve-rag-r80", "chunk"):
-        "1e4814c87644f4630fa7f4b37b7c654b274315bfe7291c4c022064a961c79dd9",
+        "c831ff5a47ec94c4d65b200c0c9d22c8172dd67f7eccb4ec6c6aba9cdaed2b4e",
     ("lfm2-8b-a1b.serve-rag-r80", "mixed"):
-        "eca722a39f10f34a9d82dc4a2e6f029d4db2f3807c691e697e5057b9a84f9281",
+        "6fdf46d7f00b3b859be1638437554e90467aa9fe1f11292a553cdd7b34653808",
     ("sarvam-105b.serve-longdoc-r80", "decode"):
-        "0631a488036c67a28edfc450eadeb1f69c871b5cbd49a1697bb6a037262117fd",
+        "e413f24ac7a58e487cab29e22063c0eaf5a90a0321596c3c15c059cafcfd216f",
     ("sarvam-105b.serve-longdoc-r80", "chunk"):
-        "091831f3a3a59a773f31d715f629251b75362a3a3c69cb64e55cd4adbcd110b3",
+        "349bd382cff460975a610b141516fd44d45c119181e61ff7eff5ee17c15e976d",
     ("sarvam-105b.serve-longdoc-r80", "mixed"):
-        "146624de818f35120c57ad8014547a82778eda5ea9a498c939142dfd68603a83",
+        "95e76e2e57330ce59b8ab9c91ea1dcc578b9b0cf31597185a53a1a29f36cc000",
+    ("trinity-large-preview.serve-longctx-r80", "decode"):
+        "412e1d4b14527e41f40412f1484931835ab82e86fe5f84e179334e928477ae8a",
+    ("trinity-large-preview.serve-longctx-r80", "chunk"):
+        "f0d921aec04ed36d50b60966100d84d23786845efa9d7eb149feaebca45e0532",
+    ("trinity-large-preview.serve-longctx-r80", "mixed"):
+        "3e3a3b44fe1c6efccded848559e08908d58083cc446d5e9c22ff49cce5bfe069",
 }
 
 
@@ -501,6 +521,13 @@ def _accepted_cell_lowered(one_chip, cell: str, program: str):
         # made in bfloat16; the router's float32 bias stays as served
         init = lambda: model.init(jax.random.PRNGKey(0), cfg,
                                   jnp.bfloat16)
+    elif traffic["job"] == "serve_afmoe":
+        import program_afmoe
+        from torchbooster_tpu.models.afmoe import Afmoe as model
+
+        cfg = program_afmoe.model_config(raw, traffic["max_positions"])
+        init = lambda: model.init(jax.random.PRNGKey(0), cfg,
+                                  jnp.bfloat16)
     else:
         import program as program_gpt
         from torchbooster_tpu.models.gpt import GPT as model
@@ -528,20 +555,21 @@ def _accepted_cell_lowered(one_chip, cell: str, program: str):
 
     state = () if engine.slot_state is None \
         else (abstract(engine.slot_state),)
-    fn, kw = _serve_program(engine, program)
+    fn, tail, kw = _serve_program(engine, program, arg)
     return jax.jit(fn, donate_argnums=(1, 2)).lower(
         params, abstract(engine.pool["k"]), abstract(engine.pool["v"]),
-        *_step_args(arg, engine), *state, **kw)
+        *_step_args(arg, engine), *state, *tail, **kw)
 
 
 @pytest.mark.parametrize("cell,program", sorted(ACCEPTED_SERVE_TEXT))
 def test_accepted_serve_cells_lower_to_the_text_pr33_left(
         one_chip, cell, program, monkeypatch):
-    """The decode, chunk and mixed programs of the three serve cells
-    (GPT-2 XL, LFM2 and the latent-attention family, each at its
-    cell's own geometry and full depth) lower to the StableHLO text
-    they lowered to at PR 33's tree, which gave every program the
-    packed operand buffer and the device-carried key. A PR that MEANS
+    """The decode, chunk and mixed programs of the four serve cells
+    (GPT-2 XL, LFM2, the latent-attention family and the window-and-
+    full one, each at its cell's own geometry and full depth) lower to
+    the StableHLO text they lowered to at PR 35's tree: PR 33's (the
+    packed operand buffer and the device-carried key) with the last
+    tokens kept on the device for the lanes. A PR that MEANS
     to change one of these programs replaces its hash here and says so
     in CHANGES.md; one that does not and fails here has moved a cell
     it did not measure."""
@@ -627,9 +655,10 @@ def test_sarvam_mla_serve_programs_fit_and_stay_in_place_on_v5e(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     tables = engine.tables
-    fn, kw = _serve_program(engine, program)
+    fn, tail, kw = _serve_program(engine, program, arg)
     compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-        params, pool_k, None, *_step_args(arg, engine), **kw).compile()
+        params, pool_k, None, *_step_args(arg, engine), *tail,
+        **kw).compile()
 
     layer_elems = pages * page * 640
     moved = _pool_sized_writes(compiled, layer_elems, pool_k, cfg.vocab)
@@ -726,9 +755,10 @@ def test_afmoe_serve_programs_fit_and_keep_both_pools_in_place_on_v5e(
     def arg(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    fn, kw = _serve_program(engine, program)
+    fn, tail, kw = _serve_program(engine, program, arg)
     compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-        params, pool_k, pool_v, *_step_args(arg, engine), **kw).compile()
+        params, pool_k, pool_v, *_step_args(arg, engine), *tail,
+        **kw).compile()
 
     nbytes = lambda tree: sum(math.prod(x.shape) * x.dtype.itemsize
                               for x in jax.tree.leaves(tree))

@@ -229,7 +229,8 @@ def test_trace_cancelled_request_exact_sequence():
     try:
         req = Request(prompt=np.arange(1, 6), max_new_tokens=8)
         b.submit(req)
-        b.step()       # seat + the single prefill chunk + one decode
+        b.step()       # seat + the single prefill chunk + one launch
+        b.step()       # ... which lands here, behind the next launch
         b.cancel(req)
         b.step()       # the cancel drains before anything else
     finally:
@@ -524,14 +525,14 @@ def test_pump_death_dumps_flight_and_trace(tmp_path):
     async def run():
         await fe.start()
 
-        def boom():
+        def boom(*launch):
             raise RuntimeError("synthetic engine death")
 
         # engine-level death: the batcher's step() wrapper still runs,
         # so the FATAL step itself must land a (partial) flight row —
         # the crash dump's last record is the step that died, not the
-        # one before it
-        fe.batcher.engine.step = boom
+        # one before it (the look-ahead loop's entry is step_ahead)
+        fe.batcher.engine.step = fe.batcher.engine.step_ahead = boom
         status, _, body = await _unary(
             fe.port, "/v1/completions",
             {"prompt": [1, 2, 3], "max_tokens": 4})

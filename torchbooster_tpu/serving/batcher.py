@@ -311,6 +311,15 @@ class _Session:
         self.aloads0 = ad.loads if ad is not None else 0
         self.aevict0 = ad.evictions if ad is not None else 0
         self.ahits0 = ad.hits if ad is not None else 0
+        # the look-ahead loop (``PagedEngine.looks_ahead``): the step
+        # launched by the last iteration and not landed yet (the
+        # engine's handle), per slot the tokens it may still be
+        # LAUNCHED for before a ``length`` stop, and the slots that
+        # stopped (EOS) with a token still in flight: retired when
+        # that step lands
+        self.flight = None
+        self.left = np.zeros(eng.max_slots, np.int64)
+        self.stopped: list[int] = []
         self.closed = False
 
     def sample(self, series: list[float], value: float) -> None:
@@ -545,7 +554,7 @@ class ContinuousBatcher:
     def has_work(self) -> bool:
         s = self._s
         return s is not None and bool(
-            s.queue or s.live or s.filling
+            s.queue or s.live or s.filling or s.flight
             or self._inbox_submit or self._inbox_cancel)
 
     @property
@@ -607,6 +616,13 @@ class ContinuousBatcher:
         if self._s is None:
             return []
         s = self._s
+        if retire_seated:
+            # a step in flight lands first, and its tokens are dropped
+            # (never delivered, never folded): whoever re-admits the
+            # request decodes them again
+            self._land_flight(s, None, None, "drain")
+        s.flight = None
+        s.stopped.clear()
         out: list[Request] = []
         while self._inbox_submit:
             out.append(self._inbox_submit.popleft())
@@ -698,6 +714,7 @@ class ContinuousBatcher:
             raise RuntimeError("no active session")
         s = self._s
         try:
+            self._land_flight(s, None, None, "drain")
             self._sentinel.__exit__(None, None, None)
         finally:
             self._land(s)
@@ -784,6 +801,24 @@ class ContinuousBatcher:
                 "serving_cow_copies_total",
                 "private tail pages copied at fork (the only bytes "
                 "n-way sampling duplicates)"),
+            # the look-ahead loop: how often the next step was
+            # launched behind one in flight, how often (and why) a
+            # step was waited for with nothing launched behind it,
+            # and what a late EOS stop cost
+            "lookahead": reg.counter(
+                "serving_lookahead_steps_total",
+                "decode steps launched while the step before them "
+                "was still in flight"),
+            "sync_lands": reg.counter(
+                "serving_sync_lands_total",
+                "decode steps landed with no step launched behind "
+                "them, by reason: mode (the engine's mode needs every "
+                "token on the host), preempt, drain (cancel, drain, "
+                "session end), idle (nothing left to launch)"),
+            "wasted": reg.counter(
+                "serving_wasted_lanes_total",
+                "tokens dropped at a land because their slot had "
+                "stopped (an EOS stop applies one step late)"),
         }
         if self.engine.structured:
             # structured generation only (absent with
@@ -963,7 +998,16 @@ class ContinuousBatcher:
             inst["queue_wait"].observe(req.admitted_at - req.arrival)
             inst["prefill"].observe(
                 req.first_token_at - req.admitted_at)
-        self.engine.retire(slot)
+        flight = s.flight
+        if flight is not None and flight.carries(slot):
+            # stopped by a token the host could not know when the
+            # step in flight was launched (EOS): the slot rides it to
+            # its end — the extra token is dropped and the pages go
+            # back when that step lands
+            self.engine.hold(slot)
+            s.stopped.append(slot)
+        else:
+            self.engine.retire(slot)
         self._release_adapter(req)
         if self.tracer.enabled:
             self.tracer.emit(req.request_id, "retired",
@@ -1056,6 +1100,20 @@ class ContinuousBatcher:
         if cs is not None:
             self._inst["slo_cancel"].inc(
                 cls=self.policy.cls_of(req).name)
+
+    def _cancel_ids(self) -> set[int]:
+        """The requests the cancel inbox names, by ``id`` (a snapshot:
+        other threads append while the loop thread reads)."""
+        return {id(r) for root in tuple(self._inbox_cancel)
+                for r in (root.branches or [root])}
+
+    def _cancels_seated(self, s: _Session) -> bool:
+        """Whether a cancel in the inbox names a seated request."""
+        if not self._inbox_cancel:
+            return False
+        named = self._cancel_ids()
+        return any(id(r) in named
+                   for r in (*s.filling.values(), *s.live.values()))
 
     def _drain_cancels(self, events: list) -> None:
         s = self._s
@@ -1220,6 +1278,28 @@ class ContinuousBatcher:
         SSE. ``run()`` ignores them (requests accumulate their own
         ``tokens``).
 
+        **One step in flight** (an engine that looks ahead,
+        ``PagedEngine.looks_ahead``): the decode step this call
+        LAUNCHES is not the one whose tokens it returns. It launches
+        step N+1 behind step N — launched by the last call and running
+        all through this one's host work — and only then waits for N:
+        the events of iteration N are returned with N+1 in flight (the
+        first call after an idle point launches and returns no decode
+        tokens; ``has_work`` stays true until what is in flight has
+        landed). A stop by ``max_new_tokens`` or the ``seq_len``
+        horizon counts the token in flight, so a slot is never
+        launched past its last; **a stop by EOS applies one step
+        late** — the request finishes when the EOS lands, its slot
+        rides the step already in flight, whose token for it is
+        dropped, and is retired when that step lands. Where the next
+        iteration cannot be predicted the step in flight is landed
+        first and the loop falls back to depth 0 for that iteration: a
+        starved ``grow`` (preemption), a cancel of a seated request,
+        ``drain_unfinished`` / ``finish_session``, nothing left to
+        launch; and an engine whose mode needs the token on the host
+        (``structured``, ``speculative``, ``parallel_sampling``,
+        adapters, ``tp > 1``) stays at depth 0 throughout.
+
         Every iteration also lands ONE row in the (always-on, fixed
         size) flight recorder — step kind, slots/pages/queue, tokens,
         accept rate, wall time from the dts this loop already
@@ -1286,38 +1366,50 @@ class ContinuousBatcher:
     def _step_body(self, s: _Session, st: dict, events: list) -> None:
         """The iteration's phases, each under its span (a shared no-op
         while the registry is off): the tree docs/observability.md
-        draws, read by the benchmark's ``sched_host_ms``."""
+        draws, read by the benchmark's ``sched_host_ms``.
+
+        Where the engine looks ahead, the decode step this iteration
+        LAUNCHES is not the one it lands: the step launched by the
+        last iteration is in flight all through the host's work here
+        (``s.flight``), the next is launched behind it from what the
+        host can predict, and only then are its tokens waited for and
+        delivered (:meth:`_decode_one`). Whatever cannot be predicted
+        lands the step in flight first and goes on as the synchronous
+        loop does (:meth:`_land_flight`)."""
         eng = self.engine
         with span("sched_admit"):
-            self._admit(s, events)
+            self._admit(s, st, events)
         # --- ONE prefill chunk per iteration, interleaved with
         # decode: long prompts stream in while the live slots keep
-        # producing tokens. A pending chunk and live slots are ONE
+        # producing tokens. A pending chunk and decoding slots are ONE
         # program (the chunk rides the decode step: each weight read
         # once) where the engine's mode allows; the lanes run in it,
         # so every live slot's write page must exist first and grow
         # moves in front — and may preempt the seat that was filling,
         # or the last live slot ---
-        grown = mixed = eng.mixes and eng.has_pending and bool(s.live)
+        grown = mixed = eng.mixes and eng.has_pending and eng.has_lanes
         if mixed:
             with span("sched_grow"):
-                self._grow(s)
-            mixed = eng.has_pending and bool(s.live)
+                self._grow(s, st, events)
+            mixed = eng.has_pending and eng.has_lanes
         if mixed:
-            self._mixed_one(s, st, events)
+            self._decode_one(s, st, events, mixed=True)
         elif eng.has_pending:
             self._prefill_one(s, st, events)
         self._inst["slots"].set(len(s.live))
         self._inst["pages"].set(eng.tables.n_free_pages)
-        if mixed or not s.live:
+        if mixed:
             return
-        if not grown:
+        if eng.has_lanes and not grown:
             with span("sched_grow"):
-                self._grow(s)
-        if s.live:
-            self._decode_one(s, st, events)
+                self._grow(s, st, events)
+        if eng.has_lanes:
+            self._decode_one(s, st, events, mixed=False)
+        else:
+            # nothing rides a further launch: what is in flight lands
+            self._land_flight(s, st, events, "idle")
 
-    def _admit(self, s: _Session, events: list) -> None:
+    def _admit(self, s: _Session, st: dict, events: list) -> None:
         now = lambda: self.clock() - s.t0
         # submits drain BEFORE cancels: a request submitted and then
         # cancelled between two steps must be found in the queue
@@ -1333,6 +1425,9 @@ class ContinuousBatcher:
             cs = self._class_stats(req)
             if cs is not None:
                 cs["n"] += 1
+        if s.flight is not None and self._cancels_seated(s):
+            # a seat cannot be taken back under a step in flight
+            self._land_flight(s, st, events, "drain")
         self._drain_cancels(events)
         # --- shed: the policy's "this deadline is already lost"
         # verdict turns into immediate backpressure (FCFS: never) ---
@@ -1431,16 +1526,19 @@ class ContinuousBatcher:
         t_chunk = self.clock()
         done = self.engine.prefill_step()
         dt = self.clock() - t_chunk
+        self._inst["chunks"].inc()
         self._chunk_issued(s, st, fill_slot, dt)
         st["wall"] += dt
         if done is not None:
+            if self.engine.looks_ahead:
+                self._first_token_issued(s, done[0])
             self._prefill_done(s, *done, events)
 
     def _chunk_issued(self, s: _Session, st: dict, fill_slot: int,
                       dt: float) -> None:
-        """Book one issued chunk (alone or in a mixed step) that took
-        ``dt`` on the host's clock."""
-        self._inst["chunks"].inc()
+        """Book one chunk (alone or in a mixed step) that took ``dt``
+        on the host's clock (the two counters of chunks move where a
+        chunk is ISSUED)."""
         self.est_chunk_s = dt if not self.est_chunk_s \
             else 0.8 * self.est_chunk_s + 0.2 * dt
         st["prefill"] = True
@@ -1481,64 +1579,60 @@ class ContinuousBatcher:
             self._maybe_stop(slot, first)  # prefill's token
             events.append((req, [int(first)]))
 
-    def _mixed_one(self, s: _Session, st: dict, events: list) -> None:
-        """The pending chunk and the decode step over every live slot
-        as ONE program (``PagedEngine.mixed_step``). The step's host
-        time feeds BOTH service-time estimates (the chunk and the
-        step are one wait); a prompt's first token leaves with this
-        step's decode tokens and its slot decodes from the next
-        iteration on."""
-        fill_slot = (self.engine.pending_slots[0]
-                     if self.tracer.enabled else -1)
-        decoders = list(s.live)
-        t_step = self.clock()
-        tokens, done = self.engine.mixed_step()
-        dt = self.clock() - t_step
-        self._inst["mixed"].inc()
-        self._chunk_issued(s, st, fill_slot, dt)
-        self._step_done(s, st, dt, len(decoders))
-        if done is not None:
-            self._prefill_done(s, *done, events)
-        with span("sched_deliver"):
-            self._deliver_tokens(s, tokens, events, decoders)
-
-    def _grow(self, s: _Session) -> None:
-        # --- grow: every live slot's next write page must exist
+    def _grow(self, s: _Session, st: dict, events: list) -> None:
+        # --- grow: every decoding slot's next write page must exist
         # (cached prefixes evict first); starved slots preempt the
         # POLICY's victim (FCFS: youngest seated) ---
         starved = self.engine.grow_slots()
+        if starved and s.flight is not None:
+            # a victim's token in flight belongs to its stream before
+            # it is folded, and the step may end a sequence and free
+            # the very pages that are short
+            self._land_flight(s, st, events, "preempt")
+            starved = self.engine.grow_slots()
         while starved:
             if not self._preempt_one(s):
                 break
             starved = self.engine.grow_slots() if s.live else []
 
-    def _decode_one(self, s: _Session, st: dict, events: list) -> None:
-        # --- one compiled step over every live slot ---
-        if self.engine.tp > 1:
+    def _decode_one(self, s: _Session, st: dict, events: list,
+                    mixed: bool) -> None:
+        """One compiled step over every decoding slot — with the
+        pending chunk in the same program where ``mixed``
+        (``PagedEngine.mixed_step``): the step's host time then feeds
+        BOTH service-time estimates (the chunk and the step are one
+        wait), a prompt's first token leaves with the step's decode
+        tokens and its slot decodes from the next step on.
+
+        An engine that looks ahead launches this step BEHIND the one
+        the last iteration launched and lands THAT one: the events
+        are one step older than the program just issued."""
+        eng = self.engine
+        if eng.tp > 1:
             # the step about to run pays its decode-output psum on
             # the wire: land the MODELED per-chip bytes (precomputed
             # constants — one float add, no device read)
             self._inst["tp_bytes"].inc(
-                self._tp_verify_bytes if self.engine.speculative
+                self._tp_verify_bytes if eng.speculative
                 else self._tp_decode_bytes)
         t_step = self.clock()
-        if self.engine.speculative:
+        if eng.speculative:
             # draft → batched verify → accept: each slot emits
             # 1..draft_len+1 tokens per step; stop checks run per
             # token IN ORDER, so EOS or max_new_tokens mid-burst
             # truncates exactly where sequential decode would have
             # stopped
-            prop0 = self.engine.spec_proposed
-            acc0 = self.engine.spec_accepted
-            emitted = self.engine.spec_step()
+            prop0 = eng.spec_proposed
+            acc0 = eng.spec_accepted
+            emitted = eng.spec_step()
             dt = self.clock() - t_step
             s.decode_time += dt
             self.est_step_s = dt if not self.est_step_s \
                 else 0.8 * self.est_step_s + 0.2 * dt
             st["spec"] = True
             st["wall"] += dt
-            st["prop"] = int(self.engine.spec_proposed - prop0)
-            st["acc"] = int(self.engine.spec_accepted - acc0)
+            st["prop"] = int(eng.spec_proposed - prop0)
+            st["acc"] = int(eng.spec_accepted - acc0)
             if self.tracer.enabled:
                 self.tracer.emit(None, "spec_verify_step",
                                  dur_s=round(dt, 6),
@@ -1546,13 +1640,107 @@ class ContinuousBatcher:
                                  proposed=st["prop"],
                                  accepted=st["acc"],
                                  step=s.n_steps)
+            self._inst["sync_lands"].inc(reason="mode")
             with span("sched_deliver"):
                 self._deliver_bursts(s, emitted, events)
-        else:
-            tokens = self.engine.step()
-            self._step_done(s, st, self.clock() - t_step, len(s.live))
-            with span("sched_deliver"):
-                self._deliver_tokens(s, tokens, events, list(s.live))
+            return
+        if mixed:
+            self._inst["mixed"].inc()
+            self._inst["chunks"].inc()
+        if not eng.looks_ahead:
+            decoders = list(s.live)
+            # the chunk's slot, read only when tracing will use it
+            chunk_slot = None if not mixed else \
+                eng.pending_slots[0] if self.tracer.enabled else -1
+            tokens, done = eng.mixed_step() if mixed \
+                else (eng.step(), None)
+            self._inst["sync_lands"].inc(reason="mode")
+            self._step_landed(s, st, events, chunk_slot, decoders,
+                              tokens, done, self.clock() - t_step)
+            return
+        # (a launch that raises leaves nothing in flight to land)
+        flight, s.flight = s.flight, None
+        ahead, landed = eng.step_ahead(flight, mixed)
+        s.flight = ahead
+        # what the launch settles for the one after it: a slot whose
+        # token in flight is its last by ``max_new_tokens`` or the
+        # ``seq_len`` horizon rides no further launch (tokens
+        # delivered plus tokens in flight are what is counted, so a
+        # ``length`` stop costs no lane)
+        s.left[ahead.active] -= 1
+        if ahead.last:
+            self._first_token_issued(s, ahead.pending["slot"])
+        for slot in np.flatnonzero(ahead.active & (s.left <= 0)):
+            eng.hold(int(slot))
+        if flight is not None:
+            self._inst["lookahead"].inc()
+            self._flight_landed(s, st, events, flight, landed,
+                                self.clock() - t_step)
+
+    def _first_token_issued(self, s: _Session, slot: int) -> None:
+        """The chunk that ends ``slot``'s prompt has been issued, so
+        its first token is on its way (a look-ahead engine): how many
+        further tokens the request may be launched for, and no launch
+        at all where the first is its last."""
+        req = s.filling[slot]
+        room = min(req.max_new_tokens,
+                   self.engine.cfg.seq_len - req.base_len)
+        s.left[slot] = room - len(req.tokens) - 1
+        if s.left[slot] <= 0:
+            self.engine.hold(slot)
+
+    def _land_flight(self, s: _Session, st: dict | None,
+                     events: list | None, reason: str) -> None:
+        """A synchronous point: wait for the step in flight (if any)
+        with nothing launched behind it, so that what follows sees
+        the engine as the synchronous loop would. ``events`` None: its
+        tokens are dropped, not delivered (a drain, the session's
+        end)."""
+        if s.flight is None:
+            return
+        flight, s.flight = s.flight, None
+        t_step = self.clock()
+        _, landed = self.engine.step_ahead(flight, None)
+        self._inst["sync_lands"].inc(reason=reason)
+        if events is None:
+            self._retire_stopped(s)
+            return
+        self._flight_landed(s, st, events, flight, landed,
+                            self.clock() - t_step)
+
+    def _flight_landed(self, s: _Session, st: dict, events: list,
+                       flight, landed: tuple, dt: float) -> None:
+        self._retire_stopped(s)
+        # the lanes it decoded, in the order they came to life (a
+        # slot whose prompt the step before it ended is live by now)
+        decoders = [slot for slot in s.live if flight.active[slot]]
+        chunk = flight.pending
+        self._step_landed(s, st, events,
+                          None if chunk is None else chunk["slot"],
+                          decoders, *landed, dt)
+
+    def _retire_stopped(self, s: _Session) -> None:
+        """The step the stopped slots were riding has landed: their
+        extra tokens are dropped, their pages go back."""
+        stopped, s.stopped = s.stopped, []
+        for slot in stopped:
+            self._inst["wasted"].inc()
+            self.engine.retire(slot)
+
+    def _step_landed(self, s: _Session, st: dict, events: list,
+                     chunk_slot: int | None, decoders: list[int],
+                     tokens: np.ndarray, done: tuple | None,
+                     dt: float) -> None:
+        """Book and deliver one landed decode step that took ``dt`` on
+        the host's clock — a mixed one where ``chunk_slot`` is the
+        slot whose chunk rode it (-1: not looked up)."""
+        if chunk_slot is not None:
+            self._chunk_issued(s, st, chunk_slot, dt)
+        self._step_done(s, st, dt, len(decoders))
+        if done is not None:
+            self._prefill_done(s, *done, events)
+        with span("sched_deliver"):
+            self._deliver_tokens(s, tokens, events, decoders)
 
     def _step_done(self, s: _Session, st: dict, dt: float,
                    n_slots: int) -> None:
@@ -1610,12 +1798,19 @@ class ContinuousBatcher:
     def _deliver_tokens(self, s: _Session, tokens: np.ndarray,
                         events: list, decoders: list[int]) -> None:
         """The step's token to every slot in ``decoders`` (the slots
-        live when it ran) that a cancel has not taken since."""
-        self._drain_cancels(events)
+        live when it ran) that a cancel has not taken since. Under a
+        step in flight a cancelled seat cannot be retired yet: its
+        token is dropped here and the cancel waits for the next
+        iteration, which lands that step first."""
+        cancelled: set[int] = set()
+        if s.flight is None:
+            self._drain_cancels(events)
+        elif self._inbox_cancel:
+            cancelled = self._cancel_ids()
         lps = self.engine.step_logprobs
         for slot in decoders:
             req = s.live.get(slot)
-            if req is None:
+            if req is None or id(req) in cancelled:
                 continue
             if lps is not None:
                 # per-branch sequence logprob — what best_of
@@ -1898,7 +2093,7 @@ class ContinuousBatcher:
             # escaping the loop still closes the watch — the policy
             # only fires on clean exits by design
             with self._sentinel:
-                while s.queue or s.live or s.filling:
+                while s.queue or s.live or s.filling or s.flight:
                     self.step()
                     if not s.live and not s.filling and s.queue:
                         # idle until the next arrival

@@ -278,6 +278,20 @@ def _merge_partials(a: tuple, b: tuple) -> tuple:
     return oa * mv(wa) + ob * mv(wb), m, la * wa + lb * wb
 
 
+class _Flight(NamedTuple):
+    """A launched decode step that has not landed: what
+    ``PagedEngine._landed`` needs to read it back and book it."""
+    active: np.ndarray      # (max_slots,) bool: the lanes that decode
+    fetch: tuple            # device results to read: tokens first
+    pending: dict | None    # the chunk's prefill where it rode
+    last: bool              # ... and was its prompt's last
+
+    def carries(self, slot: int) -> bool:
+        """Whether the step decodes ``slot`` or ends its prompt."""
+        return bool(self.active[slot]) or (
+            self.last and self.pending["slot"] == slot)
+
+
 class PagedEngine:
     """Single-compile continuous-batching decode over a paged KV pool
     with an optional prompt-prefix cache.
@@ -300,7 +314,15 @@ class PagedEngine:
     (lora lane ids are per batch row, and the mixed program has one),
     ``tp > 1`` (the shard_map wrappers fix the operand lists) and
     ``prefill_only`` (nothing decodes) keep two programs an
-    iteration. ``admit`` is the one-shot
+    iteration. **Which modes look ahead** (``self.looks_ahead``,
+    decided beside it): those that ride the mixed program, less
+    ``structured`` (its mask follows the token). Such an engine's
+    programs with lanes take the last program's ``tokens`` as an
+    operand and read a slot's last token from it where the buffer's
+    ``known`` says the host has not read it yet, so the batcher may
+    launch a step behind one still in flight (:meth:`step_ahead`;
+    :meth:`step` and :meth:`mixed_step` stay synchronous: launch,
+    then land at once). ``admit`` is the one-shot
     convenience (seat + drain this request's chunks). ``cache_dtype=
     "int8"`` stores quantized pages (``_quantize_kv`` — the same
     per-(token, head) scheme as the dense cache). ``temperature=0``
@@ -793,9 +815,28 @@ class PagedEngine:
         # option chooses it). The tables become views of it; the
         # chunk's ids and (start, s0, slot) have their slices in every
         # engine, a mode's small operands where the mode is on.
+        # whether a pending chunk rides the decode step as ONE program
+        # (mixed_step), decided once from the engine's own mode: the
+        # modes whose chunk and decode programs differ in more than
+        # their trailing VALUE operands keep two programs an iteration
+        # (the class docstring lists them)
+        self.mixes = not (speculative or self.parallel or self.lora
+                          or self.tp > 1 or self.prefill_only)
+        # whether the scheduler may launch a step BEFORE it has read
+        # the last one's tokens (one step in flight), decided the same
+        # way: the modes that need the token on the host before the
+        # next launch (the legality mask follows it; the drafter, the
+        # branches' logprobs and forks, and the two-program modes
+        # above) land every step at once
+        self.looks_ahead = self.mixes and not self.structured
         fields = self.tables.operand_fields()
         fields["chunk_ids"] = (self.chunk_tokens,)
         fields["chunk"] = (3,)
+        if self.looks_ahead:
+            # per slot: the host knows the slot's last token (the
+            # buffer's ``last_ids``); 0: it is still on the device, in
+            # the ``tokens`` the last program returned
+            fields["known"] = (max_slots,)
         if decode_backend == "pallas":
             n_walk = n_pages - 1
             fields.update(
@@ -825,6 +866,15 @@ class PagedEngine:
             else np.zeros(max_slots, np.int32))
         # host-to-device transfers issued for step operands (_put)
         self.operand_puts = 0
+        # the last program's ``tokens``, left on the device like the
+        # key (a look-ahead engine's lanes read a slot's last token
+        # from it where the host does not know it yet), and the newest
+        # launched step while it has not landed
+        self._tokens = None
+        self._flight: _Flight | None = None
+        if self.looks_ahead:
+            self._op["known"][:] = 1
+            self._tokens = jnp.zeros((max_slots,), jnp.int32)
         # where a step's operands go: replicated over the mesh at
         # tp > 1 (the key too: committed like the keys the programs
         # hand back, so the first call is no cache entry of its own)
@@ -897,13 +947,6 @@ class PagedEngine:
         # collapse contract as n_ref_lanes for the prefix cache)
         self.speculative = bool(speculative)
         self.draft_len = draft_len
-        # whether a pending chunk rides the decode step as ONE program
-        # (mixed_step), decided once from the engine's own mode: the
-        # modes whose chunk and decode programs differ in more than
-        # their trailing VALUE operands keep two programs an iteration
-        # (the class docstring lists them)
-        self.mixes = not (self.speculative or self.parallel or self.lora
-                          or self.tp > 1 or self.prefill_only)
         # tree speculative decoding: the drafter proposes a TREE of
         # candidate branches and the verify step scores every node in
         # the same single pass through ancestor-only visibility masks
@@ -982,8 +1025,9 @@ class PagedEngine:
         final-position logits ``fork()`` samples sibling branches'
         first tokens from.
 
-        **With ``lanes``** (a dict: empty, or structured mode's
-        ``smask`` for all slots, the chunk's row among them) the
+        **With ``lanes``** (a dict: structured mode's ``smask`` for
+        all slots, the chunk's row among them; a look-ahead engine's
+        ``prev``, the last program's ``tokens``; or empty) the
         decode lanes RIDE the chunk, on the SAME buffer: this
         is then the MIXED program, the second and last variant this
         function compiles to (``lanes`` is None or a dict: pytree
@@ -994,7 +1038,8 @@ class PagedEngine:
         only what is per sequence stays split (:func:`_ride`): the
         K/V writes and the two attentions, the conv state, the picks.
         Returns ``(key, chunk's token, lanes' tokens, pool_k, pool_v[,
-        state])``."""
+        state])``; where the chunk is its prompt's last, its token
+        also stands in its slot's lane of the lanes' tokens."""
         ops = self.operands.unpack(operands)
         start, s0, slot = ops["chunk"]
         # ONE split an iteration, of the key the last program left
@@ -1014,7 +1059,8 @@ class PagedEngine:
         pieces = self._chunk_pieces(params, ops["chunk_ids"][None], start,
                                     s0, ops["tables"][slot], slot)
         if lanes is not None:
-            pieces = _ride(pieces, self._lane_pieces(params, ops))
+            pieces = _ride(pieces, self._lane_pieces(
+                params, ops, lanes.get("prev")))
         x, pool_k, pool_v, state, moe_counts = self._layers(
             params, pieces, pool_k, pool_v, state, lora)
         # the head's one product: the prompt's last real row, and in
@@ -1041,6 +1087,13 @@ class PagedEngine:
             tok = self._pick(sub, _mask_logits(logits[:1], smask1))
             tokens = self._pick(sub_lanes,
                                 _mask_logits(logits[1:], smask))
+            # a prompt's LAST chunk leaves its token in the slot's
+            # lane: the next program's lanes may read it there before
+            # the host has (a lane that decoded is never the chunk's)
+            ends = start + self.chunk_tokens >= s0
+            tokens = jnp.where(
+                ends & (jnp.arange(tokens.shape[0]) == slot),
+                tok[0], tokens)
             outs = (rng, tok, tokens, pool_k, pool_v)
             return outs if state is None else outs + (state,)
         if self.model is not None:
@@ -1070,7 +1123,9 @@ class PagedEngine:
         ONE operand buffer; ``rng`` is the engine's key, split here,
         and the new key goes back first among the results. The
         trailing operands exist only on their modes: the slot state,
-        structured mode's mask, the adapter stacks."""
+        structured mode's mask, the adapter stacks, and LAST a
+        look-ahead engine's ``prev`` (the last program's ``tokens``:
+        ``_lane_pieces``)."""
         ops = self.operands.unpack(operands)
         rng, sub = jax.random.split(rng)
         # the adapter stacks strip from the END first (they append
@@ -1083,7 +1138,9 @@ class PagedEngine:
             state, extra = extra[0], extra[1:]
         # structured: the (max_slots, vocab) legality mask
         smask = extra[0] if self.structured else None
-        pieces = self._lane_pieces(params, ops)
+        # a look-ahead engine: the last program's tokens, last of all
+        prev = extra[-1] if self.looks_ahead else None
+        pieces = self._lane_pieces(params, ops, prev)
         x, pool_k, pool_v, state, moe_counts = self._layers(
             params, pieces, pool_k, pool_v, state, lora)
         logits = self._logits(params, pieces.rows(x))
@@ -1257,17 +1314,22 @@ class PagedEngine:
         return _Pieces(x, positions[None], (positions < s0)[None],
                        write, read, conv, rows)
 
-    def _lane_pieces(self, params, ops: dict) -> "_Pieces":
+    def _lane_pieces(self, params, ops: dict, prev=None) -> "_Pieces":
         """What is the decode LANES' of a program (:class:`_Pieces`):
         every slot's last token embedded at its own depth ``(slots, 1,
         d)``, the write of its K/V at ``lengths``, the slots'
         attention over the pool, the live slots' conv state shifted.
         ``ops``: the operand buffer unpacked — the tables and, on the
-        pallas backend, the compacted live-page walk ``work_*``."""
+        pallas backend, the compacted live-page walk ``work_*``.
+        ``prev`` (a look-ahead engine): the ``tokens`` the last
+        program returned, where a slot's last token is when the
+        buffer's ``known`` says the host had not read it yet."""
         cfg, ps = self.cfg, self.page_size
         tables, lengths, refs, page_pos, last_ids = (
             ops[name] for name in ("tables", "lengths", "refs",
                                    "page_pos", "last_ids"))
+        if prev is not None:
+            last_ids = jnp.where(ops["known"] != 0, last_ids, prev)
         active = ops["active"] != 0
         n_slots = last_ids.shape[0]
         n_heads_l = cfg.n_heads // self.tp    # local heads (tp shard)
@@ -1974,6 +2036,12 @@ class PagedEngine:
         return bool(self._pending)
 
     @property
+    def has_lanes(self) -> bool:
+        """Whether a decode step launched now would decode anything:
+        a slot is active and not held (:meth:`hold`)."""
+        return bool(self.tables.active.any())
+
+    @property
     def pending_chunk_count(self) -> int:
         """Prefill chunks still queued across every in-flight
         admission — the "work ahead of you" term in the front door's
@@ -2028,6 +2096,7 @@ class PagedEngine:
         self._count_rows(None, chunk=p)
         if not self._chunk_issued(p):
             return None
+        self.tables.activate(p["slot"])
         # the prompt's LAST chunk: its token is read back here, which
         # waits for the chunk program the span above only dispatched —
         # a device wait, named so that it is not taken for host work
@@ -2102,10 +2171,11 @@ class PagedEngine:
         return True
 
     def _prefill_done(self, p: dict, first: int) -> tuple[int, int]:
-        """A prompt's last chunk has given its token: the slot decodes
-        from the next step on, its full prompt pages enter the prefix
+        """A prompt's last chunk has given its token (its slot was
+        activated when the chunk was issued): the host knows the
+        slot's last token now, its full prompt pages enter the prefix
         index."""
-        self.tables.activate(p["slot"], first)
+        self.tables.last_ids[p["slot"]] = first
         self.tables.register_prefix(p["slot"], p["ids"][:p["s0"]])
         if self._drafter is not None:
             self._drafter.observe(p["slot"], [first])
@@ -2384,37 +2454,11 @@ class PagedEngine:
     def step(self) -> np.ndarray:
         """One decode step over every ACTIVE slot; advances lengths/
         last_ids for those and returns the (max_slots,) token ids
-        (garbage at inactive or mid-prefill slots)."""
-        active = self._decoding("step")
-        operands, smask = self._lane_operands()
-        if smask is not None:
-            operands += (smask,)
-        with span("decode_step"):
-            outs = self._decode_jit(
-                self.params, self.pool["k"], self.pool["v"], *operands,
-                *self._lora_operands())
-            self._rng, outs = outs[0], outs[1:]
-            if self.model is not None:
-                tokens, pool_k, pool_v, self.slot_state, counts = outs
-                self.pool = {"k": pool_k, "v": pool_v}
-                # ONE batched device->host sync for both results
-                tokens, counts = jax.device_get((tokens, counts))
-                tokens = np.asarray(tokens)
-                self._count_experts(counts)
-                self._count_rows(active)
-            elif self.parallel:
-                tokens, lps, pool_k, pool_v = outs
-                self.pool = {"k": pool_k, "v": pool_v}
-                # ONE batched device->host sync for both results
-                tokens, lps = jax.device_get((tokens, lps))
-                tokens = np.asarray(tokens)
-                self.step_logprobs = np.asarray(lps)
-            else:
-                tokens, pool_k, pool_v = outs
-                self.pool = {"k": pool_k, "v": pool_v}
-                tokens = np.asarray(tokens)
-        self._advance(active, tokens)
-        return tokens
+        (garbage at inactive or mid-prefill slots). Synchronous: the
+        launch is followed at once by the land (what
+        :meth:`step_ahead` keeps an iteration apart), the two in the
+        one ``decode_step`` span."""
+        return self._launch_and_land(False)[0]
 
     def mixed_step(self) -> tuple[np.ndarray, tuple[int, int] | None]:
         """ONE program for the oldest pending prefill's next chunk AND
@@ -2423,50 +2467,174 @@ class PagedEngine:
         :meth:`step` would do, with one pass over the weights, one
         transfer, one launch, one rng split and one read-back. Needs
         a pending chunk, and an engine whose mode rides
-        (``self.mixes``).
+        (``self.mixes``). Synchronous, as :meth:`step` is.
         Returns ``(tokens, done)``: the (max_slots,) token ids as
         :meth:`step` gives them, and ``(slot, first_token)`` when the
         chunk was its prompt's last, else None — that slot joins the
         decode lanes from the NEXT step on (the two-program iteration
         decodes it in the same one)."""
-        if not (self.mixes and self._pending):
+        return self._launch_and_land(True)
+
+    def _launch_and_land(self, mixed: bool) -> tuple:
+        """A synchronous step: ``(tokens, done)``."""
+        operands = self._launch_operands(mixed)
+        with span("decode_step"):
+            flight = self._dispatch(mixed, operands)
+            got = jax.device_get(flight.fetch)
+        return self._landed(flight, got)
+
+    def step_ahead(self, flight: "_Flight | None", mixed: bool | None
+                   ) -> tuple["_Flight | None", tuple | None]:
+        """The look-ahead loop's iteration (``self.looks_ahead``):
+        launch the next program — the mixed one, the plain decode
+        step, or with ``mixed`` None nothing — BEHIND ``flight``, the
+        step launched by the last call, and only then wait for
+        ``flight``'s tokens (None: nothing is in flight). The device
+        starts the new program the moment ``flight`` ends, so what the
+        host does between two calls runs beside a program, not between
+        two. Returns ``(the new flight, flight's (tokens, done))``,
+        either None where there was none. The wait and the dispatch in
+        front of it are the iteration's ONE ``decode_step`` span.
+
+        What a launch takes for granted of the step in front of it:
+        its lanes' lengths (+1 each: bumped at the launch), its tokens
+        (read on the device, from the ``tokens`` it returns), and a
+        slot whose prompt's last chunk it carried (active from the
+        launch on, its first token in its lane of ``tokens``). What
+        the host must decide before a launch — a slot whose token in
+        flight is its last — it says with :meth:`hold`."""
+        operands = None if mixed is None else \
+            self._launch_operands(mixed)
+        if flight is None:
+            return self._dispatch(mixed, operands), None
+        with span("decode_step"):
+            ahead = None if mixed is None else \
+                self._dispatch(mixed, operands)
+            # the step's ONE batched device->host sync
+            got = jax.device_get(flight.fetch)
+        return ahead, self._landed(flight, got)
+
+    def hold(self, slot: int) -> None:
+        """Keep a seated slot out of every further launch (its pages
+        stay its own): its token in flight is its last, or it has
+        stopped and only waits for the step in flight to land before
+        :meth:`retire`."""
+        self.tables.active[slot] = False
+
+    def _launch_operands(self, mixed: bool) -> tuple:
+        """The host's work before a launch, under its own spans: the
+        chunk written into the buffer (a mixed step), the ONE
+        transfer, and what rides beside the buffer. Returns
+        ``(active, pending, args, kwargs)`` for :meth:`_dispatch`."""
+        if mixed and not (self.mixes and self._pending):
             raise RuntimeError(
                 "mixed_step() needs a pending prefill chunk and an "
                 "engine whose mode rides the mixed program "
                 "(PagedEngine.mixes)")
-        active = self._decoding("mixed_step")
-        p = self._pending[0]
-        with span("prefill_args"):
-            self._fill_chunk(p)
+        active = self._decoding("mixed_step" if mixed else "step")
+        p = self._pending[0] if mixed else None
+        if mixed:
+            with span("prefill_args"):
+                self._fill_chunk(p)
         # the ONE transfer; structured mode's mask for all slots (the
-        # chunk's row among them) is the program's ``lanes``
+        # chunk's row among them) is the mixed program's ``lanes``
         operands, smask = self._lane_operands()
+        if not mixed:
+            beside = () if smask is None else (smask,)
+            beside += self._lora_operands()
+            if self.looks_ahead:
+                beside += (self._tokens,)
+            return active, p, operands + beside, {}
         lanes = {} if smask is None else {"smask": smask}
-        # the iteration's ONE decode_step span (dispatch + read-back),
-        # as a plain step's: the readers that divide decoded tokens by
-        # its count see every iteration that decodes
-        with span("decode_step"):
-            outs = self._chunk_jit(
-                self.params, self.pool["k"], self.pool["v"], *operands,
-                lanes=lanes)
-            self._rng, tok, tokens, pool_k, pool_v = outs[:5]
-            self.pool = {"k": pool_k, "v": pool_v}
+        if self.looks_ahead:
+            lanes["prev"] = self._tokens
+        return active, p, operands, {"lanes": lanes}
+
+    def _dispatch(self, mixed: bool, operands: tuple) -> "_Flight":
+        """Launch the program and book what is predictable of it: the
+        pool, the key, the slot state and ``tokens`` rebound to its
+        (not yet ready) results, the lanes' lengths bumped (the
+        program writes at the old length), the chunk counted and, its
+        prompt's last, its slot active from the next launch on."""
+        active, p, args, kwargs = operands
+        jit = self._chunk_jit if mixed else self._decode_jit
+        outs = jit(self.params, self.pool["k"], self.pool["v"], *args,
+                   **kwargs)
+        self._rng, outs = outs[0], outs[1:]
+        last = False
+        if mixed:
+            tok, tokens, pool_k, pool_v = outs[:4]
             if self.slot_state is not None:
-                self.slot_state = outs[5]
+                self.slot_state = outs[4]
+            fetch = (tokens,)
             self.mixed_steps += 1
-            self._count_rows(active, chunk=p)
+        elif self.model is not None:
+            tokens, pool_k, pool_v, self.slot_state, counts = outs
+            fetch = (tokens, counts)
+        elif self.parallel:
+            tokens, lps, pool_k, pool_v = outs
+            fetch = (tokens, lps)
+        else:
+            tokens, pool_k, pool_v = outs
+            fetch = (tokens,)
+        self.pool = {"k": pool_k, "v": pool_v}
+        self._count_rows(active, chunk=p)
+        self.tables.launched(active)
+        if mixed:
             last = self._chunk_issued(p)
-            # ONE device->host sync; the chunk's token only where it
-            # is the prompt's first
             if last:
-                tok, tokens = jax.device_get((tok, tokens))
-            tokens = np.asarray(tokens)
+                # the chunk's token only where it is the prompt's
+                # first; the slot rides the next launch
+                fetch += (tok,)
+                self.tables.activate(p["slot"])
+        flight = self._flight = _Flight(active, fetch, p, last)
+        if self.looks_ahead:
+            self._tokens = tokens
+            self._on_device(flight)
+        return flight
+
+    def _on_device(self, flight: "_Flight", known: int = 0) -> None:
+        """From its launch until it lands, a step's lanes (and the
+        slot whose prompt it ended) have their last token on the
+        device: the buffer's ``known`` says so to the next launch."""
+        self._op["known"][flight.active] = known
+        if flight.last:
+            self._op["known"][flight.pending["slot"]] = known
+
+    def _landed(self, flight: "_Flight", got: tuple) -> tuple:
+        """Book a landed step from what was read back of it: the
+        lanes' last tokens, the experts' counts or the branches'
+        logprobs, the drafter's and the cursors' view, and the
+        prompt's first token where the chunk was its last. Returns
+        ``(tokens, done)`` as :meth:`mixed_step` does."""
+        active, p = flight.active, flight.pending
+        tokens = np.asarray(got[0])
+        if p is None and self.model is not None:
+            self._count_experts(got[1])
+        elif p is None and self.parallel:
+            self.step_logprobs = np.asarray(got[1])
         done = None
-        if last:
+        if flight.last:
             # no wait left in it: the token came with the lanes'
             with span("prefill_finish"):
-                done = self._prefill_done(p, int(np.asarray(tok)[0]))
-        self._advance(active, tokens)
+                done = self._prefill_done(p, int(np.asarray(got[-1])[0]))
+        with span("decode_advance"):
+            self.tables.landed(active, tokens)
+            if self._drafter is not None or self.structured:
+                for slot in np.flatnonzero(active):
+                    seen = [int(tokens[slot])]
+                    if self._drafter is not None:
+                        self._drafter.observe(int(slot), seen)
+                    if self.structured:
+                        self._cursors.observe(int(slot), seen)
+        newer = self._flight
+        if newer is flight:
+            newer = self._flight = None
+        if self.looks_ahead:
+            self._on_device(flight, known=1)
+            if newer is not None:
+                # a step was launched behind this one
+                self._on_device(newer)
         return tokens, done
 
     def _decoding(self, entry: str) -> np.ndarray:
@@ -2500,17 +2668,6 @@ class PagedEngine:
             smask = self._put(self._cursors.mask) \
                 if self.structured else None
             return operands, smask
-
-    def _advance(self, active: np.ndarray, tokens: np.ndarray) -> None:
-        with span("decode_advance"):
-            for slot in np.flatnonzero(active):
-                self.tables.advance(int(slot), int(tokens[slot]))
-                if self._drafter is not None:
-                    self._drafter.observe(int(slot),
-                                          [int(tokens[slot])])
-                if self.structured:
-                    self._cursors.observe(int(slot),
-                                          [int(tokens[slot])])
 
     def spec_step(self) -> dict[int, list[int]]:
         """One speculative decode step over every ACTIVE slot: draft

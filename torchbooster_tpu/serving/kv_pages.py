@@ -860,14 +860,17 @@ class BlockTables:
         self.last_ids[slot] = 0
         return self.tables[slot, :n_total].copy(), n_matched
 
-    def activate(self, slot: int, first_id: int) -> None:
+    def activate(self, slot: int, first_id: int | None = None) -> None:
         """Mark a seated slot decode-ready (prefill done); ``first_id``
-        seeds its decode input (the prefill's sampled token)."""
+        seeds its decode input (the prefill's sampled token) — None
+        where the prompt's last chunk was only launched and its token
+        is still on the device (``last_ids`` follows when it lands)."""
         if not self.lengths[slot] or self.active[slot]:
             raise ValueError(
                 f"slot {slot} is not seated-and-inactive")
         self.active[slot] = True
-        self.last_ids[slot] = first_id
+        if first_id is not None:
+            self.last_ids[slot] = first_id
 
     def fork(self, parent_slot: int, n_children: int) -> list[int]:
         """Fork ``parent_slot`` into ``n_children`` sibling slots for
@@ -1091,6 +1094,18 @@ class BlockTables:
         position ``lengths[slot]`` by the step that produced it)."""
         self.lengths[slot] += 1
         self.last_ids[slot] = token_id
+
+    def launched(self, active: np.ndarray) -> None:
+        """The first half of :meth:`advance`, for all the slots of a
+        decode step at its LAUNCH: the program writes each one's token
+        at ``lengths`` and the next write lands one further — known
+        before the token is."""
+        self.lengths[active] += 1
+
+    def landed(self, active: np.ndarray, tokens: np.ndarray) -> None:
+        """The second half, when the step's ``tokens`` have been read
+        back: each decoded slot's most recent token."""
+        self.last_ids[active] = tokens[active]
 
     def retire(self, slot: int) -> None:
         """Release the slot: every page's refcount drops by one; pages
