@@ -273,6 +273,251 @@ def test_instrument_step_adds_zero_compiles(global_obs):
 
 
 # =====================================================================
+# compile accounting (recompile.install_compile_listener)
+# =====================================================================
+
+BENCH = Path(__file__).resolve().parent.parent / "benchmark"
+SETUP_READERS = ("setup_lower_s", "setup_load_s", "setup_compile_s",
+                 "setup_programs", "setup_build_s")
+
+
+@pytest.fixture
+def compiles():
+    """The default registry switched on as a program does
+    (``set_enabled``, which installs the listener), reset around."""
+    registry = obs.get_registry()
+    was = registry.enabled
+    registry.reset()
+    obs.set_enabled(True)
+    yield registry
+    registry.enabled = was
+    registry.reset()
+
+
+@pytest.fixture
+def own_cache(tmp_path):
+    """A persistent compilation cache of the test's own that keeps
+    every executable; the process's cache settings restored after."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    was = {name: getattr(jax.config, name) for name in names}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "jc"))
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        for name, value in was.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+
+
+def _booked(registry, programs) -> dict:
+    """``{(stage, program, span): count}`` of ``compile_seconds`` for
+    the named programs."""
+    out = {}
+    for metric in registry.metrics():
+        if metric.name != "compile_seconds":
+            continue
+        for key, series in metric.series_items():
+            labels = dict(key)
+            if labels["program"] in programs:
+                out[labels["stage"], labels["program"],
+                    labels["span"]] = series.read()[0]
+    return out
+
+
+def _nested_program():
+    """A program that calls a jitted function and jnp ops: each is
+    traced inside the program's own trace, none lowered alone."""
+
+    @jax.jit
+    def acc_inner(x):
+        return jnp.sin(x) * 2
+
+    def acc_outer(x):
+        return acc_inner(x) @ x.T + jnp.cos(x).sum()
+
+    return jax.jit(acc_outer)
+
+
+NESTED = ("acc_outer", "acc_inner", "sin", "cos", "matmul")
+
+
+def test_a_program_books_one_trace_lower_and_compile(compiles, own_cache):
+    x = np.ones((4, 4), np.float32)
+    events = []
+    unsub = obs.span_events_subscribe(events.append)
+    try:
+        _nested_program()(x).block_until_ready()
+    finally:
+        unsub()
+    assert _booked(compiles, NESTED) == {
+        ("trace", "acc_outer", "none"): 1,
+        ("lower", "acc_outer", "none"): 1,
+        ("compile", "acc_outer", "none"): 1}
+    # the same three on the span sinks: Chrome X slices in order
+    mine = [e for e in events
+            if e["cat"] == "compile" and e["args"]["program"] == "acc_outer"]
+    assert [e["name"] for e in mine] == [
+        "compile/trace", "compile/lower", "compile/compile"]
+    assert all(e["ph"] == "X" and e["cat"] == "compile" for e in mine)
+    assert all(a["ts"] + a["dur"] <= b["ts"] + 1
+               for a, b in zip(mine, mine[1:]))
+
+
+def test_a_compile_carries_the_span_that_caused_it(compiles, own_cache):
+    x = np.ones((4, 4), np.float32)
+    events = []
+    unsub = obs.span_events_subscribe(events.append)
+    try:
+        with obs.span("decode_step"):
+            _nested_program()(x).block_until_ready()
+    finally:
+        unsub()
+    assert set(_booked(compiles, NESTED)) == {
+        (stage, "acc_outer", "decode_step")
+        for stage in ("trace", "lower", "compile")}
+    # JAX's time.time() stamps moved onto the spans' perf_counter
+    # timebase: every compile slice lies inside the span round it
+    (outer,) = [e for e in events if e["name"] == "decode_step"]
+    for e in events:
+        if e.get("cat") == "compile":
+            assert outer["ts"] - 1000 <= e["ts"]
+            assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1000
+
+
+def test_a_persistent_cache_hit_books_load_not_compile(compiles,
+                                                       own_cache):
+    x = np.ones((4, 4), np.float32)
+    _nested_program()(x).block_until_ready()
+    compiles.reset()
+    jax.clear_caches()
+    _nested_program()(x).block_until_ready()
+    # traced and lowered again (the key is the lowered module), then
+    # the executable read back
+    assert _booked(compiles, NESTED) == {
+        ("trace", "acc_outer", "none"): 1,
+        ("lower", "acc_outer", "none"): 1,
+        ("load", "acc_outer", "none"): 1}
+
+
+def test_registry_off_books_nothing_and_emits_nothing():
+    from jax._src import monitoring
+
+    from torchbooster_tpu.observability import recompile
+
+    registry = obs.get_registry()
+    was = registry.enabled
+    registry.reset()
+    try:
+        obs.set_enabled(True)
+        obs.set_enabled(True)             # installed once, however often
+        obs.set_enabled(False)
+        events = []
+        unsub = obs.span_events_subscribe(events.append)
+        try:
+            jax.jit(lambda x: x * 3 + 1)(np.ones(5, np.float32))
+            with obs.span("serving_build") as s:
+                assert s is obs.span("decode_step")   # the shared no-op
+        finally:
+            unsub()
+    finally:
+        registry.enabled = was
+    assert events == []
+    assert registry.snapshot() == {}
+    assert monitoring.get_event_time_span_listeners().count(
+        recompile._on_time_span) == 1
+
+
+@pytest.mark.parametrize("snapshot,want", [
+    # a warm start: every executable a load, one compile under the build
+    ({"compile_seconds{program=_decode_fn}{span=sched_step}"
+      "{stage=trace}_sum": 0.5,
+      "compile_seconds{program=_decode_fn}{span=sched_step}"
+      "{stage=trace}_count": 1.0,
+      "compile_seconds{program=_decode_fn}{span=sched_step}"
+      "{stage=lower}_sum": 1.25,
+      "compile_seconds{program=_decode_fn}{span=sched_step}"
+      "{stage=lower}_count": 1.0,
+      "compile_seconds{program=_decode_fn}{span=sched_step}"
+      "{stage=load}_sum": 0.75,
+      "compile_seconds{program=_decode_fn}{span=sched_step}"
+      "{stage=load}_count": 1.0,
+      "compile_seconds{program=zeros}{span=serving_build}"
+      "{stage=lower}_sum": 0.125,
+      "compile_seconds{program=zeros}{span=serving_build}"
+      "{stage=lower}_count": 2.0,
+      "compile_seconds{program=zeros}{span=serving_build}"
+      "{stage=load}_sum": 0.25,
+      "compile_seconds{program=zeros}{span=serving_build}"
+      "{stage=load}_count": 2.0,
+      "span_seconds{name=serving_build}_sum": 3.0,
+      "span_seconds{name=serving_build}_count": 1.0},
+     {"setup_lower_s": 1.875, "setup_load_s": 1.0,
+      "setup_compile_s": 0.0, "setup_programs": 3.0,
+      "setup_build_s": 2.625}),
+    # a cold start: compiles, no load
+    ({"compile_seconds{program=build}{span=none}{stage=compile}_sum": 9.5,
+      "compile_seconds{program=build}{span=none}{stage=compile}_count":
+          1.0,
+      "compile_seconds{program=build}{span=none}{stage=trace}_sum": 0.5,
+      "compile_seconds{program=build}{span=none}{stage=trace}_count": 1.0,
+      "span_seconds{name=serving_build}_sum": 2.0},
+     {"setup_lower_s": 0.5, "setup_load_s": 0.0,
+      "setup_compile_s": 9.5, "setup_programs": 1.0,
+      "setup_build_s": 2.0}),
+    # the parent: spans, no compile_seconds at all
+    ({"span_seconds{name=decode_step}_sum": 4.0,
+      "span_seconds{name=serving_build}_sum": 2.0},
+     dict.fromkeys(SETUP_READERS)),
+], ids=["warm", "cold", "parent"])
+def test_the_start_up_readers(snapshot, want):
+    layers = {"registry_open": snapshot,
+              "registry_close": {**snapshot, "extra": 1.0}}
+    readers = {name: _reader(name) for name in SETUP_READERS}
+    got = {name: r.read(f"{name}.setup", layers)
+           for name, r in readers.items()}
+    assert got == pytest.approx(want)
+    # none reads anything but the registry when the window opened
+    assert all(r.read(f"{name}.setup", {"registry_close": snapshot})
+               is None for name, r in readers.items())
+
+
+def _reader(name: str):
+    """``benchmark/layer_metrics/<name>.py`` loaded as the harness
+    loads it (its directory on the path, for the shared helper)."""
+    import importlib.util
+
+    folder = BENCH / "layer_metrics"
+    if str(folder) not in sys.path:
+        sys.path.append(str(folder))
+    spec = importlib.util.spec_from_file_location(
+        f"reader_{name}", folder / f"{name}.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_the_start_up_metrics_are_the_serve_cells():
+    manifest = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    serve = [c["name"] for c in manifest["workloads"]
+             if c["traffic"].startswith("serve")]
+    entries = {m["name"]: m for m in manifest["per_layer"]
+               if m["name"].startswith("setup_")}
+    assert sorted(entries) == sorted(f"{n}.setup" for n in SETUP_READERS)
+    for entry in entries.values():
+        assert entry["workloads"] == serve
+        assert entry["moves"] == "setup_s" and entry["better"] == "lower"
+        assert (BENCH / "layer_metrics" /
+                (entry["name"].split(".")[0] + ".py")).exists()
+
+
+# =====================================================================
 # device stats
 # =====================================================================
 
@@ -280,27 +525,6 @@ def test_record_memory_gauges_cpu_is_clean_noop(reg):
     # CPU devices report no memory_stats: no gauges, no crash
     out = obs.record_memory_gauges(reg)
     assert out == {}
-
-
-def test_xla_flops_and_flop_check(caplog):
-    measured = obs.xla_flops(lambda x: x @ x, jnp.ones((64, 64)))
-    assert measured == pytest.approx(2 * 64 ** 3)
-    assert obs.flop_check("mm", 2 * 64 ** 3, measured) == 1.0
-    import logging
-
-    with caplog.at_level(logging.WARNING):
-        ratio = obs.flop_check("mm", 64 ** 3, measured)   # formula 2x off
-    assert ratio == pytest.approx(2.0)
-    assert any("disagree" in r.message for r in caplog.records)
-    # missing measurement -> None, no warning
-    assert obs.flop_check("mm", 1.0, None) is None
-
-
-def test_cost_analysis_returns_flat_dict():
-    compiled = jax.jit(lambda x: x @ x).lower(jnp.ones((8, 8))).compile()
-    costs = obs.cost_analysis(compiled)
-    assert isinstance(costs, dict)
-    assert costs.get("flops", 0) > 0
 
 
 # =====================================================================

@@ -15,7 +15,9 @@ one scheduler iteration"):
   ``serving_ttft_seconds`` per request, each observed once a request,
   under preemption and re-admission too;
 - ``enable_compile_cache`` ends in the metadata version's subdirectory
-  (placement itself: tests/test_chip_smoke.py).
+  (placement itself: tests/test_chip_smoke.py);
+- ``ServingConfig.make`` closes one ``serving_build`` span, and none
+  while the registry is off.
 """
 import re
 
@@ -225,6 +227,12 @@ def registry():
         reg.reset()
 
 
+def _spans_into(events: list):
+    """A sink that keeps the span events (a compile JAX reports meets
+    the same sinks as a ``compile/<stage>`` slice)."""
+    return lambda e: events.append(e) if e["cat"] == "span" else None
+
+
 def _engine(params, cfg, **kw):
     from torchbooster_tpu.serving import PagedEngine
 
@@ -254,7 +262,7 @@ def test_one_step_closes_the_span_tree_once(registry, mixes, ahead):
             b.step()
         b.submit(Request(prompt=np.arange(2, 8), max_new_tokens=8))
         chunks0, unsubscribe = b.engine.prefill_chunks, \
-            obs.span_events_subscribe(events.append)
+            obs.span_events_subscribe(_spans_into(events))
         try:
             # ONE iteration that seats, prefills the prompt's one and
             # LAST chunk (so prefill_finish closes too) AND decodes
@@ -267,7 +275,7 @@ def test_one_step_closes_the_span_tree_once(registry, mixes, ahead):
             # next launch: the same tree but for who finishes
             assert len(b._s.live) == 1 and b._s.flight is not None
             after: list[dict] = []
-            unsubscribe = obs.span_events_subscribe(after.append)
+            unsubscribe = obs.span_events_subscribe(_spans_into(after))
             try:
                 b.step()
             finally:
@@ -405,3 +413,39 @@ def test_tokens_carry_the_step_that_made_them():
     slots = {e["step"]: e["slots"] for e in tracer.events(None)
              if e["kind"] == "decode_step"}
     assert by_step == slots
+
+
+@pytest.mark.parametrize("on", [True, False], ids=["on", "off"])
+def test_the_serving_build_closes_one_span(on):
+    """``ServingConfig.make`` is ONE ``serving_build`` span, and the
+    compiles inside it say so; with the registry off, the shared no-op
+    span: nothing booked, nothing on the sinks."""
+    from torchbooster_tpu.config import ServingConfig
+
+    params, cfg = _model()
+    reg = obs.get_registry()
+    was = reg.enabled
+    reg.reset()
+    events: list[dict] = []
+    unsubscribe = obs.span_events_subscribe(events.append)
+    try:
+        obs.set_enabled(on)
+        ServingConfig(page_size=4, n_pages=16, max_slots=2).make(
+            params, cfg, compute_dtype=jnp.float32)
+        snap = reg.snapshot()
+    finally:
+        unsubscribe()
+        reg.enabled = was
+        reg.reset()
+    if not on:
+        assert events == [] and snap == {}
+        return
+    spans = [e for e in events if e["cat"] == "span"]
+    assert [(e["name"], e["depth"]) for e in spans] == [
+        ("serving_build", 0)]
+    assert snap["span_seconds{name=serving_build}_count"] == 1.0
+    (build,) = spans
+    for e in events:
+        if e["cat"] == "compile":
+            assert e["args"]["span"] == "serving_build"
+            assert build["ts"] <= e["ts"] + 1000

@@ -12,10 +12,9 @@ each link):
 
 The model is checked two ways: unit tests pin the formulas, and
 :func:`xla_collective_traffic` reads the collectives XLA **actually
-compiled** into a step (via ``Compiled.as_text()`` — the same
-artifact :func:`torchbooster_tpu.observability.device.cost_analysis`
-reads its scalars from, which on this backend reports only local
-bytes-accessed and so cannot price the wire) and prices them with the
+compiled** into a step (via ``Compiled.as_text()``; the compiler's
+``cost_analysis()`` reports only local bytes-accessed on this backend
+and so cannot price the wire) and prices them with the
 same conventions, so the static model and the compiled graph must
 agree within tolerance or the test fails.
 

@@ -1200,7 +1200,18 @@ class ServingConfig(BaseConfig):
         ``conf.observability.tracing.make()`` — the ONLY way the
         ``tracing:`` YAML block reaches a YAML-built batcher/fleet);
         a fleet shares it across every replica so ``/debug/trace``
-        follows a request fleet-wide."""
+        follows a request fleet-wide. The build is one
+        ``serving_build`` span (a no-op while the registry is off):
+        what its compiles cost is in ``compile_seconds{span=
+        serving_build}``, docs/observability.md "Start-up"."""
+        from torchbooster_tpu.observability.spans import span
+
+        with span("serving_build"):
+            return self._build(params, model_cfg, compute_dtype,
+                               on_recompile, mesh, tracer)
+
+    def _build(self, params: Any, model_cfg: Any, compute_dtype: Any,
+               on_recompile: str, mesh: Any, tracer: Any) -> Any:
         import jax.numpy as jnp
 
         from torchbooster_tpu.serving import ContinuousBatcher, PagedEngine

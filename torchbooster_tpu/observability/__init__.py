@@ -17,8 +17,7 @@ that ship it all on a cadence thread.
   :func:`~torchbooster_tpu.observability.spans.annotate`;
 - :mod:`recompile` — :class:`RecompileSentinel` over jit cache sizes
   (``on_recompile: ignore | warn | raise``);
-- :mod:`device`    — HBM gauges from ``memory_stats()``, XLA
-  ``cost_analysis`` FLOP cross-checks for utilization denominators;
+- :mod:`device`    — HBM gauges from ``memory_stats()``;
 - :mod:`export`    — JSONL event log + Prometheus text snapshots on a
   background cadence thread;
 - :mod:`slo`       — :class:`SLOBurnEngine`, multi-window burn rates
@@ -40,10 +39,7 @@ predictable branch per instrumented call site. Flip it on via
 from __future__ import annotations
 
 from torchbooster_tpu.observability.device import (
-    cost_analysis,
-    flop_check,
     record_memory_gauges,
-    xla_flops,
 )
 from torchbooster_tpu.observability.export import (
     JsonlExporter,
@@ -84,9 +80,9 @@ __all__ = [
     "MetricsExporter", "Observability", "RecompileError",
     "RecompileSentinel", "Registry", "RequestTracer", "SLOBurnEngine",
     "annotate",
-    "cost_analysis", "enable", "flop_check", "get_registry",
+    "enable", "get_registry",
     "prometheus_text", "record_memory_gauges", "set_enabled", "span",
-    "span_events_subscribe", "trace", "write_chrome_trace", "xla_flops",
+    "span_events_subscribe", "trace", "write_chrome_trace",
 ]
 
 

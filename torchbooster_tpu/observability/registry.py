@@ -293,6 +293,14 @@ def get_registry() -> Registry:
 
 
 def set_enabled(enabled: bool = True) -> Registry:
-    """Flip the default registry's master switch; returns it."""
+    """Flip the default registry's master switch; returns it. The first
+    switch-on also installs the compile listener
+    (:func:`~torchbooster_tpu.observability.recompile.
+    install_compile_listener`), which books into this registry."""
     _DEFAULT.enabled = enabled
+    if enabled:
+        from torchbooster_tpu.observability.recompile import (
+            install_compile_listener)
+
+        install_compile_listener()
     return _DEFAULT

@@ -133,13 +133,10 @@ class _Span:
         # write_chrome_trace call merges both onto one timeline);
         # dur_s/ok/path/depth ride along for the JSONL readers.
         if _sinks:
-            _emit({"event": "span", "name": self.name,
-                   "path": "/".join((*_tls.stack, self.name)),
-                   "depth": self._depth, "dur_s": round(dur, 6),
-                   "ph": "X", "cat": "span", "pid": _PID,
-                   "tid": threading.get_ident(),
-                   "ts": int(self._t0 * 1e6), "dur": int(dur * 1e6),
-                   "ok": exc[0] is None})
+            emit_slice(self.name, self._t0, dur, event="span", cat="span",
+                       path="/".join((*_tls.stack, self.name)),
+                       depth=self._depth, dur_s=round(dur, 6),
+                       ok=exc[0] is None)
 
 
 def span(name: str, registry: Registry | None = None):
@@ -155,6 +152,23 @@ def span(name: str, registry: Registry | None = None):
 def current_span_path() -> str:
     """The '/'-joined open-span stack of this thread ('' outside)."""
     return "/".join(getattr(_tls, "stack", ()))
+
+
+def current_span() -> str | None:
+    """The innermost open span of this thread (None outside any)."""
+    stack = getattr(_tls, "stack", None)
+    return stack[-1] if stack else None
+
+
+def emit_slice(name: str, t0: float, dur: float, **fields: Any) -> None:
+    """One closed slice of this thread's time to the span sinks, as a
+    Chrome trace ``X`` event on the spans' ``perf_counter`` timebase
+    (``t0`` in its seconds): a closed span, or a compile JAX reports
+    (:mod:`recompile`). Nothing is built when nothing subscribed."""
+    if _sinks:
+        _emit({"name": name, "ph": "X", "pid": _PID,
+               "tid": threading.get_ident(), "ts": int(t0 * 1e6),
+               "dur": int(dur * 1e6), **fields})
 
 
 class trace:
