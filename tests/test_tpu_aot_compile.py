@@ -244,6 +244,16 @@ def _pool_sized_writes(compiled, layer_elems: int, pool_k, vocab: int):
     return moved
 
 
+def _kernels_as_on_the_chip(monkeypatch):
+    """The pallas kernels of a serve program compiled by Mosaic, as the
+    chip runs them, not interpreted as the CPU process would: the
+    interpreter threads a kernel's HBM operands (the pool) through its
+    loops as values, which a compiled kernel never copies."""
+    from torchbooster_tpu.ops import _pallas_util
+
+    monkeypatch.setattr(_pallas_util, "default_interpret", lambda: False)
+
+
 def _step_args(arg, engine) -> tuple:
     """What every serve program takes after the pool, described: the
     engine's packed operand buffer (the tables, the chunk's ids and
@@ -272,7 +282,7 @@ def _serve_program(engine, program: str, arg):
                          ids=["bf16-pool", "int8-pool"])
 @pytest.mark.parametrize("program", ["decode", "chunk", "mixed"])
 def test_serve_programs_keep_the_pool_in_place_on_v5e(
-        one_chip, program, int8_pool):
+        one_chip, program, int8_pool, monkeypatch):
     """``PagedEngine._decode_fn`` / ``_chunk_fn`` (alone, and MIXED:
     the decode lanes riding the chunk, both K/V writes and both reads
     of a layer in one program) compiled for the
@@ -288,6 +298,8 @@ def test_serve_programs_keep_the_pool_in_place_on_v5e(
     import torchbooster_tpu.serving.engine as engine_mod
     from torchbooster_tpu.models.gpt import GPT, GPTConfig
     from torchbooster_tpu.serving.engine import PagedEngine
+
+    _kernels_as_on_the_chip(monkeypatch)
 
     cfg = GPTConfig(n_layers=XL_LAYERS, **XL)
     abstract = lambda tree: jax.tree.map(
@@ -374,6 +386,7 @@ def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
     # the process is pinned to the CPU; the program compiled is the
     # chip's
     monkeypatch.setattr(moe_mod, "_on_tpu", lambda: True)
+    _kernels_as_on_the_chip(monkeypatch)
     bench = Path(__file__).resolve().parent.parent / "benchmark"
     if str(bench) not in sys.path:
         sys.path.insert(0, str(bench))
@@ -454,20 +467,23 @@ def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
 # between it and the buffer's ``last_ids``, and the mixed program
 # writes a prompt's first token into its slot's lane of ``tokens``.
 # The trinity cell's three programs joined the table then (PR 34 had
-# left them out)
+# left them out). The decode and mixed programs of the GPT-2 XL, LFM2
+# and trinity cells changed since: their lanes read by the live-page
+# walk (ops/lane_walk.py), not the pool sweep; the three lone chunk
+# programs and the latent family's three kept their text
 ACCEPTED_SERVE_TEXT = {
     ("gpt2-xl.serve-chat-r80", "decode"):
-        "55d734b80a7726add900c461f55048d94d1d173c46c8654c91a750f876013797",
+        "831896eeb0957cab291136b6a6e6dbbe2e36ed94deb745d38bbbc6851c381b82",
     ("gpt2-xl.serve-chat-r80", "chunk"):
         "64fb3db321c03d436b92c9a5f2d424f1b3c17e7e16b2fb93f451d24a86d70d7a",
     ("gpt2-xl.serve-chat-r80", "mixed"):
-        "46e99daf4361c62ba336bef86ddeb4dcee2faa61cbd3e7cb36edff6def1f917a",
+        "53368ad95f1c98391c2b4e6dc7e21f419ab8bf221db29cb547b1c42c1798ef87",
     ("lfm2-8b-a1b.serve-rag-r80", "decode"):
-        "667964cc8b1ff468f11ed6fe625e3df854fef1d6b5a12e9bb0d97189df2daf5f",
+        "965e66a644d84806ea64664623c767bcf6197687d53704f8cdf29c643fd1b68a",
     ("lfm2-8b-a1b.serve-rag-r80", "chunk"):
         "c831ff5a47ec94c4d65b200c0c9d22c8172dd67f7eccb4ec6c6aba9cdaed2b4e",
     ("lfm2-8b-a1b.serve-rag-r80", "mixed"):
-        "6fdf46d7f00b3b859be1638437554e90467aa9fe1f11292a553cdd7b34653808",
+        "03a5cb238ed22d779bcee11b7ecc24b11b98165699e2e8546513beffae0cb696",
     ("sarvam-105b.serve-longdoc-r80", "decode"):
         "e413f24ac7a58e487cab29e22063c0eaf5a90a0321596c3c15c059cafcfd216f",
     ("sarvam-105b.serve-longdoc-r80", "chunk"):
@@ -475,11 +491,11 @@ ACCEPTED_SERVE_TEXT = {
     ("sarvam-105b.serve-longdoc-r80", "mixed"):
         "95e76e2e57330ce59b8ab9c91ea1dcc578b9b0cf31597185a53a1a29f36cc000",
     ("trinity-large-preview.serve-longctx-r80", "decode"):
-        "412e1d4b14527e41f40412f1484931835ab82e86fe5f84e179334e928477ae8a",
+        "7c2ff89e41120240055652ff13693724bdf98f619b48e0207d56ce4a5bec48d6",
     ("trinity-large-preview.serve-longctx-r80", "chunk"):
         "f0d921aec04ed36d50b60966100d84d23786845efa9d7eb149feaebca45e0532",
     ("trinity-large-preview.serve-longctx-r80", "mixed"):
-        "3e3a3b44fe1c6efccded848559e08908d58083cc446d5e9c22ff49cce5bfe069",
+        "2deaae7e9e024d4916e45312435bb6feb2e1ac7696ffa45a27eb79310748b323",
 }
 
 
@@ -567,9 +583,8 @@ def test_accepted_serve_cells_lower_to_the_text_pr33_left(
     """The decode, chunk and mixed programs of the four serve cells
     (GPT-2 XL, LFM2, the latent-attention family and the window-and-
     full one, each at its cell's own geometry and full depth) lower to
-    the StableHLO text they lowered to at PR 35's tree: PR 33's (the
-    packed operand buffer and the device-carried key) with the last
-    tokens kept on the device for the lanes. A PR that MEANS
+    the StableHLO text pinned in ``ACCEPTED_SERVE_TEXT`` (the comment
+    above it says what each change of it was). A PR that MEANS
     to change one of these programs replaces its hash here and says so
     in CHANGES.md; one that does not and fails here has moved a cell
     it did not measure."""
@@ -719,6 +734,7 @@ def test_afmoe_serve_programs_fit_and_keep_both_pools_in_place_on_v5e(
     from torchbooster_tpu.models.afmoe import Afmoe
 
     monkeypatch.setattr(moe_mod, "_on_tpu", lambda: True)
+    _kernels_as_on_the_chip(monkeypatch)
     bench = Path(__file__).resolve().parent.parent / "benchmark"
     if str(bench) not in sys.path:
         sys.path.insert(0, str(bench))
@@ -786,3 +802,63 @@ def test_afmoe_serve_programs_fit_and_keep_both_pools_in_place_on_v5e(
           f"{memory.temp_size_in_bytes / 1e9:.3f} GB, total "
           f"{total / 1e9:.3f} GB")
     assert nbytes(params) > 8.6e9 and total < V5E_HBM_BYTES, total
+
+
+# the decode lanes' live-page walk (ops/lane_walk.py) at the three
+# cells whose lanes it reads: (K/V layers of the pool, pages, query
+# heads, KV heads, head_dim, slots, table entries, window); the
+# window-and-full family's cell twice, one a kind
+LANE_WALKS = {
+    "gpt2-xl.serve-chat-r80": (48, 256, 25, 25, 64, 32, 16, None),
+    "lfm2-8b-a1b.serve-rag-r80": (3, 2048, 32, 8, 64, 64, 76, None),
+    "trinity-large-preview.serve-longctx-r80.full":
+        (1, 4096, 48, 8, 128, 32, 268, None),
+    "trinity-large-preview.serve-longctx-r80.window":
+        (4, 32 * 69, 48, 8, 128, 32, 4096 // 64 + 1, 4096),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(LANE_WALKS))
+def test_the_lane_walk_compiles_in_place_within_vmem_on_v5e(
+        one_chip, cell, monkeypatch):
+    """``kv_pages.walk_attention`` as the engine calls it — queries laid
+    out block-diagonally, the Mosaic kernel, each head's own lanes kept
+    — compiled for the described v5e at the cell's geometry: Mosaic
+    takes the kernel (it refuses one whose VMEM passes the kernel's
+    ``vmem_limit_bytes``, which stays under the chip's 128 MiB and
+    above what the kernel's buffers need), and no instruction writes a
+    buffer the size of a layer's pool: the pools are read where they
+    lie."""
+    from torchbooster_tpu.ops import lane_walk
+    from torchbooster_tpu.serving import kv_pages
+
+    layers, pages, heads, kv, hd, slots, entries, window = LANE_WALKS[cell]
+    page = 64
+    width = kv_pages.kv_width(kv, hd)
+    monkeypatch.setattr(kv_pages, "lane_walk_attention", functools.partial(
+        lane_walk.lane_walk_attention, interpret=False))
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((layers, pages, page, width), jnp.bfloat16)
+    text, compiled = _compile(
+        functools.partial(kv_pages.walk_attention, kv_heads=kv,
+                          window=window),
+        arg((slots, 1, heads, hd), jnp.bfloat16), pool, pool, arg(()),
+        arg((slots, entries)), arg((slots,)), arg((slots,)),
+        arg((slots,), jnp.bool_))
+    assert "tpu_custom_call" in text
+    moved = _pool_sized_writes(compiled, pages * page * width, pool, -1)
+    assert not moved, f"pool-sized buffers written: {moved}"
+    # what the kernel holds in VMEM: the queries and the float32
+    # output as pool rows (two buffers each), both halves' pages
+    # double-buffered, a slot's block-diagonal queries, its float32
+    # accumulator and statistics
+    rep = -(-heads // kv // 8) * 8
+    rows = -(-heads // 16) * 16
+    n_pp = max(4, min(16, lane_walk.ITEM_BYTES // (page * width * 2)))
+    held = 2 * slots * rep * width * (2 + 4) \
+        + 2 * 2 * n_pp * page * width * 2 \
+        + rows * (width * (2 + 4) + 2 * lane_walk.STAT_LANES * 4)
+    assert held < lane_walk.VMEM_LIMIT < 128 * 2**20, (held, cell)

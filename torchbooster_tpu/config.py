@@ -1120,12 +1120,18 @@ class ServingConfig(BaseConfig):
     lanes, gathered per slot by traced lane ids — many tenants on
     one page pool with zero recompiles across adapter churn.
 
-    ``decode_backend: pallas`` swaps the decode/verify pool READ for
-    the paged flash-decode kernel (ops/paged_attention.py): block
-    tables walked in-kernel, so bytes/step are the live context
-    instead of the pool capacity — docs/performance.md has the
-    two-regime roofline. ``xla`` (default) keeps the pool sweep and
-    is the A/B control; both are token-exact for greedy decode.
+    ``decode_backend`` chooses how the decode steps READ the pool.
+    ``xla`` (default) is the live-page walk where the pool allows it
+    and the whole-pool sweep where it does not: a pool of plain rows
+    on one device is walked (ops/lane_walk.py — each decoding slot's
+    table up to its own position, the pool read in place, a window
+    layer's ring from the window's lower edge), an int8 pool,
+    ``tp > 1`` and the speculative verify sweep every page
+    (``kv_pages.sweep_attention``); a latent pool has its own kernel.
+    ``pallas`` swaps the decode/verify READ for the older paged
+    flash-decode kernel (ops/paged_attention.py), which relays a
+    layer's pool by heads for its blocks. All are token-exact for
+    greedy decode.
 
     ``tp > 1`` runs the engine TENSOR-PARALLEL over a committed mesh's
     ``tp`` (heads) axis (serving/tp.py; pass the mesh to
@@ -1154,7 +1160,7 @@ class ServingConfig(BaseConfig):
     spec_tree: bool = False            # tree-structured drafting (greedy)
     spec_tree_width: int = 2           # max branches off the draft root
     parallel_sampling: bool = False    # CoW fork n/best_of sampling
-    decode_backend: str = "xla"        # "xla" pool sweep | "pallas" kernel
+    decode_backend: str = "xla"        # "xla" walk / sweep | "pallas" kernel
     tp: int = 1                        # tensor-parallel head shards (mesh "tp" axis)
     frontend: FrontendConfig = dataclasses.field(
         default_factory=FrontendConfig)  # HTTP front door + scheduler

@@ -21,27 +21,26 @@ Prefill/decode split — both sides compile exactly ONCE:
   cache-hit TTFT win is exactly the prefill compute not re-run.
 - **decode** is ONE jitted step over all ``max_slots`` slots: embed
   each slot's last token at its own depth, write this step's K/V into
-  each slot's current (always private) page, then attend by sweeping
-  the page pool once — every page computes a flash-style partial
-  softmax of its ``page_size`` tokens against the queries of EVERY
-  slot referencing it (``refs`` lanes: a prefix page shared by k
-  live requests serves all k from the one pool read;
-  ``_grouped_cache_attention(state=True)``, the same numerics core
-  the dense ``jit_generate`` control runs), and per-slot results
+  each slot's current (always private) page, then attend by WALKING
+  each decoding slot's table up to its own position
+  (``kv_pages.walk_attention``, ops/lane_walk.py): the pages come out
+  of the stacked pool in place, page by page, and a slot that is not
+  decoding, or a page past a slot's position, costs nothing. An int8
+  pool and ``tp > 1`` SWEEP the page pool once instead
+  (``kv_pages.sweep_attention``): every page computes a flash-style
+  partial softmax of its ``page_size`` tokens against the queries of
+  EVERY slot referencing it (``refs`` lanes), and per-slot results
   combine across (page, lane) partials with the online-softmax merge
   (``segment_max``/``segment_sum`` keyed by the lane's slot).
 
-Why the pool sweep is the length-aware read: the dense decode step
-streams ``max_slots × S_cache`` cache rows regardless of how many
-tokens each slot holds; the sweep streams ``n_pages × page_size``
-rows — the pool, which the operator sizes to expected total occupancy
-(the reserved null page rides along, one page in ``n_pages``: slicing
-it out would keep a layer's pages from being read in place) — and
-free/partial pages contribute nothing but masked lanes. Prefix
-sharing compounds it: k requests on one system prompt hold ONE copy
-of its pages, so the same pool holds more live requests. On an
-HBM-bound loop the read bytes ARE the step time (PERF.md section 5
-has the decode step's device time by scope).
+Why the walk: the dense decode step streams ``max_slots × S_cache``
+cache rows regardless of how many tokens each slot holds; the sweep
+streams ``n_pages × page_size`` rows — the pool, which the operator
+sizes to expected total occupancy; the walk streams the live context,
+whole pages. Prefix sharing holds ONE copy of a shared prompt's pages
+in the pool (each sharer's walk reads it). On an HBM-bound loop the
+read bytes ARE the step time (PERF.md section 5 has the decode step's
+device time by scope).
 
 - **a chunk beside live slots is ONE program**: where an iteration
   has a pending chunk AND slots decoding, the decode lanes ride the
@@ -103,11 +102,13 @@ from torchbooster_tpu.models.gpt import (
     qkv_to_tp_major,
 )
 from torchbooster_tpu.ops.latent_paged_attention import (
+    PAGES_PER_STEP as LATENT_PAGES_PER_STEP,
     latent_paged_attention,
 )
 from torchbooster_tpu.ops.paged_attention import paged_attention
 from torchbooster_tpu.serving.adapters import AdapterRegistry
 from torchbooster_tpu.serving.kv_pages import (
+    KINDS,
     NULL_PAGE,
     BlockTables,
     HostPagePool,
@@ -122,9 +123,11 @@ from torchbooster_tpu.serving.kv_pages import (
     quantized_rows,
     ring_pages,
     ring_positions,
+    ring_table,
     scan_layers,
     sweep_attention,
     to_rows,
+    walk_attention,
     write_rows,
 )
 from torchbooster_tpu.serving.tp import (
@@ -381,10 +384,11 @@ class PagedEngine:
     instead of the pool — on the HBM-bound decode loop that ratio is
     the tokens/s ratio (docs/performance.md, two-regime roofline).
     Greedy output is token-exact vs the sweep and the dense control
-    (tests/test_paged_kernel.py), the compiled-step count stays one
-    per executable across churn, and the default ``"xla"`` leaves the
-    engine — including its jitted call signatures — bit-for-bit
-    unchanged.
+    (tests/test_paged_kernel.py), and the compiled-step count stays
+    one per executable across churn. The default ``"xla"`` means the
+    live-page walk where the pool allows it (``self.walks``: plain
+    rows on one device; ops/lane_walk.py) and the sweep where it does
+    not (an int8 pool, ``tp > 1``, the speculative verify).
 
     ``tp > 1`` (with a committed ``mesh`` carrying a ``tp`` axis of
     that size) shards every compiled step's ATTENTION over the mesh's
@@ -400,7 +404,9 @@ class PagedEngine:
     chip walks the same tables over its own head shard, so
     seat/retire/evict/CoW logic is byte-identical to the single-chip
     engine's, and both backends (the sweep and the pallas table walk)
-    shard the same way with no kernel changes. Greedy decode is
+    shard the same way with no kernel changes (at ``tp > 1`` the
+    default backend sweeps: the live-page walk is the one-device
+    read). Greedy decode is
     token-exact vs tp=1 and vs dense ``jit_generate``; the
     zero-recompile contract holds per executable; the default
     ``tp=1`` builds no shard_map wrapper at all — same compiled
@@ -444,8 +450,9 @@ class PagedEngine:
     (``kv_pages.ring_positions``), so ``0 <= q_pos - k_pos < window``
     is the whole of visibility: it hides what a recycled page still
     holds and what the slot's last tenant left, and seating, preempting
-    and retiring touch nothing of it. The decode lanes sweep that pool
-    as they sweep the other; a chunk reads its slot's ring, never its
+    and retiring touch nothing of it. The decode lanes walk a slot's
+    ring from the window's lower edge to its newest page, as they walk
+    a block table; a chunk reads its slot's ring, never its
     table, for a window layer, and walks its table in blocks up to its
     own position for a full one.
     """
@@ -633,6 +640,12 @@ class PagedEngine:
         # (made at the first step with the registry on)
         self.window_rows_recycled = 0
         self._kv_inst: dict | None = None
+        # K/V layers of each kind (the pages the lanes' read fetches
+        # are counted against what those layers' pools hold), and the
+        # registry series (made at the first step with the registry on)
+        self._kv_layers = {kind: spec.layers_of(kind) for kind in KINDS
+                           if spec.layers_of(kind)}
+        self._lane_inst: dict | None = None
         # the host spill tier (PR 16): LRU eviction demotes registered
         # prefix pages to a host-DRAM pool (int8 + scales) and a later
         # seat promotes them back through ONE fixed-shape compiled
@@ -674,14 +687,22 @@ class PagedEngine:
             self.params, self.pool = _tp_place(self.params, self.pool,
                                                mesh)
         # decode_backend selects HOW the decode/verify steps READ the
-        # pool: "xla" (default) is the whole-pool sweep — the A/B
-        # control, bit-for-bit the pre-kernel engine; "pallas" walks
-        # the block tables in-kernel (ops/paged_attention.py) so
-        # bytes/step track live context instead of pool capacity.
+        # pool: "xla" (default) is the live-page walk where the pool
+        # allows it (below) and the whole-pool sweep where it does
+        # not; "pallas" walks the block tables in-kernel
+        # (ops/paged_attention.py) over the pool relaid out by heads.
         # Writes, sampling, bookkeeping, and every contract
         # (zero-recompile, token parity, seat/retire/evict, prefix
         # sharing) are backend-independent.
         self.decode_backend = decode_backend
+        # whether the decode lanes READ by the live-page walk
+        # (kv_pages.walk_attention), decided here from what the pool
+        # is: the default backend over a pool of plain rows on one
+        # device; an int8 pool (rows and scales), ``tp > 1`` (the
+        # pool sharded on heads) and a latent pool (its own kernel)
+        # keep their reads
+        self.walks = (decode_backend == "xla" and not self.quantized
+                      and self.tp == 1 and spec.value_dim is None)
         self.temperature = temperature
         self.top_k = top_k
         self.top_p = top_p
@@ -1346,21 +1367,25 @@ class PagedEngine:
                         params["wpe"], lengths,
                         dtype=self.compute_dtype)[:, None]
 
-        # page -> lane bookkeeping, shared by every layer: each page
-        # carries reference LANES (refs row: the slots holding it —
-        # prefix-shared pages list every sharer; empty lanes divert to
-        # the trash segment n_slots; without the prefix cache the lane
-        # axis is 1 and this is exactly the old single-owner sweep). A page's token j
-        # holds absolute position page_pos*ps + j, visible to a lane
-        # iff <= that slot's current length (the token this step
-        # writes lands AT ``lengths`` and must see itself; a sharer
-        # mid-prompt never sees past its own depth). The sweep reads
-        # ALL n_pages pages: page 0, the reserved null page (dead-slot
-        # write target), is never referenced, so its lanes are empty
-        # and its partials land in the trash segment like any other
-        # empty lane's — a static [1:] would keep the layer's slice
-        # of the stacked pool from fusing into the sweep's read
-        if self.decode_backend == "xla":
+        # the default engine's lanes read by the live-page walk
+        # (``self.walks``): each live slot its own table up to
+        # ``lengths`` — the token this step writes lands AT
+        # ``lengths`` and sees itself — and nothing else, so no
+        # bookkeeping is shared by the layers. The pool SWEEP (an int8
+        # pool, tp > 1) needs the page -> lane bookkeeping, shared by
+        # every layer: each page carries reference LANES (refs row:
+        # the slots holding it — prefix-shared pages list every
+        # sharer; empty lanes divert to the trash segment n_slots;
+        # without the prefix cache the lane axis is 1). A page's token
+        # j holds absolute position page_pos*ps + j, visible to a lane
+        # iff <= that slot's current length (a sharer mid-prompt never
+        # sees past its own depth). The sweep reads ALL n_pages pages:
+        # page 0, the reserved null page (dead-slot write target), is
+        # never referenced, so its lanes are empty and its partials
+        # land in the trash segment like any other empty lane's — a
+        # static [1:] would keep the layer's slice of the stacked pool
+        # from fusing into the sweep's read
+        if self.decode_backend == "xla" and not self.walks:
             n_lanes = refs.shape[1]             # refs (P, R)
             seg = jnp.where(refs >= 0, refs, n_slots).reshape(-1)
             ref_c = jnp.clip(refs, 0, n_slots - 1)
@@ -1403,6 +1428,12 @@ class PagedEngine:
                     ops["work_refs"], ops["work_pos"], lengths,
                     page_size=ps)
                 return o.astype(q.dtype)
+            if self.walks:
+                # each live slot's pages up to its own position, the
+                # stacked pool read in place (kv_pages.walk_attention)
+                return walk_attention(q, pk, pv, li, tables,
+                                      jnp.zeros_like(lengths), lengths,
+                                      active, k.shape[2])
             # the pool sweep: each page attends the queries of ALL
             # its reference lanes (a gather of the TINY q tensor
             # into (P, R, H, Dh) — the layer's pages are read in
@@ -1561,12 +1592,15 @@ class PagedEngine:
         """The decode lanes' ``write`` / ``read`` over pools of two
         kinds. A FULL layer is what every paged layer is
         (``write_full`` / ``read_full`` on that kind's pool: the block
-        tables' pages, the pool sweep). A WINDOW layer writes the
+        tables' pages, the live-page walk). A WINDOW layer writes the
         slot's row at its ring place — a slot that is not decoding
         writes nowhere: the ring pool has no null page, its index is
-        past the pool and the update is dropped — and sweeps the ring
-        pool, every page attended by its owner slot's query under the
-        window's mask on recovered positions."""
+        past the pool and the update is dropped — and walks its ring
+        as a table in position order: the pages from the window's
+        lower edge to the slot's newest, ``slot * ring + p % ring``
+        for sequence page ``p`` (``kv_pages.ring_positions``'s
+        arithmetic), visible where ``0 <= q_pos - k_pos < window``;
+        pages wholly below the edge are not read."""
         ps, ring, window = self.page_size, self.ring, self.window
         n_slots = lengths.shape[0]
         kv_heads = self.cfg.kv_heads
@@ -1574,10 +1608,8 @@ class PagedEngine:
             active, jnp.arange(n_slots) * ring + (lengths // ps) % ring,
             n_slots * ring)
         w_off = lengths % ps
-        pos = ring_positions(lengths // ps, ring, ps)  # (slots, ring, ps)
-        at = lengths[:, None, None]
-        visible = ((pos >= 0) & (pos <= at) & (at - pos < window)
-                   ).reshape(n_slots * ring, 1, ps)
+        # the ring's pages from the window's lower edge on
+        table, edge = ring_table(lengths, ring, ps, window)
 
         def write(k, v, pk, pv, li, kind):
             if kind == "full":
@@ -1593,21 +1625,9 @@ class PagedEngine:
         def read(q, k, v, pk, pv, li, kind):
             if kind == "full":
                 return read_full(q, k, v, pk["full"], pv["full"], li)
-            # a ring page serves its owner's one query; the (page)
-            # partials of a slot are its ``ring`` neighbours
-            q_lanes = jnp.repeat(q[:, 0], ring, axis=0)[:, None]
-            o, m, l = sweep_attention(
-                q_lanes, layer_pages(pk["window"], li),
-                layer_pages(pv["window"], li), visible, kv_heads)
-            # o (slots * ring, 1, g, rep, D); m, l (slots * ring, g,
-            # rep, 1): the online-softmax merge over a slot's pages
-            by_slot = lambda t: t.reshape(n_slots, ring, *t.shape[1:3])
-            o = o.reshape(n_slots, ring, *o.shape[2:])
-            m, l = by_slot(m), by_slot(l)
-            w = jnp.exp(m - jnp.max(m, axis=1, keepdims=True))
-            o = jnp.sum(o * w[..., None], axis=1) / jnp.maximum(
-                jnp.sum(l * w, axis=1), 1e-30)[..., None]
-            return o.reshape(n_slots, 1, -1, q.shape[3]).astype(q.dtype)
+            return walk_attention(q, pk["window"], pv["window"], li,
+                                  table, edge, lengths, active,
+                                  kv_heads, window=window)
 
         return write, read
 
@@ -2579,6 +2599,7 @@ class PagedEngine:
             fetch = (tokens,)
         self.pool = {"k": pool_k, "v": pool_v}
         self._count_rows(active, chunk=p)
+        self._count_lane_pages(active)
         self.tables.launched(active)
         if mixed:
             last = self._chunk_issued(p)
@@ -2907,6 +2928,52 @@ class PagedEngine:
         for kind, n in rows.items():
             self._kv_inst["live"].set(n, kind=kind)
             self._kv_inst["read"].inc(n, kind=kind)
+
+    def _count_lane_pages(self, active: np.ndarray) -> None:
+        """File the pages the decode lanes' read fetches at this step,
+        by host arithmetic on the lengths (made BEFORE the step's
+        advance) and the pools' geometry, against the pages the same
+        layers' pools hold: ``serving_lane_pages_read_total{kind}`` and
+        ``serving_lane_pages_pool_total{kind}``, each summed over the
+        kind's K/V layers (a page of K, and as many of V). The walk
+        fetches a decoding slot's pages through its position — for a
+        window layer from the window's lower edge on; the latent kernel
+        whole blocks of its table; the sweep every page of the pool."""
+        reg = get_registry()
+        if not reg.enabled:
+            return
+        if self._lane_inst is None:
+            self._lane_inst = {
+                "read": reg.counter(
+                    "serving_lane_pages_read_total",
+                    "pages the decode lanes' read fetched, summed over "
+                    "the K/V layers of the kind"),
+                "pool": reg.counter(
+                    "serving_lane_pages_pool_total",
+                    "pages the same layers' pools hold, summed over the "
+                    "steps that counted serving_lane_pages_read_total"),
+            }
+        ps = self.page_size
+        at = self.tables.lengths[active].astype(np.int64)
+        top = at // ps
+        pool = {"full": self.n_pages}
+        if self.ring is not None:
+            pool["window"] = self.max_slots * self.ring
+        if self.latent_dim is not None:
+            mp = self.tables.max_pages_per_slot
+            n_pp = min(LATENT_PAGES_PER_STEP, mp)
+            blocks = np.minimum(at // (n_pp * ps) + 1, -(-mp // n_pp))
+            read = {"full": int(blocks.sum()) * n_pp}
+        elif self.walks:
+            read = {"full": int((top + 1).sum())}
+            if self.ring is not None:
+                edge = np.maximum(at - self.window + 1, 0) // ps
+                read["window"] = int((top - edge + 1).sum())
+        else:
+            read = pool
+        for kind, layers in self._kv_layers.items():
+            self._lane_inst["read"].inc(read[kind] * layers, kind=kind)
+            self._lane_inst["pool"].inc(pool[kind] * layers, kind=kind)
 
     def retire(self, slot: int) -> None:
         """Release the slot (cancelling any in-flight prefill); shared

@@ -33,8 +33,11 @@ Two cooperating halves:
   :func:`from_rows` convert SMALL tensors (new tokens, a chunk's
   gathered pages, spill payloads) between ``(..., kv_heads, head_dim)``
   and pool rows; :func:`write_rows` / :func:`gather_pages` touch the
-  stacked pool at ``[layer, page, ...]`` in place; :func:`sweep_attention` is the decode / verify read, which
-  never splits the merged axis in memory; :func:`scan_layers` is the
+  stacked pool at ``[layer, page, ...]`` in place; the decode lanes'
+  read is :func:`walk_attention` (the live pages only) and, for an
+  int8 pool, ``tp > 1`` and the speculative verify,
+  :func:`sweep_attention` (every page): neither splits the merged axis
+  in memory; :func:`scan_layers` is the
   layer loop of the three serving programs, with the pool CARRIED
   (never a scan ``xs`` / ``ys``: those are two buffers and cost a copy
   of the pool a step).
@@ -118,6 +121,8 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from torchbooster_tpu.ops.lane_walk import lane_walk_attention
 
 NULL_PAGE = 0
 LANES = 128     # the device tiles an array's minor axis in 128 lanes
@@ -247,6 +252,24 @@ def ring_positions(top_page, ring: int, page_size: int):
         + jnp.arange(page_size, dtype=jnp.int32)
 
 
+def ring_table(lengths, ring: int, page_size: int, window: int):
+    """Each slot's ring as a table of the live-page walk
+    (:func:`walk_attention`) for a query at position ``lengths``: the
+    ring pages of its sequence pages from the window's lower edge
+    ``base = max(lengths - window + 1, 0) // page_size`` on, in
+    position order — sequence page ``p`` at ring page ``slot * ring +
+    p % ring``, the arithmetic of :func:`ring_positions`. Returns
+    ``(table (slots, window // page_size + 1), base (slots,))``; the
+    walk reads the entries up to the slot's newest page, those wholly
+    below the edge are not in the table."""
+    lengths = jnp.asarray(lengths, jnp.int32)
+    base = jnp.maximum(lengths - window + 1, 0) // page_size
+    seq = base[:, None] + jnp.arange(window // page_size + 1,
+                                     dtype=jnp.int32)
+    slots = jnp.arange(lengths.shape[0], dtype=jnp.int32)[:, None]
+    return slots * ring + seq % ring, base
+
+
 def make_pool(cfg: Any, page_size: int, n_pages: int,
               cache_dtype: Any = None,
               compute_dtype: Any = jnp.bfloat16,
@@ -370,13 +393,53 @@ def layer_pages(pool, layer):
                                                keepdims=False), pool)
 
 
+def walk_attention(q, pool_k, pool_v, layer, tables, base, q_pos, live,
+                   kv_heads: int, window: int | None = None):
+    """The decode lanes' READ of one layer by the live-page walk
+    (``ops/lane_walk.py``): each slot's one query over the pages its
+    table names up to its own position, the stacked pools of plain
+    rows read in place. The default engine's decode and mixed programs
+    read every kind of pool this way; an int8 pool, ``tp > 1`` and the
+    speculative verify (several queries a lane) take
+    :func:`sweep_attention`.
+
+    - ``q (slots, 1, n_heads, head_dim)``: the lanes' queries;
+    - ``pool_k`` / ``pool_v``: the stacked pools ``(layers, n_pages,
+      page_size, width)``; ``layer`` the index into them;
+    - ``tables (slots, entries)``, ``base (slots,)``: entry ``e`` of a
+      slot's table holds its sequence page ``base + e`` (a block
+      table: ``base`` 0; a window layer's ring in position order:
+      ``base`` the page of the window's lower edge);
+    - ``q_pos (slots,)``: where each query sits (its own row written);
+      ``window``: keys less than that far behind it only;
+    - ``live (slots,)``: False -> zeros.
+
+    The queries go in as pool rows and come out as pool rows: the
+    kernel lays them out block-diagonally in VMEM, as the sweep lays
+    out its columns. Returns ``(slots, 1, n_heads, head_dim)`` in
+    ``q``'s dtype. A page shared by several slots' tables (the prefix
+    cache, forks) is read once per sharer."""
+    n_slots, _, n_heads, head_dim = q.shape
+    rep = n_heads // kv_heads
+    width = pool_k.shape[-1]
+    rows = to_rows(jnp.swapaxes(
+        q[:, 0].reshape(n_slots, kv_heads, rep, head_dim), 1, 2), width)
+    o = lane_walk_attention(rows, pool_k, pool_v, layer, tables, base,
+                            q_pos, live, kv_heads=kv_heads,
+                            head_dim=head_dim, window=window)
+    o = jnp.where(live[:, None, None], o, 0.0)
+    o = jnp.swapaxes(from_rows(o, kv_heads, head_dim), 1, 2)
+    return o.reshape(n_slots, 1, n_heads, head_dim).astype(q.dtype)
+
+
 def sweep_attention(q_lanes, k_pages, v_pages, visible, kv_heads: int):
-    """The decode / verify READ of one layer's pages, in the pool's
-    own layout: every page attends the queries of its reference lanes
-    and returns its flash-style partial softmax — the contract of
-    ``models.gpt._grouped_cache_attention(state=True)`` with pages as
-    the batch axis, so the caller's online-softmax merge over (page,
-    lane) partials is unchanged.
+    """The verify READ of one layer's pages (and the decode lanes' for
+    an int8 pool or at ``tp > 1``), in the pool's own layout: every
+    page of the layer's pool, live or not, attends the queries of its
+    reference lanes and returns its flash-style partial softmax — the
+    contract of ``models.gpt._grouped_cache_attention(state=True)``
+    with pages as the batch axis, so the caller's online-softmax merge
+    over (page, lane) partials is unchanged.
 
     - ``q_lanes (n_pages, Q, n_heads, head_dim)``: the queries each
       page serves (``Q`` = reference lanes x query positions);
@@ -1381,4 +1444,5 @@ __all__ = ["BlockTables", "CacheSpec", "HostPagePool", "NULL_PAGE",
            "PoolExhausted", "cache_spec", "from_rows", "gather_pages",
            "kv_width", "layer_pages", "make_pool", "make_slot_state",
            "pool_map", "quantized_rows", "ring_pages", "ring_positions",
-           "scan_layers", "sweep_attention", "to_rows", "write_rows"]
+           "ring_table", "scan_layers", "sweep_attention", "to_rows",
+           "walk_attention", "write_rows"]
