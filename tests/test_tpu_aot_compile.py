@@ -470,7 +470,9 @@ def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
 # left them out). The decode and mixed programs of the GPT-2 XL, LFM2
 # and trinity cells changed since: their lanes read by the live-page
 # walk (ops/lane_walk.py), not the pool sweep; the three lone chunk
-# programs and the latent family's three kept their text
+# programs and the latent family's three kept their text. The
+# linear-attention family's cell joined with its three programs; the
+# twelve above kept their text
 ACCEPTED_SERVE_TEXT = {
     ("gpt2-xl.serve-chat-r80", "decode"):
         "831896eeb0957cab291136b6a6e6dbbe2e36ed94deb745d38bbbc6851c381b82",
@@ -496,6 +498,12 @@ ACCEPTED_SERVE_TEXT = {
         "f0d921aec04ed36d50b60966100d84d23786845efa9d7eb149feaebca45e0532",
     ("trinity-large-preview.serve-longctx-r80", "mixed"):
         "2deaae7e9e024d4916e45312435bb6feb2e1ac7696ffa45a27eb79310748b323",
+    ("qwen3-next-80b-a3b.serve-longgen-r80", "decode"):
+        "921f2f25dd8ddc565dc688df105ec85f74aa088426a707de45ebe1a8199f424e",
+    ("qwen3-next-80b-a3b.serve-longgen-r80", "chunk"):
+        "3107a988e92af28b70d93128551dadba36bd5707a7649eae4589ae98aefc5bf2",
+    ("qwen3-next-80b-a3b.serve-longgen-r80", "mixed"):
+        "5d80f34ab4b7563771c002aa2d032e37b685f7ac101c1accd7ff8f743c42efec",
 }
 
 
@@ -544,6 +552,14 @@ def _accepted_cell_lowered(one_chip, cell: str, program: str):
         cfg = program_afmoe.model_config(raw, traffic["max_positions"])
         init = lambda: model.init(jax.random.PRNGKey(0), cfg,
                                   jnp.bfloat16)
+    elif traffic["job"] == "serve_qwen3_next":
+        import program_qwen3_next
+        from torchbooster_tpu.models.qwen3_next import Qwen3Next as model
+
+        cfg = program_qwen3_next.model_config(raw, traffic["max_positions"])
+        # made in bfloat16; A_log and dt_bias stay float32, as served
+        init = lambda: model.init(jax.random.PRNGKey(0), cfg,
+                                  jnp.bfloat16)
     else:
         import program as program_gpt
         from torchbooster_tpu.models.gpt import GPT as model
@@ -580,9 +596,10 @@ def _accepted_cell_lowered(one_chip, cell: str, program: str):
 @pytest.mark.parametrize("cell,program", sorted(ACCEPTED_SERVE_TEXT))
 def test_accepted_serve_cells_lower_to_the_text_pr33_left(
         one_chip, cell, program, monkeypatch):
-    """The decode, chunk and mixed programs of the four serve cells
-    (GPT-2 XL, LFM2, the latent-attention family and the window-and-
-    full one, each at its cell's own geometry and full depth) lower to
+    """The decode, chunk and mixed programs of the five serve cells
+    (GPT-2 XL, LFM2, the latent-attention family, the window-and-full
+    one and the linear-attention one, each at its cell's own geometry
+    and full depth) lower to
     the StableHLO text pinned in ``ACCEPTED_SERVE_TEXT`` (the comment
     above it says what each change of it was). A PR that MEANS
     to change one of these programs replaces its hash here and says so
@@ -803,6 +820,107 @@ def test_afmoe_serve_programs_fit_and_keep_both_pools_in_place_on_v5e(
           f"{total / 1e9:.3f} GB")
     assert nbytes(params) > 8.6e9 and total < V5E_HBM_BYTES, total
 
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "mixed"])
+def test_qwen3_next_serve_programs_fit_and_keep_the_state_in_place_on_v5e(
+        one_chip, program, monkeypatch):
+    """The programs of the ``qwen3-next-80b-a3b.serve-longgen-r80`` cell
+    (decode, the chunk alone, and the chunk with the decode lanes
+    riding) at its own size (4 layers at published widths — three
+    linear, one full —, 256 of 512 experts a layer, 128 slots, chunks
+    of 256, a full-layer pool of 8192 pages of 64 under an
+    8,448-position table), compiled for the described v5e with the pool
+    AND the slot state donated: the linear layers' float32 ``S`` (3 x
+    128 slots x 2 MiB = 0.82 GB) and their bfloat16 conv rows come back
+    aliased, the lanes' update of ``S`` is made in place — the
+    program's scratch stays far under ONE layer's ``S`` (268 MB), so no
+    copy of it exists —, no instruction writes a buffer the size of the
+    pool's layer but the pool's own update, the experts run as the
+    pallas grouped product with no copy of an expert stack, and
+    weights, pool, state and scratch fit the chip."""
+    import json
+    import math
+    import sys
+    from pathlib import Path
+
+    import torchbooster_tpu.models.moe as moe_mod
+    import torchbooster_tpu.serving.engine as engine_mod
+    from torchbooster_tpu.models.qwen3_next import Qwen3Next
+
+    monkeypatch.setattr(moe_mod, "_on_tpu", lambda: True)
+    _kernels_as_on_the_chip(monkeypatch)
+    bench = Path(__file__).resolve().parent.parent / "benchmark"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    import program_qwen3_next
+
+    raw = json.loads(
+        (bench / "configs" / "qwen3-next-80b-a3b.json").read_text())
+    traffic = json.loads(
+        (bench / "traffic" / "serve-longgen-r80.json").read_text())
+    serving = traffic["serving"]
+    cfg = program_qwen3_next.model_config(raw, traffic["max_positions"])
+    slots, pages, page = (serving["max_slots"], serving["n_pages"],
+                          serving["page_size"])
+    abstract = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip), tree)
+    params = abstract(jax.eval_shape(
+        lambda: Qwen3Next.init(jax.random.PRNGKey(0), cfg, jnp.bfloat16)))
+    real = engine_mod.make_pool, engine_mod.make_slot_state
+    described = lambda make: lambda *a, **kw: jax.eval_shape(
+        lambda: make(*a, **kw))
+    engine_mod.make_pool = described(real[0])
+    engine_mod.make_slot_state = described(real[1])
+    try:
+        engine = engine_mod.PagedEngine(
+            params, cfg, page_size=page, n_pages=pages, max_slots=slots,
+            prefill_chunk_pages=serving["prefill_chunk_pages"])
+    finally:
+        engine_mod.make_pool, engine_mod.make_slot_state = real
+    assert engine.chunk_tokens == 256 and engine.walks
+    pool_k, pool_v = (abstract(engine.pool[h]) for h in "kv")
+    state = abstract(engine.slot_state)
+    assert pool_k.shape == (1, pages, page, 512)
+    assert state["delta"].shape == (3, slots, 32, 128, 128)
+    assert state["delta"].dtype == jnp.float32
+    assert state["conv"].shape == (3, slots, 3, 8192)
+    assert state["conv"].dtype == jnp.bfloat16
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, tail, kw = _serve_program(engine, program, arg)
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 5)).lower(
+        params, pool_k, pool_v, *_step_args(arg, engine), state, *tail,
+        **kw).compile()
+
+    nbytes = lambda tree: sum(math.prod(x.shape) * x.dtype.itemsize
+                              for x in jax.tree.leaves(tree))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= nbytes((pool_k, pool_v, state))
+    layer_s = nbytes(state["delta"]) // 3
+    assert memory.temp_size_in_bytes < layer_s, memory.temp_size_in_bytes
+    moved = _pool_sized_writes(compiled, pages * page * 512, pool_k,
+                               cfg.vocab)
+    # the state's own in-place updates write its shape: not a copy
+    moved = [m for m in moved if m[3] not in {
+        tuple(leaf.shape) for leaf in jax.tree.leaves(state)}]
+    assert not moved, f"pool-sized buffers written: {moved}"
+    assert "ragged-dot" not in compiled.as_text()
+    stack = cfg.experts_held[1] * cfg.d_model * cfg.expert_width
+    copies = [(name, dims) for name, op, results
+              in _top_level_results(compiled.as_text()) if op == "copy"
+              for _, dims, _ in results
+              if math.prod(int(d) for d in dims.split(",") if d) >= stack]
+    assert not copies, f"expert-stack-sized copies: {copies}"
+    total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    print(f"qwen3-next {program}: args "
+          f"{memory.argument_size_in_bytes / 1e9:.3f} GB, temp "
+          f"{memory.temp_size_in_bytes / 1e9:.3f} GB, total "
+          f"{total / 1e9:.3f} GB")
+    assert nbytes(params) > 7.3e9 and total < V5E_HBM_BYTES, total
 
 # the decode lanes' live-page walk (ops/lane_walk.py) at the three
 # cells whose lanes it reads: (K/V layers of the pool, pages, query

@@ -1185,8 +1185,9 @@ class ServingConfig(BaseConfig):
         :class:`~torchbooster_tpu.models.gpt.GPTConfig`, or another
         served model's config such as
         :class:`~torchbooster_tpu.models.lfm2.LFM2Config`,
-        :class:`~torchbooster_tpu.models.mla_moe.MLAMoEConfig` or
-        :class:`~torchbooster_tpu.models.afmoe.AfmoeConfig`: the
+        :class:`~torchbooster_tpu.models.mla_moe.MLAMoEConfig`,
+        :class:`~torchbooster_tpu.models.afmoe.AfmoeConfig` or
+        :class:`~torchbooster_tpu.models.qwen3_next.Qwen3NextConfig`: the
         engine is chosen by its type, and the features a model lacks
         raise ``NotImplementedError`` naming the feature and the
         reason). Returns the
@@ -1229,10 +1230,10 @@ class ServingConfig(BaseConfig):
 
         if not isinstance(model_cfg, GPTConfig):
             # a model with its own layer stack (models/lfm2.py,
-            # models/mla_moe.py, models/afmoe.py): the engine is chosen
-            # by the config's type and refuses the features it lacks,
-            # each with the model's own reason; these three never
-            # reach it
+            # models/mla_moe.py, models/afmoe.py, models/qwen3_next.py):
+            # the engine is chosen by the config's type and refuses the
+            # features it lacks, each with the model's own reason;
+            # these three never reach it
             from torchbooster_tpu.serving.engine import refuse_unserved
 
             refuse_unserved(model_cfg, {

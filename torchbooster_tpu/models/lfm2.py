@@ -138,15 +138,16 @@ class LFM2Config:
     def cache_spec(self):
         """What a serving engine allocates for this model: K/V rows
         for the attention layers only, and one slot-indexed state — the
-        conv layers' last ``conv_kernel - 1`` inputs."""
+        conv layers' last ``conv_kernel - 1`` inputs, in the compute
+        dtype."""
         from torchbooster_tpu.serving.kv_pages import CacheSpec
 
         n_conv = self.layer_types.count(CONV)
         return CacheSpec(
             kv_layers=self.layer_types.count(ATTENTION),
             kv_heads=self.n_kv_heads, head_dim=self.head_dim,
-            slot_states={"conv": (n_conv, self.conv_kernel - 1,
-                                  self.d_model)} if n_conv else {})
+            slot_states={"conv": ((n_conv, self.conv_kernel - 1,
+                                   self.d_model), None)} if n_conv else {})
 
 
 # ---------------------------------------------------------------------

@@ -266,20 +266,32 @@ def grouped_matmul(rows: jax.Array, kernels: jax.Array,
 
 
 def moe_route(params: dict, tokens: jax.Array, top_k: int,
-              scaling: float = 1.0, eps: float = 1e-6
+              scaling: float = 1.0, eps: float = 1e-6,
+              scoring: str = "sigmoid"
               ) -> tuple[jax.Array, jax.Array]:
-    """Sigmoid routing with a selection bias, in float32: ``s =
-    sigmoid(u @ W_g)``; the ``top_k`` experts are chosen by ``s + b``
-    (``moe_bias``, a load-balancing buffer that steers SELECTION
-    only) and weighted by the unbiased ``s``, renormalised over the
-    chosen ones: ``w = scaling * s[sel] / (sum(s[sel]) + eps)``.
-    ``tokens (T, d)`` -> ``(sel (T, k) int32, w (T, k) float32)``."""
+    """Routing in float32, by ``scoring``:
+
+    - ``"sigmoid"``: ``s = sigmoid(u @ W_g)``; the ``top_k`` experts
+      are chosen by ``s + b`` (``moe_bias``, a load-balancing buffer
+      that steers SELECTION only) and weighted by the unbiased ``s``;
+    - ``"softmax"``: ``s = softmax(u @ W_g)`` over all the router's
+      experts, the ``top_k`` chosen by ``s`` (no bias).
+
+    Either way the weights are renormalised over the chosen ones: ``w
+    = scaling * s[sel] / (sum(s[sel]) + eps)``. ``tokens (T, d)`` ->
+    ``(sel (T, k) int32, w (T, k) float32)``."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"moe_route: unknown scoring {scoring!r}")
     gate = params["moe_gate"]["kernel"].astype(jnp.float32)
-    scores = jax.nn.sigmoid(jnp.dot(
-        tokens.astype(jnp.float32), gate,
-        precision=jax.lax.Precision.HIGHEST))
-    _, sel = jax.lax.top_k(
-        scores + params["moe_bias"].astype(jnp.float32), top_k)
+    logits = jnp.dot(tokens.astype(jnp.float32), gate,
+                     precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, sel = jax.lax.top_k(scores, top_k)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, sel = jax.lax.top_k(
+            scores + params["moe_bias"].astype(jnp.float32), top_k)
     w = jnp.take_along_axis(scores, sel, axis=-1)
     w = scaling * w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return sel.astype(jnp.int32), w
@@ -290,7 +302,8 @@ def moe_dropless(params: dict, x: jax.Array, top_k: int,
                  first_group: jax.Array | int = 0,
                  route_on: jax.Array | None = None,
                  held: tuple[int, int] | None = None,
-                 tp: tuple | None = None, route_eps: float = 1e-6
+                 tp: tuple | None = None, route_eps: float = 1e-6,
+                 scoring: str = "sigmoid"
                  ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """(B, S, d) -> ((B, S, d), tokens per expert (E,) int32, pairs
     elsewhere () int32): the DROPLESS expert layer — every selected
@@ -321,7 +334,8 @@ def moe_dropless(params: dict, x: jax.Array, top_k: int,
 
     ``valid (B, S)`` marks real tokens (a dead slot, a chunk's pad):
     the others are sorted behind every group, take no expert's time
-    and count for nothing. Routing follows :func:`moe_route`. The
+    and count for nothing. Routing follows :func:`moe_route`
+    (``scoring``: sigmoid with a selection bias, or softmax). The
     count is a program output the serving engine reads
     (``serving_moe_*``, docs/observability.md).
 
@@ -354,7 +368,8 @@ def moe_dropless(params: dict, x: jax.Array, top_k: int,
     with jax.named_scope("moe_route"):
         sel, w = moe_route(
             params, tokens if route_on is None
-            else route_on.reshape(b * s, d), top_k, scaling, route_eps)
+            else route_on.reshape(b * s, d), top_k, scaling, route_eps,
+            scoring)
     with jax.named_scope("moe_experts"):
         pair_expert = sel.reshape(-1)                    # (T*k,)
         live = None if valid is None \

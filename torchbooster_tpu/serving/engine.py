@@ -72,6 +72,7 @@ size).
 """
 from __future__ import annotations
 
+import inspect
 import math
 from typing import Any, Callable, NamedTuple
 
@@ -83,6 +84,7 @@ from torchbooster_tpu.models import layers as L
 from torchbooster_tpu.models import afmoe as _afmoe
 from torchbooster_tpu.models import lfm2 as _lfm2
 from torchbooster_tpu.models import mla_moe as _mla_moe
+from torchbooster_tpu.models import qwen3_next as _qwen3_next
 from torchbooster_tpu.observability import get_registry, span
 from torchbooster_tpu.models.quant import (
     weight_stream_bytes as _weight_stream_bytes,
@@ -179,13 +181,16 @@ def _served_model(cfg: Any):
     architecture is a module with those three functions, a
     ``cache_spec()`` on its config and ``UNSERVED`` — the serving
     features it is refused, each with its reason — (models/lfm2.py,
-    models/mla_moe.py, models/afmoe.py), and a line here."""
+    models/mla_moe.py, models/afmoe.py, models/qwen3_next.py), and a
+    line here."""
     if isinstance(cfg, _lfm2.LFM2Config):
         return _lfm2
     if isinstance(cfg, _mla_moe.MLAMoEConfig):
         return _mla_moe
     if isinstance(cfg, _afmoe.AfmoeConfig):
         return _afmoe
+    if isinstance(cfg, _qwen3_next.Qwen3NextConfig):
+        return _qwen3_next
     if isinstance(cfg, GPTConfig):
         return None
     raise TypeError(
@@ -221,6 +226,14 @@ class _Pieces(NamedTuple):
     read: Callable          # (q, k, v, pool_k, pool_v, li) -> o
     conv: Callable | None   # (z, w, state, li) -> (c, state)
     rows: Callable          # x -> (n, 1, d), the rows the head reads
+    # a model's own recurrence over its slot state (models/qwen3_next.py):
+    # (step, xs, state, li, name=, scopes=, in_place=None) -> (ys,
+    # state) — the engine loads the sequences' rows of state ``name`` of
+    # layer ``li``, calls ``step(xs, rows, valid) -> (ys, rows)`` and
+    # stores what comes back, the chunk's side under ``scopes[0]``, the
+    # lanes' ``[1]``; the lanes hand ``in_place(xs, array, li, live)``
+    # the whole array instead, where the model gives one
+    carry: Callable | None = None
 
 
 def _ride(chunk: _Pieces, lanes: _Pieces) -> _Pieces:
@@ -262,10 +275,21 @@ def _ride(chunk: _Pieces, lanes: _Pieces) -> _Pieces:
         xc, xl = split(x)
         return jnp.concatenate([chunk.rows(xc), lanes.rows(xl)], axis=0)
 
+    def carry(step, xs, state, li, **named):
+        # the chunk's slot first, then the live lanes (which never
+        # include it): the lanes keep what the chunk stored
+        parts = [split(t) for t in xs]
+        yc, state = chunk.carry(step, tuple(p[0] for p in parts), state,
+                                li, **named)
+        yl, state = lanes.carry(step, tuple(p[1] for p in parts), state,
+                                li, **named)
+        return join(yc, yl), state
+
     return _Pieces(join(chunk.x, lanes.x),
                    join(chunk.positions, lanes.positions),
                    join(chunk.valid, lanes.valid), write, read,
-                   conv if chunk.conv is not None else None, rows)
+                   conv if chunk.conv is not None else None, rows,
+                   carry if chunk.carry is not None else None)
 
 
 def _merge_partials(a: tuple, b: tuple) -> tuple:
@@ -455,6 +479,16 @@ class PagedEngine:
     a block table; a chunk reads its slot's ring, never its
     table, for a window layer, and walks its table in blocks up to its
     own position for a full one.
+
+    **Linear-attention layers** (``models/qwen3_next.py``): the spec's
+    slot states are the convolution's rows and a float32 matrix ``S`` a
+    value head, each in its own dtype. The programs hand the model
+    ``carry`` (:class:`_Pieces`): the engine loads a named state's rows
+    — the chunk's slot, zeros at position 0; every lane — and stores
+    what the model's mathematics returns, the lanes' only where a slot
+    decodes, in place in the donated array. ``S`` of the lanes is read
+    and written by the model's own kernel on the array (live slots
+    only, ``ops/delta_lanes.py``).
     """
 
     def __init__(self, params: dict, cfg: Any, *,
@@ -646,6 +680,9 @@ class PagedEngine:
         self._kv_layers = {kind: spec.layers_of(kind) for kind in KINDS
                            if spec.layers_of(kind)}
         self._lane_inst: dict | None = None
+        # the registry series of the slot state's bytes (made at the
+        # first step with the registry on)
+        self._state_inst = None
         # the host spill tier (PR 16): LRU eviction demotes registered
         # prefix pages to a host-DRAM pool (int8 + scales) and a later
         # seat promotes them back through ONE fixed-shape compiled
@@ -1326,6 +1363,18 @@ class PagedEngine:
                 return c, {"conv": rows.at[li, slot].set(
                     keep.astype(rows.dtype))}
 
+        def carry(step, xs, st, li, name, scopes, in_place=None):
+            # the seating slot's rows, zeros where the prefill starts
+            # at position 0 (a fresh seat, a preempted request's
+            # replay); the model hands back the state after the
+            # prompt's last REAL token of the chunk
+            with jax.named_scope(scopes[0]):
+                a = st[name]
+                mine = jnp.where(start == 0, 0, a[li, slot])[None]
+                ys, new = step(xs, mine, (positions < s0)[None])
+                return ys, {**st, name: a.at[li, slot].set(
+                    new[0].astype(a.dtype))}
+
         def rows(x):
             # the row that picks: the prompt's position ``s0 - 1``
             # where this chunk holds it, (1, 1, d)
@@ -1333,7 +1382,8 @@ class PagedEngine:
                 x, jnp.clip(s0 - 1 - start, 0, C - 1), 1, axis=1)
 
         return _Pieces(x, positions[None], (positions < s0)[None],
-                       write, read, conv, rows)
+                       write, read, conv, rows,
+                       carry if self.slot_state is not None else None)
 
     def _lane_pieces(self, params, ops: dict, prev=None) -> "_Pieces":
         """What is the decode LANES' of a program (:class:`_Pieces`):
@@ -1499,9 +1549,27 @@ class PagedEngine:
                             zz[:, 1:].astype(rows.dtype), prev)
             return c, {"conv": rows.at[li].set(new)}
 
+        def carry(step, xs, st, li, name, scopes, in_place=None):
+            # only the live slots' rows change — a slot between two of
+            # its prompt's chunks, or a dead one, keeps what it had: the
+            # model's own step on the array reads and writes those rows
+            # alone; else every slot's rows of layer ``li`` are loaded
+            # and the live ones stored back
+            with jax.named_scope(scopes[1]):
+                a = st[name]
+                if in_place is not None:
+                    ys, a = in_place(xs, a, li, active)
+                    return ys, {**st, name: a}
+                rows = a[li]
+                ys, new = step(xs, rows, active[:, None])
+                live = active.reshape(-1, *(1,) * (rows.ndim - 1))
+                return ys, {**st, name: a.at[li].set(
+                    jnp.where(live, new.astype(a.dtype), rows))}
+
+        stateful = self.slot_state is not None
         return _Pieces(x, lengths[:, None], active[:, None], write,
-                       read, conv if self.slot_state is not None else None,
-                       lambda x: x)
+                       read, conv if stateful else None, lambda x: x,
+                       carry if stateful else None)
 
     def _chunk_kinds(self, write_full, context, table_row, slot, start,
                      C: int):
@@ -1652,12 +1720,16 @@ class PagedEngine:
             return pieces.read(q, k, v, pk, pv, li, **kind), (pk, pv)
 
         if self.model is not None:
+            # the slot-state pieces the model's stack names: LFM2 takes
+            # ``conv``, Qwen3-Next ``carry``
+            takes = inspect.signature(self.model.layers).parameters
             x, (pool_k, pool_v), state, moe_counts = self.model.layers(
                 params, pieces.x, cfg, positions=pieces.positions,
                 attend=lambda q, k, v, cache, li, **kind: attend(
                     q, k, v, *cache, li, **kind),
-                conv=pieces.conv, cache=(pool_k, pool_v), state=state,
-                valid=pieces.valid)
+                cache=(pool_k, pool_v), state=state, valid=pieces.valid,
+                **{name: getattr(pieces, name) for name in ("conv", "carry")
+                   if name in takes})
             return x, pool_k, pool_v, state, moe_counts
         lora_w, lane_ids = lora if lora is not None else (None, None)
 
@@ -2114,6 +2186,7 @@ class PagedEngine:
             self._rng, tok, pool_k, pool_v = outs
         self.pool = {"k": pool_k, "v": pool_v}
         self._count_rows(None, chunk=p)
+        self._count_state(None, chunk=p)
         if not self._chunk_issued(p):
             return None
         self.tables.activate(p["slot"])
@@ -2600,6 +2673,7 @@ class PagedEngine:
         self.pool = {"k": pool_k, "v": pool_v}
         self._count_rows(active, chunk=p)
         self._count_lane_pages(active)
+        self._count_state(active, chunk=p)
         self.tables.launched(active)
         if mixed:
             last = self._chunk_issued(p)
@@ -2974,6 +3048,34 @@ class PagedEngine:
         for kind, layers in self._kv_layers.items():
             self._lane_inst["read"].inc(read[kind] * layers, kind=kind)
             self._lane_inst["pool"].inc(pool[kind] * layers, kind=kind)
+
+    def _count_state(self, active: np.ndarray | None,
+                     chunk: dict | None = None) -> None:
+        """File the bytes of slot state a step reads and writes (a
+        model with slot state; host arithmetic where the step is
+        packed): ``serving_slot_state_bytes_total{kind=<state>,
+        part=lanes|chunk}`` — each live lane's rows of every layer read
+        and written once, the chunk's slot's written and, past position
+        0, read. ``active`` None: a lone chunk, no lane decodes."""
+        if self.slot_state is None:
+            return
+        reg = get_registry()
+        if not reg.enabled:
+            return
+        if self._state_inst is None:
+            self._state_inst = reg.counter(
+                "serving_slot_state_bytes_total",
+                "bytes of slot state the decode lanes and the prefill "
+                "chunk read and wrote, summed over the layers")
+        passes = {"lanes": 0 if active is None
+                  else 2 * int(np.count_nonzero(active)),
+                  "chunk": 0 if chunk is None
+                  else 1 + int(chunk["start"] > 0)}
+        for name, a in self.slot_state.items():
+            row = a.dtype.itemsize * math.prod(a.shape[2:]) * a.shape[0]
+            for part, n in passes.items():
+                if n:
+                    self._state_inst.inc(n * row, kind=name, part=part)
 
     def retire(self, slot: int) -> None:
         """Release the slot (cancelling any in-flight prefill); shared

@@ -145,8 +145,10 @@ class CacheSpec:
     layers of paged K/V rows of ``kv_heads * head_dim`` lanes (the
     layers that attend — not every layer of a model with other mixers),
     and ``slot_states``: arrays indexed by serving SLOT, not by page —
-    ``{name: (layers, *shape)}`` is allocated as ``(layers, max_slots,
-    *shape)`` (:func:`make_slot_state`). A model with slot state is
+    ``{name: ((layers, *shape), dtype)}`` is allocated as ``(layers,
+    max_slots, *shape)`` in ``dtype``, or in the engine's compute
+    dtype where ``dtype`` is None (:func:`make_slot_state`). A model
+    with slot state is
     seated, preempted and retired like any other (its programs reset
     the state where a prefill starts at position 0), but its pages are
     not the whole of a sequence any more: the prefix cache, rewind,
@@ -203,12 +205,14 @@ def cache_spec(cfg: Any) -> CacheSpec:
 def make_slot_state(spec: CacheSpec, max_slots: int,
                     dtype: Any = jnp.bfloat16) -> dict | None:
     """The slot-indexed states of ``spec``, zeroed: ``{name: (layers,
-    max_slots, *shape)}``; None for a model that has none (its
-    programs then carry no such operand)."""
+    max_slots, *shape)}``, each in its own dtype or ``dtype`` where
+    the spec names none; None for a model that has none (its programs
+    then carry no such operand)."""
     if not spec.slot_states:
         return None
-    return {name: jnp.zeros((shape[0], max_slots, *shape[1:]), dtype)
-            for name, shape in spec.slot_states.items()}
+    return {name: jnp.zeros((shape[0], max_slots, *shape[1:]),
+                            own or dtype)
+            for name, (shape, own) in spec.slot_states.items()}
 
 
 def kv_width(kv_heads: int, head_dim: int, shards: int = 1) -> int:
