@@ -472,20 +472,26 @@ def test_lfm2_serve_programs_fit_and_stay_in_place_on_v5e(
 # walk (ops/lane_walk.py), not the pool sweep; the three lone chunk
 # programs and the latent family's three kept their text. The
 # linear-attention family's cell joined with its three programs; the
-# twelve above kept their text
+# twelve above kept their text. All three programs of the GPT-2 XL,
+# LFM2, window-and-full and linear-attention cells changed since: a
+# chunk reads its prior context by the chunk walk (ops/chunk_walk.py),
+# not by a gather of its table, and both walks are module-level jitted
+# callees (one lowered kernel a shape in a program, where each call
+# site lowered its own: the decode programs' only change); the latent
+# family's three kept their text
 ACCEPTED_SERVE_TEXT = {
     ("gpt2-xl.serve-chat-r80", "decode"):
-        "831896eeb0957cab291136b6a6e6dbbe2e36ed94deb745d38bbbc6851c381b82",
+        "a1d07a95343af4d6f19e9bab1b723ef4342d12fcaf47ec54b3771d5f037f1ab5",
     ("gpt2-xl.serve-chat-r80", "chunk"):
-        "64fb3db321c03d436b92c9a5f2d424f1b3c17e7e16b2fb93f451d24a86d70d7a",
+        "1014b81252dc16b0da6e27a1450416389eb19f4587ff4597049f0ca3024be7e5",
     ("gpt2-xl.serve-chat-r80", "mixed"):
-        "53368ad95f1c98391c2b4e6dc7e21f419ab8bf221db29cb547b1c42c1798ef87",
+        "3f5e6b55751e3427286acf449c78f8a9b59f5fc7d4132309bcaed31f07431045",
     ("lfm2-8b-a1b.serve-rag-r80", "decode"):
-        "965e66a644d84806ea64664623c767bcf6197687d53704f8cdf29c643fd1b68a",
+        "3b4c68e9185f3cb64bd317467b8dd84c81674301bdc06e1335b94929f7857032",
     ("lfm2-8b-a1b.serve-rag-r80", "chunk"):
-        "c831ff5a47ec94c4d65b200c0c9d22c8172dd67f7eccb4ec6c6aba9cdaed2b4e",
+        "befd81f2ac4b3a365212df8d6a6a0f040e7c526b1a9298ec7fc690f1c25ab283",
     ("lfm2-8b-a1b.serve-rag-r80", "mixed"):
-        "03a5cb238ed22d779bcee11b7ecc24b11b98165699e2e8546513beffae0cb696",
+        "7e74be12eb48dda03a7355081fe2d7973c9c67d8d06d3dab94316d87202f8f88",
     ("sarvam-105b.serve-longdoc-r80", "decode"):
         "e413f24ac7a58e487cab29e22063c0eaf5a90a0321596c3c15c059cafcfd216f",
     ("sarvam-105b.serve-longdoc-r80", "chunk"):
@@ -493,17 +499,17 @@ ACCEPTED_SERVE_TEXT = {
     ("sarvam-105b.serve-longdoc-r80", "mixed"):
         "95e76e2e57330ce59b8ab9c91ea1dcc578b9b0cf31597185a53a1a29f36cc000",
     ("trinity-large-preview.serve-longctx-r80", "decode"):
-        "7c2ff89e41120240055652ff13693724bdf98f619b48e0207d56ce4a5bec48d6",
+        "66455d99e93defda7e6d9682d8551cdfc40a06cd3784163fa6b940b694a59866",
     ("trinity-large-preview.serve-longctx-r80", "chunk"):
-        "f0d921aec04ed36d50b60966100d84d23786845efa9d7eb149feaebca45e0532",
+        "8b1bf9ca981e76a73c95477f8aa930d1bb04d0990b5461c37876ad94e97ccbbd",
     ("trinity-large-preview.serve-longctx-r80", "mixed"):
-        "2deaae7e9e024d4916e45312435bb6feb2e1ac7696ffa45a27eb79310748b323",
+        "8402b0f2ed34b737ffc8f2004115a97167f26212aa0ab2ff855795ea5eda07f8",
     ("qwen3-next-80b-a3b.serve-longgen-r80", "decode"):
-        "921f2f25dd8ddc565dc688df105ec85f74aa088426a707de45ebe1a8199f424e",
+        "2c2a223fac463fa45c0f3bcba8f894b684c6ff8302a2795cea832e37c5e2b8e6",
     ("qwen3-next-80b-a3b.serve-longgen-r80", "chunk"):
-        "3107a988e92af28b70d93128551dadba36bd5707a7649eae4589ae98aefc5bf2",
+        "9aead2b0403ea8341cb0a3bbbde24b5c223947717fe60ca5cecbb58444c9dc85",
     ("qwen3-next-80b-a3b.serve-longgen-r80", "mixed"):
-        "5d80f34ab4b7563771c002aa2d032e37b685f7ac101c1accd7ff8f743c42efec",
+        "55ae3d6f2e1f0f6ed0e5f790995272b90b6f9f0949ee27b71072de836662e5ae",
 }
 
 
@@ -980,3 +986,117 @@ def test_the_lane_walk_compiles_in_place_within_vmem_on_v5e(
         + 2 * 2 * n_pp * page * width * 2 \
         + rows * (width * (2 + 4) + 2 * lane_walk.STAT_LANES * 4)
     assert held < lane_walk.VMEM_LIMIT < 128 * 2**20, (held, cell)
+
+
+# a prefill chunk's walk of its prior context (ops/chunk_walk.py) at
+# the four cells whose chunks it reads: (K/V layers of the pool, pages,
+# query heads, KV heads, head_dim, chunk tokens, table entries,
+# window); the window-and-full family's cell twice, one a kind
+CHUNK_WALKS = {
+    "gpt2-xl.serve-chat-r80": (48, 256, 25, 25, 64, 256, 16, None),
+    "lfm2-8b-a1b.serve-rag-r80": (3, 2048, 32, 8, 64, 256, 76, None),
+    "trinity-large-preview.serve-longctx-r80.full":
+        (1, 4096, 48, 8, 128, 256, 268, None),
+    "trinity-large-preview.serve-longctx-r80.window":
+        (4, 32 * 69, 48, 8, 128, 256, 4096 // 64 + 1, 4096),
+    "qwen3-next-80b-a3b.serve-longgen-r80":
+        (1, 8192, 16, 2, 256, 256, 132, None),
+}
+
+
+def _chunk_walk_lowered(one_chip, geometry, entries=None, chunk=None):
+    """``chunk_walk_attention`` as the engine calls it, lowered for the
+    described v5e with the Mosaic kernel, at ``geometry`` (a row of
+    ``CHUNK_WALKS``), its table or chunk length replaced where given."""
+    from torchbooster_tpu.ops import chunk_walk
+    from torchbooster_tpu.serving import kv_pages
+
+    layers, pages, heads, kv, hd, n_q, n_entries, window = geometry
+    width = kv_pages.kv_width(kv, hd)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((layers, pages, 64, width), jnp.bfloat16)
+    return pool, width, jax.jit(functools.partial(
+        chunk_walk.chunk_walk_attention, kv_heads=kv, window=window,
+        interpret=False)).lower(
+            arg((1, chunk or n_q, heads, hd), jnp.bfloat16), pool, pool,
+            arg(()), arg((entries or n_entries,)), arg(()), arg(()))
+
+
+@pytest.mark.parametrize("cell", sorted(CHUNK_WALKS))
+def test_the_chunk_walk_compiles_in_place_within_vmem_on_v5e(one_chip,
+                                                             cell):
+    """``chunk_walk_attention`` at the cell's chunk geometry compiled
+    for the described v5e: Mosaic takes the kernel (it refuses one
+    whose VMEM passes the kernel's ``vmem_limit_bytes``, which stays
+    under the chip's 128 MiB and above what the kernel holds), and no
+    instruction writes a buffer the size of a layer's pool: the pools
+    are read where they lie."""
+    from torchbooster_tpu.ops import chunk_walk
+
+    pool, width, lowered = _chunk_walk_lowered(one_chip, CHUNK_WALKS[cell])
+    text, compiled = lowered.as_text(), lowered.compile()
+    assert text.count('kernel_name = "chunk_walk"') == 1
+    _, pages, heads, kv, hd, n_q, _, _ = CHUNK_WALKS[cell]
+    moved = _pool_sized_writes(compiled, pages * 64 * width, pool, -1)
+    assert not moved, f"pool-sized buffers written: {moved}"
+    # what the kernel holds in VMEM: a lane block of the queries and of
+    # the float32 output (two buffers each), both halves' pages of an
+    # item double-buffered, the rows once a head of the block with
+    # their float32 accumulator and statistics, an item's scores and
+    # probabilities
+    block = chunk_walk.lane_block(width, hd)
+    hb = min(block // hd, kv)
+    rows = hb * heads // kv * n_q
+    keys = chunk_walk.ITEM_KEYS
+    held = 2 * heads // kv * n_q * block * (2 + 4) \
+        + 2 * 2 * keys * block * 2 \
+        + rows * (block * (2 + 4) + 3 * chunk_walk.STAT_LANES * 4) \
+        + 2 * rows * keys * 4
+    assert held < chunk_walk.VMEM_LIMIT < 128 * 2**20, (held, cell)
+
+
+def test_the_chunk_walk_lowers_once_per_shape_on_v5e(one_chip,
+                                                     monkeypatch):
+    """The lowering guard. The kernel's body does not grow with the
+    table or the chunk: its serialized Mosaic payload is the same size
+    at two table lengths and at two chunk sizes (a body unrolled in Python over pages, items
+    or tokens grows with them). And the call sites of one shape in a
+    program share ONE lowered callee: the window-and-full family's
+    mixed program at its cell's geometry — five attention layers, four
+    sliding and one full, each with its chunk walk and its lanes' walk
+    — holds one chunk-walk kernel a kind, and one lane-walk kernel a
+    kind."""
+    import re
+
+    def payload(**kw):
+        _, _, lowered = _chunk_walk_lowered(
+            one_chip, CHUNK_WALKS[
+                "trinity-large-preview.serve-longctx-r80.window"], **kw)
+        bodies = re.findall(r'\\22body\\22: \\22([^\\]*)\\22',
+                            lowered.as_text())
+        assert len(bodies) == 1
+        return len(bodies[0])
+
+    # the call sites' files and lines left out of the payload: they
+    # are those of whichever caller traced the shape first
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        sizes = {payload(), payload(entries=4 * 65), payload(chunk=512)}
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+    assert len(sizes) == 1, sizes
+
+    import torchbooster_tpu.models.moe as moe_mod
+
+    monkeypatch.setattr(moe_mod, "_on_tpu", lambda: True)
+    _kernels_as_on_the_chip(monkeypatch)
+    text = _accepted_cell_lowered(
+        one_chip, "trinity-large-preview.serve-longctx-r80",
+        "mixed").as_text()
+    assert text.count('kernel_name = "chunk_walk"') == 2
+    # and so do the decode lanes' walks (ops/lane_walk.py)
+    assert text.count('kernel_name = "lane_walk"') == 2

@@ -244,18 +244,33 @@ def lane_walk_attention(q_rows: jax.Array, pool_k: jax.Array,
     width)`` float32 in ``q_rows``' layout, normalised (head ``(g,
     r)``'s output in row r, KV head g's lanes); a dead slot's rows are
     left unwritten. Shapes are geometry-only: one trace serves every
-    occupancy and every length."""
+    occupancy and every length, and (a module-level ``jax.jit`` with the
+    geometry static) the call sites of one shape in a program share ONE
+    lowered callee."""
+    if pages_per_step is None:
+        page_bytes = pool_k.shape[2] * pool_k.shape[3] \
+            * pool_k.dtype.itemsize
+        pages_per_step = max(4, min(16, ITEM_BYTES // page_bytes))
+    return _lane_walk(
+        q_rows, pool_k, pool_v, jnp.asarray(layer, jnp.int32), tables,
+        jnp.asarray(base, jnp.int32), jnp.asarray(q_pos, jnp.int32), live,
+        kv_heads=kv_heads, head_dim=head_dim, window=window,
+        pages_per_step=pages_per_step,
+        interpret=_resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "kv_heads", "head_dim", "window", "pages_per_step", "interpret"))
+def _lane_walk(q_rows, pool_k, pool_v, layer, tables, base, q_pos, live, *,
+               kv_heads: int, head_dim: int, window: int | None,
+               pages_per_step: int, interpret: bool):
+    """:func:`lane_walk_attention` with its geometry static."""
     n_slots, rep, width = q_rows.shape
     n_rows = kv_heads * rep
     _, _, page_size, _ = pool_k.shape
     n_entries = tables.shape[1]
-    if pages_per_step is None:
-        page_bytes = page_size * width * pool_k.dtype.itemsize
-        pages_per_step = max(4, min(16, ITEM_BYTES // page_bytes))
     n_pp = min(pages_per_step, n_entries)
     max_blocks = -(-n_entries // n_pp)
-    q_pos = jnp.asarray(q_pos, jnp.int32)
-    base = jnp.asarray(base, jnp.int32)
     hi = jnp.clip(q_pos // page_size - base, 0, n_entries - 1)
     slot, first, count, flags, n_items = _walk_items(
         hi, live, n_pp, max_blocks)
@@ -289,8 +304,8 @@ def lane_walk_attention(q_rows: jax.Array, pool_k: jax.Array,
         compiler_params=_CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
-        interpret=_resolve_interpret(interpret),
-    )(jnp.asarray(layer, jnp.int32).reshape(1),
+        interpret=interpret,
+    )(layer.reshape(1),
       jnp.asarray(tables, jnp.int32).reshape(-1),
       slot, first, count, flags, n_items, base, q_pos,
       q_rows.astype(pool_k.dtype), pool_k, pool_v)

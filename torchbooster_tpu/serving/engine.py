@@ -8,9 +8,11 @@ Prefill/decode split — both sides compile exactly ONCE:
   between decode steps by the batcher): each chunk runs the SAME block
   math training uses (``models/gpt.py _block_core``), writes its K/V
   into the pages the block table assigned, and attends its prior
-  context by gathering the slot's own pages back out of the pool —
-  two flash-style partials (prior pages + the intra-chunk causal
-  part) merged with the online-softmax combine. The chunk's shapes
+  context by WALKING the slot's own pages up to its own position, the
+  pool read in place (``ops/chunk_walk.py``; an int8 pool, ``tp > 1``
+  and the pallas backend gather them instead) — two flash-style
+  partials (prior pages + the intra-chunk causal part) merged with
+  the online-softmax combine. The chunk's shapes
   depend only on (chunk size, pool geometry, model); prompt length,
   chunk position, and page ids are traced VALUES, so one compiled
   chunk serves every prompt length — killing the old
@@ -103,6 +105,7 @@ from torchbooster_tpu.models.gpt import (
     _quantize_kv,
     qkv_to_tp_major,
 )
+from torchbooster_tpu.ops.chunk_walk import chunk_walk_attention
 from torchbooster_tpu.ops.latent_paged_attention import (
     PAGES_PER_STEP as LATENT_PAGES_PER_STEP,
     latent_paged_attention,
@@ -124,7 +127,6 @@ from torchbooster_tpu.serving.kv_pages import (
     pool_map,
     quantized_rows,
     ring_pages,
-    ring_positions,
     ring_table,
     scan_layers,
     sweep_attention,
@@ -305,6 +307,14 @@ def _merge_partials(a: tuple, b: tuple) -> tuple:
     return oa * mv(wa) + ob * mv(wb), m, la * wa + lb * wb
 
 
+def _normalised(partial: tuple, q: jax.Array) -> jax.Array:
+    """A merged partial as the attention's output, in ``q``'s layout
+    ``(B, S_q, n_heads, head_dim)`` and dtype."""
+    o, _, l = partial
+    o = o / jnp.moveaxis(jnp.maximum(l, 1e-30), -1, 1)[..., None]
+    return o.reshape(*q.shape[:2], -1, q.shape[3]).astype(q.dtype)
+
+
 class _Flight(NamedTuple):
     """A launched decode step that has not landed: what
     ``PagedEngine._landed`` needs to read it back and book it."""
@@ -476,9 +486,10 @@ class PagedEngine:
     holds and what the slot's last tenant left, and seating, preempting
     and retiring touch nothing of it. The decode lanes walk a slot's
     ring from the window's lower edge to its newest page, as they walk
-    a block table; a chunk reads its slot's ring, never its
-    table, for a window layer, and walks its table in blocks up to its
-    own position for a full one.
+    a block table; a chunk walks its slot's ring from the window's
+    lower edge for its first query, never its table, for a window
+    layer, and its table up to its own position for a full one
+    (ops/chunk_walk.py).
 
     **Linear-attention layers** (``models/qwen3_next.py``): the spec's
     slot states are the convolution's rows and a float32 matrix ``S`` a
@@ -680,6 +691,8 @@ class PagedEngine:
         self._kv_layers = {kind: spec.layers_of(kind) for kind in KINDS
                            if spec.layers_of(kind)}
         self._lane_inst: dict | None = None
+        # ... and the pages a chunk's read of its prior context fetches
+        self._chunk_inst: dict | None = None
         # the registry series of the slot state's bytes (made at the
         # first step with the registry on)
         self._state_inst = None
@@ -733,11 +746,12 @@ class PagedEngine:
         # sharing) are backend-independent.
         self.decode_backend = decode_backend
         # whether the decode lanes READ by the live-page walk
-        # (kv_pages.walk_attention), decided here from what the pool
-        # is: the default backend over a pool of plain rows on one
-        # device; an int8 pool (rows and scales), ``tp > 1`` (the
-        # pool sharded on heads) and a latent pool (its own kernel)
-        # keep their reads
+        # (kv_pages.walk_attention) and a chunk its prior context by
+        # the chunk walk (ops/chunk_walk.py), decided here from what
+        # the pool is: the default backend over a pool of plain rows
+        # on one device; an int8 pool (rows and scales), ``tp > 1``
+        # (the pool sharded on heads) and a latent pool (its own
+        # kernel) keep their reads
         self.walks = (decode_backend == "xla" and not self.quantized
                       and self.tp == 1 and spec.value_dim is None)
         self.temperature = temperature
@@ -1259,12 +1273,6 @@ class PagedEngine:
         w_pages = jnp.where(pidx < mp,
                             table_row[jnp.clip(pidx, 0, mp - 1)],
                             NULL_PAGE)
-        # absolute position of every gathered pool token: the slot's
-        # table is sequential, so table index i holds positions
-        # i*ps + [0, ps)
-        tok_abs = (jnp.arange(mp)[:, None] * ps
-                   + jnp.arange(ps)[None, :]).reshape(-1)
-        vis_prior = (tok_abs < start)[None, None, None, None, :]
         local = jnp.arange(C)
         vis_chunk = (local[:, None] >= local[None, :])[None, None, None]
 
@@ -1285,32 +1293,32 @@ class PagedEngine:
             return new_k, new_v
 
         def read(q, k, v, pk, pv, li):
-            # the slot's own pages back out of the stacked pool:
-            # mp pages of this layer, not the layer's pool (read
-            # after the write, so the update stays in place; the
-            # chunk's own pages sit at positions >= start, which
-            # vis_prior masks)
-            gk = context(gather_pages(pk, li, table_row))
-            gv = context(gather_pages(pv, li, table_row))
-            # prior context (this slot's already-written pages,
-            # masked to < start) and the chunk itself
-            # (compute-dtype K/V — parity with the dense prefill's
-            # un-quantized intra-prompt attention) are two
-            # flash-style partials merged online-softmax style —
-            # the same math spread over a split token axis
-            oA, mA, lA = _grouped_cache_attention(
-                q, gk, gv, vis_prior, state=True)
-            oB, mB, lB = _grouped_cache_attention(
-                q, k, v, vis_chunk, state=True)
-            m = jnp.maximum(mA, mB)
-            wA = jnp.exp(mA - m)
-            wB = jnp.exp(mB - m)
-            l = jnp.maximum(lA * wA + lB * wB, 1e-30)
-            # (B, g, rep, S_q) weights -> (B, S_q, g, rep, 1)
-            mv = lambda t: jnp.moveaxis(t, -1, 1)[..., None]
-            o = (oA * mv(wA) + oB * mv(wB)) / mv(l)
-            o = o.reshape(1, C, n_heads_l, head_dim)
-            return o.astype(q.dtype)
+            # the prior context (this slot's already-written pages,
+            # masked to < start) and the chunk itself (compute-dtype
+            # K/V — parity with the dense prefill's un-quantized
+            # intra-prompt attention) are two flash-style partials
+            # merged online-softmax style. The default engine WALKS
+            # the slot's pages up to the chunk's position in one
+            # kernel, the pool read in place (ops/chunk_walk.py); an
+            # int8 pool, tp > 1 and the pallas backend gather the
+            # slot's mp pages of this layer and attend them by XLA
+            # (read after the write, so the update stays in place;
+            # the chunk's own pages sit at positions >= start, which
+            # the mask hides)
+            if self.walks:
+                prior = chunk_walk_attention(q, pk, pv, li, table_row, 0,
+                                             start, kv_heads=k.shape[2])
+            else:
+                # the slot's table is sequential: entry i holds
+                # positions i*ps + [0, ps)
+                seen = jnp.arange(mp * ps) < start
+                prior = _grouped_cache_attention(
+                    q, context(gather_pages(pk, li, table_row)),
+                    context(gather_pages(pv, li, table_row)),
+                    seen[None, None, None, None], state=True)
+            return _normalised(_merge_partials(
+                prior, _grouped_cache_attention(q, k, v, vis_chunk,
+                                                state=True)), q)
 
         if self.latent_dim is not None:
             # a latent pool: ONE row a token written, then the chunk's
@@ -1341,8 +1349,8 @@ class PagedEngine:
                 return o.reshape(1, C, n_heads_l, -1).astype(q.dtype)
 
         if self.ring is not None:
-            write, read = self._chunk_kinds(
-                write, context, table_row, slot, start, C)
+            write, read = self._chunk_kinds(write, table_row, slot,
+                                            start, C)
 
         conv = None
         if self.slot_state is not None:
@@ -1571,39 +1579,35 @@ class PagedEngine:
                        read, conv if stateful else None, lambda x: x,
                        carry if stateful else None)
 
-    def _chunk_kinds(self, write_full, context, table_row, slot, start,
-                     C: int):
+    def _chunk_kinds(self, write_full, table_row, slot, start, C: int):
         """A chunk's ``write`` / ``read`` over pools of two kinds
         (``pool_k`` / ``pool_v``: ``{kind: array}``; ``kind`` static in
         the model's layer stack). A FULL layer is written as every
-        paged layer is (``write_full``, on that kind's pool) and read
-        by walking the slot's table in blocks of ``walk`` pages up to
-        the chunk's own position — a chunk costs what its prompt so
-        far costs, never what the table could hold, and the float32
-        scores of one block are all that exists of them at a time. A
-        WINDOW layer is written into the slot's ring and reads the
-        ring and nothing else, whatever the sequence's length: every
-        row's position recovered from the chunk's place
-        (``ring_positions``), visible where ``0 <= q_pos - k_pos <
-        window``. Both reads merge the prior context with the chunk's
+        paged layer is (``write_full``, on that kind's pool); a WINDOW
+        layer into the slot's ring. Both read their prior context by
+        the chunk walk (ops/chunk_walk.py), up to the chunk's own
+        position: a full layer its table, a window layer its ring as a
+        table in position order from the window's lower edge for the
+        chunk's first query — sequence page ``p`` at ring page ``slot *
+        ring + p % ring`` (``kv_pages.ring_table``), visible where ``0
+        <= q_pos - k_pos < window``. A chunk costs what its prompt so
+        far costs (a window layer: at most its window), never what the
+        table could hold. Both merge the prior context with the chunk's
         own compute-dtype K/V, as the one-kind chunk read does."""
         ps, ring, window = self.page_size, self.ring, self.window
-        n_cp, mp = C // ps, table_row.shape[0]
+        n_cp = C // ps
         kv_heads = self.cfg.kv_heads
-        q_pos = start + jnp.arange(C)
         local = jnp.arange(C)
         back = local[:, None] - local[None, :]
         # the chunk's own keys: causal, and inside the window
         vis_own = {"full": back >= 0,
                    "window": (back >= 0) & (back < window)}
-        # the ring as the chunk's last page leaves it
-        ring_pos = ring_positions(start // ps + n_cp - 1, ring,
-                                  ps).reshape(-1)
-        vis_ring = (ring_pos >= 0) & (ring_pos < start) \
-            & (q_pos[:, None] - ring_pos[None, :] < window)
         w_ring = slot * ring + (start // ps + jnp.arange(n_cp)) % ring
-        walk = min(mp, 4 * n_cp)        # pages a block of the walk
-        n_blocks = (start + walk * ps - 1) // (walk * ps)
+        # the ring's pages from the window's lower edge on, for a query
+        # at ``start`` (``ring_table`` lays out slot 0's: moved to ours)
+        ring_row, edge = ring_table(start[None], ring, ps, window)
+        walked = {"full": (table_row, 0, None),
+                  "window": (ring_row[0] + slot * ring, edge[0], window)}
 
         def write(k, v, pk, pv, li, kind):
             if kind == "full":
@@ -1618,41 +1622,15 @@ class PagedEngine:
                                 rows(v, pv["window"]))
             return {**pk, "window": wk}, {**pv, "window": wv}
 
-        def prior_window(q, pk, pv, li):
-            # the slot's ring: ``ring`` contiguous pages of the layer
-            mine = lambda pool: context(jax.lax.dynamic_slice(
-                pool, (li, slot * ring, 0, 0),
-                (1, ring, *pool.shape[2:]))[0])
-            return _grouped_cache_attention(
-                q, mine(pk), mine(pv), vis_ring[None, None, None],
-                state=True)
-
-        def prior_full(q, pk, pv, li):
-            def block(b, acc):
-                idx = b * walk + jnp.arange(walk)
-                pages = jnp.where(idx < mp,
-                                  table_row[jnp.clip(idx, 0, mp - 1)],
-                                  NULL_PAGE)
-                pos = (idx[:, None] * ps + jnp.arange(ps)).reshape(-1)
-                return _merge_partials(acc, _grouped_cache_attention(
-                    q, context(gather_pages(pk, li, pages)),
-                    context(gather_pages(pv, li, pages)),
-                    (pos < start)[None, None, None, None], state=True))
-
-            g, rep = kv_heads, q.shape[2] // kv_heads
-            none = (jnp.zeros((1, C, g, rep, q.shape[3]), jnp.float32),
-                    jnp.full((1, g, rep, C), -1e30, jnp.float32),
-                    jnp.zeros((1, g, rep, C), jnp.float32))
-            return jax.lax.fori_loop(0, n_blocks, block, none)
-
         def read(q, k, v, pk, pv, li, kind):
-            prior = prior_full if kind == "full" else prior_window
-            o, _, l = _merge_partials(
-                prior(q, pk[kind], pv[kind], li),
-                _grouped_cache_attention(
-                    q, k, v, vis_own[kind][None, None, None], state=True))
-            o = o / jnp.moveaxis(jnp.maximum(l, 1e-30), -1, 1)[..., None]
-            return o.reshape(1, C, -1, q.shape[3]).astype(q.dtype)
+            table, base, seen = walked[kind]
+            prior = chunk_walk_attention(q, pk[kind], pv[kind], li, table,
+                                         base, start, kv_heads=kv_heads,
+                                         window=seen)
+            return _normalised(_merge_partials(
+                prior, _grouped_cache_attention(
+                    q, k, v, vis_own[kind][None, None, None], state=True)),
+                q)
 
         return write, read
 
@@ -2186,6 +2164,7 @@ class PagedEngine:
             self._rng, tok, pool_k, pool_v = outs
         self.pool = {"k": pool_k, "v": pool_v}
         self._count_rows(None, chunk=p)
+        self._count_chunk_pages(p)
         self._count_state(None, chunk=p)
         if not self._chunk_issued(p):
             return None
@@ -2676,6 +2655,7 @@ class PagedEngine:
         self._count_state(active, chunk=p)
         self.tables.launched(active)
         if mixed:
+            self._count_chunk_pages(p)
             last = self._chunk_issued(p)
             if last:
                 # the chunk's token only where it is the prompt's
@@ -3048,6 +3028,58 @@ class PagedEngine:
         for kind, layers in self._kv_layers.items():
             self._lane_inst["read"].inc(read[kind] * layers, kind=kind)
             self._lane_inst["pool"].inc(pool[kind] * layers, kind=kind)
+
+    def _count_chunk_pages(self, chunk: dict) -> None:
+        """File the pages a prefill chunk's read of its prior context
+        fetches, by host arithmetic on its start and the pools'
+        geometry, against the pages a gather of its slot's table
+        reads: ``serving_chunk_pages_read_total{kind}`` and
+        ``serving_chunk_pages_table_total{kind}``, each summed over the
+        kind's K/V layers (a page of K, and as many of V). The chunk
+        walk fetches the pages before the chunk's start — of a window
+        layer's ring, those from the window's lower edge for the
+        chunk's first query on; the latent kernel whole blocks of its
+        table, each block of the chunk's tokens to its own last
+        position; the gather every entry of the table (a window layer:
+        the whole ring)."""
+        reg = get_registry()
+        if not reg.enabled:
+            return
+        if self._chunk_inst is None:
+            self._chunk_inst = {
+                "read": reg.counter(
+                    "serving_chunk_pages_read_total",
+                    "pages a prefill chunk's read of its prior context "
+                    "fetched, summed over the K/V layers of the kind"),
+                "table": reg.counter(
+                    "serving_chunk_pages_table_total",
+                    "pages a gather of the chunk's slot's table would "
+                    "read (a window layer: its ring), summed over the "
+                    "same layers and chunks"),
+            }
+        ps, start = self.page_size, chunk["start"]
+        mp = self.tables.max_pages_per_slot
+        table = {"full": mp}
+        if self.ring is not None:
+            table["window"] = self.ring
+        if self.latent_dim is not None:
+            n_pp = min(LATENT_PAGES_PER_STEP, mp)
+            per_block = math.gcd(self.chunk_tokens, LATENT_CHUNK_TOKENS)
+            last = start + np.arange(1, self.chunk_tokens // per_block
+                                     + 1) * per_block - 1
+            blocks = np.minimum(last // (n_pp * ps) + 1, -(-mp // n_pp))
+            read = {"full": int(blocks.sum()) * n_pp}
+        elif self.walks:
+            before = -(-start // ps)
+            read = {"full": before}
+            if self.ring is not None:
+                read["window"] = before \
+                    - max(start - self.window + 1, 0) // ps
+        else:
+            read = table
+        for kind, layers in self._kv_layers.items():
+            self._chunk_inst["read"].inc(read[kind] * layers, kind=kind)
+            self._chunk_inst["table"].inc(table[kind] * layers, kind=kind)
 
     def _count_state(self, active: np.ndarray | None,
                      chunk: dict | None = None) -> None:
