@@ -928,6 +928,120 @@ def test_qwen3_next_serve_programs_fit_and_keep_the_state_in_place_on_v5e(
           f"{total / 1e9:.3f} GB")
     assert nbytes(params) > 7.3e9 and total < V5E_HBM_BYTES, total
 
+@pytest.mark.parametrize("program", ["decode", "chunk", "mixed"])
+def test_bailing_hybrid_serve_programs_fit_and_keep_pool_and_state_in_place(
+        one_chip, program, monkeypatch):
+    """The programs of the ``ling-3.0-flash.serve-longout-r80`` cell
+    (decode, the chunk alone, and the chunk with the decode lanes
+    riding) at its own size (7 layers at published widths — a dense
+    KDA layer, five KDA layers and one MLA layer —, 128 of 512 experts
+    a layer, the traffic file's slots and pool, chunks of 256 over a
+    16,640-position table), compiled for the described v5e with the
+    latent pool AND the slot state donated: the KDA layers' float32
+    ``S`` (6 x slots x 2 MiB) and their bfloat16 conv rows and the one
+    640-lane latent row array come back aliased, the lanes' update of
+    ``S`` is made in place — the program's scratch stays under ONE
+    layer's ``S``, so no copy of it exists —, the conv rows are never
+    relaid out, no instruction writes a
+    buffer the size of the pool's layer but the pool's own update, the
+    experts run as the pallas grouped product with no copy of an
+    expert stack, and weights, pool, state and scratch fit the chip."""
+    import json
+    import math
+    import sys
+    from pathlib import Path
+
+    import torchbooster_tpu.models.moe as moe_mod
+    import torchbooster_tpu.serving.engine as engine_mod
+    from torchbooster_tpu.models.bailing_hybrid import BailingHybrid
+
+    monkeypatch.setattr(moe_mod, "_on_tpu", lambda: True)
+    _kernels_as_on_the_chip(monkeypatch)
+    bench = Path(__file__).resolve().parent.parent / "benchmark"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    import program_bailing_hybrid
+
+    raw = json.loads(
+        (bench / "configs" / "ling-3.0-flash.json").read_text())
+    traffic = json.loads(
+        (bench / "traffic" / "serve-longout-r80.json").read_text())
+    serving = traffic["serving"]
+    cfg = program_bailing_hybrid.model_config(raw,
+                                              traffic["max_positions"])
+    slots, pages, page = (serving["max_slots"], serving["n_pages"],
+                          serving["page_size"])
+    abstract = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=one_chip), tree)
+    params = abstract(jax.eval_shape(lambda: BailingHybrid.init(
+        jax.random.PRNGKey(0), cfg, jnp.bfloat16)))
+    real = engine_mod.make_pool, engine_mod.make_slot_state
+    described = lambda make: lambda *a, **kw: jax.eval_shape(
+        lambda: make(*a, **kw))
+    engine_mod.make_pool = described(real[0])
+    engine_mod.make_slot_state = described(real[1])
+    try:
+        engine = engine_mod.PagedEngine(
+            params, cfg, page_size=page, n_pages=pages, max_slots=slots,
+            prefill_chunk_pages=serving["prefill_chunk_pages"])
+    finally:
+        engine_mod.make_pool, engine_mod.make_slot_state = real
+    assert engine.chunk_tokens == 256 and not engine.walks
+    assert engine.tables.max_pages_per_slot == 260
+    assert engine.pool["v"] is None
+    pool_k, state = abstract(engine.pool["k"]), abstract(engine.slot_state)
+    assert pool_k.shape == (1, pages, page, 640)
+    assert state["kda"].shape == (6, slots, 32, 128, 128)
+    assert state["kda"].dtype == jnp.float32
+    assert state["conv"].shape == (6, slots, 3 * 12288)
+    assert state["conv"].dtype == jnp.bfloat16
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, tail, kw = _serve_program(engine, program, arg)
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 5)).lower(
+        params, pool_k, None, *_step_args(arg, engine), state, *tail,
+        **kw).compile()
+
+    nbytes = lambda tree: sum(math.prod(x.shape) * x.dtype.itemsize
+                              for x in jax.tree.leaves(tree))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= nbytes((pool_k, state))
+    layer_s = nbytes(state["kda"]) // 6
+    assert memory.temp_size_in_bytes < layer_s, memory.temp_size_in_bytes
+    moved = _pool_sized_writes(compiled, pages * page * 640, pool_k,
+                               cfg.vocab)
+    # the state's own in-place updates write its shape: not a copy
+    moved = [m for m in moved if m[3] not in {
+        tuple(leaf.shape) for leaf in jax.tree.leaves(state)}]
+    assert not moved, f"pool-sized buffers written: {moved}"
+    assert "ragged-dot" not in compiled.as_text()
+    stack = cfg.experts_held[1] * cfg.d_model * cfg.expert_width
+    copies = [(name, dims) for name, op, results
+              in _top_level_results(compiled.as_text()) if op == "copy"
+              for _, dims, _ in results
+              if math.prod(int(d) for d in dims.split(",") if d) >= stack]
+    assert not copies, f"expert-stack-sized copies: {copies}"
+    # nor is the conv state relaid out: with a trailing axis of 3 rows
+    # a slot the mixed program copied all of it twice a layer
+    conv = math.prod(state["conv"].shape)
+    relaid = [(name, dims) for name, op, results
+              in _top_level_results(compiled.as_text()) if op == "copy"
+              for _, dims, _ in results
+              if math.prod(int(d) for d in dims.split(",") if d) >= conv // 2]
+    assert not relaid, f"state-sized copies: {relaid}"
+    total = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+             - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    print(f"bailing-hybrid {program}: params "
+          f"{nbytes(params) / 1e9:.3f} GB, pool {nbytes(pool_k) / 1e9:.3f}"
+          f" GB, state {nbytes(state) / 1e9:.3f} GB, args "
+          f"{memory.argument_size_in_bytes / 1e9:.3f} GB, temp "
+          f"{memory.temp_size_in_bytes / 1e9:.3f} GB, total "
+          f"{total / 1e9:.3f} GB")
+    assert nbytes(params) > 10.4e9 and total < V5E_HBM_BYTES, total
+
 # the decode lanes' live-page walk (ops/lane_walk.py) at the three
 # cells whose lanes it reads: (K/V layers of the pool, pages, query
 # heads, KV heads, head_dim, slots, table entries, window); the

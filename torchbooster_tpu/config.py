@@ -1186,9 +1186,10 @@ class ServingConfig(BaseConfig):
         served model's config such as
         :class:`~torchbooster_tpu.models.lfm2.LFM2Config`,
         :class:`~torchbooster_tpu.models.mla_moe.MLAMoEConfig`,
-        :class:`~torchbooster_tpu.models.afmoe.AfmoeConfig` or
-        :class:`~torchbooster_tpu.models.qwen3_next.Qwen3NextConfig`: the
-        engine is chosen by its type, and the features a model lacks
+        :class:`~torchbooster_tpu.models.afmoe.AfmoeConfig`,
+        :class:`~torchbooster_tpu.models.qwen3_next.Qwen3NextConfig` or
+        :class:`~torchbooster_tpu.models.bailing_hybrid.BailingHybridConfig`:
+        the engine is chosen by its type, and the features a model lacks
         raise ``NotImplementedError`` naming the feature and the
         reason). Returns the
         :class:`~torchbooster_tpu.serving.ContinuousBatcher` — with
@@ -1230,7 +1231,8 @@ class ServingConfig(BaseConfig):
 
         if not isinstance(model_cfg, GPTConfig):
             # a model with its own layer stack (models/lfm2.py,
-            # models/mla_moe.py, models/afmoe.py, models/qwen3_next.py):
+            # models/mla_moe.py, models/afmoe.py, models/qwen3_next.py,
+            # models/bailing_hybrid.py):
             # the engine is chosen by the config's type and refuses the
             # features it lacks, each with the model's own reason;
             # these three never reach it

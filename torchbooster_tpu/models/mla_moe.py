@@ -253,7 +253,9 @@ def swiglu(lp: dict, u: jax.Array, name: str) -> jax.Array:
 def attention(lp: dict, u: jax.Array, cfg: MLAMoEConfig, *, positions,
               attend: Callable, cache, li, form: str, row=None):
     """The latent-attention block over the normed ``u (B, S, d)`` in
-    either form (module docstring), without the residual. ``row``: the
+    either form (module docstring), without the residual; where ``lp``
+    holds ``attn_gate`` ``(d, heads)``, each head's output is scaled by
+    its ``sigmoid(u @ W_gate)`` before ``W_o``. ``row``: the
     layer's index into ``attn_q`` / ``attn_ukv`` where the caller hands
     those two STACKED over layers (:func:`layers` does, so that their
     read is named). Returns ``(y (B, S, d), cache)``."""
@@ -303,6 +305,14 @@ def attention(lp: dict, u: jax.Array, cfg: MLAMoEConfig, *, positions,
     else:
         raise ValueError(f"form must be one of {FORMS}, got {form!r}")
     with jax.named_scope("attn_out"):
+        if "attn_gate" in lp:
+            # a head-wise output gate, one scalar a head
+            # (models/bailing_hybrid.py): o_h * sigmoid(u @ W_gate)_h
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(
+                    L.dense(lp["attn_gate"], u).astype(jnp.float32))
+                o = (o.astype(jnp.float32) * gate[..., None]
+                     ).astype(u.dtype)
         return L.dense(lp["attn_out"], o.reshape(b, s, h * cfg.v_dim)), cache
 
 

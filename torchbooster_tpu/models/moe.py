@@ -265,9 +265,24 @@ def grouped_matmul(rows: jax.Array, kernels: jax.Array,
     return gmm(rows, kernels, sizes, rows.dtype, (tm, tk, tn))
 
 
+def group_limited(biased: jax.Array, n_group: int,
+                  topk_group: int) -> jax.Array:
+    """``biased (T, E)`` with every expert outside its token's
+    ``topk_group`` best groups set to ``-inf``: the ``E`` experts are
+    ``n_group`` groups of ``E / n_group`` in order, and a group scores
+    the sum of its two highest values."""
+    t, e = biased.shape
+    grouped = biased.reshape(t, n_group, e // n_group)
+    _, best = jax.lax.top_k(jax.lax.top_k(grouped, 2)[0].sum(-1),
+                            topk_group)
+    kept = jnp.any(best[..., None] == jnp.arange(n_group), axis=-2)
+    return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(t, e)
+
+
 def moe_route(params: dict, tokens: jax.Array, top_k: int,
               scaling: float = 1.0, eps: float = 1e-6,
-              scoring: str = "sigmoid"
+              scoring: str = "sigmoid", n_group: int = 1,
+              topk_group: int = 1
               ) -> tuple[jax.Array, jax.Array]:
     """Routing in float32, by ``scoring``:
 
@@ -278,10 +293,17 @@ def moe_route(params: dict, tokens: jax.Array, top_k: int,
       experts, the ``top_k`` chosen by ``s`` (no bias).
 
     Either way the weights are renormalised over the chosen ones: ``w
-    = scaling * s[sel] / (sum(s[sel]) + eps)``. ``tokens (T, d)`` ->
-    ``(sel (T, k) int32, w (T, k) float32)``."""
+    = scaling * s[sel] / (sum(s[sel]) + eps)``. ``n_group > 1`` (sigmoid
+    only) limits the selection by GROUP (DeepSeek-V3's ``noaux_tc``):
+    the experts form ``n_group`` equal groups in order, a group scores
+    the sum of its two highest ``s + b``, and the ``top_k`` are chosen
+    among the ``topk_group`` best groups only (:func:`group_limited`).
+    ``tokens (T, d)`` -> ``(sel (T, k) int32, w (T, k) float32)``."""
     if scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"moe_route: unknown scoring {scoring!r}")
+    if n_group > 1 and scoring != "sigmoid":
+        raise ValueError("moe_route: group-limited selection is "
+                         "sigmoid scoring's (noaux_tc)")
     gate = params["moe_gate"]["kernel"].astype(jnp.float32)
     logits = jnp.dot(tokens.astype(jnp.float32), gate,
                      precision=jax.lax.Precision.HIGHEST)
@@ -290,8 +312,10 @@ def moe_route(params: dict, tokens: jax.Array, top_k: int,
         _, sel = jax.lax.top_k(scores, top_k)
     else:
         scores = jax.nn.sigmoid(logits)
-        _, sel = jax.lax.top_k(
-            scores + params["moe_bias"].astype(jnp.float32), top_k)
+        biased = scores + params["moe_bias"].astype(jnp.float32)
+        if n_group > 1:
+            biased = group_limited(biased, n_group, topk_group)
+        _, sel = jax.lax.top_k(biased, top_k)
     w = jnp.take_along_axis(scores, sel, axis=-1)
     w = scaling * w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return sel.astype(jnp.int32), w
@@ -303,7 +327,8 @@ def moe_dropless(params: dict, x: jax.Array, top_k: int,
                  route_on: jax.Array | None = None,
                  held: tuple[int, int] | None = None,
                  tp: tuple | None = None, route_eps: float = 1e-6,
-                 scoring: str = "sigmoid"
+                 scoring: str = "sigmoid", n_group: int = 1,
+                 topk_group: int = 1
                  ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """(B, S, d) -> ((B, S, d), tokens per expert (E,) int32, pairs
     elsewhere () int32): the DROPLESS expert layer — every selected
@@ -335,7 +360,8 @@ def moe_dropless(params: dict, x: jax.Array, top_k: int,
     ``valid (B, S)`` marks real tokens (a dead slot, a chunk's pad):
     the others are sorted behind every group, take no expert's time
     and count for nothing. Routing follows :func:`moe_route`
-    (``scoring``: sigmoid with a selection bias, or softmax). The
+    (``scoring``: sigmoid with a selection bias, or softmax;
+    ``n_group`` / ``topk_group``: the selection limited by group). The
     count is a program output the serving engine reads
     (``serving_moe_*``, docs/observability.md).
 
@@ -369,7 +395,7 @@ def moe_dropless(params: dict, x: jax.Array, top_k: int,
         sel, w = moe_route(
             params, tokens if route_on is None
             else route_on.reshape(b * s, d), top_k, scaling, route_eps,
-            scoring)
+            scoring, n_group, topk_group)
     with jax.named_scope("moe_experts"):
         pair_expert = sel.reshape(-1)                    # (T*k,)
         live = None if valid is None \
@@ -404,5 +430,5 @@ def moe_dropless(params: dict, x: jax.Array, top_k: int,
     return out.astype(x.dtype).reshape(b, s, d), counts, elsewhere
 
 
-__all__ = ["SHARDING_RULES", "grouped_matmul", "moe_apply",
-           "moe_dropless", "moe_init", "moe_route"]
+__all__ = ["SHARDING_RULES", "group_limited", "grouped_matmul",
+           "moe_apply", "moe_dropless", "moe_init", "moe_route"]

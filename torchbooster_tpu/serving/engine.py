@@ -84,6 +84,7 @@ import numpy as np
 
 from torchbooster_tpu.models import layers as L
 from torchbooster_tpu.models import afmoe as _afmoe
+from torchbooster_tpu.models import bailing_hybrid as _bailing_hybrid
 from torchbooster_tpu.models import lfm2 as _lfm2
 from torchbooster_tpu.models import mla_moe as _mla_moe
 from torchbooster_tpu.models import qwen3_next as _qwen3_next
@@ -183,8 +184,8 @@ def _served_model(cfg: Any):
     architecture is a module with those three functions, a
     ``cache_spec()`` on its config and ``UNSERVED`` — the serving
     features it is refused, each with its reason — (models/lfm2.py,
-    models/mla_moe.py, models/afmoe.py, models/qwen3_next.py), and a
-    line here."""
+    models/mla_moe.py, models/afmoe.py, models/qwen3_next.py,
+    models/bailing_hybrid.py), and a line here."""
     if isinstance(cfg, _lfm2.LFM2Config):
         return _lfm2
     if isinstance(cfg, _mla_moe.MLAMoEConfig):
@@ -193,6 +194,8 @@ def _served_model(cfg: Any):
         return _afmoe
     if isinstance(cfg, _qwen3_next.Qwen3NextConfig):
         return _qwen3_next
+    if isinstance(cfg, _bailing_hybrid.BailingHybridConfig):
+        return _bailing_hybrid
     if isinstance(cfg, GPTConfig):
         return None
     raise TypeError(
@@ -500,6 +503,15 @@ class PagedEngine:
     decodes, in place in the donated array. ``S`` of the lanes is read
     and written by the model's own kernel on the array (live slots
     only, ``ops/delta_lanes.py``).
+
+    **A latent pool beside slot states** (``models/bailing_hybrid.py``:
+    KDA layers, whose ``S`` decays each key channel on its own, beside
+    latent-attention layers): the spec names both, and the pieces
+    compose — the latent ``write`` / ``read`` of the MLA layers' rows
+    and the ``carry`` of the KDA layers' states, in the same programs.
+    The block tables page the latent pool only; the states are the
+    slot's whatever its length, so admission counts pages as for any
+    other model.
     """
 
     def __init__(self, params: dict, cfg: Any, *,

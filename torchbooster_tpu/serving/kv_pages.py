@@ -161,7 +161,10 @@ class CacheSpec:
     # a LATENT cache (models/mla_moe.py): one row a token — the key
     # row's leading ``value_dim`` lanes ARE the values, so no V half is
     # allocated (``make_pool``'s "v" is None) and the reads go through
-    # ``ops/latent_paged_attention.py``
+    # ``ops/latent_paged_attention.py``; it may sit beside
+    # ``slot_states`` (models/bailing_hybrid.py: the pages hold the
+    # latent layers' rows, the states the linear layers' past), and the
+    # block tables then page the latent rows only
     value_dim: int | None = None
     # the KIND of each K/V layer where they differ (models/afmoe.py):
     # ``"full"`` layers hold every token of a sequence in pages its
